@@ -20,9 +20,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from kubedl_tpu.utils.jaxenv import ensure_cpu_if_requested
-
-ensure_cpu_if_requested()
 from kubedl_tpu.utils.compile_cache import enable_compilation_cache
 
 enable_compilation_cache()

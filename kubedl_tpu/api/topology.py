@@ -141,12 +141,18 @@ _DEVICE_KIND_ALIASES = {
 def _catalog_lookup(kind: str, getter) -> float:
     """Resolve a PJRT device_kind string to a per-chip spec value via the
     slice catalog (single source of truth for hardware numbers). 0.0 for
-    CPU/unknown kinds."""
+    what is not a TPU; a TPU kind the table does not know raises — an MFU
+    of 0 and no HBM floor would pass for a measurement."""
     kind = kind.lower()
     gens = {t.name.split("-")[0]: getter(t) for t in SLICE_CATALOG.values()}
     for sub, gen in _DEVICE_KIND_ALIASES.items():
         if sub in kind and gen in gens:
             return gens[gen]
+    if kind.startswith("tpu"):
+        raise ValueError(
+            f"TPU device kind {kind!r} is not in the slice catalog "
+            f"(known: {sorted(_DEVICE_KIND_ALIASES)})"
+        )
     return 0.0
 
 

@@ -1,9 +1,11 @@
 """ctypes binding for the C++ data loader (native/dataloader.cpp).
 
 The .so is built on demand with the system g++ (no pip deps, per the
-environment contract) and cached next to the source; when no compiler is
-available the pure-numpy fallback path serves the same interface, so the
-framework degrades instead of breaking.
+environment contract) into a path keyed by the source's content hash, so a
+binary on disk is always the one this source builds — never one that a
+copy left behind. On a host without a compiler the pure-numpy loader
+serves the same interface; it says so in the log, and every loader names
+itself (``loader_name``) for the worker summary.
 
 Why native: a training step is sub-second, so batch assembly must never
 appear on the critical path. The C++ loader memory-maps the token file and
@@ -14,6 +16,7 @@ only wraps the filled buffer in a numpy array.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -26,25 +29,29 @@ import numpy as np
 log = logging.getLogger("kubedl_tpu.data.native")
 
 _SRC = Path(__file__).resolve().parents[2] / "native" / "dataloader.cpp"
-_LIB_NAME = "libkdl_data.so"
 _lib_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_tried = False
 
 
 def _build_lib() -> Optional[Path]:
-    out = _SRC.parent / _LIB_NAME
-    if out.exists() and out.stat().st_mtime >= _SRC.stat().st_mtime:
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    out = _SRC.parent / f"libkdl_data-{digest}.so"
+    if out.exists():
         return out
+    tmp = out.with_suffix(f".{os.getpid()}.tmp")
     try:
         subprocess.run(
             ["g++", "-O3", "-shared", "-fPIC", "-pthread",
-             "-o", str(out), str(_SRC)],
+             "-o", str(tmp), str(_SRC)],
             check=True, capture_output=True, timeout=120,
         )
+        os.replace(tmp, out)  # atomic: concurrent builders never load a torn file
         return out
     except (OSError, subprocess.SubprocessError) as e:
-        log.info("native data loader unavailable (%s); using numpy fallback", e)
+        log.warning(
+            "native data loader not built (%s); the numpy loader serves", e
+        )
         return None
 
 
@@ -81,6 +88,8 @@ def native_available() -> bool:
 
 class NativeTokenLoader:
     """Batches from a binary token file via the C++ prefetch ring."""
+
+    loader_name = "native"
 
     def __init__(self, path: str, batch: int, seq: int, seed: int = 0,
                  prefetch: int = 4, token_bytes: int = 4) -> None:
@@ -129,6 +138,8 @@ class NativeTokenLoader:
 class _NumpyTokenLoader:
     """Same sampling contract, pure numpy (no compiler needed)."""
 
+    loader_name = "numpy"
+
     def __init__(self, path: str, batch: int, seq: int, seed: int = 0,
                  token_bytes: int = 4) -> None:
         dtype = np.uint16 if token_bytes == 2 else np.int32
@@ -163,7 +174,8 @@ class _NumpyTokenLoader:
 def TokenFileDataset(path: str, batch: int, seq: int, seed: int = 0,
                      prefetch: int = 4, token_bytes: int = 4):
     """Dataset over a binary token file: the native prefetch loader when a
-    compiler is available, numpy otherwise — identical interface."""
+    compiler is available, numpy otherwise (logged by ``_build_lib``) —
+    identical interface, ``loader_name`` says which."""
     if native_available():
         return NativeTokenLoader(path, batch, seq, seed, prefetch, token_bytes)
     return _NumpyTokenLoader(path, batch, seq, seed, token_bytes)

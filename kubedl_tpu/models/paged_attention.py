@@ -17,11 +17,12 @@ Two implementations behind ONE interface (:func:`paged_attention`):
   (BS=16/32) because scan-iteration overhead swamps the per-block math;
   at tile=256 the chunked scan beats the gather at every benched shape.
   Runs everywhere (tier-1 exercises it on CPU).
-- ``pallas``: a TPU kernel on grid (B, KV, MB) with the block table and
-  per-row starts as scalar-prefetch operands, so the BlockSpec index map
-  streams exactly each row's own pool blocks through VMEM — no gather,
-  no logical view, O(tile) live keys. Interpret mode covers CPU parity
-  tests.
+- ``pallas``: a TPU kernel on grid (B, row tiles, MB) with the block
+  table and per-row starts as scalar-prefetch operands, so the BlockSpec
+  index map streams exactly each row's own pool blocks through VMEM — no
+  gather, no logical view, O(tile) live keys. A grid step takes one
+  block for all kv heads as a lane-dense [BS, KV*hd] tile. Interpret mode
+  (``interpret=True``) covers CPU parity tests.
 
 Numerics: the online softmax reorders the reduction, so outputs are
 fp-close (observed ~4e-7 f32) but NOT bit-identical to the gather+dense
@@ -49,12 +50,13 @@ lax path folds the scatter in front of the chunk scan (identical ops to
 the old scatter-then-attend call-site sequence, so bit-identical); the
 pallas kernel aliases the pools in/out and patches the written row in
 VMEM at the write block, so the fresh token is attended from the
-patched tile and only the ONE dirty block per (row, kv-head) is copied
-back to HBM.
+patched tile and only the ONE dirty block per row is copied back to
+HBM.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -72,12 +74,6 @@ LOG2E = math.log2(math.e)
 #: measured CPU sweet spot for BS=16/32; the pallas kernel tiles by BS.
 DEFAULT_TILE = 256
 
-#: kernel picked when callers pass ``kernel=None``: "auto" resolves to
-#: pallas on TPU and the lax scan elsewhere. Tests override this module
-#: global to force the pallas kernel (interpret mode) through the full
-#: model stack on CPU.
-DEFAULT_KERNEL = "auto"
-
 #: trace-time counters per implementation — bench asserts the blocked
 #: path is actually in the compiled hot graph, not silently the oracle.
 #: "fused" counts paged_attention calls that carried the decode step's
@@ -94,10 +90,6 @@ def blocks_per_chunk(num_blocks: int, block_size: int,
         if num_blocks % c == 0 and c * block_size <= tile:
             best = c
     return best
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _online_fold(m, l, acc, s, vb, einsum_pv: str):
@@ -178,16 +170,47 @@ def _lax_paged_attention(
     return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd).astype(q.dtype)
 
 
+#: query rows (S * group) one grid step holds per kv head. The running
+#: (acc, m, l) scratch is [KV, rows, ...] f32, so a long suffix is walked
+#: in row tiles instead of growing VMEM with S.
+_ROW_TILE = 256
+
+
+def _row_tile(rows: int) -> int:
+    """Rows per grid step: all of them when they fit ``_ROW_TILE``, else
+    the largest 16-multiple divisor (a bf16 tile is 16 sublanes)."""
+    if rows <= _ROW_TILE:
+        return rows
+    for t in range(_ROW_TILE, 15, -16):
+        if rows % t == 0:
+            return t
+    return rows
+
+
 def _blocked_kernel(
     bt_ref, st_ref,  # scalar-prefetch: [B, MB] block table, [B] starts
-    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-    *, scale: float, group: int, block_size: int, n_blocks: int,
-    max_s: int,
+    q_ref, k_ref, v_ref, *rest,
+    scale: float, group: int, block_size: int, n_blocks: int, max_s: int,
+    n_kv: int, hd: int, fused: bool,
 ):
+    """One (row b, row tile i, pool block j) step for ALL kv heads: the
+    pool block arrives as a lane-dense [BS, KV*hd] tile (one contiguous
+    DMA) and a static loop folds each head's [BS, hd] lane slice.
+
+    ``fused`` (decode, S=1): at the block holding ``starts[b]`` the step
+    patches row ``starts % BS`` with this step's K/V in VMEM, attends the
+    patched tile, and writes the patched block through the aliased pool
+    output — the only block whose copy-out the revolving out buffer
+    performs (the out index map is constant in j). Untouched pool blocks
+    survive via the aliasing."""
+    if fused:
+        nk_ref, nv_ref, o_ref, ok_ref, ov_ref, acc_ref, m_ref, l_ref = rest
+    else:
+        o_ref, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
+    i = pl.program_id(1)
     j = pl.program_id(2)
-    hd = q_ref.shape[-1]
-    R = q_ref.shape[2]  # S * group query rows for this kv head
+    TR = q_ref.shape[2]  # query rows of this tile, row r = s*group + u
 
     @pl.when(j == 0)
     def _init():
@@ -195,37 +218,61 @@ def _blocked_kernel(
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0]  # [R, hd], row r = s*group + u (s-major)
-    k = k_ref[0, :, 0]  # [BS, hd] — row b's j-th pool block via index map
-    v = v_ref[0, :, 0]
-    s = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * (scale * LOG2E)  # [R, BS], base-2 domain
     start = st_ref[b]
-    sidx = lax.broadcasted_iota(jnp.int32, (R, block_size), 0) // group
+    sidx = (
+        i * TR + lax.broadcasted_iota(jnp.int32, (TR, block_size), 0)
+    ) // group
     qpos = jnp.minimum(start + sidx, max_s - 1)
     t = j * block_size + lax.broadcasted_iota(
-        jnp.int32, (R, block_size), 1
+        jnp.int32, (TR, block_size), 1
     )
-    s = jnp.where(t <= qpos, s, NEG_INF)
-    m_prev = m_ref[:, :1]  # [R, 1]
-    m_new = jnp.maximum(
-        jnp.maximum(m_prev, s.max(axis=-1, keepdims=True)), -1e29
-    )
-    p = jnp.exp2(s - m_new)
-    corr = jnp.exp2(m_prev - m_new)
-    pv = lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    acc_ref[:] = acc_ref[:] * corr + pv
-    l_ref[:, :1] = l_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
-    m_ref[:, :1] = m_new
+    visible = t <= qpos
+    if fused:
+        jw = start // block_size
+        sel = (
+            lax.broadcasted_iota(jnp.int32, (block_size, hd), 0)
+            == start % block_size
+        ) & (j == jw)
+
+    for g in range(n_kv):
+        lanes = slice(g * hd, (g + 1) * hd)
+        k = k_ref[0, :, lanes]  # [BS, hd] — head g of row b's j-th block
+        v = v_ref[0, :, lanes]
+        if fused:
+            k = jnp.where(sel, nk_ref[0, :, lanes], k)
+            v = jnp.where(sel, nv_ref[0, :, lanes], v)
+
+            @pl.when(j == jw)
+            def _write(k=k, v=v, lanes=lanes):
+                ok_ref[0, :, lanes] = k
+                ov_ref[0, :, lanes] = v
+
+        s = lax.dot_general(
+            q_ref[0, g], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * (scale * LOG2E)  # [TR, BS], base-2 domain
+        s = jnp.where(visible, s, NEG_INF)
+        m_prev = m_ref[g, :, :1]  # [TR, 1]
+        m_new = jnp.maximum(
+            jnp.maximum(m_prev, s.max(axis=-1, keepdims=True)), -1e29
+        )
+        p = jnp.exp2(s - m_new)
+        corr = jnp.exp2(m_prev - m_new)
+        pv = lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        acc_ref[g] = acc_ref[g] * corr + pv
+        l_ref[g, :, :1] = l_ref[g, :, :1] * corr + p.sum(
+            axis=-1, keepdims=True
+        )
+        m_ref[g, :, :1] = m_new
 
     @pl.when(j == n_blocks - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        for g in range(n_kv):
+            l = jnp.maximum(l_ref[g, :, :1], 1e-30)
+            o_ref[0, g] = (acc_ref[g] / l).astype(o_ref.dtype)
 
 
 def _pallas_paged_attention(
@@ -233,206 +280,90 @@ def _pallas_paged_attention(
     k_pool: jax.Array,  # [NB, BS, KV, hd]
     v_pool: jax.Array,
     bt: jax.Array,  # [B, MB] int32
-    starts: jax.Array,  # [B] int32
-    interpret: bool,
-) -> jax.Array:
+    starts: jax.Array,  # [B] int32 (fused: = the written position)
+    new_k: Optional[jax.Array] = None,  # [B, KV, hd] this step's K (S=1)
+    new_v: Optional[jax.Array] = None,
+    interpret: bool = False,
+):
     from jax.experimental.pallas import tpu as pltpu
 
     TRACE_COUNT["pallas"] += 1
+    fused = new_k is not None
     B, S, H, hd = q.shape
-    BS, KV = k_pool.shape[1], k_pool.shape[2]
+    NB, BS, KV, _ = k_pool.shape
     MB = bt.shape[1]
     group = H // KV
     R = S * group
+    TR = _row_tile(R)
     # [B, KV, R, hd] with row r = s*group + u: one contiguous query tile
-    # per (row, kv-head) grid cell, GQA folded into the tile rows
+    # per kv head, GQA folded into the tile rows
     qr = q.reshape(B, S, KV, group, hd).transpose(0, 2, 1, 3, 4)
     qr = qr.reshape(B, KV, R, hd)
-    kernel = lambda *refs: _blocked_kernel(  # noqa: E731
-        *refs, scale=1.0 / math.sqrt(hd), group=group, block_size=BS,
-        n_blocks=MB, max_s=MB * BS,
+    # the pool as [NB, BS, KV*hd] (a free reshape): a (1, BS, 1, hd) block
+    # of the 4-D pool is a sublane slice mosaic refuses unless KV == 1
+    pools = [p.reshape(NB, BS, KV * hd) for p in (k_pool, v_pool)]
+    kernel = functools.partial(
+        _blocked_kernel, scale=1.0 / math.sqrt(hd), group=group,
+        block_size=BS, n_blocks=MB, max_s=MB * BS, n_kv=KV, hd=hd,
+        fused=fused,
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KV, MB),  # j innermost: scratch carries across blocks
-        in_specs=[
-            pl.BlockSpec((1, 1, R, hd), lambda b, g, j, bt, st: (b, g, 0, 0)),
-            # the whole point: stream row b's OWN j-th block from the pool
-            pl.BlockSpec(
-                (1, BS, 1, hd), lambda b, g, j, bt, st: (bt[b, j], 0, g, 0)
-            ),
-            pl.BlockSpec(
-                (1, BS, 1, hd), lambda b, g, j, bt, st: (bt[b, j], 0, g, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, R, hd), lambda b, g, j, bt, st: (b, g, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((R, hd), jnp.float32),
-            pltpu.VMEM((R, 128), jnp.float32),
-            pltpu.VMEM((R, 128), jnp.float32),
-        ],
+    q_spec = pl.BlockSpec(
+        (1, KV, TR, hd), lambda b, i, j, bt, st: (b, 0, i, 0)
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, R, hd), q.dtype),
-        compiler_params=getattr(
-            pltpu, "CompilerParams", pltpu.TPUCompilerParams
-        )(dimension_semantics=("arbitrary",) * 3),
-        interpret=interpret,
-    )(bt.astype(jnp.int32), starts.astype(jnp.int32), qr, k_pool, v_pool)
-    out = out.reshape(B, KV, S, group, hd).transpose(0, 2, 1, 3, 4)
-    return out.reshape(B, S, H, hd)
-
-
-def _fused_kernel(
-    bt_ref, st_ref,  # scalar-prefetch: [B, MB] block table, [B] starts
-    q_ref, k_ref, v_ref, nk_ref, nv_ref,
-    o_ref, ok_ref, ov_ref, acc_ref, m_ref, l_ref,
-    *, scale: float, group: int, block_size: int, n_blocks: int,
-    max_s: int,
-):
-    """Decode-step (S=1) blocked kernel with the KV write fused in: at
-    the block holding ``starts[b]`` the kernel patches row ``starts%BS``
-    with this step's K/V in VMEM, attends the patched tile, and writes
-    the patched block through the aliased pool output — the only block
-    whose copy-out the revolving out buffer performs (the out index map
-    is constant in j). Untouched pool blocks survive via the aliasing."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    R = q_ref.shape[2]  # group query rows (S == 1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    start = st_ref[b]
-    jw = start // block_size
-    off = start % block_size
-    q = q_ref[0, 0]  # [R, hd]
-    k = k_ref[0, :, 0]  # [BS, hd]
-    v = v_ref[0, :, 0]
-    sel = (
-        lax.broadcasted_iota(jnp.int32, k.shape, 0) == off
-    ) & (j == jw)  # [BS, hd]
-    kj = jnp.where(sel, nk_ref[0, 0][None, :], k)
-    vj = jnp.where(sel, nv_ref[0, 0][None, :], v)
-
-    @pl.when(j == jw)
-    def _write():
-        ok_ref[0, :, 0] = kj
-        ov_ref[0, :, 0] = vj
-
-    s = lax.dot_general(
-        q, kj, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * (scale * LOG2E)  # [R, BS]
-    qpos = jnp.minimum(start, max_s - 1)
-    t = j * block_size + lax.broadcasted_iota(
-        jnp.int32, (R, block_size), 1
-    )
-    s = jnp.where(t <= qpos, s, NEG_INF)
-    m_prev = m_ref[:, :1]
-    m_new = jnp.maximum(
-        jnp.maximum(m_prev, s.max(axis=-1, keepdims=True)), -1e29
-    )
-    p = jnp.exp2(s - m_new)
-    corr = jnp.exp2(m_prev - m_new)
-    pv = lax.dot_general(
-        p.astype(vj.dtype), vj, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    acc_ref[:] = acc_ref[:] * corr + pv
-    l_ref[:, :1] = l_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
-    m_ref[:, :1] = m_new
-
-    @pl.when(j == n_blocks - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-
-def _pallas_paged_attention_fused(
-    q: jax.Array,  # [B, 1, H, hd]
-    k_pool: jax.Array,  # [NB, BS, KV, hd]
-    v_pool: jax.Array,
-    bt: jax.Array,  # [B, MB] int32
-    starts: jax.Array,  # [B] int32 (= the written position)
-    new_k: jax.Array,  # [B, KV, hd] this step's K
-    new_v: jax.Array,
-    interpret: bool,
-):
-    from jax.experimental.pallas import tpu as pltpu
-
-    TRACE_COUNT["pallas"] += 1
-    B, S, H, hd = q.shape
-    BS, KV = k_pool.shape[1], k_pool.shape[2]
-    MB = bt.shape[1]
-    group = H // KV
-    R = S * group
-    qr = q.reshape(B, S, KV, group, hd).transpose(0, 2, 1, 3, 4)
-    qr = qr.reshape(B, KV, R, hd)
-    kernel = lambda *refs: _fused_kernel(  # noqa: E731
-        *refs, scale=1.0 / math.sqrt(hd), group=group, block_size=BS,
-        n_blocks=MB, max_s=MB * BS,
-    )
+    # the whole point: stream row b's OWN j-th block from the pool
     pool_spec = pl.BlockSpec(
-        (1, BS, 1, hd), lambda b, g, j, bt, st: (bt[b, j], 0, g, 0)
+        (1, BS, KV * hd), lambda b, i, j, bt, st: (bt[b, j], 0, 0)
     )
-    # write-block spec: CONSTANT in j, so the revolving out buffer only
-    # copies the one dirty block back per (row, kv-head) group. Rows own
-    # their blocks exclusively (unowned entries all point at the trash
-    # block, where colliding writes are garbage by contract).
-    wb_spec = pl.BlockSpec(
-        (1, BS, 1, hd),
-        lambda b, g, j, bt, st: (bt[b, st[b] // BS], 0, g, 0),
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KV, MB),
-        in_specs=[
-            pl.BlockSpec((1, 1, R, hd), lambda b, g, j, bt, st: (b, g, 0, 0)),
-            pool_spec,
-            pool_spec,
-            pl.BlockSpec((1, 1, hd), lambda b, g, j, bt, st: (b, g, 0)),
-            pl.BlockSpec((1, 1, hd), lambda b, g, j, bt, st: (b, g, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (1, 1, R, hd), lambda b, g, j, bt, st: (b, g, 0, 0)
-            ),
-            wb_spec,
-            wb_spec,
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((R, hd), jnp.float32),
-            pltpu.VMEM((R, 128), jnp.float32),
-            pltpu.VMEM((R, 128), jnp.float32),
-        ],
-    )
-    out, kp, vp = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, KV, R, hd), q.dtype),
-            jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-            jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
-        ],
+    in_specs = [q_spec, pool_spec, pool_spec]
+    out_specs = [q_spec]
+    out_shape = [jax.ShapeDtypeStruct((B, KV, R, hd), q.dtype)]
+    args = [qr, *pools]
+    aliases = {}
+    if fused:
+        new_spec = pl.BlockSpec(
+            (1, 1, KV * hd), lambda b, i, j, bt, st: (b, 0, 0)
+        )
+        # write-block spec: CONSTANT in j, so the revolving out buffer
+        # only copies the one dirty block back per row. Rows own their
+        # blocks exclusively (unowned entries all point at the trash
+        # block, where colliding writes are garbage by contract).
+        wb_spec = pl.BlockSpec(
+            (1, BS, KV * hd),
+            lambda b, i, j, bt, st: (bt[b, st[b] // BS], 0, 0),
+        )
+        in_specs += [new_spec, new_spec]
+        out_specs += [wb_spec, wb_spec]
+        out_shape += [
+            jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools
+        ]
+        args += [n.reshape(B, 1, KV * hd) for n in (new_k, new_v)]
         # inputs count the 2 scalar-prefetch operands: 3/4 = the pools
-        input_output_aliases={3: 1, 4: 2},
-        compiler_params=getattr(
-            pltpu, "CompilerParams", pltpu.TPUCompilerParams
-        )(dimension_semantics=("arbitrary",) * 3),
+        aliases = {3: 1, 4: 2}
+    outs = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, R // TR, MB),  # j innermost: scratch carries across
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((KV, TR, hd), jnp.float32),
+                pltpu.VMEM((KV, TR, 128), jnp.float32),
+                pltpu.VMEM((KV, TR, 128), jnp.float32),
+            ],
+        ),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3
+        ),
         interpret=interpret,
-    )(
-        bt.astype(jnp.int32), starts.astype(jnp.int32), qr, k_pool, v_pool,
-        new_k, new_v,
-    )
-    out = out.reshape(B, KV, S, group, hd).transpose(0, 2, 1, 3, 4)
-    return out.reshape(B, S, H, hd), kp, vp
+    )(bt.astype(jnp.int32), starts.astype(jnp.int32), *args)
+    out = outs[0].reshape(B, KV, S, group, hd).transpose(0, 2, 1, 3, 4)
+    out = out.reshape(B, S, H, hd)
+    if fused:
+        return out, outs[1].reshape(k_pool.shape), outs[2].reshape(v_pool.shape)
+    return out
 
 
 def _fused_write_lax(k_pool, v_pool, bt, starts, new_k, new_v):
@@ -462,9 +393,9 @@ def paged_attention(
     self_mask: Optional[jax.Array] = None,  # [B, S, S] bool (tree verify)
     new_k: Optional[jax.Array] = None,  # [B, KV, hd] (fused decode write)
     new_v: Optional[jax.Array] = None,
-    kernel: Optional[str] = None,  # None/"auto" | "lax" | "pallas"
+    kernel: str = "auto",  # "auto" | "lax" | "pallas"
     tile: int = DEFAULT_TILE,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ):
     """Blocked paged attention over the pool — returns [B, S, H, hd],
     or ``(out, k_pool, v_pool)`` when ``new_k``/``new_v`` carry a fused
@@ -478,9 +409,12 @@ def paged_attention(
     (default) or ``self_mask`` tree mask (the read-only verify modes;
     lax path only — the pallas kernel serves the write-path decode hot
     loop).
+
+    ``kernel="auto"`` is the compiled pallas kernel on a TPU and the lax
+    scan elsewhere; a kernel the TPU compiler refuses raises, nothing
+    falls back. ``interpret=True`` (tests) runs the pallas kernel through
+    the interpreter on any backend.
     """
-    if kernel is None:
-        kernel = DEFAULT_KERNEL
     if kernel == "auto":
         kernel = "pallas" if jax.default_backend() == "tpu" else "lax"
     if kernel not in ("lax", "pallas"):
@@ -496,9 +430,7 @@ def paged_attention(
             )
         TRACE_COUNT["fused"] += 1
         if kernel == "pallas":
-            if interpret is None:
-                interpret = _default_interpret()
-            return _pallas_paged_attention_fused(
+            return _pallas_paged_attention(
                 q, k_pool, v_pool, bt, starts, new_k, new_v,
                 interpret=interpret,
             )
@@ -510,8 +442,6 @@ def paged_attention(
         )
         return out, k_pool, v_pool
     if kernel == "pallas" and self_k is None:
-        if interpret is None:
-            interpret = _default_interpret()
         return _pallas_paged_attention(
             q, k_pool, v_pool, bt, starts, interpret=interpret
         )
@@ -525,6 +455,5 @@ __all__ = [
     "paged_attention",
     "blocks_per_chunk",
     "DEFAULT_TILE",
-    "DEFAULT_KERNEL",
     "TRACE_COUNT",
 ]

@@ -27,8 +27,10 @@ v5e sweet spot (k-tile auto-clamps to 512 at long S); in-model the fused
 path cut attention custom-call time from 204 to 126 ms/step on the
 bench model (2.6x+ faster than the stock jax pallas TPU flash kernel).
 
-On CPU (tests) the kernel runs in pallas interpret mode; numerics match
-the dense oracle `kubedl_tpu.models.llama.attention`.
+Tests run the kernels in pallas interpret mode by passing
+``interpret=True``; numerics match the dense oracle
+`kubedl_tpu.models.llama.attention`. Without it the kernel is compiled for
+the TPU, and a shape it cannot tile or the compiler refuses raises.
 """
 
 from __future__ import annotations
@@ -639,8 +641,7 @@ def _compiler_params():
     grid dims) + the raised VMEM ceiling."""
     from jax.experimental.pallas import tpu as pltpu
 
-    params_cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return params_cls(
+    return pltpu.CompilerParams(
         dimension_semantics=("arbitrary",) * 4,
         vmem_limit_bytes=_VMEM_LIMIT_BYTES,
     )
@@ -903,10 +904,6 @@ _flash_rope.defvjp(_flash_rope_fwd, _flash_rope_bwd)
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 #: Times the pallas kernel was traced into a compiled graph. Incremented at
 #: trace time (once per compile, not per step) — bench.py asserts this is
 #: nonzero to prove the fused kernel is in the hot path, not the oracle.
@@ -923,30 +920,25 @@ def flash_attention(
     block_k: int = 1024,
     bwd_block_q: int = 1024,
     bwd_block_k: int = 1024,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     rope_cos: Optional[jax.Array] = None,  # [S, hd/2]: fuse rotary into
     rope_sin: Optional[jax.Array] = None,  # the kernel (q/k arrive PRE-rope)
 ) -> jax.Array:
     """Drop-in for `kubedl_tpu.models.llama.attention` (same signature, so
     it slots into `llama_forward(..., attn_fn=flash_attention)`). Arbitrary
-    masks fall back to the dense oracle — flash handles the causal/full
-    cases that training uses. Forward and backward kernels tile
-    independently. Default 1024x1024 tiles are the measured v5e sweet spot
+    masks go to the dense oracle — flash handles the causal/full cases
+    that training uses; a sequence length no tiling fits raises. Forward
+    and backward kernels tile independently. Default 1024x1024 tiles are the measured v5e sweet spot
     in-model (S=2048, hd=64: 649ms fwd+bwd for the 24-layer bench model vs
     974ms at 256-tiles, 1673ms for the stock jax pallas TPU kernel; 2048
     tiles exceed VMEM). Small sequences clamp blocks to S automatically."""
-    def _dense_fallback(q, k, v, mask=None):
+    if mask is not None:
         from kubedl_tpu.models.llama import apply_rope, attention
 
-        if rope_cos is not None:  # fallbacks must still apply the rotary
+        if rope_cos is not None:  # the dense route still applies the rotary
             q = apply_rope(q, rope_cos, rope_sin)
             k = apply_rope(k, rope_cos, rope_sin)
         return attention(q, k, v, causal=causal, mask=mask)
-
-    if mask is not None:
-        return _dense_fallback(q, k, v, mask=mask)
-    if interpret is None:
-        interpret = _default_interpret()
     qt = q.transpose(0, 2, 1, 3)  # [B, H, S, hd]
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
@@ -958,8 +950,11 @@ def flash_attention(
     bwd_q = fit_block(S, bwd_block_q)
     bwd_k = fit_block(S, bwd_block_k)
     if not (bq and bk and bwd_q and bwd_k):
-        return _dense_fallback(q, k, v)
-    # counted only on the actual kernel path — a dense-oracle fallback must
+        raise ValueError(
+            f"flash attention cannot tile seq_len={S}: not a multiple of "
+            f"128 and longer than one block ({block_q}x{block_k})"
+        )
+    # counted only on the actual kernel path — the masked dense route must
     # not satisfy the bench's "pallas kernel really traced" gate
     global TRACE_COUNT
     TRACE_COUNT += 1
@@ -978,7 +973,7 @@ def fit_block(seq_len: int, want: int) -> int:
     """Largest legal block <= ``want`` for this sequence length: the whole
     sequence if it fits in one block, else the largest multiple-of-128
     divisor (mosaic tiling wants 128-lane-aligned score tiles). 0 = no
-    legal block — caller falls back to the dense oracle."""
+    legal block — flash_attention raises."""
     if seq_len <= want:
         return seq_len
     for b in range(min(want, seq_len), 127, -128):
@@ -999,7 +994,7 @@ def make_flash_attention(
     head_axis: str = "tensor",
     block_q: int = 1024,
     block_k: int = 1024,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ):
     """Mesh-aware flash attention for the trainer hot path.
 
@@ -1009,9 +1004,8 @@ def make_flash_attention(
     parallel over both, so the body needs no collectives. On a trivial mesh
     the kernel is called directly.
     """
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from kubedl_tpu.utils.shardmap import shard_map
 
     bt = tuple(
         a for a in batch_axes if a in mesh.axis_names and mesh.shape[a] > 1

@@ -125,7 +125,7 @@ def make_pipeline(
     sum is additionally psum'd over the data axes, so it is a replicated
     scalar: the caller divides by (n_layers * M * dp) for a mean.
     """
-    from kubedl_tpu.utils.shardmap import LEGACY, shard_map
+    from jax import shard_map
 
     pspec = param_specs if param_specs is not None else P(pipe_axis)
     dt = tuple(a for a in data_axes if a in mesh.axis_names and mesh.shape[a] > 1)
@@ -136,13 +136,6 @@ def make_pipeline(
         for a in dt:  # replicate the aux scalar across data shards too
             aux = lax.psum(aux, a)
         return out, aux
-
-    if LEGACY:
-        # jax < 0.6 shard_map cannot emit rank-0 residual outputs from
-        # partial-eval ("add at least one (singleton) axis" _SpecError on
-        # grad); remat the body so the backward recomputes from the
-        # pipeline inputs and no scalar residuals cross the boundary
-        local = jax.checkpoint(local)
 
     return shard_map(
         local,

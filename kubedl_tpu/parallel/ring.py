@@ -143,7 +143,7 @@ def make_context_attention(
                          "expected 'ring' or 'ulysses'")
     if sp_axis not in mesh.axis_names or mesh.shape[sp_axis] <= 1:
         return None
-    from kubedl_tpu.utils.shardmap import shard_map
+    from jax import shard_map
 
     bt = tuple(a for a in batch_axes if a in mesh.axis_names)
     ht = head_axis if head_axis in mesh.axis_names else None

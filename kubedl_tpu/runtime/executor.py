@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import importlib
+import json
 import logging
 import os
 import signal
@@ -158,6 +159,24 @@ class SubprocessRuntime(ContainerRuntime):
             stderr=subprocess.STDOUT if stdout else None,
         )
         return _SubprocHandle(proc)
+
+
+def read_worker_summary(log_path: str) -> dict:
+    """The last ``worker_summary`` line the training entry printed into a
+    pod log under ``SubprocessRuntime.log_dir``. Lives here, not beside
+    its writer, because what reads it back is an operator-side parent
+    that must not import jax (a chip belongs to one process)."""
+    summary = None
+    with open(log_path, errors="replace") as f:
+        for line in f:
+            if '"worker_summary"' in line:
+                try:
+                    summary = json.loads(line)["worker_summary"]
+                except json.JSONDecodeError:
+                    continue
+    if summary is None:
+        raise RuntimeError(f"no worker_summary in {log_path}")
+    return summary
 
 
 #: env key under which ThreadRuntime passes the cancellation Event object

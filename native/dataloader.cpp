@@ -5,7 +5,9 @@
 // sub-second, so batch assembly must never appear on the critical path.
 // This loader memory-maps a binary token file, samples windows with a
 // seeded xorshift PRNG, and keeps a ring of pre-assembled batches filled
-// by background threads — the consumer thread only memcpy's.
+// by background threads — the consumer thread only memcpy's. Batches
+// enter the ring in the order their windows were drawn, so a seed yields
+// one fixed SEQUENCE of batches however the threads are scheduled.
 //
 // C ABI (consumed via ctypes from kubedl_tpu/data/native.py):
 //   void* kdl_loader_open(path, batch, seq, seed, prefetch, token_bytes)
@@ -46,9 +48,12 @@ struct Loader {
   uint64_t rng = 0;
 
   std::mutex mu;
-  std::condition_variable cv_full, cv_empty;
+  std::condition_variable cv_full, cv_empty, cv_order;
   std::deque<Batch> ring;
   size_t ring_cap = 0;
+  size_t in_flight = 0;      // drawn, not yet in the ring (slot reserved)
+  uint64_t next_ticket = 0;  // order of drawing
+  uint64_t next_push = 0;    // the ticket the ring takes next
   std::vector<std::thread> workers;
   std::atomic<bool> stop{false};
 
@@ -56,6 +61,7 @@ struct Loader {
     stop.store(true);
     cv_full.notify_all();
     cv_empty.notify_all();
+    cv_order.notify_all();
     for (auto& t : workers)
       if (t.joinable()) t.join();
     if (base) munmap(const_cast<uint8_t*>(base), file_bytes);
@@ -63,7 +69,7 @@ struct Loader {
   }
 
   // xorshift64*: deterministic, one state per loader (workers draw window
-  // starts under the lock, so a given seed yields a fixed SET of windows)
+  // starts under the lock and take a ticket with them)
   uint64_t next_rand() {
     uint64_t x = rng;
     x ^= x >> 12;
@@ -97,10 +103,15 @@ struct Loader {
   void worker() {
     while (!stop.load()) {
       std::vector<long> starts(batch);
+      uint64_t ticket;
       {
         std::unique_lock<std::mutex> lk(mu);
-        cv_full.wait(lk, [&] { return stop.load() || ring.size() < ring_cap; });
+        cv_full.wait(lk, [&] {
+          return stop.load() || ring.size() + in_flight < ring_cap;
+        });
         if (stop.load()) return;
+        ticket = next_ticket++;
+        ++in_flight;
         long span = n_tokens - seq;
         for (int r = 0; r < batch; ++r)
           starts[r] = span > 0 ? static_cast<long>(next_rand() % span) : 0;
@@ -109,10 +120,13 @@ struct Loader {
       fill_batch(b, starts);
       {
         std::unique_lock<std::mutex> lk(mu);
-        if (ring.size() < ring_cap) {
-          ring.push_back(std::move(b));
-          cv_empty.notify_one();
-        }
+        cv_order.wait(lk, [&] { return stop.load() || next_push == ticket; });
+        if (stop.load()) return;
+        ring.push_back(std::move(b));
+        ++next_push;
+        --in_flight;
+        cv_empty.notify_one();
+        cv_order.notify_all();
       }
     }
   }

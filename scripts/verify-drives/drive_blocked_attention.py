@@ -40,10 +40,6 @@ from pathlib import Path
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
-from kubedl_tpu.utils.jaxenv import ensure_cpu_if_requested  # noqa: E402
-
-ensure_cpu_if_requested()
-
 CHECKS = []
 
 

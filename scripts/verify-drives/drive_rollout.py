@@ -33,8 +33,6 @@ import urllib.request
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
 os.environ["JAX_PLATFORMS"] = "cpu"
-from kubedl_tpu.utils.jaxenv import ensure_cpu_if_requested
-ensure_cpu_if_requested()
 
 ok = []
 def check(name, cond, detail=""):

@@ -4,8 +4,6 @@ import json, os, sys, tempfile, time, urllib.request
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 import pathlib; sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[2]))
-from kubedl_tpu.utils.jaxenv import ensure_cpu_if_requested
-ensure_cpu_if_requested()
 
 from kubedl_tpu.lineage.types import ModelVersion, ModelVersionPhase
 from kubedl_tpu.operator import Operator, OperatorOptions

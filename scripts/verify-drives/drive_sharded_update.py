@@ -14,8 +14,6 @@ import json, os, sys, tempfile
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import pathlib; sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[2]))
-from kubedl_tpu.utils.jaxenv import ensure_cpu_if_requested
-ensure_cpu_if_requested()
 
 from kubedl_tpu.api.types import (
     JobConditionType, ReplicaSpec, ReplicaType, RestartPolicy,

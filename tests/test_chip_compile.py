@@ -1,0 +1,110 @@
+"""The Pallas kernels of the main path, compiled for a described v5e chip.
+
+Interpret-mode tests cannot see what Mosaic refuses (a block not aligned to
+the tiling, too much VMEM, a kernel that cannot be partitioned); the TPU
+compiler is installed here and compiles for a chip that is described, not
+attached. These are the only tests that load it, and this is the only file
+that may: one process at a time can hold the TPU library, so the topology
+is described inside a fixture (never at import or collection time), in the
+test's own process, and every test that needs it lives here. Nothing runs —
+a compile that passes is not a chip run (`chip_smoke.py` is).
+
+Shapes: Llama-3.2-1B's attention (32 Q / 8 KV heads, hd 64) at S=2048 for
+the flash kernels, and the decode shapes of Llama-3.2-1B and Gemma-2B
+(B=8, 16-token blocks, a block table for 2048 tokens) for the paged ones.
+"""
+
+import os
+
+import pytest
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def on_chip(topo):
+    """Shapes placed on one described chip: ``on_chip(shape, dtype)``."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    sharding = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding
+    )
+
+
+def _compiled_text(fn, *args) -> str:
+    import jax
+
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("which", ["forward", "backward-fused", "backward-split"])
+def test_flash_kernels_compile_at_llama_1b_heads(on_chip, which, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from kubedl_tpu.ops import flash_attention_module as fa
+
+    S, H, KV, hd = 2048, 32, 8, 64
+    q = on_chip((1, S, H, hd), jnp.bfloat16)
+    kv = on_chip((1, S, KV, hd), jnp.bfloat16)
+    rope = on_chip((S, hd // 2), jnp.float32)
+
+    def attn(q, k, v, cos, sin):  # the trainer's path: rotary fused in
+        return fa.flash_attention(q, k, v, rope_cos=cos, rope_sin=sin)
+
+    def loss(q, k, v, cos, sin):
+        return jnp.sum(attn(q, k, v, cos, sin).astype(jnp.float32) ** 2)
+
+    if which == "backward-split":
+        monkeypatch.setattr(fa, "_FUSED_BWD_SCRATCH_BYTES", 0)
+    fn = attn if which == "forward" else jax.grad(loss, argnums=(0, 1, 2))
+    text = _compiled_text(fn, q, kv, kv, rope, rope)
+    assert "tpu_custom_call" in text
+    kernels = {"forward": 1, "backward-fused": 2, "backward-split": 3}
+    assert text.count("custom_call_target=\"tpu_custom_call\"") >= kernels[which]
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["read", "fused-write"])
+@pytest.mark.parametrize(
+    "KV,group,hd", [(8, 4, 64), (1, 8, 256)], ids=["llama-3.2-1b", "gemma-2b"],
+)
+def test_paged_kernels_compile_at_decode_shapes(on_chip, KV, group, hd, fused):
+    import jax.numpy as jnp
+
+    from kubedl_tpu.models import paged_attention as pa
+
+    B, BS, MB = 8, 16, 2048 // 16
+    pool = on_chip((1 + B * MB, BS, KV, hd), jnp.bfloat16)
+    args = [
+        on_chip((B, 1, KV * group, hd), jnp.bfloat16), pool, pool,
+        on_chip((B, MB), jnp.int32), on_chip((B,), jnp.int32),
+    ]
+    if fused:
+        args += [on_chip((B, KV, hd), jnp.bfloat16)] * 2
+    text = _compiled_text(
+        lambda *a: pa._pallas_paged_attention(*a, interpret=False), *args
+    )
+    assert "tpu_custom_call" in text

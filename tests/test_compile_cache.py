@@ -16,32 +16,59 @@ import sys
 import time
 from pathlib import Path
 
+import pytest
+
 REPO_ROOT = str(Path(__file__).resolve().parents[1])
 
 
-def test_enable_and_count(tmp_path, monkeypatch):
-    from kubedl_tpu.utils.compile_cache import (
-        cache_entry_count,
-        enable_compilation_cache,
-    )
-
+@pytest.mark.parametrize(
+    "jax_env,arg,pod_env,want",
+    [
+        ("jaxdir", "argdir", "poddir", "jaxdir"),
+        ("", "argdir", "poddir", "argdir"),
+        ("", "", "poddir", "poddir"),
+        ("", "", "", "default"),
+    ],
+    ids=["jax-env-wins", "explicit-arg", "per-pod-env", "fixed-default"],
+)
+def test_cache_dir_placed_from_outside(
+    tmp_path, monkeypatch, jax_env, arg, pod_env, want
+):
+    """JAX_COMPILATION_CACHE_DIR wins over everything and nothing is set
+    in code; otherwise the argument, the per-pod variable, and last the
+    one fixed directory inside the checkout."""
     import jax
 
-    assert cache_entry_count(str(tmp_path / "nope")) == 0
-    # disabled when neither arg nor env names a dir
+    from kubedl_tpu.utils import compile_cache
+
+    def path(name):
+        return str(tmp_path / name) if name else ""
+
+    assert compile_cache.DEFAULT_CACHE_DIR == os.path.join(
+        REPO_ROOT, ".cache", "jax"
+    )
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.delenv("KUBEDL_COMPILE_CACHE_DIR", raising=False)
-    assert enable_compilation_cache() == ""
-    # env-driven enable creates the dir and points jax at it; jax config is
-    # process-global, so restore it (tmp_path is deleted after this test)
+    if jax_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", path(jax_env))
+    if pod_env:
+        monkeypatch.setenv("KUBEDL_COMPILE_CACHE_DIR", path(pod_env))
+    expect = (
+        compile_cache.DEFAULT_CACHE_DIR if want == "default" else path(want)
+    )
+    # jax config is process-global, so restore it (tmp_path is deleted
+    # after this test)
     prev = jax.config.jax_compilation_cache_dir
     try:
-        d = tmp_path / "cache"
-        monkeypatch.setenv("KUBEDL_COMPILE_CACHE_DIR", str(d))
-        assert enable_compilation_cache() == str(d)
-        assert d.is_dir()
-        assert jax.config.jax_compilation_cache_dir == str(d)
+        assert compile_cache.enable_compilation_cache(path(arg)) == expect
+        assert os.path.isdir(expect)
+        assert compile_cache.cache_entry_count(expect) >= 0
+        assert jax.config.jax_compilation_cache_dir == (
+            prev if jax_env else expect
+        )
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+    assert compile_cache.cache_entry_count(str(tmp_path / "nope")) == 0
 
 
 def test_operator_injects_cache_env(tmp_path):
@@ -82,6 +109,7 @@ def test_operator_injects_cache_env(tmp_path):
 
 def _run_entry(cache_dir: str, log_dir: Path, tag: str) -> dict:
     env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)  # it would win over the pod's
     env.update({
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
@@ -119,6 +147,11 @@ def test_warm_restart_hits_cache(tmp_path):
     assert n_warm == n_cold, (
         f"warm run recompiled: {n_warm - n_cold} new cache entries"
     )
+    # the worker's own counters (jax.monitoring events) say the same
+    assert cold["compile_cache"]["cache_misses"] > 0, cold["compile_cache"]
+    assert warm["compile_cache"]["cache_hits"] > 0, warm["compile_cache"]
+    assert warm["compile_cache"]["cache_misses"] == 0, warm["compile_cache"]
+    assert warm["device"]["platform"] == "cpu" and warm["device"]["count"] == 1
     # warm compile must not be slower; usually it is much faster, but CPU
     # timing jitter on a tiny model makes a strict factor flaky
     assert warm["first_step_seconds"] <= cold["first_step_seconds"] * 1.5, (

@@ -234,6 +234,18 @@ class TestCheckpointIntegrity:
 
 
 class TestTrainerAttnSelection:
+    @pytest.fixture(autouse=True)
+    def _interpreted_kernel(self, monkeypatch):
+        """attn_impl="flash" builds the compiled kernel, which a CPU cannot
+        run: these tests ask for the interpreter themselves."""
+        import functools
+
+        from kubedl_tpu.ops import flash_attention_module as fa
+
+        monkeypatch.setattr(fa, "make_flash_attention", functools.partial(
+            fa.make_flash_attention, interpret=True,
+        ))
+
     def test_forced_flash_runs_in_interpret_mode(self):
         from kubedl_tpu.ops import flash_attention_module as fa
 
@@ -248,6 +260,17 @@ class TestTrainerAttnSelection:
         assert summary["attn_impl"] == "flash"
         assert fa.TRACE_COUNT > before  # kernel actually traced
         assert np.isfinite(summary["final_loss"])
+
+    def test_untileable_seq_len_raises_instead_of_dense(self):
+        """A sequence the kernel cannot tile is an error naming the
+        length — never the dense path taken in silence."""
+        import dataclasses
+
+        mesh = build_mesh(MeshSpec({"data": 1}), jax.devices()[:1])
+        cfg = TrainConfig(model=dataclasses.replace(CFG, max_seq=2048),
+                          global_batch=2, seq_len=1100, attn_impl="flash")
+        with pytest.raises(ValueError, match="seq_len=1100"):
+            Trainer(cfg, mesh)
 
     def test_flash_matches_dense_loss(self):
         mesh = build_mesh(MeshSpec({"data": 1}), jax.devices()[:1])

@@ -192,8 +192,6 @@ DIST_PSUM = (
     f"sys.path.insert(0, {REPO_ROOT!r})\n"
     "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
     "os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=1'\n"
-    "from kubedl_tpu.utils.jaxenv import ensure_cpu_if_requested\n"
-    "ensure_cpu_if_requested()\n"
     "from kubedl_tpu.parallel.mesh import initialize_from_env\n"
     "initialize_from_env()\n"
     "import jax\n"

@@ -1,5 +1,10 @@
-"""Pallas kernel tests (interpret mode on CPU; same code path runs compiled
-on TPU). The dense oracle llama.attention is the numerics reference."""
+"""Pallas kernel tests: interpret mode, asked for by argument (the same
+kernels run compiled on a TPU — tests/test_chip_compile.py compiles them
+for one). The dense oracle llama.attention is the numerics reference.
+Multi-tile cases use S=256 with 128-blocks: `fit_block` admits no smaller
+tile of a longer sequence, and an untileable shape raises."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -7,10 +12,12 @@ import numpy as np
 import pytest
 
 from kubedl_tpu.models import llama
-from kubedl_tpu.ops import flash_attention
+from kubedl_tpu.ops import flash_attention_module as fa
+
+flash_attention = functools.partial(fa.flash_attention, interpret=True)
 
 
-def _qkv(key, B=2, S=128, H=4, KV=2, hd=32, dtype=jnp.float32):
+def _qkv(key, B=2, S=256, H=4, KV=2, hd=32, dtype=jnp.float32):
     kq, kk, kv = jax.random.split(key, 3)
     return (
         jax.random.normal(kq, (B, S, H, hd), dtype),
@@ -24,14 +31,14 @@ class TestFlashAttention:
     def test_matches_dense(self, causal):
         q, k, v = _qkv(jax.random.PRNGKey(0))
         want = llama.attention(q, k, v, causal=causal)
-        got = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32)
+        got = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
 
     def test_gqa_grouping(self):
         q, k, v = _qkv(jax.random.PRNGKey(1), H=8, KV=2)
         want = llama.attention(q, k, v, causal=True)
-        got = flash_attention(q, k, v, block_q=64, block_k=64)
+        got = flash_attention(q, k, v, block_q=128, block_k=128)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
 
@@ -51,10 +58,11 @@ class TestFlashAttention:
         """The fused backward accumulates dk/dv across the whole GQA group
         in kernel scratch (init on the group's first head, write-out on
         its last) — exercised at group sizes beyond the bench model's 2."""
-        q, k, v = _qkv(jax.random.PRNGKey(3), S=64, H=H, KV=KV, hd=16)
+        q, k, v = _qkv(jax.random.PRNGKey(3), B=1, H=H, KV=KV, hd=16)
 
         def loss_flash(q, k, v):
-            o = flash_attention(q, k, v, causal=causal, block_q=16, block_k=16)
+            o = flash_attention(q, k, v, causal=causal, block_q=128,
+                                block_k=128, bwd_block_q=128, bwd_block_k=128)
             return (o * o).sum()
 
         def loss_dense(q, k, v):
@@ -91,19 +99,20 @@ class TestFlashAttention:
         PRE-rope and the output must match apply_rope + kernel (and the
         dense oracle), forward and gradients — including the inverse
         rotation that makes the backward emit pre-rope gradients."""
-        S, hd = 128, 32
-        q, k, v = _qkv(jax.random.PRNGKey(6), S=S, H=H, KV=KV, hd=hd)
+        S, hd = 256, 32
+        q, k, v = _qkv(jax.random.PRNGKey(6), B=1, S=S, H=H, KV=KV, hd=hd)
         cos, sin = llama.rope_table(hd, 10000.0, S)
+        tiles = dict(block_q=128, block_k=128, bwd_block_q=128,
+                     bwd_block_k=128)
 
         def loss_fused(q, k, v):
-            o = flash_attention(q, k, v, block_q=32, block_k=32,
-                                rope_cos=cos, rope_sin=sin)
+            o = flash_attention(q, k, v, rope_cos=cos, rope_sin=sin, **tiles)
             return (o * o).sum()
 
         def loss_explicit(q, k, v):
             o = flash_attention(
                 llama.apply_rope(q, cos, sin), llama.apply_rope(k, cos, sin),
-                v, block_q=32, block_k=32,
+                v, **tiles,
             )
             return (o * o).sum()
 
@@ -120,14 +129,13 @@ class TestFlashAttention:
     def test_fused_rope_split_backward_path(self, monkeypatch):
         """The split two-kernel backward must apply the same in-kernel
         rotation + inverse-rotation as the fused path."""
-        from kubedl_tpu.ops import flash_attention_module as fa
-
-        S, hd = 64, 16
-        q, k, v = _qkv(jax.random.PRNGKey(7), S=S, H=4, KV=2, hd=hd)
+        S, hd = 256, 16
+        q, k, v = _qkv(jax.random.PRNGKey(7), B=1, S=S, H=4, KV=2, hd=hd)
         cos, sin = llama.rope_table(hd, 10000.0, S)
 
         def loss(q, k, v):
-            o = flash_attention(q, k, v, block_q=16, block_k=16,
+            o = flash_attention(q, k, v, block_q=128, block_k=128,
+                                bwd_block_q=128, bwd_block_k=128,
                                 rope_cos=cos, rope_sin=sin)
             return (o * o).sum()
 
@@ -138,19 +146,12 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-4, rtol=1e-4)
 
-    def test_untileable_shape_falls_back_to_oracle(self):
-        # S=48 with 32-blocks has no legal tiling; the wrapper degrades to
-        # the dense oracle instead of raising (r2: graceful fit_block path)
-        import numpy as np
-
-        from kubedl_tpu.models.llama import attention
-
+    def test_untileable_shape_raises(self):
+        # S=48 with 32-blocks has no legal tiling: an error naming the
+        # sequence length, never the dense oracle in silence
         q, k, v = _qkv(jax.random.PRNGKey(5), S=48)
-        got = flash_attention(q, k, v, block_q=32, block_k=32)
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(attention(q, k, v)),
-            rtol=2e-4, atol=2e-4,
-        )
+        with pytest.raises(ValueError, match="seq_len=48"):
+            flash_attention(q, k, v, block_q=32, block_k=32)
 
 
 class TestBlockFitting:
@@ -207,12 +208,7 @@ class TestBlockFitting:
         """seq 1536 (divisible by 512, not 1024) must still run the fused
         kernel (regression: r2 review — default-block bump silently
         narrowed support)."""
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-
         from kubedl_tpu.models.llama import attention
-        from kubedl_tpu.ops.flash_attention import flash_attention
 
         B, S, H, KV, hd = 1, 256, 2, 1, 16  # 256 % 128 == 0, < 1024
         q = jax.random.normal(jax.random.PRNGKey(0), (B, S, H, hd))
@@ -263,3 +259,49 @@ class TestRematKernelCounts:
         # not a bug — "dots" cannot name custom-call outputs; this pins
         # the contrast so the flash_rope assertion above stays meaningful
         assert self._grad_pallas_count("dots") == 3
+
+
+class TestKernelCheck:
+    """The comparisons chip_smoke.py and bench.py run on the chip
+    (kubedl_tpu/ops/kernel_check.py), driven here through the interpreter
+    at small shapes: they must pass on a correct kernel, say that no
+    compiled kernel was in the program, and fail on a wrong answer."""
+
+    def test_flash_check_passes_in_interpret_mode(self):
+        from kubedl_tpu.ops import kernel_check
+
+        r = kernel_check.flash_check(
+            1, 256, 4, 2, 16, block=128, dtype=jnp.float32, interpret=True
+        )
+        assert r["ok"] and r["finite"] and not r["compiled"], r
+        for leg in ("", "split_", "rope_"):
+            assert r[f"{leg}dk_max_abs_diff"] < 1e-4, r
+
+    @pytest.mark.parametrize(
+        "KV,group,hd,S,fused",
+        [(2, 2, 16, 1, False), (2, 2, 16, 1, True), (1, 4, 32, 8, False)],
+        ids=["decode", "decode-fused-write", "mqa-suffix"],
+    )
+    def test_paged_check_passes_in_interpret_mode(self, KV, group, hd, S, fused):
+        from kubedl_tpu.ops import kernel_check
+
+        r = kernel_check.paged_check(
+            3, KV, group, hd, max_tokens=64, S=S, fused=fused,
+            dtype=jnp.float32, interpret=True,
+        )
+        assert r["ok"] and not r["compiled"], r
+        assert ("k_pool_max_abs_diff" in r) == fused
+
+    def test_a_wrong_kernel_fails_the_check(self, monkeypatch):
+        from kubedl_tpu.models import paged_attention as pa
+        from kubedl_tpu.ops import kernel_check
+
+        real = pa._pallas_paged_attention
+        monkeypatch.setattr(
+            pa, "_pallas_paged_attention",
+            lambda *a, **kw: real(*a, **kw) * 1.5,
+        )
+        r = kernel_check.paged_check(
+            3, 2, 2, 16, max_tokens=64, dtype=jnp.float32, interpret=True
+        )
+        assert not r["ok"], r

@@ -84,6 +84,7 @@ class TestKernelParity:
             outs[kern] = np.asarray(pa.paged_attention(
                 jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
                 jnp.asarray(bt), jnp.asarray(starts), kernel=kern,
+                interpret=kern == "pallas",
             ))
         for kern, got in outs.items():
             d = np.abs(got.astype(np.float64) - ref).max()
@@ -278,9 +279,11 @@ class TestModelParity:
             )
             assert np.array_equal(multi[:, 0], np.asarray(write)), kern
 
-    def test_pallas_interpret_through_decode_step(self):
-        """Force DEFAULT_KERNEL=pallas (interpret on CPU) through the
-        full model stack: same greedy argmax as the lax path."""
+    def test_pallas_interpret_through_decode_step(self, monkeypatch):
+        """Force the pallas kernel (interpret on CPU) through the full
+        model stack: same greedy argmax as the lax path."""
+        import functools
+
         import jax.numpy as jnp
 
         from kubedl_tpu.models import paged_attention as pa
@@ -289,16 +292,14 @@ class TestModelParity:
         lg, _ = llama.paged_decode_step_batched(
             params, dict(cache), nxt, cfg, kv_attention="blocked"
         )
-        old = pa.DEFAULT_KERNEL
-        pa.DEFAULT_KERNEL = "pallas"
-        try:
-            before = pa.TRACE_COUNT["pallas"]
-            lp, _ = llama.paged_decode_step_batched(
-                params, dict(cache), nxt, cfg, kv_attention="blocked"
-            )
-            assert pa.TRACE_COUNT["pallas"] > before
-        finally:
-            pa.DEFAULT_KERNEL = old
+        monkeypatch.setattr(pa, "paged_attention", functools.partial(
+            pa.paged_attention, kernel="pallas", interpret=True,
+        ))
+        before = pa.TRACE_COUNT["pallas"]
+        lp, _ = llama.paged_decode_step_batched(
+            params, dict(cache), nxt, cfg, kv_attention="blocked"
+        )
+        assert pa.TRACE_COUNT["pallas"] > before
         d = float(jnp.max(jnp.abs(lg - lp)))
         assert d < 1e-4, d
         assert np.array_equal(np.asarray(jnp.argmax(lg, -1)),
@@ -362,11 +363,13 @@ class TestFusedKVWrite:
             ref = np.asarray(pa.paged_attention(
                 jnp.asarray(q), jnp.asarray(kp2), jnp.asarray(vp2),
                 jnp.asarray(bt), jnp.asarray(starts), kernel=kern,
+                interpret=kern == "pallas",
             ))
             before = pa.TRACE_COUNT["fused"]
             out, kpo, vpo = pa.paged_attention(
                 jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
                 jnp.asarray(bt), jnp.asarray(starts), kernel=kern,
+                interpret=kern == "pallas",
                 new_k=jnp.asarray(nk), new_v=jnp.asarray(nv),
             )
             assert pa.TRACE_COUNT["fused"] == before + 1

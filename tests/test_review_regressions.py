@@ -2,6 +2,8 @@
 
 import time
 
+import pytest
+
 from kubedl_tpu.api import constants
 from kubedl_tpu.api.topology import get_slice, peak_flops_for_device_kind
 from kubedl_tpu.api.types import (
@@ -119,6 +121,9 @@ def test_peak_flops_lookup_from_catalog():
     assert peak_flops_for_device_kind("TPU v4") == 275e12
     assert peak_flops_for_device_kind("TPU v6 lite") == 918e12
     assert peak_flops_for_device_kind("Intel Xeon") == 0.0
+    # a TPU the table does not know is an error, not a peak of 0
+    with pytest.raises(ValueError, match="not in the slice catalog"):
+        peak_flops_for_device_kind("TPU v9 ultra")
 
 
 def test_kubelet_configmap_resync_does_not_deadlock():
