@@ -4,7 +4,8 @@ Same three-surface discipline as KTL004, applied to the distributed
 tracing added with the span catalog in docs/observability.md:
 
 1. every string literal at a ``TRACER.span(...)`` / ``TRACER.begin(...)``
-   / ``TRACER.record(...)`` call site must have a row in the
+   / ``TRACER.record(...)`` / ``TRACER.phase(...)`` / ``TRACER.step(...)``
+   call site must have a row in the
    docs/observability.md span-catalog table (the ``| Span | Layer |``
    table) — trace consumers (``scripts/tracemerge.py``, the verify
    drives, dashboards keying on span names) read that table as the
@@ -30,7 +31,7 @@ RULE_ID = "KTL007"
 
 DOC_PATH = "docs/observability.md"
 
-_EMIT_METHODS = {"span", "begin", "record"}
+_EMIT_METHODS = {"span", "begin", "record", "phase", "step"}
 
 #: emission-site WRAPPERS: method name -> positional index of the span
 #: name literal (JobEngine._trace_job_milestone(job, "job.submit", ...)
@@ -39,9 +40,9 @@ _WRAPPERS = {"_trace_job_milestone": 1}
 
 
 def _call_sites(contexts) -> Dict[str, List[Tuple[str, int]]]:
-    """name -> [(relpath, line)] for every TRACER.span/begin/record
-    (or known wrapper) call whose span-name argument is a string
-    literal."""
+    """name -> [(relpath, line)] for every TRACER.span/begin/record/
+    phase/step (or known wrapper) call whose span-name argument is a
+    string literal."""
     out: Dict[str, List[Tuple[str, int]]] = {}
     for ctx in contexts:
         for node in ast.walk(ctx.tree):

@@ -493,7 +493,9 @@ class ServingMetrics:
         )
         self.dispatch_ms = r.histogram(
             "kubedl_tpu_serving_dispatch_ms",
-            "Per-tick host time enqueueing prefill/segment work (ms)",
+            "Per-tick host time of the prefill/segment/verify dispatch phases: "
+            "array build, mirror upload, KV reserve, the jitted calls and "
+            "their scheduling bookkeeping (ms)",
             buckets=_TICK_MS_BUCKETS,
         )
         self.harvest_ms = r.histogram(

@@ -12,8 +12,11 @@ every workload kind carrying the annotation.
 TPU-first notes: the same machinery also serves the XLA/TPU profiler
 (SURVEY.md §5 "surface XLA/TPU profiler the same annotation-driven way") —
 `profile: true` in the config points TensorBoard at the job's xprof trace
-dir (see observability.tracing for the writer side) and sets the env the
-tensorboard-plugin-profile expects.
+dir and sets the env the tensorboard-plugin-profile expects. Nothing in the
+repo writes that directory today: a worker has no switch that starts a
+`jax.profiler` capture (ROADMAP, Design). A capture someone does start in a
+worker holds the trainer's `train.*` phases beside the device plane
+(docs/observability.md "Device profiles").
 """
 
 from __future__ import annotations

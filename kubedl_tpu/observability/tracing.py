@@ -1,5 +1,6 @@
 """Distributed tracing: trace/span identity, context propagation, ring
-buffer, Chrome-trace export + XLA profiler hook.
+buffer, Chrome-trace export, and host *phase* spans written to the
+profiler (``Tracer.phase``).
 
 The reference has NO tracing (SURVEY.md §5: observability is logs + metrics
 only, three log stacks coexisting). The TPU build adds what the survey
@@ -22,11 +23,22 @@ enough to leave on in production (a span is two perf_counter calls, two
 cost is one attribute test + a shared null context manager — the same
 near-zero fast-path discipline as the disarmed chaos/lockwitness hooks,
 budgeted in ``scripts/scheduler_microbench.py``.
+
+A *phase* span (``TRACER.phase``) is the second kind: what a scheduler or
+training loop is doing right now, not what happened to one request. It is
+a ``jax.profiler.TraceAnnotation``, so a profiler capture in progress holds
+it on the capture's own clock beside the device plane (which is what lets a
+device idle gap be named), and it never enters the ring: a few phases a
+tick would evict the per-request trees the flight recorder keeps there.
+Its handle times itself (``.ms``) armed or not: the engine's tick feeds
+``pipeline_stats()`` from it, and those counters are not the tracer's to
+switch off; disarmed it is that clock alone, with no annotation.
+``jax`` is imported on the first armed phase; the control plane imports
+this module without it.
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
 import random
 import threading
@@ -34,7 +46,7 @@ import time
 import uuid
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, List, Optional
 
 #: HTTP header carrying the trace context between router/engine/replicas.
 TRACE_HEADER = "X-Trace-Context"
@@ -123,21 +135,6 @@ def current_context() -> Optional[TraceContext]:
     return st[-1] if st else None
 
 
-@contextlib.contextmanager
-def use_context(ctx: Optional[TraceContext]) -> Iterator[Optional[TraceContext]]:
-    """Bind a (parsed) context for the current thread — HTTP handler
-    threads use this so everything they run parents under the caller."""
-    if ctx is None:
-        yield None
-        return
-    st = _ctx_stack()
-    st.append(ctx)
-    try:
-        yield ctx
-    finally:
-        st.pop()
-
-
 @dataclass
 class Span:
     name: str
@@ -174,6 +171,52 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+
+#: ``jax.profiler``'s (TraceAnnotation, StepTraceAnnotation), bound by the
+#: first armed phase
+_ANNOTATIONS: Any = None
+
+
+def _annotations() -> Any:
+    global _ANNOTATIONS
+    if _ANNOTATIONS is None:
+        from jax import profiler
+
+        _ANNOTATIONS = (profiler.TraceAnnotation, profiler.StepTraceAnnotation)
+    return _ANNOTATIONS
+
+
+class _PhaseHandle:
+    """One host phase: its own duration (``.ms``, valid after exit)
+    whatever the tracer's state, so the caller's accounting is one
+    measurement armed or not, plus, armed, a profiler annotation for its
+    extent (``_ann``; None while disarmed, which leaves two
+    ``perf_counter`` calls). ``set()`` adds attributes known only once
+    the work is done (they land as the event's stats)."""
+
+    __slots__ = ("_ann", "_t0", "ms")
+
+    def __init__(self, ann: Any) -> None:
+        self._ann = ann
+        self._t0 = 0.0
+        self.ms = 0.0
+
+    def __enter__(self) -> "_PhaseHandle":
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        self.ms = (time.perf_counter() - self._t0) * 1e3
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **attrs: Any) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
 
 
 class _SpanHandle:
@@ -313,6 +356,25 @@ class Tracer:
         if not self.enabled:
             return _NULL_SPAN
         return _SpanHandle(self, name, parent, attrs)
+
+    def phase(self, name: str, **attrs: Any) -> _PhaseHandle:
+        """Context manager over one host phase of a loop (a scheduler
+        tick's dispatch, a training step's data fetch): written to the
+        profiler as a ``TraceAnnotation`` with ``attrs`` as its stats,
+        never to the ring. The handle's ``.ms`` is the measured duration
+        after exit. Disarmed it writes nothing and still times, so the
+        counters a loop feeds from ``.ms`` do not depend on this switch."""
+        if not self.enabled:
+            return _PhaseHandle(None)
+        return _PhaseHandle(_annotations()[0](name, **attrs))
+
+    def step(self, name: str, step_num: int) -> _PhaseHandle:
+        """A phase that is one iteration of a training loop, written as a
+        ``StepTraceAnnotation``: profile viewers group the device's work
+        by it, and the phases opened inside it are its parts."""
+        if not self.enabled:
+            return _PhaseHandle(None)
+        return _PhaseHandle(_annotations()[1](name, step_num=step_num))
 
     def begin(self, name: str, parent: Optional[TraceContext] = None,
               **attrs: Any):
@@ -475,32 +537,3 @@ class Tracer:
 
 #: process-wide default tracer (the engine, router, and manager use this)
 TRACER = Tracer()
-
-
-# ---------------------------------------------------------------------------
-# Device-side: xprof capture around training steps.
-
-
-@contextlib.contextmanager
-def xprof_trace(logdir: str, enabled: bool = True) -> Iterator[None]:
-    """Wrap a training region in a `jax.profiler` trace whose output lands
-    under ``logdir`` — the same directory the TensorBoard sidecar serves
-    when its config says `profile: true`. No-op when disabled or when the
-    profiler is unavailable (e.g. double-start)."""
-    if not enabled:
-        yield
-        return
-    try:
-        import jax
-
-        jax.profiler.start_trace(logdir)
-    except Exception:
-        yield
-        return
-    try:
-        yield
-    finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception:
-            pass

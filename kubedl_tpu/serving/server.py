@@ -65,6 +65,17 @@ class UnknownModelVersion(ValueError):
     to a replica that cannot know the version either."""
 
 
+def _named(name: str, fn):
+    """``fn`` under ``name``. JAX calls a jitted function's program
+    ``jit_<__name__>``, and that is the name a device profile's
+    ``XLA Modules`` line gives each execution — the only handle a trace
+    has for telling a prefill from a decode (docs/observability.md
+    "Device profiles"). A lambda or a ``functools.partial`` would read
+    ``jit__lambda`` / ``jit__unknown`` there."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 class _Slot:
     """One in-flight sequence occupying a batch row."""
 
@@ -266,24 +277,28 @@ class LlamaEngine:
         # instead of allocating a fresh copy every step
         if self._paged:
             self._decode = jax.jit(
-                lambda p, c, t: llama.paged_decode_step_batched(
-                    p, c, t, self.cfg, kv_attention=self.kv_attention
-                ),
+                _named("engine_decode_step", lambda p, c, t: (
+                    llama.paged_decode_step_batched(
+                        p, c, t, self.cfg, kv_attention=self.kv_attention
+                    )
+                )),
                 donate_argnums=(1,),
             )
             # whole-prompt prefill is LOCAL causal attention (no pool
             # read), so there is nothing for the blocked kernel to do
             self._prefill = jax.jit(
-                lambda p, c, t, l: llama.paged_prefill_batched(
-                    p, c, t, l, self.cfg
-                ),
+                _named("engine_prefill", lambda p, c, t, l: (
+                    llama.paged_prefill_batched(p, c, t, l, self.cfg)
+                )),
                 donate_argnums=(1,),
             )
             self._prefill_from = jax.jit(
-                lambda p, c, t, l, st: llama.paged_prefill_from(
-                    p, c, t, l, st, self.cfg,
-                    kv_attention=self.kv_attention,
-                ),
+                _named("engine_prefill_from", lambda p, c, t, l, st: (
+                    llama.paged_prefill_from(
+                        p, c, t, l, st, self.cfg,
+                        kv_attention=self.kv_attention,
+                    )
+                )),
                 donate_argnums=(1,),
             )
             #: paged prefix-cache ops: entries normally share the row's
@@ -292,21 +307,29 @@ class LlamaEngine:
             #: and _copy_block is the copy-on-write primitive for the
             #: partial tail block of a graft. One compile each.
             self._graft = jax.jit(
-                llama.paged_graft_prefix, donate_argnums=(0,)
+                _named("engine_graft", lambda c, k, v, row, n: (
+                    llama.paged_graft_prefix(c, k, v, row, n)
+                )),
+                donate_argnums=(0,),
             )
             self._copy_block = jax.jit(
-                llama.copy_kv_block, donate_argnums=(0,)
+                _named("engine_copy_block", lambda c, src, dst: (
+                    llama.copy_kv_block(c, src, dst)
+                )),
+                donate_argnums=(0,),
             )
             self._extract = None  # paged inserts never materialize arrays
         else:
             self._decode = jax.jit(
-                lambda p, c, t: llama.decode_step_batched(p, c, t, self.cfg),
+                _named("engine_decode_step", lambda p, c, t: (
+                    llama.decode_step_batched(p, c, t, self.cfg)
+                )),
                 donate_argnums=(1,),
             )
             self._prefill = jax.jit(
-                lambda p, c, t, l: llama.prefill_batched(
-                    p, c, t, l, self.cfg
-                ),
+                _named("engine_prefill", lambda p, c, t, l: (
+                    llama.prefill_batched(p, c, t, l, self.cfg)
+                )),
                 donate_argnums=(1,),
             )
             #: suffix-only prefill (per-row start offsets): newly admitted
@@ -314,9 +337,9 @@ class LlamaEngine:
             #: Same power-of-2 bucketing as _prefill, so compile count
             #: stays bounded (<= one per bucket per path).
             self._prefill_from = jax.jit(
-                lambda p, c, t, l, st: llama.prefill_batched_from(
-                    p, c, t, l, st, self.cfg
-                ),
+                _named("engine_prefill_from", lambda p, c, t, l, st: (
+                    llama.prefill_batched_from(p, c, t, l, st, self.cfg)
+                )),
                 donate_argnums=(1,),
             )
             #: prefix-cache device ops: graft writes a cached entry's K/V
@@ -324,10 +347,16 @@ class LlamaEngine:
             #: row's prefix span out as a new entry (NOT donated — the
             #: live cache survives). One compile per entry bucket length.
             self._graft = jax.jit(
-                llama.copy_prefix_into_row, donate_argnums=(0,)
+                _named("engine_graft", lambda c, k, v, row, n: (
+                    llama.copy_prefix_into_row(c, k, v, row, n)
+                )),
+                donate_argnums=(0,),
             )
             self._extract = jax.jit(
-                llama.extract_prefix_from_row, static_argnums=(2,)
+                _named("engine_extract", lambda c, row, p_len: (
+                    llama.extract_prefix_from_row(c, row, p_len)
+                )),
+                static_argnums=(2,),
             )
         # first-token sampler, ON DEVICE: fetching the prefill logits to
         # sample on the host moved the full [B, V] array to the host —
@@ -344,11 +373,15 @@ class LlamaEngine:
             )
             return _jnp.argmax(z, axis=-1).astype(_jnp.int32)
 
-        self._sample_logits = jax.jit(_pick)
+        self._sample_logits = jax.jit(_named("engine_sample_first", _pick))
         #: grafts prefill-sampled first tokens into the device token chain
         #: (llama.merge_chain_tokens) so interleaved admissions never force
         #: the chain back through the host
-        self._merge_chain = jax.jit(llama.merge_chain_tokens)
+        self._merge_chain = jax.jit(
+            _named("engine_merge_chain", lambda last, ids, mask: (
+                llama.merge_chain_tokens(last, ids, mask)
+            ))
+        )
         if self._paged:
             import math
 
@@ -429,20 +462,24 @@ class LlamaEngine:
                     self._draft = make_draft(spec_draft)
                 self._spec_stats = SpecStats()
                 self._verify = jax.jit(
-                    lambda p, c, t, l, st: llama.paged_verify(
-                        p, c, t, l, st, self.cfg,
-                        kv_attention=self.kv_attention,
-                    ),
+                    _named("engine_verify", lambda p, c, t, l, st: (
+                        llama.paged_verify(
+                            p, c, t, l, st, self.cfg,
+                            kv_attention=self.kv_attention,
+                        )
+                    )),
                     donate_argnums=(1,),
                 )
                 #: multi-candidate scorer: READ-ONLY (cache NOT donated
                 #: and not returned, so XLA drops every cache write) —
                 #: the winner goes back through the standard _verify
                 self._verify_multi = jax.jit(
-                    lambda p, c, t, l, st: llama.paged_verify_multi(
-                        p, c, t, l, st, self.cfg,
-                        kv_attention=self.kv_attention,
-                    ),
+                    _named("engine_verify_multi", lambda p, c, t, l, st: (
+                        llama.paged_verify_multi(
+                            p, c, t, l, st, self.cfg,
+                            kv_attention=self.kv_attention,
+                        )
+                    )),
                 ) if self.spec_candidates > 1 else None
                 #: tree scorer: like _verify_multi, READ-ONLY over the
                 #: trie layout; the walked winner goes back through the
@@ -450,10 +487,13 @@ class LlamaEngine:
                 #: 1 + N*k -> one compile.
                 self._spec_tree_m = 1 + self.spec_candidates * self.spec_k
                 self._verify_tree = jax.jit(
-                    lambda p, c, t, pos, m, l, st: llama.paged_verify_tree(
-                        p, c, t, pos, m, l, st, self.cfg,
-                        kv_attention=self.kv_attention,
-                    ),
+                    _named("engine_verify_tree",
+                           lambda p, c, t, pos, m, l, st: (
+                               llama.paged_verify_tree(
+                                   p, c, t, pos, m, l, st, self.cfg,
+                                   kv_attention=self.kv_attention,
+                               )
+                           )),
                 ) if self.spec_tree else None
             else:
                 self._draft = None
@@ -1371,6 +1411,15 @@ class LlamaEngine:
         # admission pass, so "last in-flight row drains" is observed at
         # the next admission opportunity
         self._maybe_evict_versions_locked()
+        if not self._waiting:
+            return
+        with TRACER.phase("engine.admit") as ph:
+            ph.set(admitted=self._admit_waiting_locked())
+
+    def _admit_waiting_locked(self) -> int:
+        """Move waiters into free rows (KV blocks, prefix match) until
+        rows or blocks run out; returns how many were admitted."""
+        admitted = 0
         for i in range(self.max_batch):
             if self._slots[i] is None and self._waiting:
                 if self._paged:
@@ -1384,16 +1433,19 @@ class LlamaEngine:
                             break  # pool dry: wait for frees
                         self._waiting.popleft()
                         if r:
+                            admitted += 1
                             self._trace_admitted_locked(head, t_adm, i)
                         continue  # r False: waiter already failed/woken
                     if not self._admit_row_paged_locked(i, head):
                         break  # pool dry: wait for frees / preemption
                     self._waiting.popleft()
+                    admitted += 1
                     self._trace_admitted_locked(head, t_adm, i)
                     continue
                 slot = self._waiting.popleft()
                 t_adm = time.perf_counter()
                 self._slots[i] = slot
+                admitted += 1
                 self._trace_admitted_locked(slot, t_adm, i)
                 # reset this row's position; stale KV is masked by pos
                 self._cache["pos"] = self._cache["pos"].at[i].set(0)
@@ -1416,6 +1468,7 @@ class LlamaEngine:
                 )
                 slot.cached_len = mlen
                 slot.pinned = entry
+        return admitted
 
     def _loop(self) -> None:
         while True:
@@ -1734,10 +1787,6 @@ class LlamaEngine:
         here — the blocks are freed on that path too."""
         if not self._paged:
             return
-        import numpy as np
-
-        from kubedl_tpu.serving.disagg import KVHandoff
-
         with self._cv:
             if not self._export_q and not self._handoffs:
                 return
@@ -1751,6 +1800,15 @@ class LlamaEngine:
             while self._export_q:
                 hid, box, ev = self._export_q.popleft()
                 work.append((hid, box, ev, self._handoffs.pop(hid, None)))
+        if work:
+            with TRACER.phase("engine.exports", handoffs=len(work)):
+                self._export_handoffs(work)
+
+    def _export_handoffs(self, work) -> None:
+        import numpy as np
+
+        from kubedl_tpu.serving.disagg import KVHandoff
+
         for hid, box, ev, rec in work:
             if rec is None:
                 box["error"] = f"unknown or expired handoff {hid!r}"
@@ -1977,18 +2035,19 @@ class LlamaEngine:
         one compile per (segment size, greedy) combination."""
         fn = self._segments.get((n_steps, greedy))
         if fn is None:
-            import functools
-
             seg = (
                 self._llama.paged_decode_segment if self._paged
                 else self._llama.decode_segment
             )
             kw = {"kv_attention": self.kv_attention} if self._paged else {}
+            # the step count is in the name: a module event of a device
+            # profile carries a duration and nothing else
+            name = f"engine_decode_seg{n_steps}" + ("" if greedy else "_sampled")
             fn = self._jax.jit(
-                functools.partial(
-                    seg, cfg=self.cfg, n_steps=n_steps, greedy=greedy,
-                    **kw,
-                ),
+                _named(name, lambda p, c, tokens, temps, key: seg(
+                    p, c, tokens, temps, key, cfg=self.cfg,
+                    n_steps=n_steps, greedy=greedy, **kw,
+                )),
                 donate_argnums=(1,),
             )
             self._segments[(n_steps, greedy)] = fn
@@ -2031,13 +2090,13 @@ class LlamaEngine:
         pend, self._pending = self._pending, None
         if pend is None:
             return 0.0, 0.0
-        t0 = time.perf_counter()
-        # np.array (copy): device_get may return a zero-copy VIEW of the
-        # device buffer, which a later donated dispatch can reuse
-        rows = np.array(self._jax.device_get(pend["toks"]))  # [B, k]
+        with TRACER.phase("engine.harvest_wait", what="segment") as wait:
+            # np.array (copy): device_get may return a zero-copy VIEW of
+            # the device buffer, which a later donated dispatch can reuse
+            rows = np.array(self._jax.device_get(pend["toks"]))  # [B, k]
         t1 = time.perf_counter()
-        seg_t0 = pend.get("t0", t0)
-        with self._cv:
+        seg_t0 = pend.get("t0", t1)
+        with TRACER.phase("engine.harvest_host") as host, self._cv:
             self._pipe["inflight"] = 0
             for i, s, take in pend["sched"]:
                 s.pending -= take
@@ -2054,7 +2113,7 @@ class LlamaEngine:
                 self._maybe_finalize_locked(i, s)
             self._admit_locked()
             self._cv.notify_all()
-        return (t1 - t0) * 1e3, (time.perf_counter() - t1) * 1e3
+        return wait.ms, host.ms
 
     def _harvest_prefill(self, pre, ids_dev):
         """Harvest prefill's device-sampled first tokens ([B] int32 — the
@@ -2063,11 +2122,10 @@ class LlamaEngine:
         compute. Returns ``(blocked_ms, host_ms)``."""
         import numpy as np
 
-        t0 = time.perf_counter()
-        ids = np.array(self._jax.device_get(ids_dev))  # copy: see harvest
-        t1 = time.perf_counter()
+        with TRACER.phase("engine.harvest_wait", what="prefill") as wait:
+            ids = np.array(self._jax.device_get(ids_dev))  # copy: see harvest
         now = time.perf_counter()
-        with self._cv:
+        with TRACER.phase("engine.harvest_host") as host, self._cv:
             for i, s, budgeted in pre:
                 if budgeted:
                     s.pending -= 1
@@ -2082,7 +2140,7 @@ class LlamaEngine:
                 if budgeted:
                     s.out_ids.append(int(ids[i]))
                 if s.span_id:
-                    p0 = s.prefill_t0 if s.prefill_t0 is not None else t0
+                    p0 = s.prefill_t0 if s.prefill_t0 is not None else now
                     TRACER.record("engine.prefill", start=p0,
                                   duration=now - p0, trace=s.trace,
                                   parent_id=s.span_id,
@@ -2095,7 +2153,7 @@ class LlamaEngine:
                 self._maybe_finalize_locked(i, s)
             self._admit_locked()
             self._cv.notify_all()
-        return (t1 - t0) * 1e3, (time.perf_counter() - t1) * 1e3
+        return wait.ms, host.ms
 
     def _prefill_chunks(self, todo, acct: Dict, params=None):
         """Chunked-admission prefill dispatch (docs/serving.md
@@ -2140,57 +2198,62 @@ class LlamaEngine:
         bucket = self._prefill_bucket(
             max(max(t for _i, _s, _b, t, _f in sched), 1)
         )
-        toks = np.zeros((self.max_batch, bucket), np.int32)
-        lens = np.zeros((self.max_batch,), np.int32)
-        starts = np.zeros((self.max_batch,), np.int32)
-        temps0 = np.zeros((self.max_batch,), np.float32)
-        saved = 0
-        for i, s, base, take, _final in sched:
-            toks[i, :take] = s.prompt[base:base + take]
-            lens[i] = take
-            starts[i] = base
-            temps0[i] = max(float(s.temperature), 0.0)
-            if s.prefill_pos < 0 and s.cached_len:
-                saved += s.cached_len  # first chunk rode a grafted prefix
-        self._key, pick_key = self._jax.random.split(self._key)
-        # host mirrors are authoritative — same contract as every dispatch
-        self._cache["pos"] = self._upload_mirror(self._pos_host)
-        self._cache["bt"] = self._upload_mirror(self._bt_host)
-        t0 = time.perf_counter()
-        logits, self._cache = self._prefill_from(
-            self.params if params is None else params, self._cache,
-            jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(starts),
-        )
-        if saved:
-            if self._pcache is not None:
-                self._pcache.add_tokens_saved(saved)
-            self.metrics.prefix_tokens_saved.inc(saved)
-        self.metrics.admission_chunks.inc(len(sched))
-        prefill_ids = self._sample_logits(
-            logits, jnp.asarray(temps0), pick_key
-        )
-        final_rows = tuple(i for i, _s, _b, _t, f in sched if f)
-        if final_rows:
-            # only finishing rows carry a token into the device chain;
-            # intermediate chunks leave the chain (and its generation)
-            # alone, so in-flight decode feeds stay valid between chunks
-            self._prefill_gen += 1
-            mask = np.zeros((self.max_batch,), bool)
-            mask[list(final_rows)] = True
-            if self._chain is not None:
-                merged = self._merge_chain(
-                    self._chain[2], prefill_ids, jnp.asarray(mask)
-                )
-                self._chain = (
-                    self._prefill_gen,
-                    tuple(sorted(set(self._chain[1]) | set(final_rows))),
-                    merged,
-                )
-            else:
-                self._chain = (
-                    self._prefill_gen, final_rows, prefill_ids[:, None]
-                )
-        acct["dispatch_ms"] += (time.perf_counter() - t0) * 1e3
+        with TRACER.phase(
+            "engine.prefill_dispatch", bucket=bucket, rows=len(sched),
+            tokens=sum(t for _i, _s, _b, t, _f in sched),
+            slots=self.max_batch,
+        ) as ph:
+            toks = np.zeros((self.max_batch, bucket), np.int32)
+            lens = np.zeros((self.max_batch,), np.int32)
+            starts = np.zeros((self.max_batch,), np.int32)
+            temps0 = np.zeros((self.max_batch,), np.float32)
+            saved = 0
+            for i, s, base, take, _final in sched:
+                toks[i, :take] = s.prompt[base:base + take]
+                lens[i] = take
+                starts[i] = base
+                temps0[i] = max(float(s.temperature), 0.0)
+                if s.prefill_pos < 0 and s.cached_len:
+                    saved += s.cached_len  # first chunk rode a grafted prefix
+            self._key, pick_key = self._jax.random.split(self._key)
+            # host mirrors are authoritative — same contract as every dispatch
+            self._cache["pos"] = self._upload_mirror(self._pos_host)
+            self._cache["bt"] = self._upload_mirror(self._bt_host)
+            t0 = time.perf_counter()
+            logits, self._cache = self._prefill_from(
+                self.params if params is None else params, self._cache,
+                jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(starts),
+            )
+            if saved:
+                if self._pcache is not None:
+                    self._pcache.add_tokens_saved(saved)
+                self.metrics.prefix_tokens_saved.inc(saved)
+            self.metrics.admission_chunks.inc(len(sched))
+            prefill_ids = self._sample_logits(
+                logits, jnp.asarray(temps0), pick_key
+            )
+            final_rows = tuple(i for i, _s, _b, _t, f in sched if f)
+            if final_rows:
+                # only finishing rows carry a token into the device chain;
+                # intermediate chunks leave the chain (and its generation)
+                # alone, so in-flight decode feeds stay valid between chunks
+                self._prefill_gen += 1
+                mask = np.zeros((self.max_batch,), bool)
+                mask[list(final_rows)] = True
+                if self._chain is not None:
+                    merged = self._merge_chain(
+                        self._chain[2], prefill_ids, jnp.asarray(mask)
+                    )
+                    self._chain = (
+                        self._prefill_gen,
+                        tuple(sorted(set(self._chain[1]) | set(final_rows))),
+                        merged,
+                    )
+                else:
+                    self._chain = (
+                        self._prefill_gen, final_rows, prefill_ids[:, None]
+                    )
+        acct["dispatch_ms"] += ph.ms
         pre = []
         with self._cv:
             for i, s, base, take, final in sched:
@@ -2265,21 +2328,20 @@ class LlamaEngine:
             ]
         if not cand:
             return
-        t_d = time.perf_counter()
-        if multi:
-            cand_lists = [
-                self._draft.propose_candidates(ctx, k, N)
-                for _, _, ctx, _ in cand
-            ]
-        else:
-            cand_lists = [
-                [p] for p in self._draft.propose_batch(
-                    [ctx for _, _, ctx, _ in cand], k
-                )
-            ]
-        draft_ms = (time.perf_counter() - t_d) * 1e3
-        self._spec_stats.record_draft_ms(draft_ms)
-        self.metrics.spec_draft_ms.observe(draft_ms, draft=draft_kind)
+        with TRACER.phase("engine.spec_draft", rows=len(cand)) as ph:
+            if multi:
+                cand_lists = [
+                    self._draft.propose_candidates(ctx, k, N)
+                    for _, _, ctx, _ in cand
+                ]
+            else:
+                cand_lists = [
+                    [p] for p in self._draft.propose_batch(
+                        [ctx for _, _, ctx, _ in cand], k
+                    )
+                ]
+        self._spec_stats.record_draft_ms(ph.ms)
+        self.metrics.spec_draft_ms.observe(ph.ms, draft=draft_kind)
 
         def _pad(drafts, ctx):
             d = [int(t) for t in drafts][:k]
@@ -2325,71 +2387,73 @@ class LlamaEngine:
         if not rows:
             return
         chaos.check("serving.dispatch")
-        self._cache["pos"] = self._upload_mirror(self._pos_host)
-        self._cache["bt"] = self._upload_mirror(self._bt_host)
-        t0 = time.perf_counter()
-        if tree:
-            # trie ranking pass (read-only, like multi): candidates
-            # sharing a prefix share trie nodes, one forward scores
-            # every node under its ancestor mask, and the deepest
-            # accepted root path becomes the write-path verify's draft
-            M = self._spec_tree_m
-            toks_tr = np.zeros((self.max_batch, M), np.int32)
-            pos_tr = np.zeros((self.max_batch, M), np.int32)
-            mask_tr = np.zeros((self.max_batch, M, M), bool)
-            mask_tr[:, np.arange(M), np.arange(M)] = True  # inactive rows
-            lens_tr = np.zeros((self.max_batch,), np.int32)
-            trees = {}
-            for i, s, dl in rows:
-                tr = build_tree(int(toks[i, 0]), dl, k, M)
-                trees[i] = tr
-                t_toks, t_dep, t_mask = tr.arrays(M)
-                toks_tr[i] = t_toks
-                pos_tr[i] = int(starts[i]) + t_dep
-                mask_tr[i] = t_mask
-                lens_tr[i] = tr.size
-            ids_tree = np.array(self._jax.device_get(self._verify_tree(
-                params, self._cache, jnp.asarray(toks_tr),
-                jnp.asarray(pos_tr), jnp.asarray(mask_tr),
-                jnp.asarray(lens_tr), jnp.asarray(starts),
-            )))  # [B, M]
-            for i, s, dl in rows:
-                path = trees[i].walk(ids_tree[i])
-                # the walk follows unique-token children, so it only
-                # leaves the greedy chain where that chain already
-                # mismatched — switching can never shorten acceptance
-                switched = bool(path) and path != dl[0][:len(path)]
-                self._spec_stats.record_candidates(trees[i].size, switched)
-                if switched:
-                    dl[0] = _pad(path, [toks[i, 0]])
-                    toks[i, 1:] = dl[0]
-        elif multi:
-            # read-only ranking pass (cache neither donated nor written)
-            ids_multi = np.array(self._jax.device_get(self._verify_multi(
-                params, self._cache, jnp.asarray(cand_toks),
+        with TRACER.phase(
+            "engine.spec_dispatch", k=k, rows=len(rows),
+            slots=self.max_batch,
+        ) as ph:
+            self._cache["pos"] = self._upload_mirror(self._pos_host)
+            self._cache["bt"] = self._upload_mirror(self._bt_host)
+            if tree:
+                # trie ranking pass (read-only, like multi): candidates
+                # sharing a prefix share trie nodes, one forward scores
+                # every node under its ancestor mask, and the deepest
+                # accepted root path becomes the write-path verify's draft
+                M = self._spec_tree_m
+                toks_tr = np.zeros((self.max_batch, M), np.int32)
+                pos_tr = np.zeros((self.max_batch, M), np.int32)
+                mask_tr = np.zeros((self.max_batch, M, M), bool)
+                mask_tr[:, np.arange(M), np.arange(M)] = True  # inactive rows
+                lens_tr = np.zeros((self.max_batch,), np.int32)
+                trees = {}
+                for i, s, dl in rows:
+                    tr = build_tree(int(toks[i, 0]), dl, k, M)
+                    trees[i] = tr
+                    t_toks, t_dep, t_mask = tr.arrays(M)
+                    toks_tr[i] = t_toks
+                    pos_tr[i] = int(starts[i]) + t_dep
+                    mask_tr[i] = t_mask
+                    lens_tr[i] = tr.size
+                ids_tree = np.array(self._jax.device_get(self._verify_tree(
+                    params, self._cache, jnp.asarray(toks_tr),
+                    jnp.asarray(pos_tr), jnp.asarray(mask_tr),
+                    jnp.asarray(lens_tr), jnp.asarray(starts),
+                )))  # [B, M]
+                for i, s, dl in rows:
+                    path = trees[i].walk(ids_tree[i])
+                    # the walk follows unique-token children, so it only
+                    # leaves the greedy chain where that chain already
+                    # mismatched — switching can never shorten acceptance
+                    switched = bool(path) and path != dl[0][:len(path)]
+                    self._spec_stats.record_candidates(trees[i].size, switched)
+                    if switched:
+                        dl[0] = _pad(path, [toks[i, 0]])
+                        toks[i, 1:] = dl[0]
+            elif multi:
+                # read-only ranking pass (cache neither donated nor written)
+                ids_multi = np.array(self._jax.device_get(self._verify_multi(
+                    params, self._cache, jnp.asarray(cand_toks),
+                    jnp.asarray(lens), jnp.asarray(starts),
+                )))  # [B, N, S]
+                for i, s, dl in rows:
+                    best = 0
+                    best_a = accept_length(dl[0], ids_multi[i, 0][:k])
+                    for c_n in range(1, N):
+                        a_n = accept_length(dl[c_n], ids_multi[i, c_n][:k])
+                        if a_n > best_a:
+                            best, best_a = c_n, a_n
+                    self._spec_stats.record_candidates(N, best != 0)
+                    if best:
+                        dl[0] = dl[best]  # the accept loop reads dl[0]
+                        toks[i, 1:] = dl[0]
+            ids_dev, self._cache = self._verify(
+                params, self._cache, jnp.asarray(toks),
                 jnp.asarray(lens), jnp.asarray(starts),
-            )))  # [B, N, S]
-            for i, s, dl in rows:
-                best = 0
-                best_a = accept_length(dl[0], ids_multi[i, 0][:k])
-                for c_n in range(1, N):
-                    a_n = accept_length(dl[c_n], ids_multi[i, c_n][:k])
-                    if a_n > best_a:
-                        best, best_a = c_n, a_n
-                self._spec_stats.record_candidates(N, best != 0)
-                if best:
-                    dl[0] = dl[best]  # the accept loop reads dl[0]
-                    toks[i, 1:] = dl[0]
-        ids_dev, self._cache = self._verify(
-            params, self._cache, jnp.asarray(toks),
-            jnp.asarray(lens), jnp.asarray(starts),
-        )
-        acct["dispatch_ms"] += (time.perf_counter() - t0) * 1e3
-        t1 = time.perf_counter()
-        ids = np.array(self._jax.device_get(ids_dev))  # [B, S] (copy)
-        acct["harvest_ms"] += (time.perf_counter() - t1) * 1e3
-        t2 = time.perf_counter()
-        with self._cv:
+            )
+        acct["dispatch_ms"] += ph.ms
+        with TRACER.phase("engine.harvest_wait", what="verify") as ph:
+            ids = np.array(self._jax.device_get(ids_dev))  # [B, S] (copy)
+        acct["harvest_ms"] += ph.ms
+        with TRACER.phase("engine.harvest_host") as ph, self._cv:
             for i, s, dl in rows:
                 drafts = dl[0]
                 a = accept_length(drafts, ids[i][:k])
@@ -2419,7 +2483,7 @@ class LlamaEngine:
         # the verify consumed host-fed tokens: any device chain is stale
         self._chain = None
         acct["segments"] += 1
-        acct["host_ms"] += (time.perf_counter() - t2) * 1e3
+        acct["host_ms"] += ph.ms
 
     def _commit_tick(self, acct: Dict, tick_ms: float) -> None:
         """Fold one tick's accounting into the pipeline stats + metrics."""
@@ -2492,18 +2556,20 @@ class LlamaEngine:
         Scheduling is count-based (``_rem`` includes in-flight tokens);
         values land one tick later and completed slots finalize at
         harvest, when their token values exist host-side."""
-        import numpy as np
-        import jax.numpy as jnp
-
-        with self._cv:
-            self._admit_locked()
-            while (
+        def nothing_to_run() -> bool:
+            return (
                 not self._stop and self._pending is None
                 and not self._export_q and not self._handoffs
                 and not any(s is not None for s in self._slots)
-            ):
-                self._cv.wait(timeout=0.2)
-                self._admit_locked()
+            )
+
+        with self._cv:
+            self._admit_locked()
+            if nothing_to_run():
+                with TRACER.phase("engine.idle_wait"):
+                    while nothing_to_run():
+                        self._cv.wait(timeout=0.2)
+                        self._admit_locked()
             stop = self._stop
             waiting = bool(self._waiting)
         # handoff exports run on THIS thread (sole owner of the donated
@@ -2513,7 +2579,21 @@ class LlamaEngine:
             self._harvest_segment()  # flush: deliver in-flight tokens
             return True
 
-        t_tick = time.perf_counter()
+        # the tick's times ARE its phase spans' durations: one measurement
+        # feeds pipeline_stats(), /metrics and a profiler capture alike
+        with TRACER.phase("engine.tick") as tick:
+            acct = self._run_tick(waiting)
+            tick.set(segments=acct["segments"], waiting=int(waiting),
+                     rebuilds=acct["rebuilds"])
+        self._commit_tick(acct, tick.ms)
+        return False
+
+    def _run_tick(self, waiting: bool) -> Dict:
+        """The body of one tick (see `_loop_once`); returns its accounting
+        for `_commit_tick`."""
+        import numpy as np
+        import jax.numpy as jnp
+
         acct = {"dispatch_ms": 0.0, "harvest_ms": 0.0, "host_ms": 0.0,
                 "overlapped": False, "segments": 0, "deferred": 0,
                 "flushes": 0, "rebuilds": 0}
@@ -2588,61 +2668,66 @@ class LlamaEngine:
                     for _, s in bad:
                         s.cached_len = 0
                         self._release_prefix_locked(s)
-            toks = np.zeros((self.max_batch, bucket), np.int32)
-            lens = np.zeros((self.max_batch,), np.int32)
-            starts = np.zeros((self.max_batch,), np.int32)
-            temps0 = np.zeros((self.max_batch,), np.float32)
-            for i, s in todo:
-                suffix = s.prompt[s.cached_len:]
-                toks[i, : len(suffix)] = suffix
-                lens[i] = len(suffix)
-                starts[i] = s.cached_len
-                temps0[i] = max(float(s.temperature), 0.0)
-            self._key, pick_key = self._jax.random.split(self._key)
-            if self._paged:
-                # the HOST mirrors are authoritative: upload pos + block
-                # table before every dispatch so rollbacks (speculative
-                # rejection, preemption, vacation) are plain mirror edits
-                self._cache["pos"] = self._upload_mirror(self._pos_host)
-                self._cache["bt"] = self._upload_mirror(self._bt_host)
-            t0 = time.perf_counter()
-            if np.any(starts > 0):
-                logits, self._cache = self._prefill_from(
-                    vp, self._cache, jnp.asarray(toks),
-                    jnp.asarray(lens), jnp.asarray(starts),
-                )
-                saved = int(starts.sum())
-                if self._pcache is not None:
-                    self._pcache.add_tokens_saved(saved)
-                self.metrics.prefix_tokens_saved.inc(saved)
-            else:
-                logits, self._cache = self._prefill(
-                    vp, self._cache, jnp.asarray(toks),
-                    jnp.asarray(lens),
-                )
-            prefill_ids = self._sample_logits(
-                logits, jnp.asarray(temps0), pick_key
-            )  # [B] int32, stays on device until after the next dispatch
-            self._prefill_gen += 1
-            # graft the sampled first tokens into the device chain so the
-            # new rows can join THIS tick's decode segment with zero
-            # host->device traffic (per-row chain validity: untouched
-            # rows keep the in-flight segment's output tokens)
-            rows = tuple(i for i, _ in todo)
-            mask = np.zeros((self.max_batch,), bool)
-            mask[list(rows)] = True
-            if self._chain is not None:
-                merged = self._merge_chain(
-                    self._chain[2], prefill_ids, jnp.asarray(mask)
-                )
-                self._chain = (
-                    self._prefill_gen,
-                    tuple(sorted(set(self._chain[1]) | set(rows))),
-                    merged,
-                )
-            else:
-                self._chain = (self._prefill_gen, rows, prefill_ids[:, None])
-            acct["dispatch_ms"] += (time.perf_counter() - t0) * 1e3
+            with TRACER.phase(
+                "engine.prefill_dispatch", bucket=bucket, rows=len(todo),
+                tokens=sum(len(s.prompt) - s.cached_len for _, s in todo),
+                slots=self.max_batch,
+            ) as ph:
+                toks = np.zeros((self.max_batch, bucket), np.int32)
+                lens = np.zeros((self.max_batch,), np.int32)
+                starts = np.zeros((self.max_batch,), np.int32)
+                temps0 = np.zeros((self.max_batch,), np.float32)
+                for i, s in todo:
+                    suffix = s.prompt[s.cached_len:]
+                    toks[i, : len(suffix)] = suffix
+                    lens[i] = len(suffix)
+                    starts[i] = s.cached_len
+                    temps0[i] = max(float(s.temperature), 0.0)
+                self._key, pick_key = self._jax.random.split(self._key)
+                if self._paged:
+                    # the HOST mirrors are authoritative: upload pos + block
+                    # table before every dispatch so rollbacks (speculative
+                    # rejection, preemption, vacation) are plain mirror edits
+                    self._cache["pos"] = self._upload_mirror(self._pos_host)
+                    self._cache["bt"] = self._upload_mirror(self._bt_host)
+                t0 = time.perf_counter()
+                if np.any(starts > 0):
+                    logits, self._cache = self._prefill_from(
+                        vp, self._cache, jnp.asarray(toks),
+                        jnp.asarray(lens), jnp.asarray(starts),
+                    )
+                    saved = int(starts.sum())
+                    if self._pcache is not None:
+                        self._pcache.add_tokens_saved(saved)
+                    self.metrics.prefix_tokens_saved.inc(saved)
+                else:
+                    logits, self._cache = self._prefill(
+                        vp, self._cache, jnp.asarray(toks),
+                        jnp.asarray(lens),
+                    )
+                prefill_ids = self._sample_logits(
+                    logits, jnp.asarray(temps0), pick_key
+                )  # [B] int32, stays on device until after the next dispatch
+                self._prefill_gen += 1
+                # graft the sampled first tokens into the device chain so the
+                # new rows can join THIS tick's decode segment with zero
+                # host->device traffic (per-row chain validity: untouched
+                # rows keep the in-flight segment's output tokens)
+                rows = tuple(i for i, _ in todo)
+                mask = np.zeros((self.max_batch,), bool)
+                mask[list(rows)] = True
+                if self._chain is not None:
+                    merged = self._merge_chain(
+                        self._chain[2], prefill_ids, jnp.asarray(mask)
+                    )
+                    self._chain = (
+                        self._prefill_gen,
+                        tuple(sorted(set(self._chain[1]) | set(rows))),
+                        merged,
+                    )
+                else:
+                    self._chain = (self._prefill_gen, rows, prefill_ids[:, None])
+            acct["dispatch_ms"] += ph.ms
             with self._cv:
                 for i, s in todo:
                     if self._paged:
@@ -2726,12 +2811,12 @@ class LlamaEngine:
             greedy = not np.any(temps > 0.0)
             # feed from the DEVICE chain whenever it covers the decoding
             # rows: long generations never ship tokens host->device
-            chain_ok = (
+            tokens_dev = None
+            if (
                 self._chain is not None
                 and self._chain[0] == self._prefill_gen
                 and {i for i, _ in decoding} <= set(self._chain[1])
-            )
-            if chain_ok:
+            ):
                 tokens_dev = self._chain[2]
             else:
                 # stale/absent chain (post-error recovery): rebuild the
@@ -2752,54 +2837,14 @@ class LlamaEngine:
                     (i, s) for i, s in decoding
                     if self._slots[i] is s and self._rem(s) > 0
                 ]
-                tokens = np.zeros((self.max_batch, 1), np.int32)
-                for i, s in decoding:
-                    tokens[i, 0] = s.next_input()
-                tokens_dev = jnp.asarray(tokens)
-        if decoding and self._paged:
-            # block growth for the segment's k appends; on exhaustion the
-            # reserve preempts-and-requeues victims, and rows that still
-            # cannot grow sit this dispatch out (their device pos mirror
-            # stays put, so the skipped steps never happened for them)
-            with self._cv:
-                decoding = self._reserve_decode_locked(decoding, k)
         if decoding:
-            # injected device fault mid-flight: raising here exercises the
-            # _loop recovery contract (fail in-flight slots, rebuild the
-            # donated cache, reset the pipeline, keep serving)
-            chaos.check("serving.dispatch")
-            fp = temps.tobytes()
-            if self._temps_cache is None or self._temps_cache[0] != fp:
-                self._temps_cache = (fp, jnp.asarray(temps))
-            if self._paged:
-                self._cache["pos"] = self._upload_mirror(self._pos_host)
-                self._cache["bt"] = self._upload_mirror(self._bt_host)
-            t0 = time.perf_counter()
-            toks, last, self._key, self._cache = self._segment_fn(k, greedy)(
-                vp, self._cache, tokens_dev,
-                self._temps_cache[1], self._key,
-            )
-            acct["dispatch_ms"] += (time.perf_counter() - t0) * 1e3
-            self._chain = (
-                self._prefill_gen, tuple(i for i, _ in decoding), last
-            )
-            sched = []
-            with self._cv:
-                for i, s in decoding:
-                    take = min(k, self._rem(s))
-                    s.pending += take
-                    s.fed += take
-                    sched.append((i, s, take))
-                    if self._paged:
-                        # scheduled rows advance k steps on device; rows
-                        # NOT scheduled keep their mirror (the upload
-                        # before the next dispatch rewinds device pos)
-                        self._pos_host[i] = min(
-                            int(self._pos_host[i]) + k, self.max_seq - 1
-                        )
-                self._pipe["inflight"] = 1
-            new_pending = {"toks": toks, "sched": sched, "k": k, "t0": t0}
-            acct["segments"] += 1
+            with TRACER.phase("engine.decode_dispatch") as ph:
+                new_pending = self._dispatch_segment(
+                    decoding, k, temps, greedy, tokens_dev, vp, ph
+                )
+            acct["dispatch_ms"] += ph.ms
+            if new_pending is not None:
+                acct["segments"] += 1
 
         # ---- harvest: segment N-1's ids (then prefill's first tokens)
         # while segment N runs on device — the overlap window
@@ -2817,8 +2862,72 @@ class LlamaEngine:
             acct["harvest_ms"] += h
             acct["host_ms"] += b
         self._pending = new_pending
-        self._commit_tick(acct, (time.perf_counter() - t_tick) * 1e3)
-        return False
+        return acct
+
+    def _dispatch_segment(self, decoding, k: int, temps, greedy: bool,
+                          tokens_dev, params, phase) -> Optional[Dict]:
+        """Dispatch one ``k``-step decode segment over ``decoding`` rows:
+        the token feed (``tokens_dev``, the device chain, or rebuilt here
+        from host tokens when it is None), KV block growth, the mirror
+        upload, the jitted call, and the rows' scheduling bookkeeping.
+        Returns the pending-segment record `_harvest_segment` consumes, or
+        None when the block reserve left no row to run. ``phase`` is the
+        caller's ``engine.decode_dispatch`` span; it gets what was run:
+        ``k``, ``rows``, ``take`` (sum over rows of min(k, remaining): the
+        tokens the segment will deliver) and ``slots`` (rows computed)."""
+        import numpy as np
+        import jax.numpy as jnp
+
+        if tokens_dev is None:
+            tokens = np.zeros((self.max_batch, 1), np.int32)
+            for i, s in decoding:
+                tokens[i, 0] = s.next_input()
+            tokens_dev = jnp.asarray(tokens)
+        if self._paged:
+            # block growth for the segment's k appends; on exhaustion the
+            # reserve preempts-and-requeues victims, and rows that still
+            # cannot grow sit this dispatch out (their device pos mirror
+            # stays put, so the skipped steps never happened for them)
+            with self._cv:
+                decoding = self._reserve_decode_locked(decoding, k)
+            if not decoding:
+                return None
+        # injected device fault mid-flight: raising here exercises the
+        # _loop recovery contract (fail in-flight slots, rebuild the
+        # donated cache, reset the pipeline, keep serving)
+        chaos.check("serving.dispatch")
+        fp = temps.tobytes()
+        if self._temps_cache is None or self._temps_cache[0] != fp:
+            self._temps_cache = (fp, jnp.asarray(temps))
+        if self._paged:
+            self._cache["pos"] = self._upload_mirror(self._pos_host)
+            self._cache["bt"] = self._upload_mirror(self._bt_host)
+        t0 = time.perf_counter()  # start of the rows' engine.decode_segment
+        toks, last, self._key, self._cache = self._segment_fn(k, greedy)(
+            params, self._cache, tokens_dev,
+            self._temps_cache[1], self._key,
+        )
+        self._chain = (
+            self._prefill_gen, tuple(i for i, _ in decoding), last
+        )
+        sched = []
+        with self._cv:
+            for i, s in decoding:
+                take = min(k, self._rem(s))
+                s.pending += take
+                s.fed += take
+                sched.append((i, s, take))
+                if self._paged:
+                    # scheduled rows advance k steps on device; rows
+                    # NOT scheduled keep their mirror (the upload
+                    # before the next dispatch rewinds device pos)
+                    self._pos_host[i] = min(
+                        int(self._pos_host[i]) + k, self.max_seq - 1
+                    )
+            self._pipe["inflight"] = 1
+        phase.set(k=k, rows=len(sched), slots=self.max_batch,
+                  take=sum(t for _i, _s, t in sched))
+        return {"toks": toks, "sched": sched, "k": k, "t0": t0}
 
 
 def make_handler(engine: LlamaEngine, model_name: str):

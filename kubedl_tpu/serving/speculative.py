@@ -181,9 +181,12 @@ class ModelDraft(DraftModel):
         self.pad_to = max(8, int(pad_to))
         self._llama = llama
         self._jnp = jax.numpy
-        self._prefill = jax.jit(
-            lambda p, c, t, l: llama.prefill_batched(p, c, t, l, cfg)
-        )
+        # named functions, not lambdas: a device profile shows a jitted
+        # program as jit_<__name__> (docs/observability.md)
+        def draft_prefill(p, c, t, l):
+            return llama.prefill_batched(p, c, t, l, cfg)
+
+        self._prefill = jax.jit(draft_prefill)
         self._segments: Dict[int, object] = {}
         self._key = jax.random.PRNGKey(0)  # greedy: never consumed
 
@@ -286,11 +289,13 @@ class ModelDraft(DraftModel):
         llama, cfg = self._llama, self.cfg
         fn = self._segments.get(n_steps)
         if fn is None:
-            fn = jax.jit(
-                lambda p, c, t, z, key: llama.decode_segment(
+            def draft_decode_seg(p, c, t, z, key):
+                return llama.decode_segment(
                     p, c, t, z, key, cfg, n_steps, greedy=True
                 )
-            )
+
+            draft_decode_seg.__name__ = f"draft_decode_seg{n_steps}"
+            fn = jax.jit(draft_decode_seg)
             self._segments[n_steps] = fn
         return fn
 

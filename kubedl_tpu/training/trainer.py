@@ -53,6 +53,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubedl_tpu.api.topology import MeshSpec
 from kubedl_tpu.models import llama
+from kubedl_tpu.observability.tracing import TRACER
 from kubedl_tpu.parallel import mesh as meshlib
 
 
@@ -979,56 +980,71 @@ class Trainer:
         try:
             with self.mesh:
                 for i in range(start, steps):
-                    batch = self.shard_batch(next(data))
-                    if i == start and step_fn is not self.train_step:
-                        try:
-                            state, metrics = step_fn(state, batch)
-                        except (TypeError, ValueError):
-                            # AOT executable rejected the args (sharding/layout
-                            # drift — argument validation raises TypeError/
-                            # ValueError BEFORE any execution, so donation has
-                            # not consumed the buffers): fall back to the jit,
-                            # which recompiles or hits the persistent cache
-                            # entry the AOT compile wrote. Runtime failures
-                            # (XlaRuntimeError etc.) propagate — retrying them
-                            # with donated/deleted buffers would mask the
-                            # real error.
-                            step_fn = self.train_step
-                            self._warm_compiled = None  # don't re-pick it
-                            self._aot_used = False
-                            state, metrics = step_fn(state, batch)
-                    else:
-                        state, metrics = step_fn(state, batch)
-                    last_loss_arr = metrics["loss"]
-                    steps_run += 1
-                    if i == start:
-                        first_loss = _fetch_scalar(metrics["loss"])
-                        first_step_s = time.perf_counter() - t0
-                        t_run = time.perf_counter()
-                    elif (
-                        log_every
-                        and (i + 1) % log_every == 0
-                        and i + 1 < steps  # final step fetches below anyway
-                    ):
-                        loss_log.append((i + 1, _fetch_scalar(metrics["loss"])))
-                    if on_step is not None:
-                        on_step(i, metrics)
-                    if (
-                        ckpt_dir
-                        and ckpt_every
-                        and (i + 1) % ckpt_every == 0
-                    ):
-                        t_ck = time.perf_counter()
-                        if checkpointer is not None:
-                            checkpointer.save(state, i + 1)
-                        else:
-                            save_checkpoint(ckpt_dir, state, i + 1)
-                        last_saved_step = i + 1
-                        ckpt_overhead += time.perf_counter() - t_ck
+                    # phase spans: a profiler capture of this process holds
+                    # the loop's parts beside the device plane, which is
+                    # what names a device idle gap (docs/observability.md)
+                    with TRACER.step("train.step", i):
+                        with TRACER.phase("train.data"):
+                            batch = self.shard_batch(next(data))
+                        with TRACER.phase("train.dispatch"):
+                            if i == start and step_fn is not self.train_step:
+                                try:
+                                    state, metrics = step_fn(state, batch)
+                                except (TypeError, ValueError):
+                                    # AOT executable rejected the args
+                                    # (sharding/layout drift — argument
+                                    # validation raises TypeError/ValueError
+                                    # BEFORE any execution, so donation has
+                                    # not consumed the buffers): fall back to
+                                    # the jit, which recompiles or hits the
+                                    # persistent cache entry the AOT compile
+                                    # wrote. Runtime failures (XlaRuntimeError
+                                    # etc.) propagate — retrying them with
+                                    # donated/deleted buffers would mask the
+                                    # real error.
+                                    step_fn = self.train_step
+                                    self._warm_compiled = None  # don't re-pick it
+                                    self._aot_used = False
+                                    state, metrics = step_fn(state, batch)
+                            else:
+                                state, metrics = step_fn(state, batch)
+                        last_loss_arr = metrics["loss"]
+                        steps_run += 1
+                        if i == start:
+                            with TRACER.phase("train.fetch"):
+                                first_loss = _fetch_scalar(metrics["loss"])
+                            first_step_s = time.perf_counter() - t0
+                            t_run = time.perf_counter()
+                        elif (
+                            log_every
+                            and (i + 1) % log_every == 0
+                            and i + 1 < steps  # final step fetches below anyway
+                        ):
+                            with TRACER.phase("train.fetch"):
+                                loss_log.append(
+                                    (i + 1, _fetch_scalar(metrics["loss"]))
+                                )
+                        if on_step is not None:
+                            with TRACER.phase("train.on_step"):
+                                on_step(i, metrics)
+                        if (
+                            ckpt_dir
+                            and ckpt_every
+                            and (i + 1) % ckpt_every == 0
+                        ):
+                            with TRACER.phase("train.checkpoint"):
+                                t_ck = time.perf_counter()
+                                if checkpointer is not None:
+                                    checkpointer.save(state, i + 1)
+                                else:
+                                    save_checkpoint(ckpt_dir, state, i + 1)
+                                last_saved_step = i + 1
+                                ckpt_overhead += time.perf_counter() - t_ck
                 # stop the clock on a true barrier: the last loss transitively
                 # depends on every dispatched step via the donated state chain
                 if steps_run:
-                    last_loss = _fetch_scalar(last_loss_arr)
+                    with TRACER.phase("train.fetch"):
+                        last_loss = _fetch_scalar(last_loss_arr)
                 else:  # resume found nothing left to do
                     last_loss = first_loss = float("nan")
         except BaseException:

@@ -84,8 +84,10 @@ BUCKET_BUDGET_MS = 5.0
 
 #: p50 per-call budget (µs) for a DISARMED tracer span. Every hot path
 #: — scheduler tick, router dispatch, reconcile — calls TRACER.span /
-#: TRACER.record unconditionally; with `enabled = False` the call must
-#: collapse to one attribute test returning a shared null handle.
+#: TRACER.record / TRACER.phase unconditionally; with `enabled = False` the call must
+#: collapse to one attribute test returning a shared null handle (a phase
+#: keeps its clock, since the tick's accounting reads it: one small object
+#: and two perf_counter calls, no annotation).
 #: Sub-microsecond on any CPU; 5 µs leaves slack for slow shared CI
 #: machines while catching an accidental allocation, lock acquisition,
 #: or id-minting sneaking onto the disarmed path.
@@ -673,9 +675,10 @@ def run_workqueue_microbench(keys: int = 200,
 
 def run_tracing_microbench(calls: int = 200_000) -> dict:
     """Per-call cost of the DISARMED tracing fast path: a fresh local
-    Tracer with ``enabled = False``, timing the three hot-path entry
-    points (``span`` context manager, ``begin``/``finish``, ``record``)
-    against TRACING_DISARMED_US. Uses a local instance so the shared
+    Tracer with ``enabled = False``, timing the four hot-path entry
+    points (``span`` context manager, ``begin``/``finish``, ``record``,
+    and the ``phase`` context manager with attributes, as the engine's
+    tick and the trainer's loop open it) against TRACING_DISARMED_US. Uses a local instance so the shared
     TRACER singleton's arm state is untouched."""
     from kubedl_tpu.observability.tracing import Tracer
 
@@ -698,13 +701,20 @@ def run_tracing_microbench(calls: int = 200_000) -> dict:
         t.record("bench.noop", duration=0.0)
     record_us = (time.perf_counter() - t0) * 1e6 / calls
 
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        with t.phase("bench.noop", k=32, rows=2) as ph:
+            ph.set(take=40)
+    phase_us = (time.perf_counter() - t0) * 1e6 / calls
+
     assert not t.spans(), "disarmed tracer must record nothing"
-    worst = max(span_us, begin_us, record_us)
+    worst = max(span_us, begin_us, record_us, phase_us)
     return {
         "calls": calls,
         "span_us": round(span_us, 4),
         "begin_finish_us": round(begin_us, 4),
         "record_us": round(record_us, 4),
+        "phase_us": round(phase_us, 4),
         "budget_us": TRACING_DISARMED_US,
         "within_budget": worst <= TRACING_DISARMED_US,
     }

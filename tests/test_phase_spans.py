@@ -1,0 +1,396 @@
+"""Phase spans and program names (docs/observability.md "Device profiles").
+
+The engine's scheduler loop and the trainer's loop write their phases to
+the profiler (``TRACER.phase`` / ``TRACER.step``), and every jitted program
+of the engine has a name. These tests read a CPU ``jax.profiler`` capture
+back, so they check what a capture on the chip will hold beside the device
+plane: names, nesting, and counts that close on what was served. Times from
+a CPU run are never read as speeds here; they are only compared with one
+another."""
+
+import contextlib
+import glob
+import os
+import sys
+import threading
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from kubedl_tpu.analysis import engine as analysis_engine
+from kubedl_tpu.analysis.rules import span_names
+from kubedl_tpu.observability import tracing
+from kubedl_tpu.observability.tracing import TRACER, Tracer
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PIPELINE_KEYS = {
+    "ticks", "segments", "deferred_harvests", "flushes", "chain_rebuilds",
+    "errors", "inflight", "queued", "dispatch_ms_avg", "harvest_ms_avg",
+    "host_ms_avg", "tick_ms_avg", "overlap_ratio", "dispatch_ms_p50",
+    "harvest_ms_p50", "host_ms_p50", "tick_ms_p50",
+}
+
+
+@pytest.fixture(autouse=True)
+def _armed_tracer():
+    TRACER.clear()
+    TRACER.enabled = True
+    yield
+    TRACER.clear()
+    TRACER.enabled = True
+
+
+class Capture:
+    """Host-plane ``engine.*`` / ``train.*`` events of one CPU capture:
+    ``(line, name, start_ns, end_ns, stats)``."""
+
+    def __init__(self):
+        self.events = []
+
+    def named(self, name):
+        return [e for e in self.events if e[1] == name]
+
+    def total(self, name, stat):
+        return sum(e[4][stat] for e in self.named(name) if stat in e[4])
+
+
+@contextlib.contextmanager
+def capture(tmp_path):
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # as the benchmark's traced run
+    cap = Capture()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        yield cap
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("engine.", "train.")):
+                    cap.events.append((line.name, e.name, e.start_ns,
+                                       e.start_ns + e.duration_ns, dict(e.stats)))
+
+
+def make_engine(**kw):
+    from kubedl_tpu.serving.server import LlamaEngine
+
+    return LlamaEngine(preset="tiny", max_batch=2, max_seq=128, **kw)
+
+
+def serve(eng, requests):
+    """Every ``(prompt, max_tokens)`` at once, from a thread each."""
+    replies = [None] * len(requests)
+
+    def one(i, prompt, n):
+        replies[i] = eng.generate(prompt, max_tokens=n, temperature=0.0)
+
+    threads = [threading.Thread(target=one, args=(i, p, n))
+               for i, (p, n) in enumerate(requests)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert all(r is not None and "error" not in r for r in replies), replies
+    return replies
+
+
+REQUESTS = [
+    (list(range(1, 6)), 9),      # 5-token prompt
+    (list(range(10, 33)), 5),    # 23 tokens
+    (list(range(40, 81)), 14),   # 41 tokens: three chunks of 16 when chunked
+]
+
+
+@pytest.fixture(scope="module")
+def engine():
+    eng = make_engine()
+    yield eng
+    eng.close()
+
+
+@pytest.fixture(scope="module")
+def chunked_engine():
+    eng = make_engine(prefill_chunk_tokens=16)
+    yield eng
+    eng.close()
+
+
+# ---- the tracer's new span kind -------------------------------------------
+
+
+class TestPhaseHandle:
+    def test_armed_phase_measures_and_takes_attributes(self):
+        t = Tracer()
+        with t.phase("engine.decode_dispatch", k=4) as ph:
+            ph.set(rows=2)  # no capture listening: still legal
+            sum(range(1000))
+        assert ph.ms > 0.0
+        with t.step("train.step", 7) as st:
+            pass
+        assert st.ms >= 0.0
+
+    def test_phases_never_enter_the_ring(self, engine):
+        t = Tracer()
+        with t.phase("engine.tick"):
+            pass
+        assert t.spans() == []
+        before = len(TRACER.spans())
+        serve(engine, REQUESTS[:2])  # no trace context: no per-request span
+        assert engine.pipeline_stats()["ticks"] > 0
+        assert len(TRACER.spans()) == before
+
+    def test_disarmed_phase_times_and_annotates_nothing(self):
+        # the tick's accounting reads .ms: it must not depend on the switch
+        t = Tracer()
+        t.enabled = False
+        for ph in (t.phase("engine.tick", k=1), t.step("train.step", 0)):
+            assert ph._ann is None
+            with ph:
+                ph.set(rows=1)
+                sum(range(1000))
+            assert ph.ms > 0.0
+
+    def test_disarmed_budget_covers_phase(self):
+        sys.path.insert(0, os.path.join(REPO, "scripts"))
+        try:
+            from scheduler_microbench import run_tracing_microbench
+        finally:
+            sys.path.pop(0)
+        out = run_tracing_microbench(calls=20_000)
+        assert out["within_budget"] and 0 < out["phase_us"] <= out["budget_us"]
+
+    def test_dead_hooks_are_gone(self):
+        assert not hasattr(tracing, "xprof_trace")
+        assert not hasattr(tracing, "use_context")
+
+
+# ---- program names ---------------------------------------------------------
+
+
+def _shapes(tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+
+def _lowerings(eng):
+    """name -> a thunk that lowers that program of ``eng`` on shapes."""
+    b = eng.max_batch
+    p, c = _shapes(eng.params), _shapes(eng._cache)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    key = _shapes(eng._key)
+    vocab = eng.cfg.vocab_size
+    return {
+        "engine_decode_step": lambda: eng._decode.lower(p, c, i32(b, 1)),
+        "engine_prefill": lambda: eng._prefill.lower(p, c, i32(b, 16), i32(b)),
+        "engine_prefill_from": lambda: eng._prefill_from.lower(
+            p, c, i32(b, 16), i32(b), i32(b)),
+        "engine_decode_seg4": lambda: eng._segment_fn(4, True).lower(
+            p, c, i32(b, 1), f32(b), key),
+        "engine_decode_seg4_sampled": lambda: eng._segment_fn(4, False).lower(
+            p, c, i32(b, 1), f32(b), key),
+        "engine_sample_first": lambda: eng._sample_logits.lower(
+            f32(b, vocab), f32(b), key),
+        "engine_merge_chain": lambda: eng._merge_chain.lower(
+            i32(b, 1), i32(b), jax.ShapeDtypeStruct((b,), jnp.bool_)),
+        "engine_copy_block": lambda: eng._copy_block.lower(c, 1, 2),
+    }
+
+
+class TestProgramNames:
+    @pytest.mark.parametrize("name", [
+        "engine_decode_step", "engine_prefill", "engine_prefill_from",
+        "engine_decode_seg4", "engine_decode_seg4_sampled",
+        "engine_sample_first", "engine_merge_chain", "engine_copy_block",
+    ])
+    def test_program_lowers_under_its_name(self, engine, name):
+        text = _lowerings(engine)[name]().as_text()
+        assert f"module @jit_{name} " in text or f"module @jit_{name}\n" in text, text[:200]
+
+    def test_no_program_is_a_lambda_or_unknown(self):
+        """Every jitted attribute of an engine, the speculative ones and the
+        contiguous layout's included, wraps a function named ``engine_*``."""
+        jitted = type(jax.jit(lambda: 0))
+        seen = set()
+        for kw in ({"spec_k": 2, "spec_candidates": 2, "spec_tree": True},
+                   {"kv_layout": "contiguous"}):
+            eng = make_engine(**kw)
+            try:
+                eng._segment_fn(4, True), eng._segment_fn(1, False)
+                fns = [v for v in vars(eng).values() if isinstance(v, jitted)]
+                fns += list(eng._segments.values())
+                assert len(fns) >= 8
+                for fn in fns:
+                    assert fn.__name__.startswith("engine_"), fn
+                    seen.add(fn.__name__)
+            finally:
+                eng.close()
+        assert {"engine_verify", "engine_verify_multi", "engine_verify_tree",
+                "engine_graft", "engine_extract", "engine_copy_block",
+                "engine_decode_seg4", "engine_decode_seg1_sampled"} <= seen
+
+
+# ---- the engine's phases in a capture -------------------------------------
+
+
+class TestEnginePhases:
+    def test_phases_nest_inside_their_tick_on_the_scheduler_line(self, engine, tmp_path):
+        with capture(tmp_path) as cap:
+            serve(engine, REQUESTS)
+        names = {e[1] for e in cap.events}
+        assert {"engine.tick", "engine.admit", "engine.prefill_dispatch",
+                "engine.decode_dispatch", "engine.harvest_wait",
+                "engine.harvest_host"} <= names
+        ticks = cap.named("engine.tick")
+        assert len({e[0] for e in cap.events}) == 1, "one thread runs the loop"
+        assert all("segments" in t[4] and "waiting" in t[4] for t in ticks)
+        for leaf in ("engine.prefill_dispatch", "engine.decode_dispatch",
+                     "engine.harvest_wait", "engine.harvest_host"):
+            for e in cap.named(leaf):
+                assert any(t[2] <= e[2] and e[3] <= t[3] for t in ticks), e
+        assert any(t[2] <= a[2] and a[3] <= t[3]
+                   for a in cap.named("engine.admit") for t in ticks)
+        assert {e[4]["what"] for e in cap.named("engine.harvest_wait")} == {
+            "segment", "prefill"}
+        # a tick is not idle time, and idle time is not in a tick
+        for w in cap.named("engine.idle_wait"):
+            assert not any(t[2] < w[3] and w[2] < t[3] for t in ticks)
+
+    @pytest.mark.parametrize("which", ["whole_prompt", "chunked"])
+    def test_counts_close_on_what_was_served(self, which, engine, chunked_engine, tmp_path):
+        eng = engine if which == "whole_prompt" else chunked_engine
+        # the last request repeats the first one's prompt, so part of it may
+        # come from the prefix cache; the reply says how much
+        requests = REQUESTS + [(REQUESTS[2][0], 3)]
+        with capture(tmp_path) as cap:
+            replies = serve(eng, requests)
+        delivered = sum(len(r["token_ids"]) for r in replies)
+        assert delivered == sum(n for _p, n in requests)
+        # every request's first token comes from its prefill
+        assert cap.total("engine.decode_dispatch", "take") == delivered - len(requests)
+        prefilled = sum(len(p) - int(r.get("cached_prefix_len", 0))
+                        for (p, _n), r in zip(requests, replies))
+        assert cap.total("engine.prefill_dispatch", "tokens") == prefilled
+        for e in cap.named("engine.prefill_dispatch"):
+            assert e[4]["slots"] == eng.max_batch
+            assert 0 < e[4]["tokens"] <= e[4]["rows"] * e[4]["bucket"]
+        for e in cap.named("engine.decode_dispatch"):
+            assert 0 < e[4]["take"] <= e[4]["rows"] * e[4]["k"]
+            assert e[4]["rows"] <= e[4]["slots"] == eng.max_batch
+        if which == "chunked":
+            # 41 tokens at 16 a tick: the prompt landed in three dispatches
+            assert len(cap.named("engine.prefill_dispatch")) >= 5
+
+    def test_tick_accounting_is_the_spans_own_durations(self, monkeypatch):
+        eng = make_engine()
+        handles, ticks = [], []
+        real_phase, real_commit = TRACER.phase, eng._commit_tick
+
+        def phase(name, **attrs):
+            h = real_phase(name, **attrs)
+            handles.append((name, h))
+            return h
+
+        def commit(acct, tick_ms):
+            at = max(i for i, (n, _h) in enumerate(handles) if n == "engine.tick")
+            ticks.append((dict(acct), tick_ms, handles[at][1],
+                          [(n, h.ms) for n, h in handles[at + 1:]]))
+            return real_commit(acct, tick_ms)
+
+        monkeypatch.setattr(TRACER, "phase", phase)
+        monkeypatch.setattr(eng, "_commit_tick", commit)
+        try:
+            serve(eng, REQUESTS)
+            stats = eng.pipeline_stats()
+        finally:
+            eng.close()
+        assert len(ticks) >= 3 and PIPELINE_KEYS <= set(stats)
+        assert stats["dispatch_ms_avg"] > 0 and stats["tick_ms_p50"] > 0
+        for acct, tick_ms, tick, parts in ticks:
+            ms = lambda *names: sum(m for n, m in parts if n in names)  # noqa: E731
+            assert acct["dispatch_ms"] == pytest.approx(
+                ms("engine.prefill_dispatch", "engine.decode_dispatch"), abs=1e-9)
+            assert acct["harvest_ms"] == pytest.approx(ms("engine.harvest_wait"), abs=1e-9)
+            assert acct["host_ms"] == pytest.approx(ms("engine.harvest_host"), abs=1e-9)
+            assert tick_ms == tick.ms
+            assert tick.ms >= acct["dispatch_ms"] + acct["harvest_ms"] + acct["host_ms"] - 1e-6
+
+    def test_disarmed_engine_leaves_no_event_and_keeps_its_accounting(self, tmp_path):
+        TRACER.enabled = False
+        eng = make_engine()
+        try:
+            with capture(tmp_path) as cap:
+                replies = serve(eng, REQUESTS[:2])
+            stats = eng.pipeline_stats()
+        finally:
+            eng.close()
+        assert [len(r["token_ids"]) for r in replies] == [9, 5]
+        assert cap.events == []
+        # the tick's times are not the tracer's to switch off
+        assert PIPELINE_KEYS <= set(stats)
+        for key in ("dispatch_ms_avg", "harvest_ms_avg", "host_ms_avg", "tick_ms_avg",
+                    "tick_ms_p50", "overlap_ratio"):
+            assert stats[key] > 0, key
+
+
+# ---- the trainer's phases --------------------------------------------------
+
+
+def test_fit_leaves_one_data_and_one_dispatch_a_step(tmp_path):
+    from kubedl_tpu.api.topology import MeshSpec
+    from kubedl_tpu.training.data import SyntheticTokens
+    from kubedl_tpu.models import llama
+    from kubedl_tpu.parallel.mesh import build_mesh
+    from kubedl_tpu.training.trainer import TrainConfig, Trainer
+
+    cfg = TrainConfig(model=llama.TINY, global_batch=2, seq_len=16, steps=3)
+    trainer = Trainer(cfg, build_mesh(MeshSpec({"data": 1}), jax.devices()[:1]))
+    state = trainer.init_state()
+    seen = []
+    with capture(tmp_path) as cap:
+        trainer.fit(iter(SyntheticTokens(2, 16, llama.TINY.vocab_size)), state=state,
+                    on_step=lambda i, _m: seen.append(i))
+    steps = sorted(cap.named("train.step"), key=lambda e: e[2])
+    assert [e[4]["step_num"] for e in steps] == seen == [0, 1, 2]
+    for step in steps:
+        inside = [e[1] for e in cap.events
+                  if e is not step and step[2] <= e[2] and e[3] <= step[3]]
+        assert inside.count("train.data") == 1
+        assert inside.count("train.dispatch") == 1
+        assert inside.count("train.on_step") == 1
+    # the first step's loss is fetched inside it; the last one's after the loop
+    assert len(cap.named("train.fetch")) == 2
+
+
+# ---- the catalog rule ------------------------------------------------------
+
+
+class TestSpanCatalogRule:
+    def test_undocumented_phase_literal_is_flagged(self, tmp_path):
+        (tmp_path / "docs").mkdir()
+        (tmp_path / "docs" / "observability.md").write_text(
+            "| Span | Layer | Meaning |\n|---|---|---|\n| `engine.tick` | engine | a tick |\n")
+        src = tmp_path / "kubedl_tpu" / "loop.py"
+        src.parent.mkdir()
+        src.write_text(
+            "from kubedl_tpu.observability.tracing import TRACER\n"
+            "with TRACER.phase('engine.tick'):\n"
+            "    with TRACER.phase('x'):\n"
+            "        pass\n"
+            "with TRACER.step('y', 0):\n"
+            "    pass\n")
+        ctx = analysis_engine.parse_file(src, tmp_path)
+        found = {f.snippet for f in span_names.check_project(tmp_path, [ctx])}
+        assert found == {"undocumented-span:x", "undocumented-span:y"}
+
+    def test_the_tree_passes(self):
+        root = analysis_engine.REPO_ROOT
+        contexts = [analysis_engine.parse_file(p, root)
+                    for p in analysis_engine.iter_source_files(root)]
+        assert span_names.check_project(root, [c for c in contexts if c]) == []

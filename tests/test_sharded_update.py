@@ -33,6 +33,28 @@ from kubedl_tpu.training.trainer import (
     state_bytes_per_device,
 )
 
+
+@pytest.fixture(autouse=True, scope="module")
+def _fresh_compiles_only():
+    """Keep JAX's persistent compilation cache out of this file. A program
+    of the ``data=2 x fsdp=4`` mesh that XLA:CPU *loads* from the cache
+    (``cpu_aot_loader``) runs its independent collectives side by side,
+    each holding a thread of the eight-thread pool in its rendezvous, and
+    deadlocks (``rendezvous.cc`` "Termination timeout", then abort); the
+    same program compiled in this process runs them in order. The cache is
+    on in a tier-1 worker once any earlier test has run a training pod in
+    process (``training/entry.py`` turns it on for the process). Same
+    idiom as ``test_chip_compile.py``."""
+    from jax._src import compilation_cache as cc
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
 #: trajectory tolerance vs the replicated arm: the sharded update is the
 #: SAME math in a different placement, so only reduction-order float32
 #: noise separates the arms (measured 0.0 on pure-data meshes)
