@@ -1246,6 +1246,19 @@ def paged_decode_segment(
     return toks.T, last, next_key, cache
 
 
+def _advance_pos(
+    pos: jax.Array, rows: Optional[jax.Array], active: jax.Array,
+    new: jax.Array, max_s: int,
+) -> jax.Array:
+    """Per-row positions after a prefill: ``new`` (clamped) at the active
+    rows of the batch, unchanged everywhere else. ``rows`` None: the
+    batch is every cache row; else only ``pos[rows]`` can move."""
+    new = jnp.minimum(new, max_s - 1).astype(jnp.int32)
+    if rows is None:
+        return jnp.where(active, new, pos)
+    return pos.at[rows].set(jnp.where(active, new, pos[rows]))
+
+
 def _paged_suffix_forward(
     params: Params,
     cache: Params,
@@ -1257,6 +1270,7 @@ def _paged_suffix_forward(
     self_contained: bool = False,
     positions: Optional[jax.Array] = None,  # [B, S] per-token positions
     self_mask: Optional[jax.Array] = None,  # [B, S, S] in-suffix mask
+    rows: Optional[jax.Array] = None,  # [B] cache rows of a compact batch
 ) -> Tuple[jax.Array, Params]:
     """Shared body of paged prefill and speculative verify: run suffix
     tokens at global positions ``starts[b] + s`` against the gathered
@@ -1281,7 +1295,14 @@ def _paged_suffix_forward(
     share a depth (and so a RoPE angle). ``self_mask[b, s, t]`` replaces
     the in-suffix causal block with an arbitrary visibility mask (the
     trie's ancestor mask). Both are read-only-mode-only: the write path
-    demands consecutive causal suffixes."""
+    demands consecutive causal suffixes.
+
+    ``rows`` makes the batch COMPACT: ``tokens``/``lengths``/``starts``
+    describe only the cache rows listed (distinct indices), each read and
+    written through its own block table ``bt[rows[b]]``; every other
+    row's blocks and ``pos`` are left as they were. The same mathematics
+    on fewer rows — prefill computes the rows that hold a prompt. None
+    (the verify entry points) means every cache row, in order."""
     _check_kv_attention(kv_attention)
     if (positions is not None or self_mask is not None) \
             and not self_contained:
@@ -1290,7 +1311,7 @@ def _paged_suffix_forward(
         )
     B, S = tokens.shape
     hd = cfg.head_dim
-    bt = cache["bt"]
+    bt = cache["bt"] if rows is None else cache["bt"][rows]  # [B, MB]
     BS = cache["k"].shape[2]
     max_s = bt.shape[1] * BS
     active = lengths > 0
@@ -1376,11 +1397,10 @@ def _paged_suffix_forward(
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
     if self_contained:
         return x, cache
-    pos = jnp.where(
-        active, jnp.minimum(starts + lengths, max_s - 1), cache["pos"]
-    )
     return x, {
-        "k": new_k, "v": new_v, "pos": pos.astype(jnp.int32), "bt": bt,
+        "k": new_k, "v": new_v, "bt": cache["bt"],
+        "pos": _advance_pos(cache["pos"], rows, active, starts + lengths,
+                            max_s),
     }
 
 
@@ -1390,9 +1410,11 @@ def paged_prefill_batched(
     tokens: jax.Array,
     lengths: jax.Array,
     cfg: LlamaConfig,
+    rows: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Params]:
     """Block-table twin of :func:`prefill_batched` (whole prompts from
-    position 0): last-token logits + updated cache.
+    position 0): last-token logits + updated cache. ``rows`` as in
+    :func:`_paged_suffix_forward`: a compact batch of those cache rows.
 
     NOT routed through the suffix forward: prompts starting at 0 attend
     only to their own fresh K/V, so this mirrors `prefill_batched`'s
@@ -1402,7 +1424,7 @@ def paged_prefill_batched(
     contiguous row update)."""
     B, S = tokens.shape
     hd = cfg.head_dim
-    bt = cache["bt"]
+    bt = cache["bt"] if rows is None else cache["bt"][rows]
     BS = cache["k"].shape[2]
     max_s = bt.shape[1] * BS
     active = lengths > 0
@@ -1439,9 +1461,9 @@ def paged_prefill_batched(
         x, idx[:, None, None].astype(jnp.int32), axis=1
     )[:, 0]
     logits = (x_last @ lm_head_of(params, cfg)).astype(jnp.float32)
-    pos = jnp.where(active, jnp.minimum(lengths, max_s - 1), cache["pos"])
     return logits, {
-        "k": new_k, "v": new_v, "pos": pos.astype(jnp.int32), "bt": bt,
+        "k": new_k, "v": new_v, "bt": cache["bt"],
+        "pos": _advance_pos(cache["pos"], rows, active, lengths, max_s),
     }
 
 
@@ -1453,12 +1475,14 @@ def paged_prefill_from(
     starts: jax.Array,
     cfg: LlamaConfig,
     kv_attention: str = "gather",
+    rows: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Params]:
     """Block-table twin of :func:`prefill_batched_from` (suffix-only
-    prefill over a grafted prefix): last-token logits + updated cache."""
+    prefill over a grafted prefix): last-token logits + updated cache.
+    ``rows`` as in :func:`_paged_suffix_forward`: a compact batch."""
     x, cache = _paged_suffix_forward(
         params, cache, tokens, lengths, starts, cfg,
-        kv_attention=kv_attention,
+        kv_attention=kv_attention, rows=rows,
     )
     idx = jnp.maximum(lengths - 1, 0)
     x_last = jnp.take_along_axis(

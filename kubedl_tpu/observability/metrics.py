@@ -633,6 +633,19 @@ class ServingMetrics:
             "count per row per chunk, so chunks/rows ~= prompt_len / "
             "prefill_chunk_tokens)",
         )
+        self.prefill_tokens = r.counter(
+            "kubedl_tpu_serving_prefill_tokens",
+            "Prompt tokens fed to prefill programs (grafted prefix "
+            "tokens are not: they are never computed)",
+        )
+        self.prefill_positions = r.counter(
+            "kubedl_tpu_serving_prefill_positions",
+            "Positions prefill programs computed: rows computed x "
+            "bucket length, summed over dispatches. prefill_tokens over "
+            "this is the share of prefill compute that held a prompt "
+            "token (the rest is bucket padding and, on a contiguous "
+            "cache, rows with nothing to prefill)",
+        )
         # controller-side replica health (the probe-failure satellite:
         # a replica that stops answering its stats probe must SURFACE,
         # not silently drop out of the QPS math)

@@ -284,22 +284,39 @@ class LlamaEngine:
                 )),
                 donate_argnums=(1,),
             )
-            # whole-prompt prefill is LOCAL causal attention (no pool
-            # read), so there is nothing for the blocked kernel to do
+            # a paged prefill program computes a COMPACT batch: the cache
+            # rows ``rows`` that hold prompt tokens this dispatch, and no
+            # others (`_dispatch_prefill`). Their last-token logits land
+            # at ``rows`` of ``acc``, the [max_batch, V] array the
+            # first-token sampler takes, so the sampler, its noise and
+            # the token chain stay indexed by cache row.
+            def _prefill_rows(p, c, t, l, rows, acc):
+                # whole-prompt prefill is LOCAL causal attention (no pool
+                # read), so there is nothing for the blocked kernel to do
+                lg, c = llama.paged_prefill_batched(
+                    p, c, t, l, self.cfg, rows=rows
+                )
+                return acc.at[rows].set(lg), c
+
+            def _prefill_from_rows(p, c, t, l, st, rows, acc):
+                lg, c = llama.paged_prefill_from(
+                    p, c, t, l, st, self.cfg,
+                    kv_attention=self.kv_attention, rows=rows,
+                )
+                return acc.at[rows].set(lg), c
+
             self._prefill = jax.jit(
-                _named("engine_prefill", lambda p, c, t, l: (
-                    llama.paged_prefill_batched(p, c, t, l, self.cfg)
-                )),
+                _named("engine_prefill", _prefill_rows),
                 donate_argnums=(1,),
             )
             self._prefill_from = jax.jit(
-                _named("engine_prefill_from", lambda p, c, t, l, st: (
-                    llama.paged_prefill_from(
-                        p, c, t, l, st, self.cfg,
-                        kv_attention=self.kv_attention,
-                    )
-                )),
+                _named("engine_prefill_from", _prefill_from_rows),
                 donate_argnums=(1,),
+            )
+            #: ``acc`` of a tick's first prefill program: rows no program
+            #: computes sample from zeros, and nobody reads their token
+            self._no_logits = jax.numpy.zeros(
+                (self.max_batch, self.cfg.vocab_size), jax.numpy.float32
             )
             #: paged prefix-cache ops: entries normally share the row's
             #: blocks by reference (no device copy at all); _graft only
@@ -573,6 +590,7 @@ class LlamaEngine:
                        "kv_preemptions": 0, "kv_sheds": 0,
                        "handoffs_out": 0, "handoffs_in": 0,
                        "handoff_failures": 0,
+                       "prefill_tokens": 0, "prefill_positions": 0,
                        "started_at": time.time()}
         #: load-shedding budget: reject (503) instead of queueing once the
         #: queue is deeper than max_queue_depth or its head has waited
@@ -1305,6 +1323,7 @@ class LlamaEngine:
         self._release_prefix_locked(s)
         s.fed = 0
         s.cached_len = 0
+        s.prefill_pos = -1  # its chunks went with its blocks: start over
         s.out_ids = []
         s.pending = 0
         self._waiting.appendleft(s)
@@ -2155,6 +2174,129 @@ class LlamaEngine:
             self._cv.notify_all()
         return wait.ms, host.ms
 
+    def _dispatch_prefill(self, sched, acct: Dict, params, suffix: bool):
+        """Dispatch the prefill of ``sched`` = ``[(row, slot, base, take,
+        final)]``: ``take`` prompt tokens of each row from position
+        ``base``, in ``sched``'s order. A paged engine computes ONLY the
+        rows that hold prompt tokens: one one-row program for each row of
+        ``sched``, each in its own power-of-2 bucket — the program set is
+        one per bucket however many rows arrive together, so nothing new
+        compiles when a second row shares a tick, and a row's last chunk
+        does not pad to its neighbour's. A contiguous cache is addressed
+        by batch row, so there one program still computes all
+        ``max_batch`` rows. ``suffix`` forces the suffix program (chunks
+        always attend through the pool); else a row (a batch, contiguous)
+        with nothing grafted runs the local whole-prompt program.
+
+        One key split, one first-token sample over the ``[max_batch, V]``
+        logits and one chain merge (final rows only) for the whole of
+        ``sched``, whatever the number of programs, so a sampled request
+        draws the noise it always drew. Each program runs under its own
+        ``engine.prefill_dispatch`` phase (``slots`` = rows it computes).
+        Returns ``(prefill_ids, t0)``: the sampled ids, still on the
+        device, and the time of the first dispatch."""
+        import numpy as np
+        import jax.numpy as jnp
+
+        groups = [[t] for t in sched] if self._paged else [sched]
+        logits = self._no_logits if self._paged else None
+        prefill_ids = t0 = None
+        saved = positions = 0
+        for n, group in enumerate(groups):
+            slots = len(group) if self._paged else self.max_batch
+            bucket = self._prefill_bucket(
+                max(max(t for _i, _s, _b, t, _f in group), 1)
+            )
+            with TRACER.phase(
+                "engine.prefill_dispatch", bucket=bucket, rows=len(group),
+                tokens=sum(t for _i, _s, _b, t, _f in group), slots=slots,
+            ) as ph:
+                toks = np.zeros((slots, bucket), np.int32)
+                lens = np.zeros((slots,), np.int32)
+                starts = np.zeros((slots,), np.int32)
+                for j, (i, s, base, take, _final) in enumerate(group):
+                    r = j if self._paged else i
+                    toks[r, :take] = s.prompt[base:base + take]
+                    lens[r] = take
+                    starts[r] = base
+                    if s.prefill_pos < 0 and s.cached_len:
+                        saved += s.cached_len  # first dispatch of a graft
+                if n == 0:
+                    self._key, pick_key = self._jax.random.split(self._key)
+                    if self._paged:
+                        # the HOST mirrors are authoritative: upload pos +
+                        # block table before every dispatch so rollbacks
+                        # (speculative rejection, preemption, vacation)
+                        # are plain mirror edits. The programs after the
+                        # first run on the cache the one before returned.
+                        self._cache["pos"] = self._upload_mirror(self._pos_host)
+                        self._cache["bt"] = self._upload_mirror(self._bt_host)
+                    t0 = time.perf_counter()
+                args = [jnp.asarray(toks), jnp.asarray(lens)]
+                from_prefix = suffix or bool(np.any(starts > 0))
+                if from_prefix:
+                    args.append(jnp.asarray(starts))
+                if self._paged:
+                    rows = np.array([i for i, *_ in group], np.int32)
+                    args += [jnp.asarray(rows), logits]
+                logits, self._cache = (
+                    self._prefill_from if from_prefix else self._prefill
+                )(params, self._cache, *args)
+                positions += slots * bucket
+                if n == len(groups) - 1:
+                    prefill_ids = self._sample_first(sched, logits, pick_key)
+            acct["dispatch_ms"] += ph.ms
+        tokens = sum(t for _i, _s, _b, t, _f in sched)
+        if saved:
+            if self._pcache is not None:
+                self._pcache.add_tokens_saved(saved)
+            self.metrics.prefix_tokens_saved.inc(saved)
+        self.metrics.prefill_tokens.inc(tokens)
+        self.metrics.prefill_positions.inc(positions)
+        with self._cv:
+            self._stats["prefill_tokens"] += tokens
+            self._stats["prefill_positions"] += positions
+        return prefill_ids, t0
+
+    def _sample_first(self, sched, logits, pick_key):
+        """Sample the first token of every row on the device (``[B]``
+        int32; only the rows of ``sched`` are ever read) and graft the
+        FINAL rows' into the device token chain, so they join the next
+        decode segment with zero host->device traffic. Rows whose chunk
+        was intermediate leave the chain (and its generation) alone, so
+        in-flight decode feeds stay valid between chunks."""
+        import numpy as np
+        import jax.numpy as jnp
+
+        temps0 = np.zeros((self.max_batch,), np.float32)
+        for i, s, _base, _take, _final in sched:
+            temps0[i] = max(float(s.temperature), 0.0)
+        prefill_ids = self._sample_logits(
+            logits, jnp.asarray(temps0), pick_key
+        )  # stays on device until after the next dispatch
+        final_rows = tuple(i for i, _s, _b, _t, f in sched if f)
+        if not final_rows:
+            return prefill_ids
+        self._prefill_gen += 1
+        mask = np.zeros((self.max_batch,), bool)
+        mask[list(final_rows)] = True
+        if self._chain is not None:
+            # per-row chain validity: untouched rows keep the in-flight
+            # segment's output tokens
+            merged = self._merge_chain(
+                self._chain[2], prefill_ids, jnp.asarray(mask)
+            )
+            self._chain = (
+                self._prefill_gen,
+                tuple(sorted(set(self._chain[1]) | set(final_rows))),
+                merged,
+            )
+        else:
+            self._chain = (
+                self._prefill_gen, final_rows, prefill_ids[:, None]
+            )
+        return prefill_ids
+
     def _prefill_chunks(self, todo, acct: Dict, params=None):
         """Chunked-admission prefill dispatch (docs/serving.md
         "Continuous batching"): spend at most ``prefill_chunk_tokens``
@@ -2171,9 +2313,6 @@ class LlamaEngine:
         leftover — later arrivals never overtake it. Returns the
         ``(pre, prefill_ids)`` pair the caller's deferred
         `_harvest_prefill` consumes (final rows only)."""
-        import numpy as np
-        import jax.numpy as jnp
-
         bs = self.kv_block_size
         left = self.prefill_chunk_tokens
         sched = []  # (row, slot, base, take, final)
@@ -2195,65 +2334,11 @@ class LlamaEngine:
         # (fail in-flight slots, rebuild the donated cache, keep
         # serving) exactly as for a decode-segment fault
         chaos.check("serving.chunk_admit")
-        bucket = self._prefill_bucket(
-            max(max(t for _i, _s, _b, t, _f in sched), 1)
+        prefill_ids, t0 = self._dispatch_prefill(
+            sched, acct, self.params if params is None else params,
+            suffix=True,
         )
-        with TRACER.phase(
-            "engine.prefill_dispatch", bucket=bucket, rows=len(sched),
-            tokens=sum(t for _i, _s, _b, t, _f in sched),
-            slots=self.max_batch,
-        ) as ph:
-            toks = np.zeros((self.max_batch, bucket), np.int32)
-            lens = np.zeros((self.max_batch,), np.int32)
-            starts = np.zeros((self.max_batch,), np.int32)
-            temps0 = np.zeros((self.max_batch,), np.float32)
-            saved = 0
-            for i, s, base, take, _final in sched:
-                toks[i, :take] = s.prompt[base:base + take]
-                lens[i] = take
-                starts[i] = base
-                temps0[i] = max(float(s.temperature), 0.0)
-                if s.prefill_pos < 0 and s.cached_len:
-                    saved += s.cached_len  # first chunk rode a grafted prefix
-            self._key, pick_key = self._jax.random.split(self._key)
-            # host mirrors are authoritative — same contract as every dispatch
-            self._cache["pos"] = self._upload_mirror(self._pos_host)
-            self._cache["bt"] = self._upload_mirror(self._bt_host)
-            t0 = time.perf_counter()
-            logits, self._cache = self._prefill_from(
-                self.params if params is None else params, self._cache,
-                jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(starts),
-            )
-            if saved:
-                if self._pcache is not None:
-                    self._pcache.add_tokens_saved(saved)
-                self.metrics.prefix_tokens_saved.inc(saved)
-            self.metrics.admission_chunks.inc(len(sched))
-            prefill_ids = self._sample_logits(
-                logits, jnp.asarray(temps0), pick_key
-            )
-            final_rows = tuple(i for i, _s, _b, _t, f in sched if f)
-            if final_rows:
-                # only finishing rows carry a token into the device chain;
-                # intermediate chunks leave the chain (and its generation)
-                # alone, so in-flight decode feeds stay valid between chunks
-                self._prefill_gen += 1
-                mask = np.zeros((self.max_batch,), bool)
-                mask[list(final_rows)] = True
-                if self._chain is not None:
-                    merged = self._merge_chain(
-                        self._chain[2], prefill_ids, jnp.asarray(mask)
-                    )
-                    self._chain = (
-                        self._prefill_gen,
-                        tuple(sorted(set(self._chain[1]) | set(final_rows))),
-                        merged,
-                    )
-                else:
-                    self._chain = (
-                        self._prefill_gen, final_rows, prefill_ids[:, None]
-                    )
-        acct["dispatch_ms"] += ph.ms
+        self.metrics.admission_chunks.inc(len(sched))
         pre = []
         with self._cv:
             for i, s, base, take, final in sched:
@@ -2645,21 +2730,19 @@ class LlamaEngine:
                 active = list(self._slots)
         elif todo:
             # suffix-only prefill: rows with a grafted prefix consume only
-            # prompt[cached_len:]. The bucket is sized by the LONGEST
-            # suffix; `lax.dynamic_update_slice` CLAMPS out-of-bounds
-            # starts, so any graft whose start + bucket would spill past
-            # max_seq is dropped (full prefill for that row) and the
-            # bucket recomputed — terminates because starts=0 always fits.
-            while True:
+            # prompt[cached_len:]. A paged engine needs no overflow fixup:
+            # its suffix prefill routes pad/clamped writes to the trash
+            # block, so a graft whose start + bucket spills past max_seq
+            # is harmless by construction (proven in test_kv_blocks). The
+            # contiguous bucket is sized by the LONGEST suffix, and
+            # `lax.dynamic_update_slice` CLAMPS out-of-bounds starts, so
+            # any graft whose start + bucket would spill past max_seq is
+            # dropped (full prefill for that row) and the bucket
+            # recomputed — terminates because starts=0 always fits.
+            while not self._paged:
                 bucket = self._prefill_bucket(
                     max(len(s.prompt) - s.cached_len for _, s in todo)
                 )
-                if self._paged:
-                    # no overflow fixup needed: the paged suffix prefill
-                    # routes pad/clamped writes to the trash block, so a
-                    # graft whose start + bucket spills past max_seq is
-                    # harmless by construction (proven in test_kv_blocks)
-                    break
                 bad = [(i, s) for i, s in todo
                        if s.cached_len and s.cached_len + bucket > self.max_seq]
                 if not bad:
@@ -2668,73 +2751,18 @@ class LlamaEngine:
                     for _, s in bad:
                         s.cached_len = 0
                         self._release_prefix_locked(s)
-            with TRACER.phase(
-                "engine.prefill_dispatch", bucket=bucket, rows=len(todo),
-                tokens=sum(len(s.prompt) - s.cached_len for _, s in todo),
-                slots=self.max_batch,
-            ) as ph:
-                toks = np.zeros((self.max_batch, bucket), np.int32)
-                lens = np.zeros((self.max_batch,), np.int32)
-                starts = np.zeros((self.max_batch,), np.int32)
-                temps0 = np.zeros((self.max_batch,), np.float32)
-                for i, s in todo:
-                    suffix = s.prompt[s.cached_len:]
-                    toks[i, : len(suffix)] = suffix
-                    lens[i] = len(suffix)
-                    starts[i] = s.cached_len
-                    temps0[i] = max(float(s.temperature), 0.0)
-                self._key, pick_key = self._jax.random.split(self._key)
-                if self._paged:
-                    # the HOST mirrors are authoritative: upload pos + block
-                    # table before every dispatch so rollbacks (speculative
-                    # rejection, preemption, vacation) are plain mirror edits
-                    self._cache["pos"] = self._upload_mirror(self._pos_host)
-                    self._cache["bt"] = self._upload_mirror(self._bt_host)
-                t0 = time.perf_counter()
-                if np.any(starts > 0):
-                    logits, self._cache = self._prefill_from(
-                        vp, self._cache, jnp.asarray(toks),
-                        jnp.asarray(lens), jnp.asarray(starts),
-                    )
-                    saved = int(starts.sum())
-                    if self._pcache is not None:
-                        self._pcache.add_tokens_saved(saved)
-                    self.metrics.prefix_tokens_saved.inc(saved)
-                else:
-                    logits, self._cache = self._prefill(
-                        vp, self._cache, jnp.asarray(toks),
-                        jnp.asarray(lens),
-                    )
-                prefill_ids = self._sample_logits(
-                    logits, jnp.asarray(temps0), pick_key
-                )  # [B] int32, stays on device until after the next dispatch
-                self._prefill_gen += 1
-                # graft the sampled first tokens into the device chain so the
-                # new rows can join THIS tick's decode segment with zero
-                # host->device traffic (per-row chain validity: untouched
-                # rows keep the in-flight segment's output tokens)
-                rows = tuple(i for i, _ in todo)
-                mask = np.zeros((self.max_batch,), bool)
-                mask[list(rows)] = True
-                if self._chain is not None:
-                    merged = self._merge_chain(
-                        self._chain[2], prefill_ids, jnp.asarray(mask)
-                    )
-                    self._chain = (
-                        self._prefill_gen,
-                        tuple(sorted(set(self._chain[1]) | set(rows))),
-                        merged,
-                    )
-                else:
-                    self._chain = (self._prefill_gen, rows, prefill_ids[:, None])
-            acct["dispatch_ms"] += ph.ms
+            prefill_ids, t0 = self._dispatch_prefill(
+                [(i, s, s.cached_len, len(s.prompt) - s.cached_len, True)
+                 for i, s in todo],
+                acct, vp, suffix=False,
+            )
             with self._cv:
                 for i, s in todo:
                     if self._paged:
                         # mirror the device's pos update for dispatched
                         # rows (vacated rows get reset at readmission)
                         self._pos_host[i] = min(
-                            int(starts[i]) + int(lens[i]), self.max_seq - 1
+                            len(s.prompt), self.max_seq - 1
                         )
                     if self._slots[i] is not s:
                         continue  # vacated (request timeout) mid-prefill

@@ -156,7 +156,9 @@ def build_stub_engine(max_batch: int = 4, max_seq: int = 128,
     seg_toks = {}
     jax.block_until_ready((last, ids, logits))
 
-    eng._prefill = lambda p, c, t, l: (logits, c)
+    # whole-prompt and suffix prefill alike, whatever batch they are
+    # handed (a paged engine's is compact: rows + the logits array)
+    eng._prefill = eng._prefill_from = lambda p, c, *batch: (logits, c)
     eng._sample_logits = lambda lg, temps, key: ids
     eng._merge_chain = lambda lastv, i, m: lastv
 
@@ -247,9 +249,6 @@ def run_prefix_microbench(requests: int = 32, max_tokens: int = 8,
     try:
         eng._graft = lambda c, k, v, row, n: c
         eng._extract = lambda c, i, p: (None, None)
-        eng._prefill_from = lambda p, c, t, l, st: (
-            eng._prefill(p, c, t, l)
-        )
         prefix = list(range(3, 3 + prefix_len))
         payload = np.zeros((1,), np.float32)
         assert eng._pcache is not None, "stub engine must enable the cache"
@@ -372,9 +371,6 @@ def run_chunked_admission_microbench(requests: int = 16,
     eng = build_stub_engine(max_batch=max_batch, kv_layout="paged",
                             prefill_chunk_tokens=chunk)
     try:
-        eng._prefill_from = lambda p, c, t, l, st: (
-            eng._prefill(p, c, t, l)
-        )
         assert eng.prefill_chunk_tokens == chunk
         slots = [
             # distinct multi-chunk prompts (no prefix-cache rides)

@@ -108,7 +108,10 @@ def test_operator_injects_cache_env(tmp_path):
 
 
 def _run_entry(cache_dir: str, log_dir: Path, tag: str) -> dict:
-    env = dict(os.environ)
+    # a pod run as a thread earlier in this worker leaves its KUBEDL_*
+    # environment behind (train_main writes os.environ): a stale
+    # KUBEDL_MODEL_PATH would make this process publish to a dead store
+    env = {k: v for k, v in os.environ.items() if not k.startswith("KUBEDL_")}
     env.pop("JAX_COMPILATION_CACHE_DIR", None)  # it would win over the pod's
     env.update({
         "JAX_PLATFORMS": "cpu",

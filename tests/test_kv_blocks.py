@@ -234,7 +234,10 @@ class TestPagedModelExactness:
         clamped writes onto live tail positions. The paged suffix
         forward routes every beyond-lens / beyond-max_seq write to the
         trash block instead — prove the spill case leaves real rows
-        bit-identical."""
+        as a clean prefill computes them. The two programs attend over
+        differently shaped key sets (a 64-key gathered view against 16
+        local keys), so the logits agree to float tolerance and pick the
+        same token; bit-identity is not theirs to have."""
         import jax.numpy as jnp
         import numpy as np
 
@@ -256,7 +259,10 @@ class TestPagedModelExactness:
         l2, _ = llama.paged_prefill_batched(
             params2, fresh, jnp.asarray(toks), lens, cfg2
         )
-        assert np.array_equal(np.asarray(logits[1]), np.asarray(l2[1]))
+        np.testing.assert_allclose(
+            np.asarray(logits[1]), np.asarray(l2[1]), rtol=1e-5, atol=1e-5
+        )
+        assert int(np.argmax(logits[1])) == int(np.argmax(l2[1]))
         # and the spill landed in the trash block, not in live rows
         after = np.asarray(cache_p["k"][:, TRASH_BLOCK])
         assert not np.array_equal(before, after)

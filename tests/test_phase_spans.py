@@ -188,9 +188,11 @@ def _lowerings(eng):
     vocab = eng.cfg.vocab_size
     return {
         "engine_decode_step": lambda: eng._decode.lower(p, c, i32(b, 1)),
-        "engine_prefill": lambda: eng._prefill.lower(p, c, i32(b, 16), i32(b)),
+        # a paged prefill program computes one row, named by ``rows``
+        "engine_prefill": lambda: eng._prefill.lower(
+            p, c, i32(1, 16), i32(1), i32(1), f32(b, vocab)),
         "engine_prefill_from": lambda: eng._prefill_from.lower(
-            p, c, i32(b, 16), i32(b), i32(b)),
+            p, c, i32(1, 16), i32(1), i32(1), i32(1), f32(b, vocab)),
         "engine_decode_seg4": lambda: eng._segment_fn(4, True).lower(
             p, c, i32(b, 1), f32(b), key),
         "engine_decode_seg4_sampled": lambda: eng._segment_fn(4, False).lower(
@@ -278,8 +280,11 @@ class TestEnginePhases:
                         for (p, _n), r in zip(requests, replies))
         assert cap.total("engine.prefill_dispatch", "tokens") == prefilled
         for e in cap.named("engine.prefill_dispatch"):
-            assert e[4]["slots"] == eng.max_batch
+            # a paged prefill program computes the rows it feeds, one each
+            assert e[4]["rows"] == e[4]["slots"] == 1
             assert 0 < e[4]["tokens"] <= e[4]["rows"] * e[4]["bucket"]
+        stats = eng.stats()
+        assert stats["prefill_tokens"] <= stats["prefill_positions"]
         for e in cap.named("engine.decode_dispatch"):
             assert 0 < e[4]["take"] <= e[4]["rows"] * e[4]["k"]
             assert e[4]["rows"] <= e[4]["slots"] == eng.max_batch
