@@ -37,16 +37,16 @@ def main() -> int:
 
     import numpy as np
 
-    from benchmark import correctness, weights
+    from benchmark import correctness, families
     from benchmark.generators import _serve
-    from benchmark import program as prog_mod
     from benchmark import run as harness
 
     manifest = harness.load_json(Path(args.manifest))
     seeds = [int(s) for s in args.seeds.split(",")]
     ctx = harness.Context(ROOT, manifest, args.workload, seeds[0], args.seconds, False)
     print("device:", harness.device_info(ctx.chips, not args.allow_cpu), flush=True)
-    prog_mod.enable_cache(ROOT)
+    family = families.load(ctx.config)
+    family.enable_cache(ROOT)
     generator = importlib.import_module(f"benchmark.generators.{ctx.mix['generator']}")
     vocab = int(ctx.config["vocab_size"])
     if ctx.mix["generator"] == "train_job":
@@ -65,14 +65,14 @@ def main() -> int:
                       flush=True)
         return 0
     for n, seed in enumerate(seeds):
-        program = ctx.make_serve_program(prog_mod, weights.decoder_weights(seed, ctx.config))
+        program = family.serve_program(ctx.config_name, ctx.config, family.weights(seed, ctx.config))
         _serve.warm_up(program, ctx.mix, vocab)
         requests = generator.drive(program, ctx.mix, seed, args.seconds, vocab, time.perf_counter())
         program.close()
         del program
         gc.collect()
         sample = correctness.pick_sample(requests, seed, **ctx.limits.get("sample", {}))
-        tree = weights.decoder_weights(seed, ctx.config)
+        tree = family.weights(seed, ctx.config)
         out = {"seed": seed, "requests": len(requests),
                "failed": sum(1 for r in requests if not r["ok"]),
                "sampled_tokens": sum(r["n_out"] for r in sample),

@@ -25,8 +25,7 @@ from typing import Any, Dict, List, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark import weights as weights_mod
-from benchmark.reference import decoder_ref
+from benchmark import families
 
 #: sequences are padded to a multiple of this for the reference, so that a
 #: handful of compiled shapes serves every sample (padding sits after the
@@ -65,7 +64,7 @@ def _reference_logits(tree: Any, config: Dict[str, Any], req: Dict[str, Any],
     first, count = (0, len(seq)) if every_position else (p - 1, n)
     # positions too are padded (by repeats of the last), to bound the shapes
     positions = np.minimum(np.arange(first, first + -(-count // 64) * 64), first + count - 1)
-    logits = decoder_ref.logits_at(tree, tokens, jnp.asarray(positions), config, precision)
+    logits = families.load(config).logits_at(tree, tokens, jnp.asarray(positions), config, precision)
     return np.asarray(logits)[:count]
 
 
@@ -116,7 +115,7 @@ def check_served(seed: int, config: Dict[str, Any], requests: Sequence[Dict[str,
     """Run after the program's state is freed: the reference's weights are a
     second tree from the same seed and would not fit beside the first."""
     sample = pick_sample(requests, seed, **limits.get("sample", {}))
-    tree = weights_mod.decoder_weights(seed, config)
+    tree = families.load(config).weights(seed, config)
     numbers = gap_numbers(served_gaps(tree, config, sample))
     compared = compare(numbers, limits)
     compared.append({"name": "sampled_tokens", "value": float(sum(r["n_out"] for r in sample)),
@@ -154,10 +153,9 @@ def check_trained(seed: int, config: Dict[str, Any], batches: Sequence[np.ndarra
                   limits: Dict[str, Any]) -> List[Dict[str, Any]]:
     """The reference follows the trainer's first steps on the same batches
     from the same seeded weights; run after the trainer's state is freed."""
-    from benchmark.reference import train_ref
-
-    ref = train_ref.follow(lambda: weights_mod.decoder_weights(seed, config), list(batches), config,
-                           first_grad_seen=seen["first_grad"], seen_scale=seen["first_grad_scale"])
+    family = families.load(config)
+    ref = family.train_follow(lambda: family.weights(seed, config), list(batches), config,
+                              first_grad_seen=seen["first_grad"], seen_scale=seen["first_grad_scale"])
     compared = compare(trained_numbers(seen, ref), limits)
     fell = record["loss_first"] - record["loss_last"]
     compared.append({"name": "loss_fell_by", "value": float(fell), "limit": 0.0,
@@ -169,9 +167,8 @@ def control_trained(seed: int, config: Dict[str, Any], batches: Sequence[np.ndar
                     precision: str = "int8") -> Dict[str, float]:
     """The control: the reference in ``precision`` put in the trainer's place,
     compared with the float32 reference exactly as a trainer is."""
-    from benchmark.reference import train_ref
-
-    make = lambda: weights_mod.decoder_weights(seed, config)  # noqa: E731
-    low = train_ref.follow(make, list(batches), config, precision, keep_first=True)
-    ref = train_ref.follow(make, list(batches), config, first_grad_seen=low["first_grad"])
+    family = families.load(config)
+    make = lambda: family.weights(seed, config)  # noqa: E731
+    low = family.train_follow(make, list(batches), config, precision, keep_first=True)
+    ref = family.train_follow(make, list(batches), config, first_grad_seen=low["first_grad"])
     return trained_numbers(low, ref)

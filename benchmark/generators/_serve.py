@@ -17,7 +17,7 @@ from typing import Any, Callable, Dict, List
 import jax
 import numpy as np
 
-from benchmark import correctness, weights
+from benchmark import correctness, families
 
 
 def draw_lengths(spec: Dict[str, Any], n: int, rng: np.random.Generator) -> np.ndarray:
@@ -107,13 +107,12 @@ def run_cell(ctx: Any, drive: Callable[..., List[Dict[str, Any]]]) -> Dict[str, 
     ``drive(program, mix, seed, seconds, vocab, t0)`` offers the mix's load
     for ``seconds`` and returns one record for every request it handed over,
     after the last of them has come back."""
-    from benchmark import program as prog_mod
-
     config, mix = ctx.config, ctx.mix
+    family = families.load(config, needs=("enable_cache", "weights", "serve_program", "logits_at"))
     vocab = int(config["vocab_size"])
-    prog_mod.enable_cache(ctx.root)
-    tree = weights.decoder_weights(ctx.seed, config)
-    program = ctx.make_serve_program(prog_mod, tree)
+    family.enable_cache(ctx.root)
+    tree = family.weights(ctx.seed, config)
+    program = family.serve_program(ctx.config_name, config, tree)
     del tree
     warm_up(program, mix, vocab)
     program.mark_window()

@@ -23,7 +23,7 @@ from typing import Any, Dict, Iterator, List
 
 import numpy as np
 
-from benchmark import correctness, weights
+from benchmark import correctness, families
 
 
 def write_token_file(path: Path, mix: Dict[str, Any], seed: int, vocab: int) -> None:
@@ -46,13 +46,12 @@ def recording(data: Iterator, keep: List[np.ndarray], first: int) -> Iterator:
 
 
 def run_cell(ctx: Any) -> Dict[str, Any]:
-    from benchmark import program as prog_mod
-
     config, mix = ctx.config, ctx.mix
+    family = families.load(config, needs=("enable_cache", "weights", "train_program", "train_follow"))
     check_steps = int(mix["check_steps"])
     marks = [("imports", time.perf_counter())]
-    prog_mod.enable_cache(ctx.root)
-    program = prog_mod.TrainProgram(config, weights.decoder_weights(ctx.seed, config), mix, ctx.chips)
+    family.enable_cache(ctx.root)
+    program = family.train_program(config, family.weights(ctx.seed, config), mix, ctx.chips)
     marks.append(("weights_and_trainer", time.perf_counter()))
     path = ctx.root / ".cache" / "bench_data" / f"{ctx.cell['name']}.bin"
     write_token_file(path, mix, ctx.seed, int(config["vocab_size"]))
@@ -69,7 +68,7 @@ def run_cell(ctx: Any) -> Dict[str, Any]:
     first_moment = program.first_moment_host()
     if check_steps > 1:
         program.run_steps(feed, check_steps - 1, losses.append, lag=lag)
-    change = program.change_norms(weights.decoder_weights(ctx.seed, config))
+    change = program.change_norms(family.weights(ctx.seed, config))
     marks.append(("first_steps_and_readings", time.perf_counter()))
     seen = {"losses": list(losses), "first_grad_norms": first_grad, "change_norms": change,
             # after one step the first moment is (1 - b1) times the gradient the optimizer got

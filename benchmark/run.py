@@ -5,8 +5,9 @@ One process; it holds the chip, builds the cell's program, warms up the
 shapes the cell's traffic uses (set-up), measures for ``--seconds``, compares
 what the timed path produced with the plain reference, and prints one JSON
 object as the last line of its output. Everything that belongs to one
-configuration, one traffic mix, one generator kind or one metric is a file
-found by the name in ``BENCHMARK.json``; see ``benchmark/README.md``.
+configuration, one model family, one traffic mix, one generator kind or one
+metric is a file found by the name in ``BENCHMARK.json`` (a family's by the
+configuration's ``family`` key); see ``benchmark/README.md``.
 """
 
 from __future__ import annotations
@@ -99,9 +100,6 @@ class Context:
         self.trace_dir = root / ".cache" / "bench_trace" / workload
         self._tracer: Optional[threading.Thread] = None
         self.trace_error = ""
-
-    def make_serve_program(self, prog_mod: Any, tree: Any) -> Any:
-        return prog_mod.ServeProgram(self.config_name, self.config, tree)
 
     def _on_event(self, event: str, _secs: float, **_kw: Any) -> None:
         if self._in_window and event == COMPILE_EVENT:

@@ -29,17 +29,17 @@ def main() -> int:
     ap.add_argument("--seconds", type=float, default=20.0)
     ap.add_argument("--seed", type=int, default=11)
     args = ap.parse_args()
-    from benchmark import program as prog_mod
+    from benchmark import families
     from benchmark import run as harness
-    from benchmark import weights
     from benchmark.generators import _serve, open_loop
     from benchmark.stats import percentile
 
     manifest = harness.load_json(ROOT / "BENCHMARK.json")
     ctx = harness.Context(ROOT, manifest, args.workload, args.seed, args.seconds, False)
     print("device:", harness.device_info(ctx.chips, True), flush=True)
-    prog_mod.enable_cache(ROOT)
-    program = ctx.make_serve_program(prog_mod, weights.decoder_weights(args.seed, ctx.config))
+    family = families.load(ctx.config)
+    family.enable_cache(ROOT)
+    program = family.serve_program(ctx.config_name, ctx.config, family.weights(args.seed, ctx.config))
     vocab = int(ctx.config["vocab_size"])
     _serve.warm_up(program, ctx.mix, vocab)
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
