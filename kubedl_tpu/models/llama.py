@@ -1087,7 +1087,9 @@ def init_paged_cache(
     """Paged serving cache: K/V pools ``[L, NB, BS, KV, hd]`` + per-row
     positions + the ``[B, MB]`` block table (all entries start at the
     trash block 0). ``max_seq`` must be a multiple of ``block_size`` so
-    the gathered view is exactly [B, max_seq, KV, hd]."""
+    the gathered view is exactly [B, max_seq, KV, hd]. Every paged program
+    carries the whole pools through its layer scan and, under donation,
+    writes them in place (:func:`_scan_layers_over_pools`)."""
     if max_seq % block_size != 0:
         raise ValueError(
             f"max_seq {max_seq} not a multiple of block_size {block_size}"
@@ -1102,13 +1104,38 @@ def init_paged_cache(
     }
 
 
-def _paged_view(pool: jax.Array, bt: jax.Array) -> jax.Array:
-    """Gather one layer's pool [NB, BS, KV, hd] through the block table
-    [B, MB] into the logical [B, MB*BS, KV, hd] view the contiguous
-    attention math runs over unchanged."""
+def _paged_view(pool: jax.Array, layer: jax.Array, bt: jax.Array) -> jax.Array:
+    """Gather layer ``layer`` of the pool [L, NB, BS, KV, hd] through the
+    block table [B, MB] into the logical [B, MB*BS, KV, hd] view the
+    contiguous attention math runs over unchanged. ONE gather with the
+    layer in the index: ``pool[layer][bt]`` would first slice the layer's
+    whole pool out into a buffer of its own. The index is the block's
+    number in the pool seen as ``L * NB`` blocks (a reshape that moves no
+    byte), a gather over one axis."""
+    L, NB, BS, KV, hd = pool.shape
     B, MB = bt.shape
-    BS = pool.shape[1]
-    return pool[bt].reshape(B, MB * BS, pool.shape[2], pool.shape[3])
+    blocks = pool.reshape(L * NB, BS, KV, hd)[layer * NB + bt]
+    return blocks.reshape(B, MB * BS, KV, hd)
+
+
+def _scan_layers_over_pools(body, x, layers: Params, k_pool, v_pool):
+    """The paged programs' layer scan: ``body(x, k_pool, v_pool, lp,
+    layer) -> (x, k_pool, v_pool)`` runs once a layer with the WHOLE
+    ``[L, NB, BS, KV, hd]`` pools as loop carries beside ``x`` and the
+    layer's index as the scanned input. A body writes new K/V by
+    ``pool.at[layer, blk, off].set(new)``: on a loop carry (and, at the
+    program's edge, a donated argument) XLA scatters in place, so the
+    bytes moved are ``new``'s, not the pool's. Scanning the pools in as
+    ``xs`` and stacking them out as ``ys`` instead (the contiguous
+    cache's scans still do) makes every layer slice its pool out into a
+    fresh buffer and write the whole layer back into a new stacked
+    array, to store one token a row. Returns ``(x, k_pool, v_pool)``."""
+    carry, _ = lax.scan(
+        lambda carry, inp: (body(*carry, *inp), None),
+        (x, k_pool, v_pool),
+        (layers, jnp.arange(k_pool.shape[0], dtype=jnp.int32)),
+    )
+    return carry
 
 
 def _check_kv_attention(kv_attention: str) -> None:
@@ -1129,6 +1156,9 @@ def paged_decode_step_batched(
     validity mask. Rows whose table entry is unmapped write to the trash
     block (vacant rows keep advancing pos exactly like the contiguous
     path — their writes just land in garbage).
+
+    The pools are carried through the layer scan and written in place
+    under donation (:func:`_scan_layers_over_pools`).
 
     ``kv_attention`` picks the attention implementation: ``"gather"``
     (the default bit-exactness oracle — materialize the logical view,
@@ -1162,8 +1192,7 @@ def paged_decode_step_batched(
             [t1 * cos_t - t2 * sin_t, t1 * sin_t + t2 * cos_t], axis=-1
         ).astype(t.dtype)
 
-    def body(x, inp):
-        lp, ckp, cvp = inp  # ckp/cvp: [NB, BS, KV, hd] this layer's pool
+    def body(x, kp, vp, lp, layer):  # kp/vp: [L, NB, BS, KV, hd], whole
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, cfg.norm_plus_one)
         q = rot((h @ deq(lp["wq"])).reshape(B, 1, cfg.n_heads, hd))
         k = rot((h @ deq(lp["wk"])).reshape(B, 1, cfg.n_kv_heads, hd))
@@ -1171,25 +1200,30 @@ def paged_decode_step_batched(
         if kv_attention == "blocked":
             # fused KV write: the kernel lands this step's K/V into the
             # row's current block itself, retiring the separate scatter
-            # dispatch the gather path still performs
+            # dispatch the gather path still performs. It aliases ONE
+            # layer's pool, so that layer is sliced out and put back
             attn, ckp, cvp = blocked_attention.paged_attention(
-                q, ckp, cvp, bt, pos, new_k=k[:, 0], new_v=v[:, 0]
+                q, lax.dynamic_index_in_dim(kp, layer, keepdims=False),
+                lax.dynamic_index_in_dim(vp, layer, keepdims=False),
+                bt, pos, new_k=k[:, 0], new_v=v[:, 0],
             )
+            kp = lax.dynamic_update_index_in_dim(kp, ckp, layer, 0)
+            vp = lax.dynamic_update_index_in_dim(vp, cvp, layer, 0)
         else:
-            ckp = ckp.at[blk, off].set(k[:, 0])
-            cvp = cvp.at[blk, off].set(v[:, 0])
+            kp = kp.at[layer, blk, off].set(k[:, 0])
+            vp = vp.at[layer, blk, off].set(v[:, 0])
             attn = attention(
-                q, _paged_view(ckp, bt), _paged_view(cvp, bt),
+                q, _paged_view(kp, layer, bt), _paged_view(vp, layer, bt),
                 causal=False, mask=mask,
             )
         x = x + attn.reshape(B, 1, cfg.n_heads * hd) @ deq(lp["wo"])
         h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, cfg.norm_plus_one)
         gate = _act(cfg)((h @ deq(lp["w_gate"])).astype(jnp.float32)).astype(h.dtype)
         x = x + (gate * (h @ deq(lp["w_up"]))) @ deq(lp["w_down"])
-        return x, (ckp, cvp)
+        return x, kp, vp
 
-    x, (new_k, new_v) = lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"])
+    x, new_k, new_v = _scan_layers_over_pools(
+        body, x, params["layers"], cache["k"], cache["v"]
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
     logits = (x[:, 0] @ lm_head_of(params, cfg)).astype(jnp.float32)
@@ -1282,6 +1316,10 @@ def _paged_suffix_forward(
     clamped write can only ever land in garbage, never inside a row.
     Returns (final-norm hidden states [B, S, D], updated cache).
 
+    The pools are carried through the layer scan and written in place
+    under donation (:func:`_scan_layers_over_pools`); the read-only mode
+    carries them through untouched.
+
     ``self_contained=True`` is the READ-ONLY scoring mode behind
     :func:`paged_verify_multi`: the pool is never written (so several
     candidate suffixes can share one row's blocks in a single forward)
@@ -1357,18 +1395,20 @@ def _paged_suffix_forward(
             [t1 * cos_t - t2 * sin_t, t1 * sin_t + t2 * cos_t], axis=-1
         ).astype(t.dtype)
 
-    def body(x, inp):
-        lp, ckp, cvp = inp
+    def body(x, kp, vp, lp, layer):  # kp/vp: [L, NB, BS, KV, hd], whole
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, cfg.norm_plus_one)
         q = rot((h @ deq(lp["wq"])).reshape(B, S, cfg.n_heads, hd))
         k = rot((h @ deq(lp["wk"])).reshape(B, S, cfg.n_kv_heads, hd))
         v = (h @ deq(lp["wv"])).reshape(B, S, cfg.n_kv_heads, hd)
         if not self_contained:
-            ckp = ckp.at[blk, off].set(k)
-            cvp = cvp.at[blk, off].set(v)
+            kp = kp.at[layer, blk, off].set(k)
+            vp = vp.at[layer, blk, off].set(v)
         if kv_attention == "blocked":
+            # the blocked attention walks ONE layer's pool
             attn = blocked_attention.paged_attention(
-                q, ckp, cvp, bt, starts,
+                q, lax.dynamic_index_in_dim(kp, layer, keepdims=False),
+                lax.dynamic_index_in_dim(vp, layer, keepdims=False),
+                bt, starts,
                 self_k=k if self_contained else None,
                 self_v=v if self_contained else None,
                 self_mask=self_mask,
@@ -1376,23 +1416,23 @@ def _paged_suffix_forward(
         elif self_contained:
             attn = attention(
                 q,
-                jnp.concatenate([_paged_view(ckp, bt), k], axis=1),
-                jnp.concatenate([_paged_view(cvp, bt), v], axis=1),
+                jnp.concatenate([_paged_view(kp, layer, bt), k], axis=1),
+                jnp.concatenate([_paged_view(vp, layer, bt), v], axis=1),
                 causal=False, mask=mask,
             )
         else:
             attn = attention(
-                q, _paged_view(ckp, bt), _paged_view(cvp, bt),
+                q, _paged_view(kp, layer, bt), _paged_view(vp, layer, bt),
                 causal=False, mask=mask,
             )
         x = x + attn.reshape(B, S, cfg.n_heads * hd) @ deq(lp["wo"])
         h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, cfg.norm_plus_one)
         gate = _act(cfg)((h @ deq(lp["w_gate"])).astype(jnp.float32)).astype(h.dtype)
         x = x + (gate * (h @ deq(lp["w_up"]))) @ deq(lp["w_down"])
-        return x, (ckp, cvp)
+        return x, kp, vp
 
-    x, (new_k, new_v) = lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"])
+    x, new_k, new_v = _scan_layers_over_pools(
+        body, x, params["layers"], cache["k"], cache["v"]
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
     if self_contained:
@@ -1437,8 +1477,7 @@ def paged_prefill_batched(
     blk = jnp.where(writable, bt[:, posw // BS], 0)  # [B, S]
     off = jnp.broadcast_to((posw % BS)[None, :], (B, S))
 
-    def body(x, inp):
-        lp, ckp, cvp = inp
+    def body(x, kp, vp, lp, layer):  # kp/vp: [L, NB, BS, KV, hd], whole
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, cfg.norm_plus_one)
         q = apply_rope((h @ deq(lp["wq"])).reshape(B, S, cfg.n_heads, hd), cos, sin)
         k = apply_rope((h @ deq(lp["wk"])).reshape(B, S, cfg.n_kv_heads, hd), cos, sin)
@@ -1448,12 +1487,11 @@ def paged_prefill_batched(
         h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, cfg.norm_plus_one)
         gate = _act(cfg)((h @ deq(lp["w_gate"])).astype(jnp.float32)).astype(h.dtype)
         x = x + (gate * (h @ deq(lp["w_up"]))) @ deq(lp["w_down"])
-        ckp = ckp.at[blk, off].set(k)
-        cvp = cvp.at[blk, off].set(v)
-        return x, (ckp, cvp)
+        return (x, kp.at[layer, blk, off].set(k),
+                vp.at[layer, blk, off].set(v))
 
-    x, (new_k, new_v) = lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"])
+    x, new_k, new_v = _scan_layers_over_pools(
+        body, x, params["layers"], cache["k"], cache["v"]
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
     idx = jnp.maximum(lengths - 1, 0)

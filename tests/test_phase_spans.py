@@ -242,9 +242,16 @@ class TestProgramNames:
 
 
 class TestEnginePhases:
-    def test_phases_nest_inside_their_tick_on_the_scheduler_line(self, engine, tmp_path):
+    def test_phases_nest_inside_their_tick_on_the_scheduler_line(self, tmp_path):
+        # `serve` returns from inside the loop's last tick (its harvest hands
+        # the reply over), and a capture stopped there drops that tick's span
+        # and keeps its leaves: the loop is joined before the capture stops
+        eng = make_engine()
         with capture(tmp_path) as cap:
-            serve(engine, REQUESTS)
+            try:
+                serve(eng, REQUESTS)
+            finally:
+                eng.close()
         names = {e[1] for e in cap.events}
         assert {"engine.tick", "engine.admit", "engine.prefill_dispatch",
                 "engine.decode_dispatch", "engine.harvest_wait",

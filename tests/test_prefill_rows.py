@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from kubedl_tpu.models import llama
+from kubedl_tpu.models import llama, paged_attention
 from kubedl_tpu.observability.tracing import TRACER
 from tests.test_kv_blocks import _oracle
 
@@ -141,6 +141,98 @@ def test_every_row_in_order_is_the_all_rows_call(model):
 
 
 # ---- engine level ----------------------------------------------------------
+
+
+# ---- the pool carried through the layer scan: the same bits ------------------
+
+
+def _scanned_pools_forward(params, cache, tokens, lengths, starts, cfg, kv_attention):
+    """The form ``models/llama.py`` had before the pools were carried: the
+    write path of the suffix forward over every cache row, with this
+    layer's pool ``[NB, BS, KV, hd]`` scanned in as ``xs`` and stacked out
+    as ``ys``. Decode is its one-token case (``lengths`` 1, ``starts`` =
+    ``pos``). Returns (final-norm hidden states, K pool, V pool)."""
+    from jax import lax
+
+    nb, S = tokens.shape
+    hd, bt, bs = cfg.head_dim, cache["bt"], cache["k"].shape[2]
+    max_s = bt.shape[1] * bs
+    x = llama.gather_embed(params["embed"], tokens).astype(cfg.dtype)
+    cos, sin = llama.rope_freqs(cfg, max_s)
+    posq = jnp.minimum(starts[:, None] + jnp.arange(S)[None, :], max_s - 1)
+    cos_t, sin_t = cos[posq][:, :, None, :], sin[posq][:, :, None, :]
+    mask = (jnp.arange(max_s)[None, None, :] <= posq[:, :, None])[:, None, None]
+    writable = (lengths > 0)[:, None] & (jnp.arange(S)[None, :] < lengths[:, None])
+    blk = jnp.where(writable, bt[jnp.arange(nb)[:, None], posq // bs], 0)
+    off = posq % bs
+
+    def rot(t):
+        t1, t2 = jnp.split(t.astype(jnp.float32), 2, axis=-1)
+        return jnp.concatenate(
+            [t1 * cos_t - t2 * sin_t, t1 * sin_t + t2 * cos_t], axis=-1).astype(t.dtype)
+
+    def view(pool):
+        return pool[bt].reshape(nb, max_s, pool.shape[2], pool.shape[3])
+
+    def body(x, inp):
+        lp, ckp, cvp = inp
+        h = llama.rmsnorm(x, lp["attn_norm"], cfg.norm_eps, cfg.norm_plus_one)
+        q = rot((h @ lp["wq"]).reshape(nb, S, cfg.n_heads, hd))
+        k = rot((h @ lp["wk"]).reshape(nb, S, cfg.n_kv_heads, hd))
+        v = (h @ lp["wv"]).reshape(nb, S, cfg.n_kv_heads, hd)
+        ckp, cvp = ckp.at[blk, off].set(k), cvp.at[blk, off].set(v)
+        if kv_attention == "blocked":
+            attn = paged_attention.paged_attention(q, ckp, cvp, bt, starts)
+        else:
+            attn = llama.attention(q, view(ckp), view(cvp), causal=False, mask=mask)
+        x = x + attn.reshape(nb, S, cfg.n_heads * hd) @ lp["wo"]
+        h = llama.rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, cfg.norm_plus_one)
+        gate = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32)).astype(h.dtype)
+        x = x + (gate * (h @ lp["w_up"])) @ lp["w_down"]
+        return x, (ckp, cvp)
+
+    x, (new_k, new_v) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    return (llama.rmsnorm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one),
+            new_k, new_v)
+
+
+@pytest.mark.parametrize("kv_attention", ["gather", "blocked"])
+@pytest.mark.parametrize("program", ["decode", "prefill_from", "verify"])
+def test_carried_pools_are_the_scanned_pools_to_the_bit(model, program, kv_attention):
+    """Logits (ids for verify, with its hidden states) and both returned
+    pools of the carried form equal the xs/ys form's, bit for bit."""
+    cfg, params = model
+    cache = _cache_with_history(cfg, params)
+    head = llama.lm_head_of(params, cfg)
+    if program == "decode":
+        toks = jnp.asarray([[7], [11], [13], [17]], jnp.int32)
+        lens, starts = jnp.ones((B,), jnp.int32), cache["pos"]
+        got, new = llama.paged_decode_step_batched(
+            params, dict(cache), toks, cfg, kv_attention=kv_attention)
+        x, want_k, want_v = _scanned_pools_forward(
+            params, cache, toks, lens, starts, cfg, kv_attention)
+        want = (x[:, 0] @ head).astype(jnp.float32)
+    else:
+        toks = jnp.asarray(np.arange(1, 1 + B * 8, dtype=np.int32).reshape(B, 8))
+        lens, starts = jnp.asarray([8, 0, 3, 5], jnp.int32), cache["pos"]
+        x, want_k, want_v = _scanned_pools_forward(
+            params, cache, toks, lens, starts, cfg, kv_attention)
+        if program == "prefill_from":
+            got, new = llama.paged_prefill_from(
+                params, dict(cache), toks, lens, starts, cfg, kv_attention=kv_attention)
+            last = jnp.maximum(lens - 1, 0)[:, None, None]
+            want = (jnp.take_along_axis(x, last, axis=1)[:, 0] @ head).astype(jnp.float32)
+        else:
+            got, new = llama.paged_verify(
+                params, dict(cache), toks, lens, starts, cfg, kv_attention=kv_attention)
+            want = jnp.argmax((x @ head).astype(jnp.float32), axis=-1).astype(jnp.int32)
+            hidden, _ = llama._paged_suffix_forward(
+                params, dict(cache), toks, lens, starts, cfg, kv_attention=kv_attention)
+            assert np.array_equal(np.asarray(hidden), np.asarray(x))
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert np.array_equal(np.asarray(new["k"]), np.asarray(want_k))
+    assert np.array_equal(np.asarray(new["v"]), np.asarray(want_v))
+    assert not np.array_equal(np.asarray(new["k"]), np.asarray(cache["k"]))
 
 
 def _engine(chunk, **kw):
