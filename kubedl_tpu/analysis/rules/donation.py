@@ -4,7 +4,7 @@ Historical bugs pinned: PR 6 (checkpoint restore leaves zero-copied from
 aligned host arrays, donated on the first step, heap recycled under live
 weights) and PR 8 (``jnp.asarray`` borrowing the numpy ``self._bt_host``
 / ``self._pos_host`` mirrors while the donated cache let XLA alias
-segment outputs onto them). Canonical fix: ``serving/server.py``
+segment outputs onto them). Canonical fix: ``serving/model_runner.py``
 ``_upload_mirror`` — ``jnp.asarray(arr) + 0`` forces an XLA-owned buffer.
 
 What makes a borrow dangerous is *persistence*: ``jnp.asarray`` of a
@@ -20,7 +20,9 @@ harmless. The rule therefore flags, per file (given at least one
    recycle the borrowed numpy heap under live data — the PR 6 restore
    shape);
 3. ANY borrow stored into a **donated-cache attribute** (an attribute
-   that is itself passed at a donated position somewhere in the file).
+   that is itself passed at a donated position somewhere in the file),
+   or into an item of one (``self.cache["bt"] = jnp.asarray(bt)``: the
+   runner's shape, where the mirror arrives as an argument).
 
 Taint propagates through simple local assignment and is cleared by the
 defensive copies above.
@@ -192,7 +194,8 @@ class _TaintChecker(ast.NodeVisitor):
                 else:
                     self._tainted[-1].pop(t.id, None)
             else:
-                attr = _attr_key(t)
+                # ``self.X[...] = borrow`` lands in self.X as ``self.X = `` does
+                attr = _attr_key(t.value if isinstance(t, ast.Subscript) else t)
                 if attr and taint and attr in self.index.donated_attrs:
                     self.findings.append(self.ctx.finding(
                         RULE_ID, node,
@@ -219,7 +222,7 @@ class _TaintChecker(ast.NodeVisitor):
                     f"(jnp.asarray/np.frombuffer of a self attribute) "
                     f"passed to donated call {key}() at arg {i} without a "
                     f"defensive copy — the PR 8 aliasing bug shape "
-                    f"(see serving/server.py _upload_mirror)",
+                    f"(see serving/model_runner.py _upload_mirror)",
                 ))
             elif taint and i in pos:
                 self.findings.append(self.ctx.finding(

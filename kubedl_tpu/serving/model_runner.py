@@ -1,0 +1,414 @@
+"""The model's side of the serving engine: how the weights are made, the
+K/V arrays and every device program, behind one class.
+
+``LlamaEngine`` (serving/server.py) owns the schedule; what it schedules
+ONTO is a ``ModelRunner``. The scheduler never names the model; the runner
+never takes the engine's lock. Three facts live here and nowhere else:
+which family of device functions runs (paged or contiguous, gather or
+blocked attention: chosen once, in the constructor); the K/V row's format
+(the ``[L, NB, BS, KV, hd]`` pools, ``block_bytes``, block export/import);
+and which programs donate the cache (it lives here, every program that
+consumes it reassigns it here, and the engine's host mirrors reach it only
+through :meth:`upload_mirrors`). A new model kind brings a runner.
+"""
+
+from __future__ import annotations
+
+import functools
+import logging
+from typing import Dict, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from kubedl_tpu import chaos
+from kubedl_tpu.models import llama
+
+log = logging.getLogger("kubedl_tpu.serving.model_runner")
+
+
+def _named(name: str, fn):
+    """``fn`` under ``name``. JAX calls a jitted function's program
+    ``jit_<__name__>``, and that is the name a device profile's
+    ``XLA Modules`` line gives each execution — the only handle a trace
+    has for telling a prefill from a decode (docs/observability.md
+    "Device profiles"). A lambda or a ``functools.partial`` would read
+    ``jit__lambda`` / ``jit__unknown`` there."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+class ModelRunner:
+    """One model's config, K/V arrays and jitted programs. Programs take
+    ``params`` explicitly, so a second weight tree (hot swap) rides the
+    same compiles. ``llama.preset`` and ``llama.llama_init`` are looked up
+    on the module at call time: benchmark/program.py swaps both while it
+    builds an engine."""
+
+    def __init__(self, preset: str, *, max_batch: int, max_seq: int = 0,
+                 paged: bool = True, kv_block_size: int = 16,
+                 kv_attention: str = "gather", quantize: str = "",
+                 mesh_axes: Optional[Dict] = None, spec_k: int = 0,
+                 spec_candidates: int = 1, spec_tree: bool = False) -> None:
+        self.cfg = cfg = llama.preset(preset)
+        self.max_batch = max_batch
+        self.max_seq = max_seq or min(cfg.max_seq, 512)
+        self.paged = paged
+        if paged:
+            # the gathered view is [B, MB * BS]: max_seq rounds UP to a
+            # whole number of blocks so view position t == logical t
+            bs = max(1, int(kv_block_size))
+            self.kv_block_size = bs
+            self.max_seq = ((self.max_seq + bs - 1) // bs) * bs
+            #: bytes one block holds across both pools and all layers —
+            #: the unit prefix-cache budget accounting is charged in
+            self.block_bytes = int(
+                2 * cfg.n_layers * bs * cfg.n_kv_heads
+                * cfg.head_dim * np.dtype(cfg.dtype).itemsize
+            )
+        if quantize and quantize != "int8":
+            raise ValueError(f"unknown quantize mode {quantize!r}")
+        self.quantize = quantize
+        self.mesh = None
+        if mesh_axes:
+            # multi-chip serving (BASELINE target 5: Gemma-2B on v5e-4):
+            # megatron-shard the weights over the mesh; XLA inserts the
+            # collectives in the jitted decode/prefill
+            from kubedl_tpu.api.topology import MeshSpec
+            from kubedl_tpu.parallel.mesh import build_mesh
+
+            spec = MeshSpec({k: int(v) for k, v in mesh_axes.items()})
+            self.mesh = build_mesh(spec, jax.devices()[: spec.size()])
+            log.info("serving over mesh %s", dict(mesh_axes))
+        self.cache = None  # the K/V arrays, ``pos`` and ``bt``: new_cache()
+
+        # ---- the one place that picks the device-function family ----
+        if paged:
+            att = {"kv_attention": kv_attention}
+
+            def decode_step(p, c, t):
+                return llama.paged_decode_step_batched(p, c, t, cfg, **att)
+
+            # a paged prefill program computes a COMPACT batch: the cache
+            # rows ``rows`` that hold prompt tokens this dispatch, and no
+            # others (`LlamaEngine._dispatch_prefill`). Their last-token
+            # logits land at ``rows`` of ``acc``, the [max_batch, V] array
+            # the first-token sampler takes, so the sampler, its noise and
+            # the token chain stay indexed by cache row.
+            def prefill(p, c, t, l, rows, acc):
+                # whole-prompt prefill is LOCAL causal attention (no pool
+                # read), so there is nothing for the blocked kernel to do
+                lg, c = llama.paged_prefill_batched(p, c, t, l, cfg, rows=rows)
+                return acc.at[rows].set(lg), c
+
+            def prefill_from(p, c, t, l, st, rows, acc):
+                lg, c = llama.paged_prefill_from(
+                    p, c, t, l, st, cfg, rows=rows, **att)
+                return acc.at[rows].set(lg), c
+
+            #: ``acc`` of a tick's first prefill program: rows no program
+            #: computes sample from zeros, and nobody reads their token
+            self._no_logits = jnp.zeros(
+                (max_batch, cfg.vocab_size), jnp.float32
+            )
+            # paged prefix-cache ops: entries normally share the row's
+            # blocks by reference (no device copy at all); graft only
+            # fires for array-payload entries (direct inserts in tests)
+            graft, segment = llama.paged_graft_prefix, llama.paged_decode_segment
+        else:
+            att = {}
+
+            def decode_step(p, c, t):
+                return llama.decode_step_batched(p, c, t, cfg)
+
+            def prefill(p, c, t, l):
+                return llama.prefill_batched(p, c, t, l, cfg)
+
+            # suffix-only prefill (per-row start offsets): newly admitted
+            # rows with a grafted prefix consume only their uncached tail.
+            # Same power-of-2 bucketing as prefill, so compile count
+            # stays bounded (<= one per bucket per path).
+            def prefill_from(p, c, t, l, st):
+                return llama.prefill_batched_from(p, c, t, l, st, cfg)
+
+            # graft writes a cached entry's K/V into a row (donated:
+            # in-place in HBM). One compile per entry bucket length.
+            graft, segment = llama.copy_prefix_into_row, llama.decode_segment
+        self._segment = functools.partial(segment, **att)
+
+        # the cache is DONATED: decode/prefill update it in place in HBM
+        # instead of allocating a fresh copy every step
+        self._decode = jax.jit(
+            _named("engine_decode_step", decode_step), donate_argnums=(1,)
+        )
+        self._prefill = jax.jit(
+            _named("engine_prefill", prefill), donate_argnums=(1,)
+        )
+        self._prefill_from = jax.jit(
+            _named("engine_prefill_from", prefill_from), donate_argnums=(1,)
+        )
+        self._graft = jax.jit(
+            _named("engine_graft", lambda c, k, v, row, n: (
+                graft(c, k, v, row, n)
+            )),
+            donate_argnums=(0,),
+        )
+        #: the copy-on-write primitive for the partial tail block of a
+        #: paged graft or insert. One compile.
+        self._copy_block = jax.jit(
+            _named("engine_copy_block", lambda c, src, dst: (
+                llama.copy_kv_block(c, src, dst)
+            )),
+            donate_argnums=(0,),
+        ) if paged else None
+        #: copies a contiguous row's prefix span out as a new entry (NOT
+        #: donated — the live cache survives); paged inserts never
+        #: materialize arrays
+        self._extract = None if paged else jax.jit(
+            _named("engine_extract", lambda c, row, p_len: (
+                llama.extract_prefix_from_row(c, row, p_len)
+            )),
+            static_argnums=(2,),
+        )
+
+        # first-token sampler, ON DEVICE: fetching the prefill logits to
+        # sample on the host moved the full [B, V] array to the host —
+        # 8MB for Gemma-2B at B=8. Only the sampled ids ([B] int32)
+        # cross now.
+        def _pick(logits, temps, key):
+            g = jax.random.gumbel(key, logits.shape, dtype=logits.dtype)
+            z = jnp.where(
+                temps[:, None] > 0.0,
+                logits / jnp.maximum(temps[:, None], 1e-4) + g,
+                logits,
+            )
+            return jnp.argmax(z, axis=-1).astype(jnp.int32)
+
+        self.sample_first = jax.jit(_named("engine_sample_first", _pick))
+        #: grafts prefill-sampled first tokens into the device token chain
+        #: (llama.merge_chain_tokens) so interleaved admissions never force
+        #: the chain back through the host
+        self.merge_chain = jax.jit(
+            _named("engine_merge_chain", lambda last, ids, mask: (
+                llama.merge_chain_tokens(last, ids, mask)
+            ))
+        )
+        #: jitted multi-step decode segments keyed by (n_steps, greedy),
+        #: built on first use — llama.decode_segment
+        self._segments: Dict[tuple, object] = {}
+
+        # speculation's device half (paged only; the engine's `_spec_tick`
+        # holds the policy)
+        self._verify = self._verify_multi = self._verify_tree = None
+        if spec_k:
+            self._verify = jax.jit(
+                _named("engine_verify", lambda p, c, t, l, st: (
+                    llama.paged_verify(p, c, t, l, st, cfg, **att)
+                )),
+                donate_argnums=(1,),
+            )
+            #: multi-candidate scorer: READ-ONLY (cache NOT donated
+            #: and not returned, so XLA drops every cache write) —
+            #: the winner goes back through the standard _verify
+            self._verify_multi = jax.jit(
+                _named("engine_verify_multi", lambda p, c, t, l, st: (
+                    llama.paged_verify_multi(p, c, t, l, st, cfg, **att)
+                )),
+            ) if spec_candidates > 1 else None
+            #: tree scorer: like _verify_multi, READ-ONLY over the
+            #: trie layout; the walked winner goes back through the
+            #: standard write-path _verify. Fixed node budget
+            #: 1 + N*k -> one compile.
+            self._verify_tree = jax.jit(
+                _named("engine_verify_tree",
+                       lambda p, c, t, pos, m, l, st: (
+                           llama.paged_verify_tree(
+                               p, c, t, pos, m, l, st, cfg, **att)
+                       )),
+            ) if spec_tree else None
+
+    # -- weights ------------------------------------------------------------
+
+    def build_params(self, ckpt_dir: str, require_ckpt: bool = False):
+        """Build one servable parameter tree end to end: init → checkpoint
+        restore → optional int8 quantization → mesh sharding. The whole
+        pipeline runs OFF the dispatch path (init time or a hot-swap
+        load), and nothing is committed anywhere until it returns — a
+        failure at any stage leaves every already-serving version
+        untouched, never a torn tree. The ``serving.weight_swap`` chaos
+        site fires at the top so injected corrupt-artifact / mid-swap
+        crashes exercise exactly that contract.
+
+        ``require_ckpt`` (hot-swap loads): a version whose artifact is
+        missing or torn beyond recovery must FAIL the load — serving
+        freshly initialized random weights under a version id would be a
+        silent model swap. Init keeps the permissive behaviour (tests and
+        cold starts serve the preset without a checkpoint)."""
+        from kubedl_tpu.training import checkpoint
+
+        chaos.check("serving.weight_swap")
+        params = llama.llama_init(jax.random.PRNGKey(0), self.cfg)
+        step = checkpoint.latest_step(ckpt_dir) if ckpt_dir else None
+        if require_ckpt and step is None:
+            raise ValueError(f"no checkpoint found under {ckpt_dir!r}")
+        if ckpt_dir and step is not None:
+            state = checkpoint.restore_checkpoint(ckpt_dir, {"params": params})
+            if state is not None:
+                params = state["params"]
+                log.info("restored checkpoint from %s", ckpt_dir)
+            elif require_ckpt:
+                raise ValueError(
+                    f"no complete checkpoint step under {ckpt_dir!r} "
+                    "(every step torn/incomplete)"
+                )
+        if self.quantize == "int8":
+            # weight-only int8: decode is HBM-bound and weights dominate
+            # the bytes — halves the per-token floor (docs/serving.md)
+            params = llama.quantize_params(params, self.cfg)
+            log.info("serving with int8 weight-only quantization")
+        if self.mesh is not None:
+            params = llama.shard_serving_params(params, self.cfg, self.mesh)
+        return params
+
+    # -- the donated device state -------------------------------------------
+
+    def new_cache(self, kv_blocks: int = 0) -> None:
+        """(Re)build the K/V arrays, zeroed; a paged pool of ``kv_blocks``
+        blocks. Called by the engine's constructor and by its error
+        recovery: a program that raised after donation leaves ``cache``
+        pointing at deleted buffers."""
+        if self.paged:
+            self.cache = llama.init_paged_cache(
+                self.cfg, self.max_batch, self.max_seq, kv_blocks,
+                self.kv_block_size,
+            )
+        else:
+            self.cache = llama.init_batched_cache(
+                self.cfg, self.max_batch, self.max_seq
+            )
+
+    def _upload_mirror(self, arr):
+        """Upload a host mirror as an XLA-OWNED device buffer.
+
+        ``jnp.asarray`` zero-copy BORROWS an aligned numpy buffer, and the
+        cache is donated into every jitted dispatch — donating a
+        borrowed buffer lets XLA alias segment outputs onto it, which
+        either scribbles sampled tokens into the live mirror or hands the
+        harvest a stale view of the block table (both observed on the CPU
+        backend; whether a given numpy allocation is 64-byte aligned is
+        luck, hence flaky). The no-op add forces materialization into a
+        fresh buffer XLA owns outright. The add is dispatched
+        asynchronously, though, and the scheduler goes on editing the
+        mirror in place: it reads a private snapshot, or the device sees
+        whatever the mirror holds by the time the add runs (greedy
+        streams then differ from run to run on the CPU backend)."""
+        return jnp.asarray(arr.copy()) + 0
+
+    def upload_mirrors(self, bt, pos=None) -> None:
+        """Make the engine's authoritative HOST mirrors the paged cache's
+        block table and, when given, positions."""
+        if pos is not None:
+            self.cache["pos"] = self._upload_mirror(pos)
+        self.cache["bt"] = self._upload_mirror(bt)
+
+    def reset_row(self, row: int) -> None:
+        """Contiguous admission: position 0; stale KV is masked by pos."""
+        self.cache["pos"] = self.cache["pos"].at[row].set(0)
+
+    # -- the K/V row's format -----------------------------------------------
+
+    @property
+    def pool_shape(self) -> tuple:
+        return tuple(self.cache["k"].shape)
+
+    def fits_pool(self, kv_shape) -> bool:
+        """Whether exported blocks of shape ``[L, n, BS, KV, hd]`` can be
+        imported into this pool (any ``n``)."""
+        L, _nb, bs, kv, hd = self.pool_shape
+        return tuple(kv_shape[0:1]) + tuple(kv_shape[2:]) == (L, bs, kv, hd)
+
+    def export_blocks(self, blocks):
+        """``blocks``' K/V payloads as host arrays ``[L, n, BS, KV, hd]``
+        (a disaggregated handoff's body)."""
+        k, v = llama.export_kv_blocks(self.cache, blocks)
+        return np.array(jax.device_get(k)), np.array(jax.device_get(v))
+
+    def import_blocks(self, k, v, blocks) -> None:
+        """Scatter exported payloads into ``blocks`` of this pool."""
+        self.cache = llama.import_kv_blocks(self.cache, k, v, blocks)
+
+    def copy_block(self, src: int, dst: int) -> None:
+        self.cache = self._copy_block(self.cache, src, dst)
+
+    def graft(self, k, v, row: int, length: int) -> None:
+        """Write an array-payload prefix entry's K/V into ``row``."""
+        self.cache = self._graft(self.cache, k, v, row, length)
+
+    def extract(self, row: int, p_len: int):
+        """A contiguous row's first ``p_len`` positions as ``(k, v)``."""
+        return self._extract(self.cache, row, p_len)
+
+    # -- the programs, run on the runner's cache ----------------------------
+
+    def warmup(self, params) -> None:
+        # cache is donated — reassign, the old buffer is dead after the call
+        logits, self.cache = self._decode(
+            params, self.cache, jnp.zeros((self.max_batch, 1), jnp.int32),
+        )
+        jax.block_until_ready(logits)
+
+    def prefill(self, params, toks, lens, starts=None, rows=None, acc=None):
+        """One prefill program: whole prompts from position 0, or, given
+        ``starts``, suffixes that attend through the cache. Paged: ``rows``
+        names the compact batch's cache rows and ``acc`` holds the logits
+        of the tick's earlier programs (None: none yet). Returns the
+        ``[max_batch, V]`` logits."""
+        args = [toks, lens] if starts is None else [toks, lens, starts]
+        if self.paged:
+            args += [rows, self._no_logits if acc is None else acc]
+        fn = self._prefill if starts is None else self._prefill_from
+        logits, self.cache = fn(params, self.cache, *args)
+        return logits
+
+    def _segment_fn(self, n_steps: int, greedy: bool):
+        """Jitted n-step decode with on-device sampling (cache donated);
+        one compile per (segment size, greedy) combination."""
+        fn = self._segments.get((n_steps, greedy))
+        if fn is None:
+            seg, cfg = self._segment, self.cfg
+            # the step count is in the name: a module event of a device
+            # profile carries a duration and nothing else
+            name = f"engine_decode_seg{n_steps}" + ("" if greedy else "_sampled")
+            fn = jax.jit(
+                _named(name, lambda p, c, tokens, temps, key: seg(
+                    p, c, tokens, temps, key, cfg=cfg,
+                    n_steps=n_steps, greedy=greedy,
+                )),
+                donate_argnums=(1,),
+            )
+            self._segments[(n_steps, greedy)] = fn
+        return fn
+
+    def decode_segment(self, n_steps: int, greedy: bool, params, tokens,
+                       temps, key):
+        """``n_steps`` decode steps, sampled on the device. Returns
+        ``(toks [B, n_steps], last [B, 1], key)``."""
+        toks, last, key, self.cache = self._segment_fn(n_steps, greedy)(
+            params, self.cache, tokens, temps, key,
+        )
+        return toks, last, key
+
+    def verify(self, params, toks, lens, starts):
+        """The write-path verify: consume ``toks`` from ``starts``, return
+        the target's argmax after each input ``[B, S]``."""
+        ids, self.cache = self._verify(params, self.cache, toks, lens, starts)
+        return ids
+
+    def verify_multi(self, params, cand_toks, lens, starts):
+        return self._verify_multi(params, self.cache, cand_toks, lens, starts)
+
+    def verify_tree(self, params, toks, pos, mask, lens, starts):
+        return self._verify_tree(
+            params, self.cache, toks, pos, mask, lens, starts
+        )

@@ -65,15 +65,15 @@ class UnknownModelVersion(ValueError):
     to a replica that cannot know the version either."""
 
 
-def _named(name: str, fn):
-    """``fn`` under ``name``. JAX calls a jitted function's program
-    ``jit_<__name__>``, and that is the name a device profile's
-    ``XLA Modules`` line gives each execution — the only handle a trace
-    has for telling a prefill from a decode (docs/observability.md
-    "Device profiles"). A lambda or a ``functools.partial`` would read
-    ``jit__lambda`` / ``jit__unknown`` there."""
-    fn.__name__ = fn.__qualname__ = name
-    return fn
+def _to_host(dev):
+    """A device array's value as a numpy array the host owns (blocks until
+    the device has it). The copy matters: ``device_get`` may return a
+    zero-copy VIEW of the device buffer, which a later donated dispatch
+    can reuse."""
+    import jax
+    import numpy as np
+
+    return np.array(jax.device_get(dev))
 
 
 class _Slot:
@@ -167,7 +167,7 @@ class LlamaEngine:
                  model_version: str = "base") -> None:
         import jax
 
-        from kubedl_tpu.models import llama
+        from kubedl_tpu.serving.model_runner import ModelRunner
 
         if kv_layout not in ("paged", "contiguous"):
             raise ValueError(f"unknown kv_layout {kv_layout!r}")
@@ -220,15 +220,21 @@ class LlamaEngine:
         self.spec_tree = (
             bool(spec_tree) and self.spec_k > 0 and self.spec_candidates > 1
         )
-        self.cfg = llama.preset(preset)
-        self.max_seq = max_seq or min(self.cfg.max_seq, 512)
         self.max_batch = batch or max_batch
+        #: the model's side (serving/model_runner.py): the weights' making,
+        #: the K/V arrays and every device program. Nothing below names
+        #: the model; the runner never takes ``_cv``.
+        self._runner = runner = ModelRunner(
+            preset, max_batch=self.max_batch, max_seq=max_seq,
+            paged=self._paged, kv_block_size=kv_block_size,
+            kv_attention=self.kv_attention, quantize=quantize,
+            mesh_axes=mesh_axes, spec_k=self.spec_k,
+            spec_candidates=self.spec_candidates, spec_tree=self.spec_tree,
+        )
+        self.cfg = runner.cfg
+        self.max_seq = runner.max_seq
         if self._paged:
-            # the gathered view is [B, MB * BS]: max_seq rounds UP to a
-            # whole number of blocks so view position t == logical t
-            bs = max(1, int(kv_block_size))
-            self.kv_block_size = bs
-            self.max_seq = ((self.max_seq + bs - 1) // bs) * bs
+            self.kv_block_size = runner.kv_block_size
         #: chunked prefill (docs/serving.md "Continuous batching"): > 0
         #: caps the PROMPT tokens one scheduler tick may prefill, so
         #: long prompts land block-sized chunk by chunk, interleaved
@@ -242,22 +248,6 @@ class LlamaEngine:
             self.prefill_chunk_tokens = pct
         else:
             self.prefill_chunk_tokens = 0
-        if quantize and quantize != "int8":
-            raise ValueError(f"unknown quantize mode {quantize!r}")
-        self.quantize = quantize
-        self.mesh = None
-        if mesh_axes:
-            # multi-chip serving (BASELINE target 5: Gemma-2B on v5e-4):
-            # megatron-shard the weights over the mesh; XLA inserts the
-            # collectives in the jitted decode/prefill
-            from kubedl_tpu.api.topology import MeshSpec
-            from kubedl_tpu.parallel.mesh import build_mesh
-
-            spec = MeshSpec({k: int(v) for k, v in mesh_axes.items()})
-            self.mesh = build_mesh(spec, jax.devices()[: spec.size()])
-            log.info("serving over mesh %s", dict(mesh_axes))
-        self._llama = llama
-        self._jax = jax
         #: versioned weights (docs/serving.md "Model lifecycle"): every
         #: loaded parameter tree lives here under a version id; the
         #: default version serves requests that name none. All jitted
@@ -265,7 +255,7 @@ class LlamaEngine:
         #: tree rides the SAME compiles — hot-swap is just passing a
         #: different pytree.
         self._default_version = str(model_version) or "base"
-        params = self._build_params(ckpt_dir)
+        params = runner.build_params(ckpt_dir)
         self.params = params
         self._versions: Dict[str, object] = {self._default_version: params}
         #: versions drained-and-awaiting-eviction: unroutable for new
@@ -273,148 +263,14 @@ class LlamaEngine:
         #: row frees — never while a row still dispatches on them
         self._retiring: set = set()
         self._vers_rr = 0
-        # the cache is DONATED: decode/prefill update it in place in HBM
-        # instead of allocating a fresh copy every step
-        if self._paged:
-            self._decode = jax.jit(
-                _named("engine_decode_step", lambda p, c, t: (
-                    llama.paged_decode_step_batched(
-                        p, c, t, self.cfg, kv_attention=self.kv_attention
-                    )
-                )),
-                donate_argnums=(1,),
-            )
-            # a paged prefill program computes a COMPACT batch: the cache
-            # rows ``rows`` that hold prompt tokens this dispatch, and no
-            # others (`_dispatch_prefill`). Their last-token logits land
-            # at ``rows`` of ``acc``, the [max_batch, V] array the
-            # first-token sampler takes, so the sampler, its noise and
-            # the token chain stay indexed by cache row.
-            def _prefill_rows(p, c, t, l, rows, acc):
-                # whole-prompt prefill is LOCAL causal attention (no pool
-                # read), so there is nothing for the blocked kernel to do
-                lg, c = llama.paged_prefill_batched(
-                    p, c, t, l, self.cfg, rows=rows
-                )
-                return acc.at[rows].set(lg), c
-
-            def _prefill_from_rows(p, c, t, l, st, rows, acc):
-                lg, c = llama.paged_prefill_from(
-                    p, c, t, l, st, self.cfg,
-                    kv_attention=self.kv_attention, rows=rows,
-                )
-                return acc.at[rows].set(lg), c
-
-            self._prefill = jax.jit(
-                _named("engine_prefill", _prefill_rows),
-                donate_argnums=(1,),
-            )
-            self._prefill_from = jax.jit(
-                _named("engine_prefill_from", _prefill_from_rows),
-                donate_argnums=(1,),
-            )
-            #: ``acc`` of a tick's first prefill program: rows no program
-            #: computes sample from zeros, and nobody reads their token
-            self._no_logits = jax.numpy.zeros(
-                (self.max_batch, self.cfg.vocab_size), jax.numpy.float32
-            )
-            #: paged prefix-cache ops: entries normally share the row's
-            #: blocks by reference (no device copy at all); _graft only
-            #: fires for array-payload entries (direct inserts in tests),
-            #: and _copy_block is the copy-on-write primitive for the
-            #: partial tail block of a graft. One compile each.
-            self._graft = jax.jit(
-                _named("engine_graft", lambda c, k, v, row, n: (
-                    llama.paged_graft_prefix(c, k, v, row, n)
-                )),
-                donate_argnums=(0,),
-            )
-            self._copy_block = jax.jit(
-                _named("engine_copy_block", lambda c, src, dst: (
-                    llama.copy_kv_block(c, src, dst)
-                )),
-                donate_argnums=(0,),
-            )
-            self._extract = None  # paged inserts never materialize arrays
-        else:
-            self._decode = jax.jit(
-                _named("engine_decode_step", lambda p, c, t: (
-                    llama.decode_step_batched(p, c, t, self.cfg)
-                )),
-                donate_argnums=(1,),
-            )
-            self._prefill = jax.jit(
-                _named("engine_prefill", lambda p, c, t, l: (
-                    llama.prefill_batched(p, c, t, l, self.cfg)
-                )),
-                donate_argnums=(1,),
-            )
-            #: suffix-only prefill (per-row start offsets): newly admitted
-            #: rows with a grafted prefix consume only their uncached tail.
-            #: Same power-of-2 bucketing as _prefill, so compile count
-            #: stays bounded (<= one per bucket per path).
-            self._prefill_from = jax.jit(
-                _named("engine_prefill_from", lambda p, c, t, l, st: (
-                    llama.prefill_batched_from(p, c, t, l, st, self.cfg)
-                )),
-                donate_argnums=(1,),
-            )
-            #: prefix-cache device ops: graft writes a cached entry's K/V
-            #: into a row (donated: in-place in HBM), extract copies a
-            #: row's prefix span out as a new entry (NOT donated — the
-            #: live cache survives). One compile per entry bucket length.
-            self._graft = jax.jit(
-                _named("engine_graft", lambda c, k, v, row, n: (
-                    llama.copy_prefix_into_row(c, k, v, row, n)
-                )),
-                donate_argnums=(0,),
-            )
-            self._extract = jax.jit(
-                _named("engine_extract", lambda c, row, p_len: (
-                    llama.extract_prefix_from_row(c, row, p_len)
-                )),
-                static_argnums=(2,),
-            )
-        # first-token sampler, ON DEVICE: fetching the prefill logits to
-        # sample on the host moved the full [B, V] array to the host —
-        # 8MB for Gemma-2B at B=8. Only the sampled ids ([B] int32)
-        # cross now.
-        import jax.numpy as _jnp
-
-        def _pick(logits, temps, key):
-            g = jax.random.gumbel(key, logits.shape, dtype=logits.dtype)
-            z = _jnp.where(
-                temps[:, None] > 0.0,
-                logits / _jnp.maximum(temps[:, None], 1e-4) + g,
-                logits,
-            )
-            return _jnp.argmax(z, axis=-1).astype(_jnp.int32)
-
-        self._sample_logits = jax.jit(_named("engine_sample_first", _pick))
-        #: grafts prefill-sampled first tokens into the device token chain
-        #: (llama.merge_chain_tokens) so interleaved admissions never force
-        #: the chain back through the host
-        self._merge_chain = jax.jit(
-            _named("engine_merge_chain", lambda last, ids, mask: (
-                llama.merge_chain_tokens(last, ids, mask)
-            ))
-        )
+        self.kv_blocks = 0
+        self._draft = self._spec_stats = None
         if self._paged:
             import math
 
-            import numpy as np
-
-            from kubedl_tpu.serving.kv_blocks import BlockAllocator
             from kubedl_tpu.serving.speculative import SpecStats, make_draft
 
-            bs = self.kv_block_size
-            mb = self.max_seq // bs
-            #: bytes one block holds across both pools and all layers —
-            #: the unit prefix-cache budget accounting is charged in
-            self._block_bytes = int(
-                2 * self.cfg.n_layers * bs * self.cfg.n_kv_heads
-                * self.cfg.head_dim * np.dtype(self.cfg.dtype).itemsize
-            )
+            mb = self.max_seq // self.kv_block_size
             if kv_blocks:
                 nb = int(kv_blocks)
                 if nb < mb + 1:
@@ -429,102 +285,22 @@ class LlamaEngine:
                 prefix_blocks = 0
                 if prefix_cache_mb > 0:
                     prefix_blocks = min(
-                        math.ceil(prefix_cache_mb * 1e6 / self._block_bytes),
+                        math.ceil(prefix_cache_mb * 1e6 / runner.block_bytes),
                         self.max_batch * mb,
                     )
                 nb = 1 + self.max_batch * mb + prefix_blocks
             self.kv_blocks = nb
-            self._alloc = BlockAllocator(
-                nb, bs, low_watermark=kv_low_watermark,
-                high_watermark=kv_high_watermark,
-            )
-            #: host-authoritative mirrors of the device cache's pos/bt —
-            #: uploaded before EVERY dispatch so rollbacks (speculative
-            #: rejection, preemption, vacation) are just mirror edits
-            self._pos_host = np.zeros((self.max_batch,), np.int32)
-            self._bt_host = np.zeros((self.max_batch, mb), np.int32)
-            self._row_blocks: list = [[] for _ in range(self.max_batch)]
-            self._cache = llama.init_paged_cache(
-                self.cfg, self.max_batch, self.max_seq, nb, bs
-            )
+            self._new_block_state(kv_low_watermark, kv_high_watermark)
             self.spec_draft = spec_draft
             if self.spec_k:
-                if spec_draft == "model":
-                    from kubedl_tpu.serving.speculative import ModelDraft
-
-                    # early-exit draft carved out of the target's own
-                    # stacked weights (views, no copies); depth defaults
-                    # to half the target
-                    n_draft = spec_draft_layers or max(
-                        1, self.cfg.n_layers // 2
-                    )
-                    self._draft = ModelDraft.from_target(
-                        self.params, self.cfg, n_layers=n_draft,
-                        max_context=self.max_seq,
-                    )
-                elif spec_draft.startswith("zoo:"):
-                    # trained small-model draft shaped by the planner
-                    # MODEL_ZOO; KUBEDL_SPEC_DRAFT_CKPT restores weights
-                    # saved after distillation (fresh weights propose
-                    # noise — harmless, just zero acceptance)
-                    from kubedl_tpu.serving.speculative import ModelDraft
-
-                    ckpt = os.environ.get("KUBEDL_SPEC_DRAFT_CKPT", "")
-                    self._draft = ModelDraft.from_zoo(
-                        spec_draft.split(":", 1)[1], self.cfg,
-                        ckpt_path=ckpt or None,
-                        max_context=self.max_seq,
-                    )
-                else:
-                    self._draft = make_draft(spec_draft)
-                self._spec_stats = SpecStats()
-                self._verify = jax.jit(
-                    _named("engine_verify", lambda p, c, t, l, st: (
-                        llama.paged_verify(
-                            p, c, t, l, st, self.cfg,
-                            kv_attention=self.kv_attention,
-                        )
-                    )),
-                    donate_argnums=(1,),
+                self._draft = make_draft(
+                    spec_draft, params=self.params, cfg=self.cfg,
+                    max_context=self.max_seq, n_layers=spec_draft_layers,
                 )
-                #: multi-candidate scorer: READ-ONLY (cache NOT donated
-                #: and not returned, so XLA drops every cache write) —
-                #: the winner goes back through the standard _verify
-                self._verify_multi = jax.jit(
-                    _named("engine_verify_multi", lambda p, c, t, l, st: (
-                        llama.paged_verify_multi(
-                            p, c, t, l, st, self.cfg,
-                            kv_attention=self.kv_attention,
-                        )
-                    )),
-                ) if self.spec_candidates > 1 else None
-                #: tree scorer: like _verify_multi, READ-ONLY over the
-                #: trie layout; the walked winner goes back through the
-                #: standard write-path _verify. Fixed node budget
-                #: 1 + N*k -> one compile.
+                self._spec_stats = SpecStats()
+                #: the tree scorer's fixed node budget (one compile)
                 self._spec_tree_m = 1 + self.spec_candidates * self.spec_k
-                self._verify_tree = jax.jit(
-                    _named("engine_verify_tree",
-                           lambda p, c, t, pos, m, l, st: (
-                               llama.paged_verify_tree(
-                                   p, c, t, pos, m, l, st, self.cfg,
-                                   kv_attention=self.kv_attention,
-                               )
-                           )),
-                ) if self.spec_tree else None
-            else:
-                self._draft = None
-                self._spec_stats = None
-                self._verify_multi = None
-                self._verify_tree = None
-        else:
-            self._cache = llama.init_batched_cache(
-                self.cfg, self.max_batch, self.max_seq
-            )
-            self._draft = None
-            self._spec_stats = None
-            self._verify_multi = None
-            self._verify_tree = None
+        runner.new_cache(self.kv_blocks)
         from collections import deque as _deque
 
         self._slots: list = [None] * self.max_batch
@@ -563,9 +339,7 @@ class LlamaEngine:
         #: request_id -> slot for requests that opted into cancellation
         #: (the router's hedge-loser path)
         self._requests: Dict[str, _Slot] = {}
-        #: jitted multi-step decode segments keyed by (n_steps, greedy)
-        #: + the PRNG chain for on-device sampling — llama.decode_segment
-        self._segments: Dict[tuple, object] = {}
+        #: the PRNG chain for on-device sampling (a segment's output)
         self._key = jax.random.PRNGKey(0)
         #: device-chained feed between segments: (prefill_gen, rows,
         #: last-token device array) where ``rows`` are the rows whose
@@ -624,53 +398,11 @@ class LlamaEngine:
         #: gets its own p50/p95 in stats() and the Poisson bench arm
         self._queue_wait_recent: "deque[float]" = deque(maxlen=4096)
         self.qps_window_s = 60.0
-        self._warmup()
+        runner.warmup(self.params)
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="decode-scheduler"
         )
         self._thread.start()
-
-    def _build_params(self, ckpt_dir: str, require_ckpt: bool = False):
-        """Build one servable parameter tree end to end: init → checkpoint
-        restore → optional int8 quantization → mesh sharding. The whole
-        pipeline runs OFF the dispatch path (init time or a hot-swap
-        load), and nothing is committed anywhere until it returns — a
-        failure at any stage leaves every already-serving version
-        untouched, never a torn tree. The ``serving.weight_swap`` chaos
-        site fires at the top so injected corrupt-artifact / mid-swap
-        crashes exercise exactly that contract.
-
-        ``require_ckpt`` (hot-swap loads): a version whose artifact is
-        missing or torn beyond recovery must FAIL the load — serving
-        freshly initialized random weights under a version id would be a
-        silent model swap. Init keeps the permissive behaviour (tests and
-        cold starts serve the preset without a checkpoint)."""
-        from kubedl_tpu.training import checkpoint
-
-        llama, jax = self._llama, self._jax
-        chaos.check("serving.weight_swap")
-        params = llama.llama_init(jax.random.PRNGKey(0), self.cfg)
-        step = checkpoint.latest_step(ckpt_dir) if ckpt_dir else None
-        if require_ckpt and step is None:
-            raise ValueError(f"no checkpoint found under {ckpt_dir!r}")
-        if ckpt_dir and step is not None:
-            state = checkpoint.restore_checkpoint(ckpt_dir, {"params": params})
-            if state is not None:
-                params = state["params"]
-                log.info("restored checkpoint from %s", ckpt_dir)
-            elif require_ckpt:
-                raise ValueError(
-                    f"no complete checkpoint step under {ckpt_dir!r} "
-                    "(every step torn/incomplete)"
-                )
-        if self.quantize == "int8":
-            # weight-only int8: decode is HBM-bound and weights dominate
-            # the bytes — halves the per-token floor (docs/serving.md)
-            params = llama.quantize_params(params, self.cfg)
-            log.info("serving with int8 weight-only quantization")
-        if self.mesh is not None:
-            params = llama.shard_serving_params(params, self.cfg, self.mesh)
-        return params
 
     # -- versioned weights / hot swap (docs/serving.md "Model lifecycle") --
 
@@ -700,7 +432,7 @@ class LlamaEngine:
                 )
             if version in self._versions:
                 return
-        params = self._build_params(ckpt_dir, require_ckpt=True)
+        params = self._runner.build_params(ckpt_dir, require_ckpt=True)
         with self._cv:
             self._versions[version] = params
         log.info("hot-loaded model version %r from %s", version, ckpt_dir)
@@ -811,16 +543,6 @@ class LlamaEngine:
         self._vers_rr = (self._vers_rr + 1) % len(vers)
         return vers[self._vers_rr]
 
-    def _warmup(self) -> None:
-        import jax.numpy as jnp
-
-        # cache is donated — reassign, the old buffer is dead after the call
-        logits, self._cache = self._decode(
-            self.params, self._cache,
-            jnp.zeros((self.max_batch, 1), jnp.int32),
-        )
-        self._jax.block_until_ready(logits)
-
     def close(self) -> None:
         with self._cv:
             self._stop = True
@@ -877,19 +599,43 @@ class LlamaEngine:
             slot = self._requests.pop(request_id, None)
             if slot is None or slot.done.is_set():
                 return False
-            try:
-                self._waiting.remove(slot)
-            except ValueError:
-                pass
-            for i, s in enumerate(self._slots):
-                if s is slot:
-                    self._slots[i] = None
-                    self._free_row_locked(i)
-            self._release_prefix_locked(slot)
+            self._vacate_locked(slot)
             slot.result = {"error": "cancelled", "cancelled": True}
             slot.done.set()
             self._cv.notify_all()
         return True
+
+    def _vacate_locked(self, slot: _Slot) -> None:
+        """Take ``slot`` out of the queue and out of its row: a cancelled
+        or abandoned request must not keep occupying a batch slot (and
+        decode work) under overload, nor keep its prefix-cache entry
+        pinned — the pin would block eviction for good. Caller holds cv."""
+        try:
+            self._waiting.remove(slot)
+        except ValueError:
+            pass
+        for i, s in enumerate(self._slots):
+            if s is slot:
+                self._slots[i] = None
+                self._free_row_locked(i)
+        self._release_prefix_locked(slot)
+
+    def _await_slot(self, slot: _Slot, timeout_s: float) -> Dict:
+        """The scheduler's result for ``slot``, counted into stats(); one
+        that does not come in ``timeout_s`` is vacated and reads
+        ``timed_out``."""
+        if not slot.done.wait(timeout=timeout_s):
+            with self._cv:
+                self._vacate_locked(slot)
+        result = slot.result or {"error": "timed out", "timed_out": True}
+        with self._cv:
+            if slot.request_id:
+                self._requests.pop(slot.request_id, None)
+            self._stats["requests"] += 1
+            self._stats["tokens_in"] += len(slot.prompt)
+            self._stats["tokens_out"] += len(result.get("token_ids", []))
+            self._recent.append(time.time())
+        return result
 
     # -- distributed tracing (docs/observability.md) -----------------------
 
@@ -967,71 +713,10 @@ class LlamaEngine:
         max_tokens = max(0, min(int(max_tokens), budget - len(prompt)))
         slot = _Slot(prompt, max_tokens, float(temperature), cache_prefix,
                      request_id=request_id)
+        slot.version = str(model_version or "")
         self._arm_trace(slot, trace, debug_trace)
-        with self._cv:
-            slot.version = self._resolve_version_locked(model_version)
-            if self._draining:
-                self._stats["drain_rejects"] += 1
-                raise EngineOverloaded(
-                    "engine is draining", retry_after_s=1.0,
-                    reason="draining",
-                )
-            depth = len(self._waiting)
-            head_age = (
-                time.perf_counter() - self._waiting[0].t0 if self._waiting else 0.0
-            )
-            if depth >= self.max_queue_depth or head_age > self.max_queue_age_s:
-                # shed instead of queueing: an over-budget queue serves
-                # nobody well — tell the client when to come back and let
-                # the autoscaler see the rejected demand as backlog
-                self._stats["shed"] += 1
-                self._shed_recent.append(time.time())
-                self.metrics.shed_requests.inc()
-                retry = max(1.0, min(self.max_queue_age_s, 0.25 * depth))
-                raise EngineOverloaded(
-                    f"queue depth {depth} (budget {self.max_queue_depth}), "
-                    f"head age {head_age:.1f}s (budget {self.max_queue_age_s}s)",
-                    retry_after_s=retry,
-                )
-            if self._paged and not self._alloc.admission_open():
-                # KV-pool pressure sheds too: below the low watermark a
-                # queued request cannot be admitted anyway, so reject at
-                # the door (hysteresis reopens at the high watermark)
-                self._stats["shed"] += 1
-                self._stats["kv_sheds"] += 1
-                self._shed_recent.append(time.time())
-                self.metrics.shed_requests.inc()
-                self.metrics.kv_block_sheds.inc()
-                raise EngineOverloaded(
-                    f"free KV blocks {self._alloc.free_count}/"
-                    f"{self._alloc.total} below low watermark",
-                    retry_after_s=1.0,
-                )
-            self._waiting.append(slot)
-            if request_id:
-                self._requests[request_id] = slot
-            self._cv.notify_all()
-        if not slot.done.wait(timeout=timeout_s):
-            # free the row/queue entry: an abandoned request must not keep
-            # occupying a batch slot (and decode work) under overload
-            with self._cv:
-                if slot in self._waiting:
-                    self._waiting.remove(slot)
-                for i, s in enumerate(self._slots):
-                    if s is slot:
-                        self._slots[i] = None
-                        self._free_row_locked(i)
-                # a vacated row must not keep its prefix-cache entry
-                # pinned forever — the pin would block eviction for good
-                self._release_prefix_locked(slot)
-        result = slot.result or {"error": "timed out", "timed_out": True}
-        with self._cv:
-            if request_id:
-                self._requests.pop(request_id, None)
-            self._stats["requests"] += 1
-            self._stats["tokens_in"] += len(prompt)
-            self._stats["tokens_out"] += len(result.get("token_ids", []))
-            self._recent.append(time.time())
+        self._enqueue_slot_locked_checks(slot)
+        result = self._await_slot(slot, timeout_s)
         return self._trace_result(slot, result, debug_trace)
 
     def stats(self) -> Dict:
@@ -1055,11 +740,13 @@ class LlamaEngine:
             draining = self._draining
             parked_handoffs = len(self._handoffs)
         up = max(now - out["started_at"], 1e-9)
-        dev = self._jax.devices()[0]
+        import jax
+
+        dev = jax.devices()[0]
         # which device the counters below were taken on
         out["device"] = {"platform": dev.platform,
                          "device_kind": dev.device_kind,
-                         "count": self._jax.device_count()}
+                         "count": jax.device_count()}
         out["role"] = self.role
         out["handoffs_parked"] = parked_handoffs
         # surfaced so both the router (stop picking this replica, don't
@@ -1075,18 +762,13 @@ class LlamaEngine:
         out["max_batch"] = self.max_batch
         out["queued"] = queued
         out["shed_recent"] = shed_recent
-        if ttft:
-            srt = sorted(ttft)
-            out["ttft_ms_p50"] = round(srt[len(srt) // 2], 3)
-            out["ttft_ms_p95"] = round(
-                srt[min(len(srt) - 1, int(len(srt) * 0.95))], 3
-            )
-        if qwait:
-            srt = sorted(qwait)
-            out["queue_wait_ms_p50"] = round(srt[len(srt) // 2], 3)
-            out["queue_wait_ms_p95"] = round(
-                srt[min(len(srt) - 1, int(len(srt) * 0.95))], 3
-            )
+        for name, samples in (("ttft_ms", ttft), ("queue_wait_ms", qwait)):
+            if samples:
+                srt = sorted(samples)
+                out[f"{name}_p50"] = round(srt[len(srt) // 2], 3)
+                out[f"{name}_p95"] = round(
+                    srt[min(len(srt) - 1, int(len(srt) * 0.95))], 3
+                )
         if self._pcache is not None:
             out["prefix_cache"] = self._pcache.stats()
             # block-aware affinity advertisement: digests of the cached
@@ -1194,21 +876,19 @@ class LlamaEngine:
                 got = self._alloc.alloc(1)
                 if got is None:
                     return  # pool pressure: skip the insert
-                self._cache = self._copy_block(
-                    self._cache, row_blocks[full], got[0]
-                )
+                self._runner.copy_block(row_blocks[full], got[0])
                 blocks.append(got[0])
             self._alloc.incref(blocks[:full])
             ok = self._pcache.insert(
                 s.prompt[:cand], None, None, cand,
                 blocks=tuple(blocks),
-                nbytes=len(blocks) * self._block_bytes,
+                nbytes=len(blocks) * self._runner.block_bytes,
             )
             if not ok:
                 self._alloc.free(blocks)  # duplicate/over-budget: undo
                 return
         else:
-            k, v = self._extract(self._cache, i, self._prefill_bucket(cand))
+            k, v = self._runner.extract(i, self._prefill_bucket(cand))
             if not self._pcache.insert(s.prompt[:cand], k, v, cand):
                 return
         st = self._pcache.stats()
@@ -1223,22 +903,23 @@ class LlamaEngine:
 
     # -- paged KV bookkeeping (host mirrors + block lifecycle) -------------
 
-    def _upload_mirror(self, arr):
-        """Upload a host mirror as an XLA-OWNED device buffer.
+    def _new_block_state(self, low: float, high: float) -> None:
+        """Every block free, no row owning any: the constructor's state
+        and, the pool rebuilt, recovery's. ``_pos_host``/``_bt_host`` are
+        the host-authoritative mirrors of the device cache's pos/bt —
+        uploaded before EVERY dispatch so rollbacks (speculative
+        rejection, preemption, vacation) are just mirror edits."""
+        import numpy as np
 
-        ``jnp.asarray`` zero-copy BORROWS an aligned numpy buffer, and the
-        engine donates the cache into every jitted dispatch — donating a
-        borrowed buffer lets XLA alias segment outputs onto it, which
-        either scribbles sampled tokens into the live mirror or hands the
-        harvest a stale view of the block table (both observed on the CPU
-        backend; whether a given numpy allocation is 64-byte aligned is
-        luck, hence flaky). The no-op add forces materialization into a
-        fresh buffer XLA owns outright. The add is dispatched
-        asynchronously, though, and the scheduler goes on editing the
-        mirror in place: it reads a private snapshot, or the device sees
-        whatever the mirror holds by the time the add runs (greedy
-        streams then differ from run to run on the CPU backend)."""
-        return self._jax.numpy.asarray(arr.copy()) + 0
+        from kubedl_tpu.serving.kv_blocks import BlockAllocator
+
+        bs = self.kv_block_size
+        self._alloc = BlockAllocator(
+            self.kv_blocks, bs, low_watermark=low, high_watermark=high,
+        )
+        self._pos_host = np.zeros((self.max_batch,), np.int32)
+        self._bt_host = np.zeros((self.max_batch, self.max_seq // bs), np.int32)
+        self._row_blocks: list = [[] for _ in range(self.max_batch)]
 
     def _free_row_locked(self, i: int) -> None:
         """Return row ``i``'s blocks to the pool and point its table rows
@@ -1296,7 +977,7 @@ class LlamaEngine:
         cache entries are an optimization, resident rows are work."""
         if self._pcache is None or not self._paged:
             return False
-        return self._pcache.reclaim(self._block_bytes) > 0
+        return self._pcache.reclaim(self._runner.block_bytes) > 0
 
     def _pick_victim_locked(self, held) -> Optional[int]:
         """Pick the preemption victim: the YOUNGEST resident row (latest
@@ -1363,8 +1044,6 @@ class LlamaEngine:
         block, and allocate fresh blocks for the suffix. All-or-nothing:
         on pool exhaustion every side effect is rolled back and the slot
         stays queued. Caller holds cv."""
-        import jax.numpy as jnp
-
         a = self._alloc
         bs = self.kv_block_size
         need_total = a.blocks_for(min(len(slot.prompt) + 1, self.max_seq))
@@ -1398,7 +1077,7 @@ class LlamaEngine:
             # this row's suffix prefill appends inside it — copy before
             # any divergent write can land
             tail_copy = got.pop(0)
-            self._cache = self._copy_block(self._cache, tail_src, tail_copy)
+            self._runner.copy_block(tail_src, tail_copy)
             blocks.append(tail_copy)
         blocks.extend(got)
         self._row_blocks[i] = blocks
@@ -1421,8 +1100,8 @@ class LlamaEngine:
         if not entry_blocks:
             # array-payload entry (direct insert): scatter its K/V into
             # the row's fresh blocks through the just-updated table
-            self._cache["bt"] = self._upload_mirror(self._bt_host)
-            self._cache = self._graft(self._cache, entry.k, entry.v, i, mlen)
+            self._runner.upload_mirrors(self._bt_host)
+            self._runner.graft(entry.k, entry.v, i, mlen)
         return True
 
     def _admit_locked(self) -> None:
@@ -1467,7 +1146,7 @@ class LlamaEngine:
                 admitted += 1
                 self._trace_admitted_locked(slot, t_adm, i)
                 # reset this row's position; stale KV is masked by pos
-                self._cache["pos"] = self._cache["pos"].at[i].set(0)
+                self._runner.reset_row(i)
                 if self._pcache is None:
                     continue
                 # prefix reuse: graft the longest cached prefix into the
@@ -1482,9 +1161,7 @@ class LlamaEngine:
                     self.metrics.prefix_misses.inc()
                     continue
                 self.metrics.prefix_hits.inc()
-                self._cache = self._graft(
-                    self._cache, entry.k, entry.v, i, mlen
-                )
+                self._runner.graft(entry.k, entry.v, i, mlen)
                 slot.cached_len = mlen
                 slot.pinned = entry
         return admitted
@@ -1505,31 +1182,19 @@ class LlamaEngine:
                             self._release_prefix_locked(s)
                             s.done.set()
                     # the cache is DONATED to prefill/decode: a call that
-                    # raised after donation leaves self._cache pointing at
-                    # deleted buffers — rebuild or every later tick dies.
+                    # raised after donation leaves the runner's cache
+                    # pointing at deleted buffers — rebuild or every later
+                    # tick dies.
                     # The PRNG key and token chain are segment OUTPUTS
                     # too: a segment that failed after the assignment
                     # leaves them referencing poisoned buffers, which
                     # would wedge every later request — re-seed/clear.
+                    self._runner.new_cache(self.kv_blocks)
                     if self._paged:
-                        from kubedl_tpu.serving.kv_blocks import (
-                            BlockAllocator,
+                        self._new_block_state(
+                            self._alloc.low_watermark,
+                            self._alloc.high_watermark,
                         )
-
-                        self._cache = self._llama.init_paged_cache(
-                            self.cfg, self.max_batch, self.max_seq,
-                            self.kv_blocks, self.kv_block_size,
-                        )
-                        self._alloc = BlockAllocator(
-                            self.kv_blocks, self.kv_block_size,
-                            low_watermark=self._alloc.low_watermark,
-                            high_watermark=self._alloc.high_watermark,
-                        )
-                        self._pos_host[:] = 0
-                        self._bt_host[:] = 0
-                        self._row_blocks = [
-                            [] for _ in range(self.max_batch)
-                        ]
                         if self._pcache is not None:
                             # every entry references the dead pool's
                             # blocks — drop them all (no evict callbacks:
@@ -1544,11 +1209,9 @@ class LlamaEngine:
                             )
                             ev.set()
                         self._export_q.clear()
-                    else:
-                        self._cache = self._llama.init_batched_cache(
-                            self.cfg, self.max_batch, self.max_seq
-                        )
-                    self._key = self._jax.random.PRNGKey(
+                    import jax
+
+                    self._key = jax.random.PRNGKey(
                         int(time.time()) & 0x7FFFFFFF
                     )
                     self._reset_pipeline_locked()
@@ -1708,29 +1371,14 @@ class LlamaEngine:
         slot.version = str(model_version or "")
         self._arm_trace(slot, trace)
         self._enqueue_slot_locked_checks(slot)
-        if not slot.done.wait(timeout=timeout_s):
-            with self._cv:
-                if slot in self._waiting:
-                    self._waiting.remove(slot)
-                for i, s in enumerate(self._slots):
-                    if s is slot:
-                        self._slots[i] = None
-                        self._free_row_locked(i)
-                self._release_prefix_locked(slot)
-        result = slot.result or {"error": "timed out", "timed_out": True}
-        with self._cv:
-            if request_id:
-                self._requests.pop(request_id, None)
-            self._stats["requests"] += 1
-            self._stats["tokens_in"] += len(prompt)
-            self._recent.append(time.time())
+        result = self._await_slot(slot, timeout_s)
         hid = result.get("handoff_id")
         if hid is None:
             raise HandoffError(result.get("error", "prefill failed"))
         return self.fetch_handoff(hid, timeout_s=min(timeout_s, 60.0))
 
     def _enqueue_slot_locked_checks(self, slot: _Slot) -> None:
-        """Admission gate shared by generate()'s disaggregated siblings:
+        """Admission gate of generate() and its disaggregated siblings:
         drain rejection, queue-depth/age shedding, KV watermark shedding
         — identical budgets, identical 503 reasons. Also resolves the
         slot's weight version (slot.version holds the REQUESTED id on
@@ -1750,6 +1398,9 @@ class LlamaEngine:
                 if self._waiting else 0.0
             )
             if depth >= self.max_queue_depth or head_age > self.max_queue_age_s:
+                # shed instead of queueing: an over-budget queue serves
+                # nobody well — tell the client when to come back and let
+                # the autoscaler see the rejected demand as backlog
                 self._stats["shed"] += 1
                 self._shed_recent.append(time.time())
                 self.metrics.shed_requests.inc()
@@ -1761,6 +1412,9 @@ class LlamaEngine:
                     retry_after_s=retry,
                 )
             if self._paged and not self._alloc.admission_open():
+                # KV-pool pressure sheds too: below the low watermark a
+                # queued request cannot be admitted anyway, so reject at
+                # the door (hysteresis reopens at the high watermark)
                 self._stats["shed"] += 1
                 self._stats["kv_sheds"] += 1
                 self._shed_recent.append(time.time())
@@ -1824,8 +1478,6 @@ class LlamaEngine:
                 self._export_handoffs(work)
 
     def _export_handoffs(self, work) -> None:
-        import numpy as np
-
         from kubedl_tpu.serving.disagg import KVHandoff
 
         for hid, box, ev, rec in work:
@@ -1836,11 +1488,7 @@ class LlamaEngine:
             t0 = time.perf_counter()
             try:
                 chaos.check("serving.kv_handoff")
-                k, v = self._llama.export_kv_blocks(
-                    self._cache, rec["blocks"]
-                )
-                k = np.array(self._jax.device_get(k))
-                v = np.array(self._jax.device_get(v))
+                k, v = self._runner.export_blocks(rec["blocks"])
                 # the handoff carries its trace as a header-format string
                 # (parent = the prefill request span) so a decode engine
                 # adopting it WITHOUT an HTTP header still joins the trace
@@ -1905,13 +1553,11 @@ class LlamaEngine:
                 f"handoff block_size {h.block_size} != engine "
                 f"{self.kv_block_size}"
             )
-        pool = self._cache["k"].shape
-        if tuple(h.k.shape[0:1]) + tuple(h.k.shape[2:]) != (
-            pool[0], pool[2], pool[3], pool[4]
-        ):
+        if not self._runner.fits_pool(h.k.shape):
             raise ValueError(
                 f"handoff KV geometry {h.k.shape} does not fit pool "
-                f"{pool} (model mismatch? handoff model={h.model!r})"
+                f"{self._runner.pool_shape} "
+                f"(model mismatch? handoff model={h.model!r})"
             )
         prompt = [int(t) for t in h.prompt_ids]
         budget = self.max_seq - 1
@@ -1934,23 +1580,7 @@ class LlamaEngine:
             trace = parse_trace_header(getattr(h, "trace", ""))
         self._arm_trace(slot, trace, debug_trace)
         self._enqueue_slot_locked_checks(slot)
-        if not slot.done.wait(timeout=timeout_s):
-            with self._cv:
-                if slot in self._waiting:
-                    self._waiting.remove(slot)
-                for i, s in enumerate(self._slots):
-                    if s is slot:
-                        self._slots[i] = None
-                        self._free_row_locked(i)
-                self._release_prefix_locked(slot)
-        result = slot.result or {"error": "timed out", "timed_out": True}
-        with self._cv:
-            if slot.request_id:
-                self._requests.pop(slot.request_id, None)
-            self._stats["requests"] += 1
-            self._stats["tokens_in"] += len(prompt)
-            self._stats["tokens_out"] += len(result.get("token_ids", []))
-            self._recent.append(time.time())
+        result = self._await_slot(slot, timeout_s)
         return self._trace_result(slot, result, debug_trace)
 
     def _admit_row_adopt_locked(self, i: int, slot: _Slot):
@@ -2005,9 +1635,8 @@ class LlamaEngine:
         t0 = time.perf_counter()
         if got:
             start = len(shared)
-            self._cache = self._llama.import_kv_blocks(
-                self._cache, h.k[:, start:n_blocks],
-                h.v[:, start:n_blocks], got,
+            self._runner.import_blocks(
+                h.k[:, start:n_blocks], h.v[:, start:n_blocks], got
             )
         self._row_blocks[i] = blocks
         self._bt_host[i, :] = 0
@@ -2049,29 +1678,6 @@ class LlamaEngine:
         self._maybe_finalize_locked(i, slot)
         return True
 
-    def _segment_fn(self, n_steps: int, greedy: bool):
-        """Jitted n-step decode with on-device sampling (cache donated);
-        one compile per (segment size, greedy) combination."""
-        fn = self._segments.get((n_steps, greedy))
-        if fn is None:
-            seg = (
-                self._llama.paged_decode_segment if self._paged
-                else self._llama.decode_segment
-            )
-            kw = {"kv_attention": self.kv_attention} if self._paged else {}
-            # the step count is in the name: a module event of a device
-            # profile carries a duration and nothing else
-            name = f"engine_decode_seg{n_steps}" + ("" if greedy else "_sampled")
-            fn = self._jax.jit(
-                _named(name, lambda p, c, tokens, temps, key: seg(
-                    p, c, tokens, temps, key, cfg=self.cfg,
-                    n_steps=n_steps, greedy=greedy, **kw,
-                )),
-                donate_argnums=(1,),
-            )
-            self._segments[(n_steps, greedy)] = fn
-        return fn
-
     def _prefill_bucket(self, max_len: int) -> int:
         """Pad prompts to power-of-2 buckets: bounded compile count
         (one per bucket, <= log2(max_seq)) with at most 2x padding."""
@@ -2098,21 +1704,17 @@ class LlamaEngine:
 
     # -- pipeline stages ---------------------------------------------------
 
-    def _harvest_segment(self):
+    def _harvest_segment(self, acct: Dict) -> None:
         """Harvest the deferred in-flight decode segment: `device_get` its
         sampled ids (blocks until the device finishes the segment), append
         the values to each slot, finalize completed requests, and admit
-        waiters. No-op when nothing is in flight. Returns
-        ``(blocked_ms, host_ms)`` for the tick accounting."""
-        import numpy as np
-
+        waiters. No-op when nothing is in flight. Adds the time blocked
+        and the host's time to the tick's ``acct``."""
         pend, self._pending = self._pending, None
         if pend is None:
-            return 0.0, 0.0
+            return
         with TRACER.phase("engine.harvest_wait", what="segment") as wait:
-            # np.array (copy): device_get may return a zero-copy VIEW of
-            # the device buffer, which a later donated dispatch can reuse
-            rows = np.array(self._jax.device_get(pend["toks"]))  # [B, k]
+            rows = _to_host(pend["toks"])  # [B, k]
         t1 = time.perf_counter()
         seg_t0 = pend.get("t0", t1)
         with TRACER.phase("engine.harvest_host") as host, self._cv:
@@ -2132,17 +1734,16 @@ class LlamaEngine:
                 self._maybe_finalize_locked(i, s)
             self._admit_locked()
             self._cv.notify_all()
-        return wait.ms, host.ms
+        acct["harvest_ms"] += wait.ms
+        acct["host_ms"] += host.ms
 
-    def _harvest_prefill(self, pre, ids_dev):
+    def _harvest_prefill(self, pre, ids_dev, acct: Dict) -> None:
         """Harvest prefill's device-sampled first tokens ([B] int32 — the
         logits never left the device) and record them. Runs AFTER the next
         decode segment is dispatched, so the copy-out overlaps device
-        compute. Returns ``(blocked_ms, host_ms)``."""
-        import numpy as np
-
+        compute. Adds its times to ``acct`` as `_harvest_segment` does."""
         with TRACER.phase("engine.harvest_wait", what="prefill") as wait:
-            ids = np.array(self._jax.device_get(ids_dev))  # copy: see harvest
+            ids = _to_host(ids_dev)
         now = time.perf_counter()
         with TRACER.phase("engine.harvest_host") as host, self._cv:
             for i, s, budgeted in pre:
@@ -2172,7 +1773,8 @@ class LlamaEngine:
                 self._maybe_finalize_locked(i, s)
             self._admit_locked()
             self._cv.notify_all()
-        return wait.ms, host.ms
+        acct["harvest_ms"] += wait.ms
+        acct["host_ms"] += host.ms
 
     def _dispatch_prefill(self, sched, acct: Dict, params, suffix: bool):
         """Dispatch the prefill of ``sched`` = ``[(row, slot, base, take,
@@ -2195,11 +1797,12 @@ class LlamaEngine:
         ``engine.prefill_dispatch`` phase (``slots`` = rows it computes).
         Returns ``(prefill_ids, t0)``: the sampled ids, still on the
         device, and the time of the first dispatch."""
-        import numpy as np
+        import jax
         import jax.numpy as jnp
+        import numpy as np
 
         groups = [[t] for t in sched] if self._paged else [sched]
-        logits = self._no_logits if self._paged else None
+        logits = None  # of the tick's earlier programs
         prefill_ids = t0 = None
         saved = positions = 0
         for n, group in enumerate(groups):
@@ -2222,26 +1825,23 @@ class LlamaEngine:
                     if s.prefill_pos < 0 and s.cached_len:
                         saved += s.cached_len  # first dispatch of a graft
                 if n == 0:
-                    self._key, pick_key = self._jax.random.split(self._key)
+                    self._key, pick_key = jax.random.split(self._key)
                     if self._paged:
-                        # the HOST mirrors are authoritative: upload pos +
-                        # block table before every dispatch so rollbacks
-                        # (speculative rejection, preemption, vacation)
-                        # are plain mirror edits. The programs after the
-                        # first run on the cache the one before returned.
-                        self._cache["pos"] = self._upload_mirror(self._pos_host)
-                        self._cache["bt"] = self._upload_mirror(self._bt_host)
+                        # the programs after the first run on the cache
+                        # the one before returned
+                        self._runner.upload_mirrors(
+                            self._bt_host, self._pos_host
+                        )
                     t0 = time.perf_counter()
-                args = [jnp.asarray(toks), jnp.asarray(lens)]
                 from_prefix = suffix or bool(np.any(starts > 0))
-                if from_prefix:
-                    args.append(jnp.asarray(starts))
-                if self._paged:
-                    rows = np.array([i for i, *_ in group], np.int32)
-                    args += [jnp.asarray(rows), logits]
-                logits, self._cache = (
-                    self._prefill_from if from_prefix else self._prefill
-                )(params, self._cache, *args)
+                logits = self._runner.prefill(
+                    params, jnp.asarray(toks), jnp.asarray(lens),
+                    starts=jnp.asarray(starts) if from_prefix else None,
+                    rows=jnp.asarray(
+                        np.array([i for i, *_ in group], np.int32)
+                    ) if self._paged else None,
+                    acc=logits,
+                )
                 positions += slots * bucket
                 if n == len(groups) - 1:
                     prefill_ids = self._sample_first(sched, logits, pick_key)
@@ -2271,7 +1871,7 @@ class LlamaEngine:
         temps0 = np.zeros((self.max_batch,), np.float32)
         for i, s, _base, _take, _final in sched:
             temps0[i] = max(float(s.temperature), 0.0)
-        prefill_ids = self._sample_logits(
+        prefill_ids = self._runner.sample_first(
             logits, jnp.asarray(temps0), pick_key
         )  # stays on device until after the next dispatch
         final_rows = tuple(i for i, _s, _b, _t, f in sched if f)
@@ -2283,7 +1883,7 @@ class LlamaEngine:
         if self._chain is not None:
             # per-row chain validity: untouched rows keep the in-flight
             # segment's output tokens
-            merged = self._merge_chain(
+            merged = self._runner.merge_chain(
                 self._chain[2], prefill_ids, jnp.asarray(mask)
             )
             self._chain = (
@@ -2296,6 +1896,19 @@ class LlamaEngine:
                 self._prefill_gen, final_rows, prefill_ids[:, None]
             )
         return prefill_ids
+
+    def _prompt_fed_locked(self, i: int, s: _Slot) -> tuple:
+        """Row ``i``'s whole prompt is dispatched: it decodes from here, and
+        owes a first token (in flight now) unless it has no budget for one.
+        Returns the row's entry for `_harvest_prefill`. Caller holds cv."""
+        s.fed = len(s.prompt)
+        budgeted = (
+            s.max_tokens > 0
+            and len(s.prompt) + len(s.out_ids) < self.max_seq - 1
+        )
+        if budgeted:
+            s.pending += 1
+        return i, s, budgeted
 
     def _prefill_chunks(self, todo, acct: Dict, params=None):
         """Chunked-admission prefill dispatch (docs/serving.md
@@ -2350,17 +1963,8 @@ class LlamaEngine:
                 s.prefill_pos = base + take
                 if s.prefill_t0 is None:
                     s.prefill_t0 = t0  # first chunk starts the TTFT span
-                if not final:
-                    continue
-                s.fed = len(s.prompt)
-                budgeted = (
-                    s.max_tokens > 0
-                    and len(s.prompt) + len(s.out_ids)
-                    < self.max_seq - 1
-                )
-                if budgeted:
-                    s.pending += 1
-                pre.append((i, s, budgeted))
+                if final:
+                    pre.append(self._prompt_fed_locked(i, s))
         return pre, (prefill_ids if pre else None)
 
     def _spec_tick(self, decoding, acct: Dict, params=None) -> None:
@@ -2398,8 +2002,8 @@ class LlamaEngine:
         k = self.spec_k
         S = k + 1
         N = self.spec_candidates
-        multi = N > 1 and self._verify_multi is not None
-        tree = multi and self._verify_tree is not None
+        multi = N > 1
+        tree = multi and self.spec_tree
         draft_kind = getattr(self._draft, "name", self.spec_draft)
         # phase 1 — snapshot contexts under the lock, DRAFT OUTSIDE IT:
         # a model draft's forward must not stall admission/finalize.
@@ -2413,6 +2017,7 @@ class LlamaEngine:
             ]
         if not cand:
             return
+        t_d = time.perf_counter()  # start of the rows' engine.spec_round
         with TRACER.phase("engine.spec_draft", rows=len(cand)) as ph:
             if multi:
                 cand_lists = [
@@ -2476,8 +2081,7 @@ class LlamaEngine:
             "engine.spec_dispatch", k=k, rows=len(rows),
             slots=self.max_batch,
         ) as ph:
-            self._cache["pos"] = self._upload_mirror(self._pos_host)
-            self._cache["bt"] = self._upload_mirror(self._bt_host)
+            self._runner.upload_mirrors(self._bt_host, self._pos_host)
             if tree:
                 # trie ranking pass (read-only, like multi): candidates
                 # sharing a prefix share trie nodes, one forward scores
@@ -2498,11 +2102,11 @@ class LlamaEngine:
                     pos_tr[i] = int(starts[i]) + t_dep
                     mask_tr[i] = t_mask
                     lens_tr[i] = tr.size
-                ids_tree = np.array(self._jax.device_get(self._verify_tree(
-                    params, self._cache, jnp.asarray(toks_tr),
+                ids_tree = _to_host(self._runner.verify_tree(
+                    params, jnp.asarray(toks_tr),
                     jnp.asarray(pos_tr), jnp.asarray(mask_tr),
                     jnp.asarray(lens_tr), jnp.asarray(starts),
-                )))  # [B, M]
+                ))  # [B, M]
                 for i, s, dl in rows:
                     path = trees[i].walk(ids_tree[i])
                     # the walk follows unique-token children, so it only
@@ -2515,10 +2119,10 @@ class LlamaEngine:
                         toks[i, 1:] = dl[0]
             elif multi:
                 # read-only ranking pass (cache neither donated nor written)
-                ids_multi = np.array(self._jax.device_get(self._verify_multi(
-                    params, self._cache, jnp.asarray(cand_toks),
+                ids_multi = _to_host(self._runner.verify_multi(
+                    params, jnp.asarray(cand_toks),
                     jnp.asarray(lens), jnp.asarray(starts),
-                )))  # [B, N, S]
+                ))  # [B, N, S]
                 for i, s, dl in rows:
                     best = 0
                     best_a = accept_length(dl[0], ids_multi[i, 0][:k])
@@ -2530,13 +2134,13 @@ class LlamaEngine:
                     if best:
                         dl[0] = dl[best]  # the accept loop reads dl[0]
                         toks[i, 1:] = dl[0]
-            ids_dev, self._cache = self._verify(
-                params, self._cache, jnp.asarray(toks),
+            ids_dev = self._runner.verify(
+                params, jnp.asarray(toks),
                 jnp.asarray(lens), jnp.asarray(starts),
             )
         acct["dispatch_ms"] += ph.ms
         with TRACER.phase("engine.harvest_wait", what="verify") as ph:
-            ids = np.array(self._jax.device_get(ids_dev))  # [B, S] (copy)
+            ids = _to_host(ids_dev)  # [B, S]
         acct["harvest_ms"] += ph.ms
         with TRACER.phase("engine.harvest_host") as ph, self._cv:
             for i, s, dl in rows:
@@ -2661,7 +2265,8 @@ class LlamaEngine:
         # cache between dispatches) before the tick's own dispatches
         self._service_exports()
         if stop:
-            self._harvest_segment()  # flush: deliver in-flight tokens
+            # flush: deliver in-flight tokens (no tick to account them to)
+            self._harvest_segment({"harvest_ms": 0.0, "host_ms": 0.0})
             return True
 
         # the tick's times ARE its phase spans' durations: one measurement
@@ -2677,7 +2282,6 @@ class LlamaEngine:
         """The body of one tick (see `_loop_once`); returns its accounting
         for `_commit_tick`."""
         import numpy as np
-        import jax.numpy as jnp
 
         acct = {"dispatch_ms": 0.0, "harvest_ms": 0.0, "host_ms": 0.0,
                 "overlapped": False, "segments": 0, "deferred": 0,
@@ -2688,9 +2292,7 @@ class LlamaEngine:
             # admission waits for at most ONE (small) segment instead of
             # queueing behind a freshly dispatched one — trades this
             # tick's overlap for bounded admission latency
-            h, b = self._harvest_segment()
-            acct["harvest_ms"] += h
-            acct["host_ms"] += b
+            self._harvest_segment(acct)
             acct["flushes"] += 1
 
         with self._cv:
@@ -2766,16 +2368,8 @@ class LlamaEngine:
                         )
                     if self._slots[i] is not s:
                         continue  # vacated (request timeout) mid-prefill
-                    s.fed = len(s.prompt)
                     s.prefill_t0 = t0  # dispatch start, for engine.prefill
-                    budgeted = (
-                        s.max_tokens > 0
-                        and len(s.prompt) + len(s.out_ids)
-                        < self.max_seq - 1
-                    )
-                    if budgeted:
-                        s.pending += 1
-                    pre.append((i, s, budgeted))
+                    pre.append(self._prompt_fed_locked(i, s))
                 active = list(self._slots)
 
         if self.spec_k and pre:
@@ -2783,9 +2377,7 @@ class LlamaEngine:
             # (prompt + harvested tokens), so the deferred prefill
             # harvest has nothing to overlap — collect first tokens now
             # and let fresh rows join this tick's verify
-            h, b = self._harvest_prefill(pre, prefill_ids)
-            acct["harvest_ms"] += h
-            acct["host_ms"] += b
+            self._harvest_prefill(pre, prefill_ids, acct)
             pre = []
             prefill_ids = None
             with self._cv:
@@ -2814,9 +2406,7 @@ class LlamaEngine:
             if self._pending is not None:
                 # a deferred segment still owes tokens the verify's host-
                 # side draft context needs — flush it first
-                h, b = self._harvest_segment()
-                acct["harvest_ms"] += h
-                acct["host_ms"] += b
+                self._harvest_segment(acct)
                 acct["flushes"] += 1
                 with self._cv:
                     decoding = [
@@ -2851,13 +2441,9 @@ class LlamaEngine:
                 # feed from HOST tokens. In-flight values must land
                 # first — s.next_input() indexes into out_ids the
                 # deferred segment has not delivered yet.
-                h, b = self._harvest_segment()
-                acct["harvest_ms"] += h
-                acct["host_ms"] += b
+                self._harvest_segment(acct)
                 if pre:
-                    h, b = self._harvest_prefill(pre, prefill_ids)
-                    acct["harvest_ms"] += h
-                    acct["host_ms"] += b
+                    self._harvest_prefill(pre, prefill_ids, acct)
                     pre = []
                 acct["flushes"] += 1
                 acct["rebuilds"] += 1
@@ -2882,13 +2468,9 @@ class LlamaEngine:
                 acct["deferred"] += 1
             else:
                 acct["flushes"] += 1  # pipeline drains this tick
-            h, b = self._harvest_segment()
-            acct["harvest_ms"] += h
-            acct["host_ms"] += b
+            self._harvest_segment(acct)
         if pre:
-            h, b = self._harvest_prefill(pre, prefill_ids)
-            acct["harvest_ms"] += h
-            acct["host_ms"] += b
+            self._harvest_prefill(pre, prefill_ids, acct)
         self._pending = new_pending
         return acct
 
@@ -2928,12 +2510,10 @@ class LlamaEngine:
         if self._temps_cache is None or self._temps_cache[0] != fp:
             self._temps_cache = (fp, jnp.asarray(temps))
         if self._paged:
-            self._cache["pos"] = self._upload_mirror(self._pos_host)
-            self._cache["bt"] = self._upload_mirror(self._bt_host)
+            self._runner.upload_mirrors(self._bt_host, self._pos_host)
         t0 = time.perf_counter()  # start of the rows' engine.decode_segment
-        toks, last, self._key, self._cache = self._segment_fn(k, greedy)(
-            params, self._cache, tokens_dev,
-            self._temps_cache[1], self._key,
+        toks, last, self._key = self._runner.decode_segment(
+            k, greedy, params, tokens_dev, self._temps_cache[1], self._key,
         )
         self._chain = (
             self._prefill_gen, tuple(i for i, _ in decoding), last
@@ -3014,6 +2594,12 @@ def make_handler(engine: LlamaEngine, model_name: str):
                 })
             else:
                 self._json(404, {"error": "not found"})
+
+        def _shed(self, e: EngineOverloaded) -> None:
+            self._json(
+                503, {"error": str(e), "shed": True, "reason": e.reason},
+                headers={"Retry-After": str(int(e.retry_after_s + 0.999))},
+            )
 
         def _read_json(self) -> dict:
             length = int(self.headers.get("Content-Length", "0"))
@@ -3113,13 +2699,7 @@ def make_handler(engine: LlamaEngine, model_name: str):
                     self.end_headers()
                     self.wfile.write(body)
                 except EngineOverloaded as e:
-                    self._json(
-                        503,
-                        {"error": str(e), "shed": True, "reason": e.reason},
-                        headers={
-                            "Retry-After": str(int(e.retry_after_s + 0.999))
-                        },
-                    )
+                    self._shed(e)
                 except HandoffError as e:
                     self._json(
                         502, {"error": str(e), "handoff_failed": True}
@@ -3156,13 +2736,7 @@ def make_handler(engine: LlamaEngine, model_name: str):
                         return
                     self._json(200, result)
                 except EngineOverloaded as e:
-                    self._json(
-                        503,
-                        {"error": str(e), "shed": True, "reason": e.reason},
-                        headers={
-                            "Retry-After": str(int(e.retry_after_s + 0.999))
-                        },
-                    )
+                    self._shed(e)
                 except Exception as e:
                     self._json(400, {"error": str(e)})
                 return
@@ -3204,10 +2778,7 @@ def make_handler(engine: LlamaEngine, model_name: str):
             except UnknownModelVersion as e:
                 self._json(400, {"error": str(e), "unknown_version": True})
             except EngineOverloaded as e:
-                self._json(
-                    503, {"error": str(e), "shed": True, "reason": e.reason},
-                    headers={"Retry-After": str(int(e.retry_after_s + 0.999))},
-                )
+                self._shed(e)
             except Exception as e:  # serving must not die on a bad request
                 self._json(400, {"error": str(e)})
 

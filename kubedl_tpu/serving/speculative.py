@@ -65,6 +65,7 @@ acceptance rate the engine exports (`stats()["speculative"]` and the
 
 from __future__ import annotations
 
+import os
 import threading
 from collections import deque
 from typing import Dict, List, Optional, Sequence
@@ -540,18 +541,29 @@ _DRAFTS = {
 }
 
 
-def make_draft(name: str, **kwargs) -> DraftModel:
-    """Draft factory for the engine's ``spec_draft`` knob. "model"
-    needs weights — the engine constructs :class:`ModelDraft` itself
-    (`ModelDraft.from_target`) instead of going through here."""
+def make_draft(name: str, params=None, cfg=None, max_context: int = 0,
+               n_layers: int = 0) -> DraftModel:
+    """Draft factory for the engine's ``spec_draft`` knob. The model
+    drafts take the target's ``params``/``cfg`` and the engine's context
+    length: "model" is an early-exit draft carved out of the target's own
+    stacked weights (views, no copies; ``n_layers`` deep, half the target
+    by default), "zoo:<name>" a trained small model shaped by the planner
+    MODEL_ZOO (KUBEDL_SPEC_DRAFT_CKPT restores weights saved after
+    distillation; fresh weights propose noise — harmless, just zero
+    acceptance)."""
     if name == "model":
-        raise ValueError(
-            "draft 'model' needs target weights: use "
-            "ModelDraft.from_target(...) (the engine's spec_draft="
-            "'model' path does this)"
+        return ModelDraft.from_target(
+            params, cfg, n_layers=n_layers or max(1, cfg.n_layers // 2),
+            max_context=max_context,
+        )
+    if name.startswith("zoo:"):
+        ckpt = os.environ.get("KUBEDL_SPEC_DRAFT_CKPT", "")
+        return ModelDraft.from_zoo(
+            name.split(":", 1)[1], cfg, ckpt_path=ckpt or None,
+            max_context=max_context,
         )
     try:
-        return _DRAFTS[name](**kwargs)
+        return _DRAFTS[name]()
     except KeyError:
         raise ValueError(
             f"unknown draft {name!r} (have: {sorted(_DRAFTS)})"

@@ -4,8 +4,8 @@ stubbed out.
 The double-buffered pipeline's whole point is that host work (dispatch
 bookkeeping, harvest copy-out handling, slot finalization, admission)
 hides behind device compute — which only works while that host work stays
-small. This bench replaces every jitted model call on a real
-`LlamaEngine` with an instant stub, drives `_loop_once` directly, and
+small. This bench puts one fake (`StubRunner`) where a real `LlamaEngine`'s
+`ModelRunner` stood, drives `_loop_once` directly, and
 reports the tick timings the engine itself accounts
 (`pipeline_stats()`). With a no-op device, tick time IS host overhead.
 
@@ -127,6 +127,55 @@ WORKQUEUE_ADD_BUDGET_US = 10.0
 WORKQUEUE_STORM_PICKUPS_PER_KEY = 3.0
 
 
+class StubRunner:
+    """Stands where an engine's ``ModelRunner`` stood: every program the
+    tick dispatches returns at once, with arrays made ahead of time, and
+    everything else (the cache, the mirror upload, the block format) is
+    the real runner's."""
+
+    def __init__(self, real):
+        import jax
+        import jax.numpy as jnp
+
+        from kubedl_tpu.serving.server import LlamaEngine
+
+        self._real = real
+        B = real.max_batch
+        self._last = jnp.ones((B, 1), jnp.int32)
+        self._ids = jnp.ones((B,), jnp.int32)
+        self._logits = jnp.zeros((B, 8), jnp.float32)  # shape never inspected
+        self._seg_toks = {
+            k: jnp.ones((B, k), jnp.int32)
+            for k in LlamaEngine.SEGMENT_BUCKETS
+        }
+        jax.block_until_ready(
+            (self._last, self._ids, self._logits, self._seg_toks)
+        )
+
+    def __getattr__(self, name):
+        return getattr(self._real, name)
+
+    # whole-prompt and suffix prefill alike, whatever batch they are
+    # handed (a paged engine's is compact: rows + the logits array)
+    def prefill(self, params, toks, lens, starts=None, rows=None, acc=None):
+        return self._logits
+
+    def sample_first(self, logits, temps, key):
+        return self._ids
+
+    def merge_chain(self, last, ids, mask):
+        return last
+
+    def decode_segment(self, n_steps, greedy, params, tokens, temps, key):
+        return self._seg_toks[n_steps], self._last, key
+
+    def graft(self, k, v, row, length):
+        pass
+
+    def extract(self, row, p_len):
+        return None, None
+
+
 def build_stub_engine(max_batch: int = 4, max_seq: int = 128,
                       kv_layout: str = "contiguous",
                       kv_attention: str = "gather",
@@ -134,9 +183,6 @@ def build_stub_engine(max_batch: int = 4, max_seq: int = 128,
     """A real LlamaEngine whose device calls are instant stubs: the
     scheduler loop, slot machinery, chain/pending bookkeeping, and
     accounting all run for real; only the model math is elided."""
-    import jax
-    import jax.numpy as jnp
-
     from kubedl_tpu.serving.server import LlamaEngine
 
     eng = LlamaEngine(preset="tiny", max_batch=max_batch, max_seq=max_seq,
@@ -148,28 +194,7 @@ def build_stub_engine(max_batch: int = 4, max_seq: int = 128,
         eng._cv.notify_all()
     eng._thread.join(timeout=10)
     eng._stop = False
-
-    B = eng.max_batch
-    last = jnp.ones((B, 1), jnp.int32)
-    ids = jnp.ones((B,), jnp.int32)
-    logits = jnp.zeros((B, 8), jnp.float32)  # shape never inspected
-    seg_toks = {}
-    jax.block_until_ready((last, ids, logits))
-
-    # whole-prompt and suffix prefill alike, whatever batch they are
-    # handed (a paged engine's is compact: rows + the logits array)
-    eng._prefill = eng._prefill_from = lambda p, c, *batch: (logits, c)
-    eng._sample_logits = lambda lg, temps, key: ids
-    eng._merge_chain = lambda lastv, i, m: lastv
-
-    def segment_fn(k, greedy):
-        toks = seg_toks.get(k)
-        if toks is None:
-            toks = jax.block_until_ready(jnp.ones((B, k), jnp.int32))
-            seg_toks[k] = toks
-        return lambda p, c, tok, temps, key: (toks, last, key, c)
-
-    eng._segment_fn = segment_fn
+    eng._runner = StubRunner(eng._runner)
     return eng
 
 
@@ -247,8 +272,6 @@ def run_prefix_microbench(requests: int = 32, max_tokens: int = 8,
 
     eng = build_stub_engine(max_batch=max_batch)
     try:
-        eng._graft = lambda c, k, v, row, n: c
-        eng._extract = lambda c, i, p: (None, None)
         prefix = list(range(3, 3 + prefix_len))
         payload = np.zeros((1,), np.float32)
         assert eng._pcache is not None, "stub engine must enable the cache"
@@ -260,7 +283,7 @@ def run_prefix_microbench(requests: int = 32, max_tokens: int = 8,
         t0 = time.perf_counter()
         for _ in range(iters):
             e, n = eng._pcache.match(probe)
-            eng._graft(eng._cache, e.k, e.v, 0, n)
+            eng._runner.graft(e.k, e.v, 0, n)
             eng._pcache.unpin(e)
         match_graft_ms = (time.perf_counter() - t0) * 1e3 / iters
         hits0 = eng._pcache.stats()["hits"]
@@ -310,10 +333,8 @@ def run_paged_microbench(requests: int = 32, max_tokens: int = 32,
         iters = 2000
         t0 = time.perf_counter()
         for _ in range(iters):
-            jax.block_until_ready((
-                eng._upload_mirror(eng._pos_host),
-                eng._upload_mirror(eng._bt_host),
-            ))
+            eng._runner.upload_mirrors(eng._bt_host, eng._pos_host)
+            jax.block_until_ready(eng._runner.cache)
         mirror_upload_ms = (time.perf_counter() - t0) * 1e3 / iters
 
         slots = [

@@ -76,7 +76,7 @@ check("http probe returns full stats dict",
       f"qps={v.get('qps')} queued={v.get('queued')}")
 
 # injected failure mid-service, then engine keeps serving over HTTP
-orig = eng._segment_fn
+orig = eng._runner._segment_fn
 state = {"armed": True}
 def boom(k, greedy):
     fn = orig(k, greedy)
@@ -86,7 +86,7 @@ def boom(k, greedy):
             raise RuntimeError("injected")
         return fn(*a, **kw)
     return w
-eng._segment_fn = boom
+eng._runner._segment_fn = boom
 r1 = post({"prompt_ids": [9], "max_tokens": 8})
 r2 = post({"prompt_ids": [9], "max_tokens": 8})
 check("failure fails one request, next serves",

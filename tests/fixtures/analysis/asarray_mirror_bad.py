@@ -2,7 +2,7 @@
 ``jnp.asarray`` of a persistent numpy host mirror (``self._bt_host``)
 passed into a call whose donated cache lets XLA alias segment outputs
 onto the borrowed mirror memory. Canonical fix lives in
-serving/server.py ``_upload_mirror``.
+serving/model_runner.py ``_upload_mirror``.
 """
 
 import jax

@@ -53,6 +53,7 @@ class TestSeedRegressions:
     CASES = [
         ("donated_restore", "KTL001"),  # PR 6: frombuffer -> donated step
         ("asarray_mirror", "KTL001"),   # PR 8: self._bt_host borrow
+        ("runner_mirror", "KTL001"),    # PR 30: the same borrow, stored by the runner
         ("env_race", "KTL003"),         # PR 6: environ rewrite on re-entry
         ("lock_blocking", "KTL002"),    # PR 11: harvest under the cv
         ("fsync_loop", "KTL010"),

@@ -1,0 +1,178 @@
+"""The seam between the scheduler and the model (PR 30): `LlamaEngine`
+keeps the schedule, one `ModelRunner` (serving/model_runner.py) owns the
+weights, the K/V arrays and every device program. What must hold at that
+seam, and what files outside the repo's reach rely on."""
+
+import ast
+import contextlib
+import dataclasses
+import inspect
+import os
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SERVING = os.path.join(REPO, "kubedl_tpu", "serving")
+
+
+def _tree(name):
+    with open(os.path.join(SERVING, name)) as f:
+        return ast.parse(f.read())
+
+
+class TestTheSchedulerNamesNoModel:
+    def test_server_imports_no_model_and_builds_no_program(self):
+        tree = _tree("server.py")
+        imported = []
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                imported += [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                imported += [f"{node.module}.{a.name}" for a in node.names]
+        assert not [m for m in imported if m.startswith("kubedl_tpu.models")]
+        jits = [n.lineno for n in ast.walk(tree)
+                if isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute)
+                and n.func.attr == "jit"]
+        assert not jits, f"server.py builds a program at lines {jits}"
+        handles = [n.lineno for n in ast.walk(tree)
+                   if isinstance(n, ast.Attribute) and n.attr in ("_llama", "_jax")]
+        assert not handles, f"server.py keeps a module handle at lines {handles}"
+
+    def test_only_the_runner_names_engine_programs(self):
+        """A program's name (`engine_*`) is given where it is built."""
+        for name in sorted(os.listdir(SERVING)):
+            if not name.endswith(".py") or name == "model_runner.py":
+                continue
+            named = [n.value for n in ast.walk(_tree(name))
+                     if isinstance(n, ast.Constant) and isinstance(n.value, str)
+                     and n.value.startswith("engine_")]
+            assert not named, (name, named)
+
+    def test_the_device_function_family_is_chosen_once(self):
+        """One `if paged:` picks paged or contiguous `llama` functions: no
+        other statement of the runner names both a paged and a contiguous one."""
+        contiguous = {"decode_step_batched", "prefill_batched", "prefill_batched_from",
+                      "copy_prefix_into_row", "decode_segment", "init_batched_cache"}
+        sites = []
+        for node in ast.walk(_tree("model_runner.py")):
+            if not isinstance(node, ast.If):
+                continue
+            used = {n.attr for n in ast.walk(node) if isinstance(n, ast.Attribute)
+                    and isinstance(n.value, ast.Name) and n.value.id == "llama"}
+            if used & contiguous and {u for u in used if u.startswith("paged_")}:
+                sites.append(node.lineno)
+        assert len(sites) == 1, sites
+
+
+def test_constructor_signature_is_the_one_the_benchmark_calls():
+    """`benchmark/program.py` passes a configuration file's `engine` keys
+    to `LlamaEngine(preset=name, **settings)`, and `engine_kwargs` maps the
+    `KUBEDL_SERVE_*` variables onto the same parameters."""
+    from kubedl_tpu.serving.server import LlamaEngine
+
+    sig = inspect.signature(LlamaEngine.__init__)
+    got = {k: v.default for k, v in sig.parameters.items() if k != "self"}
+    assert got == {
+        "preset": "tiny", "ckpt_dir": "", "batch": 0, "max_seq": 0,
+        "max_batch": 4, "quantize": "", "mesh_axes": None, "metrics": None,
+        "max_queue_depth": 64, "max_queue_age_s": 30.0,
+        "prefix_cache_mb": 64.0, "prefix_min_len": 8, "kv_layout": "paged",
+        "kv_block_size": 16, "kv_blocks": 0, "kv_low_watermark": 0.05,
+        "kv_high_watermark": 0.15, "spec_k": 0, "spec_draft": "ngram",
+        "kv_attention": "gather", "spec_candidates": 1,
+        "spec_draft_layers": 0, "spec_tree": False,
+        "prefill_chunk_tokens": 0, "role": "colocated",
+        "advertise_prefix_len": 8, "handoff_ttl_s": 30.0,
+        "model_version": "base",
+    }
+
+
+@contextlib.contextmanager
+def _bridged(name, cfg, weights):
+    """As `benchmark/program.py` `_bridged`: the two module attributes are
+    swapped while the engine is built, and put back."""
+    from kubedl_tpu.models import llama
+
+    real_preset, real_init = llama.preset, llama.llama_init
+
+    def preset(asked):
+        return cfg if asked == name else real_preset(asked)
+
+    def init(_key, asked):
+        if asked is not cfg:
+            return real_init(_key, asked)
+        return weights
+
+    llama.preset, llama.llama_init = preset, init
+    try:
+        yield
+    finally:
+        llama.preset, llama.llama_init = real_preset, real_init
+
+
+def test_engine_serves_the_bridged_config_and_tree():
+    """The benchmark's bridge: a name no preset table holds, a config and a
+    weights tree made outside. The engine must look `llama.preset` and
+    `llama.llama_init` up on the module when it is built."""
+    import jax
+
+    from kubedl_tpu.models import llama
+    from kubedl_tpu.serving.server import LlamaEngine
+    from test_serving import TestContinuousBatching
+
+    cfg = dataclasses.replace(llama.preset("tiny"), ffn_dim=96)
+    weights = llama.llama_init(jax.random.PRNGKey(7), cfg)
+    with pytest.raises(KeyError):
+        llama.preset("not-in-the-table")
+    with _bridged("not-in-the-table", cfg, weights):
+        eng = LlamaEngine(preset="not-in-the-table", max_batch=2, max_seq=64)
+    try:
+        assert eng.cfg is cfg
+        assert eng.params is weights
+        got = eng.generate([5, 9, 13], max_tokens=6)
+        want = TestContinuousBatching()._reference_generate(eng, [5, 9, 13], 6)
+        assert got["token_ids"] == want
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("kv_layout", ["paged", "contiguous"])
+def test_recovery_builds_the_cache_as_the_constructor_did(kv_layout):
+    """One `new_cache` with two callers: after a segment fails, the loop's
+    recovery rebuilds the runner's cache through the constructor's call,
+    with its argument, to its shapes and dtypes."""
+    import jax
+
+    from kubedl_tpu.serving.server import LlamaEngine
+
+    calls = []
+    eng = LlamaEngine(preset="tiny", max_batch=2, max_seq=64,
+                      kv_layout=kv_layout)
+    try:
+        runner = eng._runner
+        built = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), runner.cache)
+        new_cache, segment_fn = runner.new_cache, runner._segment_fn
+
+        def counted(kv_blocks=0):
+            calls.append(kv_blocks)
+            new_cache(kv_blocks)
+
+        def boom(k, greedy):
+            runner._segment_fn = segment_fn
+
+            def raises(*a, **kw):
+                raise RuntimeError("injected segment failure")
+
+            return raises
+
+        runner.new_cache, runner._segment_fn = counted, boom
+        r1 = eng.generate([5, 9], max_tokens=6, timeout_s=60)
+        assert "injected segment failure" in r1.get("error", ""), r1
+        r2 = eng.generate([5, 9, 13], max_tokens=6, timeout_s=60)
+        assert len(r2["token_ids"]) == 6
+        assert calls == [eng.kv_blocks]
+        assert (eng.kv_blocks > 0) == (kv_layout == "paged")
+        rebuilt = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), runner.cache)
+        assert rebuilt == built
+    finally:
+        eng.close()

@@ -181,27 +181,28 @@ def _shapes(tree):
 def _lowerings(eng):
     """name -> a thunk that lowers that program of ``eng`` on shapes."""
     b = eng.max_batch
-    p, c = _shapes(eng.params), _shapes(eng._cache)
+    r = eng._runner
+    p, c = _shapes(eng.params), _shapes(r.cache)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
     key = _shapes(eng._key)
     vocab = eng.cfg.vocab_size
     return {
-        "engine_decode_step": lambda: eng._decode.lower(p, c, i32(b, 1)),
+        "engine_decode_step": lambda: r._decode.lower(p, c, i32(b, 1)),
         # a paged prefill program computes one row, named by ``rows``
-        "engine_prefill": lambda: eng._prefill.lower(
+        "engine_prefill": lambda: r._prefill.lower(
             p, c, i32(1, 16), i32(1), i32(1), f32(b, vocab)),
-        "engine_prefill_from": lambda: eng._prefill_from.lower(
+        "engine_prefill_from": lambda: r._prefill_from.lower(
             p, c, i32(1, 16), i32(1), i32(1), i32(1), f32(b, vocab)),
-        "engine_decode_seg4": lambda: eng._segment_fn(4, True).lower(
+        "engine_decode_seg4": lambda: r._segment_fn(4, True).lower(
             p, c, i32(b, 1), f32(b), key),
-        "engine_decode_seg4_sampled": lambda: eng._segment_fn(4, False).lower(
+        "engine_decode_seg4_sampled": lambda: r._segment_fn(4, False).lower(
             p, c, i32(b, 1), f32(b), key),
-        "engine_sample_first": lambda: eng._sample_logits.lower(
+        "engine_sample_first": lambda: r.sample_first.lower(
             f32(b, vocab), f32(b), key),
-        "engine_merge_chain": lambda: eng._merge_chain.lower(
+        "engine_merge_chain": lambda: r.merge_chain.lower(
             i32(b, 1), i32(b), jax.ShapeDtypeStruct((b,), jnp.bool_)),
-        "engine_copy_block": lambda: eng._copy_block.lower(c, 1, 2),
+        "engine_copy_block": lambda: r._copy_block.lower(c, 1, 2),
     }
 
 
@@ -216,7 +217,7 @@ class TestProgramNames:
         assert f"module @jit_{name} " in text or f"module @jit_{name}\n" in text, text[:200]
 
     def test_no_program_is_a_lambda_or_unknown(self):
-        """Every jitted attribute of an engine, the speculative ones and the
+        """Every jitted attribute of an engine's runner, the speculative ones and the
         contiguous layout's included, wraps a function named ``engine_*``."""
         jitted = type(jax.jit(lambda: 0))
         seen = set()
@@ -224,9 +225,10 @@ class TestProgramNames:
                    {"kv_layout": "contiguous"}):
             eng = make_engine(**kw)
             try:
-                eng._segment_fn(4, True), eng._segment_fn(1, False)
-                fns = [v for v in vars(eng).values() if isinstance(v, jitted)]
-                fns += list(eng._segments.values())
+                r = eng._runner
+                r._segment_fn(4, True), r._segment_fn(1, False)
+                fns = [v for v in vars(r).values() if isinstance(v, jitted)]
+                fns += list(r._segments.values())
                 assert len(fns) >= 8
                 for fn in fns:
                     assert fn.__name__.startswith("engine_"), fn

@@ -359,7 +359,8 @@ class TestEngineComputesOnlyRowsWithWork:
         try:
             for n in (16, 32):  # one request a bucket, one at a time
                 _serve_together(eng, [(_prompt(n, n), 3)])
-            programs = [eng._prefill, eng._prefill_from, eng._sample_logits, eng._merge_chain]
+            r = eng._runner
+            programs = [r._prefill, r._prefill_from, r.sample_first, r.merge_chain]
             before = [f._cache_size() for f in programs]
             assert sum(before[:2]) == 2, before
             slots = _serve_together(eng, [(_prompt(9, 12), 3), (_prompt(10, 30), 3)])

@@ -876,7 +876,7 @@ def test_scheduler_recovers_after_segment_failure():
     eng = LlamaEngine(preset="tiny", max_batch=2, max_seq=64)
     oracle = TestContinuousBatching()
     try:
-        orig = eng._segment_fn
+        orig = eng._runner._segment_fn
         state = {"armed": True}
 
         def boom(k, greedy):
@@ -890,7 +890,7 @@ def test_scheduler_recovers_after_segment_failure():
 
             return wrapped
 
-        eng._segment_fn = boom
+        eng._runner._segment_fn = boom
         r1 = eng.generate([5, 9], max_tokens=6, timeout_s=60)
         assert "injected segment failure" in r1.get("error", ""), r1
         r2 = eng.generate([5, 9, 13], max_tokens=6, timeout_s=60)
@@ -1207,7 +1207,7 @@ class TestPrefixReuse:
                           prefix_cache_mb=8, prefix_min_len=4)
         try:
             self._freeze(eng)  # test drives admission; prefill never runs
-            L, _, _, KV, hd = eng._cache["k"].shape
+            L, _, _, KV, hd = eng._runner.pool_shape
             k = np.zeros((L, 16, KV, hd), np.float32)
             prefix = [1, 2, 3, 4, 5, 6]
             assert eng._pcache.insert(prefix, k, k.copy(), len(prefix))

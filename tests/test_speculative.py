@@ -335,13 +335,31 @@ class TestTreeSpeculativeEngine:
                           kv_layout="paged", spec_k=4, spec_candidates=3,
                           spec_tree=True)
         try:
-            assert eng._verify_tree is not None
+            assert eng._runner._verify_tree is not None
             for p in prompts:
                 got = eng.generate(p, max_tokens=10)
                 assert got["token_ids"] == _oracle(eng, p, 10), p
             snap = eng.stats()["speculative"]
             assert snap["verifies"] > 0
             assert snap["candidates_scored"] > 0
+        finally:
+            eng.close()
+
+    def test_traced_request_through_speculation_records_its_rounds(self):
+        """A traced request's verify rounds are `engine.spec_round` spans.
+        From PR 24 to PR 30 the span's start was an undefined name: the
+        tick raised and the request failed."""
+        import json
+
+        from kubedl_tpu.serving.server import LlamaEngine
+
+        eng = LlamaEngine(preset="tiny", max_batch=2, max_seq=64,
+                          kv_layout="paged", spec_k=4)
+        try:
+            got = eng.generate([5, 9, 13], max_tokens=8, debug_trace=True)
+            assert got.get("token_ids") == _oracle(eng, [5, 9, 13], 8), got
+            assert "engine.spec_round" in json.dumps(got["trace"])
+            assert eng.pipeline_stats()["errors"] == 0
         finally:
             eng.close()
 
@@ -354,7 +372,7 @@ class TestTreeSpeculativeEngine:
                           kv_layout="paged", spec_k=4, spec_tree=True)
         try:
             assert eng.spec_tree is False
-            assert eng._verify_tree is None
+            assert eng._runner._verify_tree is None
         finally:
             eng.close()
 
