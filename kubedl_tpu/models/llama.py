@@ -1118,6 +1118,66 @@ def _paged_view(pool: jax.Array, layer: jax.Array, bt: jax.Array) -> jax.Array:
     return blocks.reshape(B, MB * BS, KV, hd)
 
 
+def _attend_over_span(spans: Tuple[int, ...], index: jax.Array,
+                      q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                      layer: jax.Array, bt: jax.Array,
+                      pos: jax.Array) -> jax.Array:
+    """Gather attention over the first ``spans[index]`` keys of each row's
+    block table. ``spans`` are ascending key counts, whole blocks, the
+    last the whole table; ``index`` is :func:`_span_index` of the caller's
+    ``live_to``, a traced scalar one past the highest position it will
+    READ a result for. One branch of a ``lax.switch`` a span, so one program serves
+    every span and only the branch taken runs: the view, the validity
+    mask, the scores and the value product follow its length, a
+    ``span / max_seq`` share of the whole table's gather and attention.
+    Nothing that reaches a result is dropped (a key beyond a row's
+    position is masked to an exact 0.0 either way), and a row that stands
+    below the span gets the whole table's result to the bit. ``pos`` is
+    the position of each query, ``[B]`` (decode) or ``[B, S]``; key ``t``
+    is valid for a query at ``pos`` when ``t <= pos``. The row's real
+    length (the rope table, the clamp of ``pos``) stays the whole
+    table's. Who picks: ``ModelRunner`` (serving/model_runner.py) owns
+    the ladder, the engine passes ``live_to`` from its position mirror."""
+    BS = k_pool.shape[2]
+    posq = pos.reshape(pos.shape[0], -1)  # [B, S]
+
+    def attend(span, q, k_pool, v_pool, layer, bt, posq):
+        view_bt = bt[:, :span // BS]
+        mask = (jnp.arange(span)[None, None, :] <= posq[:, :, None])
+        return attention(
+            q, _paged_view(k_pool, layer, view_bt),
+            _paged_view(v_pool, layer, view_bt),
+            causal=False, mask=mask[:, None, None],
+        )
+
+    return lax.switch(
+        index, [partial(attend, span) for span in spans],
+        q, k_pool, v_pool, layer, bt, posq,
+    )
+
+
+def _span_index(spans: Tuple[int, ...], live_to: jax.Array) -> jax.Array:
+    """Which of ``spans`` holds positions ``[0, live_to)``: the smallest
+    that does, the last for anything longer."""
+    return jnp.sum(live_to > jnp.asarray(spans[:-1], jnp.int32)).astype(jnp.int32)
+
+
+def _check_spans(spans: Optional[Tuple[int, ...]], bt: jax.Array, BS: int,
+                 kv_attention: str, live_to) -> None:
+    if spans is None:
+        return
+    if kv_attention != "gather":
+        raise ValueError("spans cut the gathered view: kv_attention='gather'")
+    if live_to is None:
+        raise ValueError("spans need live_to")
+    if (list(spans) != sorted(set(spans)) or any(s <= 0 or s % BS for s in spans)
+            or spans[-1] != bt.shape[1] * BS):
+        raise ValueError(
+            f"spans {spans} must ascend in whole blocks of {BS} up to the "
+            f"table's {bt.shape[1] * BS} keys"
+        )
+
+
 def _scan_layers_over_pools(body, x, layers: Params, k_pool, v_pool):
     """The paged programs' layer scan: ``body(x, k_pool, v_pool, lp,
     layer) -> (x, k_pool, v_pool)`` runs once a layer with the WHOLE
@@ -1149,6 +1209,8 @@ def _check_kv_attention(kv_attention: str) -> None:
 def paged_decode_step_batched(
     params: Params, cache: Params, tokens: jax.Array, cfg: LlamaConfig,
     kv_attention: str = "gather",
+    spans: Optional[Tuple[int, ...]] = None,
+    live_to: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Params]:
     """Block-table twin of :func:`decode_step_batched`: scatter the new
     K/V into each row's current block at ``(bt[b, pos//BS], pos%BS)``,
@@ -1167,7 +1229,16 @@ def paged_decode_step_batched(
     walks the block table; fp-close, greedy-token-identical). The
     blocked path hands the step's K/V to the kernel (``new_k``/``new_v``)
     which writes them into the pool block in the same invocation — one
-    dispatch per layer instead of scatter + attend."""
+    dispatch per layer instead of scatter + attend.
+
+    ``spans`` (static; gather only) with ``live_to`` (traced scalar):
+    attend over the smallest span that holds ``live_to``, see
+    :func:`_attend_over_span`. The caller promises that every row it will
+    READ stands below ``live_to``; any row at or beyond the chosen span
+    (one the dispatch did not schedule) computes garbage nobody reads and
+    writes its K/V to the trash block, because a view that stops short of
+    it says nothing about where it may write. None: the whole table, the
+    program this was before it took a span."""
     _check_kv_attention(kv_attention)
     B = tokens.shape[0]
     hd = cfg.head_dim
@@ -1175,6 +1246,7 @@ def paged_decode_step_batched(
     bt = cache["bt"]  # [B, MB]
     BS = cache["k"].shape[2]
     max_s = bt.shape[1] * BS
+    _check_spans(spans, bt, BS, kv_attention, live_to)
     x = gather_embed(params["embed"], tokens).astype(cfg.dtype)  # [B, 1, D]
     if cfg.embed_scale:
         x = x * math.sqrt(cfg.dim)
@@ -1184,6 +1256,9 @@ def paged_decode_step_batched(
     valid = (jnp.arange(max_s)[None, :] <= pos[:, None])  # [B, T]
     mask = valid[:, None, None, None, :]
     blk = bt[jnp.arange(B), pos // BS]  # [B] current block per row
+    if spans is not None:
+        span_at = _span_index(spans, live_to)
+        blk = jnp.where(pos < jnp.asarray(spans, jnp.int32)[span_at], blk, 0)
     off = pos % BS
 
     def rot(t):
@@ -1212,10 +1287,14 @@ def paged_decode_step_batched(
         else:
             kp = kp.at[layer, blk, off].set(k[:, 0])
             vp = vp.at[layer, blk, off].set(v[:, 0])
-            attn = attention(
-                q, _paged_view(kp, layer, bt), _paged_view(vp, layer, bt),
-                causal=False, mask=mask,
-            )
+            if spans is None:
+                attn = attention(
+                    q, _paged_view(kp, layer, bt), _paged_view(vp, layer, bt),
+                    causal=False, mask=mask,
+                )
+            else:
+                attn = _attend_over_span(
+                    spans, span_at, q, kp, vp, layer, bt, pos)
         x = x + attn.reshape(B, 1, cfg.n_heads * hd) @ deq(lp["wo"])
         h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, cfg.norm_plus_one)
         gate = _act(cfg)((h @ deq(lp["w_gate"])).astype(jnp.float32)).astype(h.dtype)
@@ -1245,11 +1324,15 @@ def paged_decode_segment(
     n_steps: int,
     greedy: bool = False,
     kv_attention: str = "gather",
+    spans: Optional[Tuple[int, ...]] = None,
+    live_to: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, Params]:
     """Block-table twin of :func:`decode_segment` — same on-device
     sample->feed chain and return contract, over the paged step. The
     engine reserves blocks covering ``pos + n_steps`` for every decoding
     row BEFORE dispatch, so in-segment writes never need a host trip.
+    ``spans`` and ``live_to`` as in :func:`paged_decode_step_batched`:
+    ``live_to`` holds ``pos + n_steps`` of every row whose tokens are read.
 
     The gumbel sample chain is keyed off ``key`` alone — per step, one
     split shared by every row — so for a fixed seed the sampled path is
@@ -1262,7 +1345,8 @@ def paged_decode_segment(
     def body(carry, step_key):
         cache, toks = carry
         logits, cache = paged_decode_step_batched(
-            params, cache, toks, cfg, kv_attention=kv_attention
+            params, cache, toks, cfg, kv_attention=kv_attention,
+            spans=spans, live_to=live_to,
         )
         if greedy:
             z = logits
@@ -1305,6 +1389,8 @@ def _paged_suffix_forward(
     positions: Optional[jax.Array] = None,  # [B, S] per-token positions
     self_mask: Optional[jax.Array] = None,  # [B, S, S] in-suffix mask
     rows: Optional[jax.Array] = None,  # [B] cache rows of a compact batch
+    spans: Optional[Tuple[int, ...]] = None,  # the view's spans, in keys
+    live_to: Optional[jax.Array] = None,  # scalar: picks one of ``spans``
 ) -> Tuple[jax.Array, Params]:
     """Shared body of paged prefill and speculative verify: run suffix
     tokens at global positions ``starts[b] + s`` against the gathered
@@ -1340,18 +1426,28 @@ def _paged_suffix_forward(
     written through its own block table ``bt[rows[b]]``; every other
     row's blocks and ``pos`` are left as they were. The same mathematics
     on fewer rows — prefill computes the rows that hold a prompt. None
-    (the verify entry points) means every cache row, in order."""
+    (the verify entry points) means every cache row, in order.
+
+    ``spans`` (static; the gather write path only) with ``live_to``
+    (traced scalar): attend over the smallest span that holds ``live_to``,
+    which must hold ``starts + S`` of every active row
+    (:func:`_attend_over_span`). Writes go through the whole table as
+    ever (pad and inactive positions to the trash block)."""
     _check_kv_attention(kv_attention)
     if (positions is not None or self_mask is not None) \
             and not self_contained:
         raise ValueError(
             "positions/self_mask require self_contained=True"
         )
+    if spans is not None and self_contained:
+        raise ValueError("spans are the write path's: self_contained=False")
     B, S = tokens.shape
     hd = cfg.head_dim
     bt = cache["bt"] if rows is None else cache["bt"][rows]  # [B, MB]
     BS = cache["k"].shape[2]
     max_s = bt.shape[1] * BS
+    _check_spans(spans, bt, BS, kv_attention, live_to)
+    span_at = None if spans is None else _span_index(spans, live_to)
     active = lengths > 0
     x = gather_embed(params["embed"], tokens).astype(cfg.dtype)
     if cfg.embed_scale:
@@ -1420,11 +1516,14 @@ def _paged_suffix_forward(
                 jnp.concatenate([_paged_view(vp, layer, bt), v], axis=1),
                 causal=False, mask=mask,
             )
-        else:
+        elif spans is None:
             attn = attention(
                 q, _paged_view(kp, layer, bt), _paged_view(vp, layer, bt),
                 causal=False, mask=mask,
             )
+        else:
+            attn = _attend_over_span(
+                spans, span_at, q, kp, vp, layer, bt, posq)
         x = x + attn.reshape(B, S, cfg.n_heads * hd) @ deq(lp["wo"])
         h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, cfg.norm_plus_one)
         gate = _act(cfg)((h @ deq(lp["w_gate"])).astype(jnp.float32)).astype(h.dtype)
@@ -1514,13 +1613,17 @@ def paged_prefill_from(
     cfg: LlamaConfig,
     kv_attention: str = "gather",
     rows: Optional[jax.Array] = None,
+    spans: Optional[Tuple[int, ...]] = None,
+    live_to: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Params]:
     """Block-table twin of :func:`prefill_batched_from` (suffix-only
     prefill over a grafted prefix): last-token logits + updated cache.
-    ``rows`` as in :func:`_paged_suffix_forward`: a compact batch."""
+    ``rows``, ``spans`` and ``live_to`` as in
+    :func:`_paged_suffix_forward`: a compact batch, over a view that
+    stops at the span holding ``live_to``."""
     x, cache = _paged_suffix_forward(
         params, cache, tokens, lengths, starts, cfg,
-        kv_attention=kv_attention, rows=rows,
+        kv_attention=kv_attention, rows=rows, spans=spans, live_to=live_to,
     )
     idx = jnp.maximum(lengths - 1, 0)
     x_last = jnp.take_along_axis(

@@ -646,6 +646,18 @@ class ServingMetrics:
             "token (the rest is bucket padding and, on a contiguous "
             "cache, rows with nothing to prefill)",
         )
+        self.view_keys = r.counter(
+            "kubedl_tpu_serving_view_keys",
+            "Keys of the paged gathered view the decode and suffix-"
+            "prefill programs attended over, a layer: span x rows "
+            "computed, once a decode step and once a prefill program",
+        )
+        self.view_keys_full = r.counter(
+            "kubedl_tpu_serving_view_keys_full",
+            "The same count had every view spanned max_seq keys. "
+            "view_keys over this is the share of the full view that was "
+            "gathered and scored (1.0 for an engine with one span)",
+        )
         # controller-side replica health (the probe-failure satellite:
         # a replica that stops answering its stats probe must SURFACE,
         # not silently drop out of the QPS math)

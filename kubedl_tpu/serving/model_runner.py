@@ -10,6 +10,12 @@ blocked attention: chosen once, in the constructor); the K/V row's format
 and which programs donate the cache (it lives here, every program that
 consumes it reassigns it here, and the engine's host mirrors reach it only
 through :meth:`upload_mirrors`). A new model kind brings a runner.
+
+A fourth since the view got a span: how many keys of a row the paged
+gather programs attend over. The engine says how far a dispatch reaches
+(``live_to``); the runner owns the ladder of spans (:attr:`ModelRunner.spans`)
+its programs hold, and each program takes the branch of the smallest span
+that holds ``live_to``.
 """
 
 from __future__ import annotations
@@ -45,6 +51,13 @@ class ModelRunner:
     same compiles. ``llama.preset`` and ``llama.llama_init`` are looked up
     on the module at call time: benchmark/program.py swaps both while it
     builds an engine."""
+
+    #: the shortest span of the gathered view, in keys. The ladder is the
+    #: powers of two from here up to ``max_seq``, ``max_seq`` itself the
+    #: last: an engine whose ``max_seq`` is no longer than this has one
+    #: span and compiles what it always compiled. A class constant and no
+    #: option, like ``LlamaEngine.SEGMENT_BUCKETS``; a test may shrink it.
+    SPAN_FLOOR = 1024
 
     def __init__(self, preset: str, *, max_batch: int, max_seq: int = 0,
                  paged: bool = True, kv_block_size: int = 16,
@@ -82,10 +95,22 @@ class ModelRunner:
             self.mesh = build_mesh(spec, jax.devices()[: spec.size()])
             log.info("serving over mesh %s", dict(mesh_axes))
         self.cache = None  # the K/V arrays, ``pos`` and ``bt``: new_cache()
+        #: the spans of the gathered view, ascending, ``max_seq`` the last.
+        #: Only the paged gather programs have a view to cut; with one span
+        #: they take no ``live_to`` and are the programs they always were.
+        self.spans = (self.max_seq,)
+        if paged and kv_attention == "gather":
+            self.spans = self.span_ladder(self.max_seq, self.kv_block_size)
 
         # ---- the one place that picks the device-function family ----
         if paged:
             att = {"kv_attention": kv_attention}
+            spans = self.spans
+
+            def view(live_to):
+                """What a program handed ``live_to`` (a 1-tuple, or none
+                with one span) passes on to its ``llama`` function."""
+                return {"spans": spans, "live_to": live_to[0]} if live_to else {}
 
             def decode_step(p, c, t):
                 return llama.paged_decode_step_batched(p, c, t, cfg, **att)
@@ -102,9 +127,9 @@ class ModelRunner:
                 lg, c = llama.paged_prefill_batched(p, c, t, l, cfg, rows=rows)
                 return acc.at[rows].set(lg), c
 
-            def prefill_from(p, c, t, l, st, rows, acc):
+            def prefill_from(p, c, t, l, st, rows, acc, *live_to):
                 lg, c = llama.paged_prefill_from(
-                    p, c, t, l, st, cfg, rows=rows, **att)
+                    p, c, t, l, st, cfg, rows=rows, **att, **view(live_to))
                 return acc.at[rows].set(lg), c
 
             #: ``acc`` of a tick's first prefill program: rows no program
@@ -135,7 +160,10 @@ class ModelRunner:
             # graft writes a cached entry's K/V into a row (donated:
             # in-place in HBM). One compile per entry bucket length.
             graft, segment = llama.copy_prefix_into_row, llama.decode_segment
-        self._segment = functools.partial(segment, **att)
+
+            def view(live_to):
+                return {}
+        self._segment, self._view = functools.partial(segment, **att), view
 
         # the cache is DONATED: decode/prefill update it in place in HBM
         # instead of allocating a fresh copy every step
@@ -349,6 +377,37 @@ class ModelRunner:
         """A contiguous row's first ``p_len`` positions as ``(k, v)``."""
         return self._extract(self.cache, row, p_len)
 
+    # -- the span of the gathered view ---------------------------------------
+
+    @classmethod
+    def span_ladder(cls, max_seq: int, block: int = 1) -> tuple:
+        """The view's spans for a row of ``max_seq`` keys: powers of two
+        times :attr:`SPAN_FLOOR` below ``max_seq`` (whole blocks only),
+        then ``max_seq``."""
+        spans, s = [], max(1, int(cls.SPAN_FLOOR))
+        while s < max_seq:
+            if s % block == 0:
+                spans.append(s)
+            s *= 2
+        return tuple(spans) + (max_seq,)
+
+    def span_for(self, live_to: Optional[int]) -> int:
+        """The smallest span that holds positions ``[0, live_to)``, which
+        is the branch a program given ``live_to`` takes; ``max_seq`` for
+        None or anything longer."""
+        if live_to is not None:
+            for span in self.spans:
+                if live_to <= span:
+                    return span
+        return self.spans[-1]
+
+    def _live_to(self, live_to: Optional[int]) -> tuple:
+        """The argument that picks a program's span: none at all for a
+        runner with one span (its programs take none)."""
+        if len(self.spans) == 1:
+            return ()
+        return (np.int32(self.max_seq if live_to is None else live_to),)
+
     # -- the programs, run on the runner's cache ----------------------------
 
     def warmup(self, params) -> None:
@@ -358,32 +417,40 @@ class ModelRunner:
         )
         jax.block_until_ready(logits)
 
-    def prefill(self, params, toks, lens, starts=None, rows=None, acc=None):
+    def prefill(self, params, toks, lens, starts=None, rows=None, acc=None,
+                live_to: Optional[int] = None):
         """One prefill program: whole prompts from position 0, or, given
         ``starts``, suffixes that attend through the cache. Paged: ``rows``
         names the compact batch's cache rows and ``acc`` holds the logits
-        of the tick's earlier programs (None: none yet). Returns the
+        of the tick's earlier programs (None: none yet). ``live_to``: one
+        past the highest position a suffix program reads or writes, which
+        picks its view's span (None: the whole table). Returns the
         ``[max_batch, V]`` logits."""
         args = [toks, lens] if starts is None else [toks, lens, starts]
         if self.paged:
             args += [rows, self._no_logits if acc is None else acc]
-        fn = self._prefill if starts is None else self._prefill_from
+        if starts is None:
+            fn = self._prefill
+        else:
+            fn = self._prefill_from
+            args += self._live_to(live_to)
         logits, self.cache = fn(params, self.cache, *args)
         return logits
 
     def _segment_fn(self, n_steps: int, greedy: bool):
         """Jitted n-step decode with on-device sampling (cache donated);
-        one compile per (segment size, greedy) combination."""
+        one compile per (segment size, greedy) combination. With several
+        spans it takes ``live_to`` last, and holds a branch a span."""
         fn = self._segments.get((n_steps, greedy))
         if fn is None:
-            seg, cfg = self._segment, self.cfg
+            seg, cfg, view = self._segment, self.cfg, self._view
             # the step count is in the name: a module event of a device
             # profile carries a duration and nothing else
             name = f"engine_decode_seg{n_steps}" + ("" if greedy else "_sampled")
             fn = jax.jit(
-                _named(name, lambda p, c, tokens, temps, key: seg(
+                _named(name, lambda p, c, tokens, temps, key, *live_to: seg(
                     p, c, tokens, temps, key, cfg=cfg,
-                    n_steps=n_steps, greedy=greedy,
+                    n_steps=n_steps, greedy=greedy, **view(live_to),
                 )),
                 donate_argnums=(1,),
             )
@@ -391,11 +458,13 @@ class ModelRunner:
         return fn
 
     def decode_segment(self, n_steps: int, greedy: bool, params, tokens,
-                       temps, key):
-        """``n_steps`` decode steps, sampled on the device. Returns
-        ``(toks [B, n_steps], last [B, 1], key)``."""
+                       temps, key, live_to: Optional[int] = None):
+        """``n_steps`` decode steps, sampled on the device, over the view
+        span that holds ``live_to``: one past the highest position a row
+        whose tokens are read will stand at (None: the whole table).
+        Returns ``(toks [B, n_steps], last [B, 1], key)``."""
         toks, last, key, self.cache = self._segment_fn(n_steps, greedy)(
-            params, self.cache, tokens, temps, key,
+            params, self.cache, tokens, temps, key, *self._live_to(live_to),
         )
         return toks, last, key
 

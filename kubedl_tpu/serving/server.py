@@ -365,6 +365,7 @@ class LlamaEngine:
                        "handoffs_out": 0, "handoffs_in": 0,
                        "handoff_failures": 0,
                        "prefill_tokens": 0, "prefill_positions": 0,
+                       "view_keys": 0, "view_keys_full": 0,
                        "started_at": time.time()}
         #: load-shedding budget: reject (503) instead of queueing once the
         #: queue is deeper than max_queue_depth or its head has waited
@@ -1686,6 +1687,15 @@ class LlamaEngine:
             b <<= 1
         return min(b, self.max_seq)
 
+    def _count_view_keys_locked(self, keys: int, gathers: int) -> None:
+        """``gathers`` row views, ``keys`` keys in all, were gathered (a
+        layer): the counters that say how often the view's span engages.
+        Caller holds cv."""
+        self.metrics.view_keys.inc(keys)
+        self.metrics.view_keys_full.inc(self.max_seq * gathers)
+        self._stats["view_keys"] += keys
+        self._stats["view_keys_full"] += self.max_seq * gathers
+
     @staticmethod
     def segment_size(need: int, cap: int,
                      buckets: tuple = SEGMENT_BUCKETS) -> int:
@@ -1804,7 +1814,7 @@ class LlamaEngine:
         groups = [[t] for t in sched] if self._paged else [sched]
         logits = None  # of the tick's earlier programs
         prefill_ids = t0 = None
-        saved = positions = 0
+        saved = positions = view_keys = views = 0
         for n, group in enumerate(groups):
             slots = len(group) if self._paged else self.max_batch
             bucket = self._prefill_bucket(
@@ -1834,15 +1844,23 @@ class LlamaEngine:
                         )
                     t0 = time.perf_counter()
                 from_prefix = suffix or bool(np.any(starts > 0))
+                # every position the program reads or writes lies below
+                live_to = min(int(starts.max()) + bucket, self.max_seq)
                 logits = self._runner.prefill(
                     params, jnp.asarray(toks), jnp.asarray(lens),
                     starts=jnp.asarray(starts) if from_prefix else None,
                     rows=jnp.asarray(
                         np.array([i for i, *_ in group], np.int32)
                     ) if self._paged else None,
-                    acc=logits,
+                    acc=logits, live_to=live_to,
                 )
                 positions += slots * bucket
+                if from_prefix and self._paged:
+                    # the whole-prompt program attends locally: no view
+                    span = self._runner.span_for(live_to)
+                    ph.set(span=span)
+                    view_keys += span * slots
+                    views += slots
                 if n == len(groups) - 1:
                     prefill_ids = self._sample_first(sched, logits, pick_key)
             acct["dispatch_ms"] += ph.ms
@@ -1856,6 +1874,7 @@ class LlamaEngine:
         with self._cv:
             self._stats["prefill_tokens"] += tokens
             self._stats["prefill_positions"] += positions
+            self._count_view_keys_locked(view_keys, views)
         return prefill_ids, t0
 
     def _sample_first(self, sched, logits, pick_key):
@@ -2511,15 +2530,29 @@ class LlamaEngine:
             self._temps_cache = (fp, jnp.asarray(temps))
         if self._paged:
             self._runner.upload_mirrors(self._bt_host, self._pos_host)
+        # the reserve above has grown every scheduled row to pos + k; rows
+        # it left out run too, and nobody reads what they compute
+        live_to = None
+        if self._paged:
+            live_to = min(
+                max(int(self._pos_host[i]) for i, _ in decoding) + k,
+                self.max_seq,
+            )
         t0 = time.perf_counter()  # start of the rows' engine.decode_segment
         toks, last, self._key = self._runner.decode_segment(
             k, greedy, params, tokens_dev, self._temps_cache[1], self._key,
+            live_to=live_to,
         )
         self._chain = (
             self._prefill_gen, tuple(i for i, _ in decoding), last
         )
         sched = []
+        attrs = {}
         with self._cv:
+            if self._paged:
+                attrs["span"] = span = self._runner.span_for(live_to)
+                self._count_view_keys_locked(
+                    span * k * self.max_batch, k * self.max_batch)
             for i, s in decoding:
                 take = min(k, self._rem(s))
                 s.pending += take
@@ -2534,7 +2567,7 @@ class LlamaEngine:
                     )
             self._pipe["inflight"] = 1
         phase.set(k=k, rows=len(sched), slots=self.max_batch,
-                  take=sum(t for _i, _s, t in sched))
+                  take=sum(t for _i, _s, t in sched), **attrs)
         return {"toks": toks, "sched": sched, "k": k, "t0": t0}
 
 

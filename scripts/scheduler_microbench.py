@@ -157,7 +157,8 @@ class StubRunner:
 
     # whole-prompt and suffix prefill alike, whatever batch they are
     # handed (a paged engine's is compact: rows + the logits array)
-    def prefill(self, params, toks, lens, starts=None, rows=None, acc=None):
+    def prefill(self, params, toks, lens, starts=None, rows=None, acc=None,
+                live_to=None):
         return self._logits
 
     def sample_first(self, logits, temps, key):
@@ -166,7 +167,8 @@ class StubRunner:
     def merge_chain(self, last, ids, mask):
         return last
 
-    def decode_segment(self, n_steps, greedy, params, tokens, temps, key):
+    def decode_segment(self, n_steps, greedy, params, tokens, temps, key,
+                       live_to=None):
         return self._seg_toks[n_steps], self._last, key
 
     def graft(self, k, v, row, length):

@@ -108,3 +108,53 @@ def test_paged_kernels_compile_at_decode_shapes(on_chip, KV, group, hd, fused):
         lambda *a: pa._pallas_paged_attention(*a, interpret=False), *args
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("program", ["decode_segment", "prefill_from"])
+def test_view_span_branches_compile_in_place(on_chip, program):
+    """The paged gather programs with the view's spans as branches (PR 31),
+    at Mistral-7B's attention widths, 2 layers, a 4096-key table: the TPU
+    compiler keeps one conditional with a branch a span inside the layer
+    loop, and the K/V pools still travel through it in place: no operation
+    copies a pool-shaped array (PR 29's property, which a conditional that
+    took the pools by value would lose)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from kubedl_tpu.models import llama
+
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, dim=4096, n_layers=2, n_heads=32, n_kv_heads=8,
+        ffn_dim=14336, max_seq=4096, rope_theta=1e6, dtype=jnp.bfloat16,
+    )
+    B, BS, NB, spans = 4, 16, 1087, (1024, 2048, 4096)
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: on_chip(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: llama.llama_init(jax.random.PRNGKey(0), cfg)))
+    cache = place(jax.eval_shape(
+        lambda: llama.init_paged_cache(cfg, B, 4096, NB, BS)))
+    i32 = lambda *s: on_chip(s, jnp.int32)  # noqa: E731
+    if program == "decode_segment":
+        def fn(p, c, tokens, temps, key, live_to):
+            return llama.paged_decode_segment(
+                p, c, tokens, temps, key, cfg, n_steps=4, greedy=True,
+                spans=spans, live_to=live_to)
+
+        args = (params, cache, i32(B, 1), on_chip((B,), jnp.float32),
+                on_chip((2,), jnp.uint32), i32())
+    else:
+        def fn(p, c, toks, lens, starts, rows, live_to):
+            return llama.paged_prefill_from(
+                p, c, toks, lens, starts, cfg, rows=rows, spans=spans,
+                live_to=live_to)
+
+        args = (params, cache, i32(1, 256), i32(1), i32(1), i32(1), i32())
+    text = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+    (cond,) = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}", text)
+    assert len(cond.split(",")) == len(spans)
+    pool = f"bf16[{cfg.n_layers},{NB},{BS},{cfg.n_kv_heads},{cfg.head_dim}]"
+    copies = [ln for ln in text.splitlines() if " copy(" in ln and pool in ln]
+    assert not copies, copies[:2]

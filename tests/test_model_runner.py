@@ -176,3 +176,126 @@ def test_recovery_builds_the_cache_as_the_constructor_did(kv_layout):
         assert rebuilt == built
     finally:
         eng.close()
+
+
+# ---- the view's span, at the engine (PR 31) --------------------------------
+
+#: (prompt length, max_tokens): decode and prefill end in the 64-, the 128-
+#: and the 256-key span of a 256-key engine whose floor is shrunk to 64
+SPAN_REQUESTS = [(10, 8), (70, 20), (150, 40)]
+
+
+def _span_prompts():
+    import numpy as np
+
+    rng = np.random.default_rng(31)
+    return [(rng.integers(1, 200, n).tolist(), out) for n, out in SPAN_REQUESTS]
+
+
+@pytest.fixture(scope="module")
+def span_engines():
+    """``(many, one)``: the same engine with three spans and with one."""
+    from kubedl_tpu.serving.model_runner import ModelRunner
+    from kubedl_tpu.serving.server import LlamaEngine
+
+    kw = dict(preset="tiny", max_batch=4, max_seq=256,
+              prefill_chunk_tokens=64, prefix_cache_mb=0)
+    floor = ModelRunner.SPAN_FLOOR
+    ModelRunner.SPAN_FLOOR = 64
+    try:
+        many = LlamaEngine(**kw)
+    finally:
+        ModelRunner.SPAN_FLOOR = floor
+    one = LlamaEngine(**kw)
+    yield many, one
+    many.close()
+    one.close()
+
+
+class TestSeveralSpans:
+    def test_one_program_holds_every_span(self, span_engines):
+        """Spans are branches inside a program, not programs: both engines
+        have the same programs under the same names, and the one with
+        several spans hands each a ``live_to`` where the other hands none."""
+        many, one = span_engines
+        assert many._runner.spans == (64, 128, 256) and one._runner.spans == (256,)
+        assert many._runner._live_to(70) == (70,) and one._runner._live_to(70) == ()
+        names = lambda r: sorted(f.__name__ for f in r._segments.values())  # noqa: E731
+        for eng in span_engines:
+            eng.generate([5, 9, 13], max_tokens=6)
+        assert names(many._runner) == names(one._runner) != []
+
+    @pytest.mark.parametrize("how", ["alone", "together"])
+    def test_serves_the_one_span_engines_tokens(self, span_engines, how):
+        from test_phase_spans import serve
+
+        many, one = span_engines
+        requests = _span_prompts()
+        if how == "together":
+            got, want = serve(many, requests), serve(one, requests)
+        else:
+            got = [serve(many, [r])[0] for r in requests]
+            want = [serve(one, [r])[0] for r in requests]
+        assert [r["token_ids"] for r in got] == [r["token_ids"] for r in want]
+        assert [len(r["token_ids"]) for r in got] == [n for _p, n in SPAN_REQUESTS]
+
+    def test_nothing_compiles_while_requests_of_every_span_are_served(self, span_engines):
+        """A request reaches a span by how long its row is, and every span
+        is a branch of programs that short requests have already compiled:
+        after a warm-up of SHORT requests (one of every token bucket and
+        segment size, as a deployment's warm-up sends), requests that run
+        in the longer spans compile nothing."""
+        import jax
+
+        from test_phase_spans import serve
+
+        many, _one = span_engines
+        requests = _span_prompts()
+        # short rows only: buckets 16, 32, 64 and every segment size, all
+        # inside the 64-key span
+        serve(many, [([7] * n, 3) for n in (9, 20, 40)] + [([5, 6], 40)])
+        serve(many, [([8, 9], 2), ([8, 9, 10], 9)])
+        fired, armed = [], [True]
+
+        def listener(event, _secs, **_kw):
+            if armed[0] and event == "/jax/core/compile/backend_compile_duration":
+                fired.append(event)
+
+        jax.monitoring.register_event_duration_secs_listener(listener)
+        try:
+            for r in requests:
+                serve(many, [r])
+            serve(many, requests)
+        finally:
+            armed[0] = False
+        assert not fired
+
+    def test_phases_carry_the_span_and_the_counters_close(self, span_engines, tmp_path, monkeypatch):
+        from kubedl_tpu.observability.tracing import TRACER
+        from test_phase_spans import capture, serve
+
+        monkeypatch.setattr(TRACER, "enabled", True)
+        many, one = span_engines
+        before = many.stats()
+        with capture(tmp_path) as cap:
+            serve(many, _span_prompts())
+        after = many.stats()
+        decode = cap.named("engine.decode_dispatch")
+        prefill = cap.named("engine.prefill_dispatch")
+        assert {e[4]["span"] for e in decode} == {64, 128, 256}
+        assert {e[4]["span"] for e in prefill} == {64, 128, 256}
+        for e in prefill:  # a chunk's view holds every position it touches
+            assert e[4]["bucket"] <= e[4]["span"]
+        keys = (sum(e[4]["span"] * e[4]["k"] * e[4]["slots"] for e in decode)
+                + sum(e[4]["span"] * e[4]["slots"] for e in prefill))
+        full = (sum(256 * e[4]["k"] * e[4]["slots"] for e in decode)
+                + sum(256 * e[4]["slots"] for e in prefill))
+        assert all(e[4]["slots"] == many.max_batch for e in decode)
+        assert after["view_keys"] - before["view_keys"] == keys
+        assert after["view_keys_full"] - before["view_keys_full"] == full
+        assert 0 < keys < full
+        assert many.metrics.view_keys.value() == after["view_keys"]
+        assert many.metrics.view_keys_full.value() == after["view_keys_full"]
+        # an engine with one span gathers the whole view every time
+        st = one.stats()
+        assert 0 < st["view_keys"] == st["view_keys_full"]
