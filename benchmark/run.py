@@ -4,7 +4,10 @@
 One process; it holds the chip, builds the cell's program, warms up the
 shapes the cell's traffic uses (set-up), measures for ``--seconds``, compares
 what the timed path produced with the plain reference, and prints one JSON
-object as the last line of its output. Everything that belongs to one
+object as the last line of its standard output. Each number compared stands
+beside its limit three times: in ``compared:`` lines before that object, under
+the object's last key, and as the last lines on standard error (what a
+driver keeps of a run that was not correct). Everything that belongs to one
 configuration, one model family, one traffic mix, one generator kind or one
 metric is a file found by the name in ``BENCHMARK.json`` (a family's by the
 configuration's ``family`` key); see ``benchmark/README.md``.
@@ -171,17 +174,27 @@ def device_info(chips: int, require_tpu: bool) -> Dict[str, Any]:
     return info
 
 
-def _summary_line(record: Dict[str, Any]) -> str:
+def _summary_line(record: Dict[str, Any], stats: Dict[str, Any]) -> str:
     if record["kind"] == "serve":
+        from benchmark.stats import percentile
+
         ok = [r for r in record["requests"] if r["ok"]]
         med = lambda xs: statistics.median(xs) if xs else float("nan")  # noqa: E731
         multi = [r for r in ok if r["n_out"] >= 2]
+        decode_ms = sum(r["latency_ms"] - r["ttft_ms"] for r in multi)
         return (
             f"requests attempted {record['attempted']} failed {record['failed']} "
-            f"ttft_ms median {med([r['ttft_ms'] for r in ok]):.2f} (n={len(ok)}) "
+            f"shed {stats.get('shed')} open at the window's end "
+            f"{sum(1 for r in record['requests'] if r['done_s'] > record['window_s'])} "
+            f"ttft_ms median {med([r['ttft_ms'] for r in ok]):.2f} "
+            f"mean {statistics.fmean([r['ttft_ms'] for r in ok] or [float('nan')]):.2f} "
+            f"p95 {percentile([r['ttft_ms'] for r in ok] or [float('nan')], 95):.2f} "
+            f"p99 {percentile([r['ttft_ms'] for r in ok] or [float('nan')], 99):.2f} (n={len(ok)}) "
             f"tpot_ms median "
             f"{med([(r['latency_ms'] - r['ttft_ms']) / (r['n_out'] - 1) for r in multi]):.3f} "
-            f"(n={len(multi)}) late_ms median {med([r['late_ms'] for r in record['requests']]):.3f} "
+            f"over all gaps {decode_ms / max(1, sum(r['n_out'] - 1 for r in multi)):.3f} "
+            f"(n={len(multi)}) latency_ms a token {sum(r['latency_ms'] for r in ok) / max(1, sum(r['n_out'] for r in ok)):.3f} "
+            f"late_ms median {med([r['late_ms'] for r in record['requests']]):.3f} "
             f"output tokens {sum(r['n_out'] for r in ok)}"
         )
     return (
@@ -202,13 +215,13 @@ def run(root: Path, manifest: Dict[str, Any], workload: str, seed: int, seconds:
     record, stats = out["record"], out["stats"]
     record.update(setup_s=ctx.setup_s, chips=ctx.chips, config=ctx.config,
                   device_kind=device["kind"])
-    print(f"summary: setup_s {ctx.setup_s:.2f} {_summary_line(record)}", flush=True)
+    print(f"summary: setup_s {ctx.setup_s:.2f} {_summary_line(record, stats)}", flush=True)
     print(f"compiles_in_window: {ctx.compiles_in_window}; the comparison with the reference "
           f"took {record['check_s']:.1f} s, outside the window and outside setup_s", flush=True)
-    for c in record["compared"]:
-        rel = ">=" if c.get("at_least") else "<="
-        print(f"compared: {c['name']} = {c['value']:.6g} (limit {rel} {c['limit']:.6g}) "
-              f"{'ok' if c['ok'] else 'NOT OK'}", flush=True)
+    compared_lines = [
+        f"compared: {c['name']} = {c['value']:.6g} (limit {'>=' if c.get('at_least') else '<='} "
+        f"{c['limit']:.6g}) {'ok' if c['ok'] else 'NOT OK'}" for c in record["compared"]]
+    print("\n".join(compared_lines), flush=True)
     device["memory_peak_bytes"] = ctx.memory_peak_bytes
     result: Dict[str, Any] = {
         "correct": all(c["ok"] for c in record["compared"]),
@@ -246,7 +259,14 @@ def run(root: Path, manifest: Dict[str, Any], workload: str, seed: int, seconds:
                 and value > 105.0):
             raise SystemExit(f"{m['name']} = {value}% is over 105%: the count or the peak is wrong")
         result["metrics"][m["name"]] = {"value": float(value), "unit": m["unit"]}
+    if "ran_dry_s" in record:  # a closed loop: when its sequence ran out, or null
+        result["ran_dry_s"] = record["ran_dry_s"]
+    result["compared"] = {
+        c["name"]: {"value": c["value"], "limit": c["limit"],
+                    "must_be": "at_least" if c.get("at_least") else "at_most", "ok": c["ok"]}
+        for c in record["compared"]}
     print(json.dumps(result), flush=True)
+    print("\n".join(compared_lines), file=sys.stderr, flush=True)
     return result
 
 

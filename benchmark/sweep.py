@@ -1,12 +1,18 @@
 """Find a serving cell's knee, once, by hand, on the chip:
-``python3 benchmark/sweep.py --workload chat-open --rates 1,1.5,2,2.5,3,4 --seconds 20``.
+``python3 benchmark/sweep.py --workload chat-open --rates 4,5,6,7,8,10 --seconds 51``.
 
 One process and one set-up; each rate is offered for ``--seconds`` through the
-cell's own open-loop generator, then the engine drains. The knee is the highest
-rate at which the backlog does not grow over the window: requests still in the
-engine when the window closes stay near the batch size, and the second half's
-time to first token is not far above the first half's. The rate written into
-the traffic file is 0.8 of it, as a number. Not part of a benchmark run.
+cell's own open-loop generator, then the engine drains. Give it the benchmark's
+own window: at 20 s a rate holds some tens of requests, and PR 33's first sweep
+read a knee of 5.0 where whole windows then sustained 6.0. The knee is the
+highest rate at which the backlog does not grow over the window: requests still
+in the engine when the window closes are no more than the batch (the
+configuration's ``max_batch``), nothing is shed, and the second half's time to
+first token is not far above the first half's. The rate written into the
+traffic file is 0.75 of it, rounded down to a quarter request a second, beside
+the knee itself (``knee_per_s``) and the sweep's lines (``notes``);
+``tests/test_manifest.py`` holds the two numbers to each other. Not part of a
+benchmark run.
 """
 
 from __future__ import annotations
@@ -26,7 +32,7 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
     ap.add_argument("--rates", required=True)
-    ap.add_argument("--seconds", type=float, default=20.0)
+    ap.add_argument("--seconds", type=float, default=51.0)
     ap.add_argument("--seed", type=int, default=11)
     args = ap.parse_args()
     from benchmark import families
@@ -53,7 +59,9 @@ def main() -> int:
         open_at_end = sum(1 for r in reqs if r["done_s"] > args.seconds)
         print(json.dumps({
             "rate_per_s": rate, "sent": len(reqs), "failed": len(reqs) - len(ok),
-            "open_at_window_end": open_at_end,
+            "open_at_window_end": open_at_end, "max_batch": ctx.config["engine"]["max_batch"],
+            "shed_so_far": program.stats().get("shed"),
+            "gen_late_p95_ms": percentile([r["late_ms"] for r in reqs], 95),
             "drain_s": max(r["done_s"] for r in reqs) - args.seconds,
             "ttft_ms_median_first_half": statistics.median(first) if first else None,
             "ttft_ms_median_second_half": statistics.median(second) if second else None,

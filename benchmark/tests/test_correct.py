@@ -84,6 +84,11 @@ def test_an_altered_token_is_not_correct(monkeypatch):
     monkeypatch.setattr(program.ServeProgram, "generate", altered)
     result = _run("tiny-closed", 5)
     assert result["failed"] == 0 and not result["correct"]
+    # the line says whether the closed loop stayed loaded, and ends with each
+    # number compared beside its limit
+    assert "ran_dry_s" in result and list(result)[-1] == "compared"
+    assert not result["compared"]["served_gap_mean"]["ok"]
+    assert set(result["compared"]["served_gap_mean"]) == {"value", "limit", "must_be", "ok"}
 
 
 def test_the_sample_holds_the_longest_request():

@@ -4,7 +4,9 @@ import json
 import time
 from pathlib import Path
 
-from benchmark.generators import _serve, open_loop
+import pytest
+
+from benchmark.generators import _serve, closed_loop, open_loop
 
 MIX = json.loads((Path(__file__).parents[1] / "traffic" / "chat-open.json").read_text())
 
@@ -55,3 +57,34 @@ def test_a_failed_request_is_not_ok():
     t0 = time.perf_counter()
     rec = _serve.call(Shed(), {"prompt": [1], "max_tokens": 3}, t0, t0)
     assert not rec["ok"] and rec["ttft_ms"] is None
+
+
+CLOSED = {"clients": 2, "requests_drawn": 8, "shape_seed": 3,
+          "prompt_tokens": {"dist": "uniform", "min": 4, "max": 8},
+          "output_tokens": {"dist": "uniform", "min": 2, "max": 4}}
+
+
+class _Paced:
+    def __init__(self, seconds):
+        self.seconds = seconds
+
+    def generate(self, prompt, max_tokens):
+        time.sleep(self.seconds)
+        return {"token_ids": [1] * max_tokens, "ttft_ms": 1.0}
+
+
+@pytest.mark.parametrize("pace_s, runs_dry", [(0.005, True), (0.25, False)])
+def test_a_closed_loop_says_when_its_sequence_ran_out(pace_s, runs_dry):
+    """Four requests a client: at 5 ms each the sequence is used up long before
+    the window's 0.6 s end and ``ran_dry_s`` is that time; at 250 ms each a
+    client is still sending at the end and it is null."""
+    found = {}
+    t0 = time.perf_counter()
+    reqs = closed_loop.drive(_Paced(pace_s), CLOSED, 1, 0.6, 64, t0, found)
+    if runs_dry:
+        assert len(reqs) == CLOSED["requests_drawn"]
+        assert 0.0 < found["ran_dry_s"] < 0.3
+        assert found["ran_dry_s"] <= max(r["done_s"] for r in reqs) + 0.05
+    else:
+        assert len(reqs) < CLOSED["requests_drawn"] and found["ran_dry_s"] is None
+    assert closed_loop.drive(_Paced(0.0), CLOSED, 1, 0.1, 64, time.perf_counter())  # and without it

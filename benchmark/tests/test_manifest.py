@@ -225,6 +225,30 @@ def test_cell_resolves_to_files(world, cell):
         assert shape["prompt_tokens"] + shape["max_tokens"] < ctx.config["engine"]["max_seq"]
 
 
+@pytest.mark.parametrize("world, cell", [("repo", c) for c in CELLS]
+                         + [("synthetic", c) for c in CELLS + [SPARSE_CELL]], indirect=["world"])
+def test_a_serve_mix_states_what_its_load_was_set_from(world, cell):
+    """No generator reads these two keys; they hold the file to the runs it was
+    sized by. A closed loop must not run dry: it draws at least twice the most
+    requests any run of the two sets finished. An open loop below the knee
+    states the knee as swept, offers at most 0.75 of it, and its cell lists an
+    end-to-end metric beside the rate it was offered."""
+    root, manifest = world
+    mix = harness.Context(root, manifest, cell, 1, 1.0, False).mix
+    if mix["generator"] == "closed_loop":
+        assert mix["finished_most"] >= 1
+        assert mix["requests_drawn"] >= 2 * mix["finished_most"], (
+            "a window can finish more than half of the sequence: draw more")
+        assert mix["requests_drawn"] % mix["clients"] == 0
+    elif mix["generator"] == "open_loop":
+        assert mix["knee_per_s"] > 0
+        assert mix["rate_per_s"] <= 0.75 * mix["knee_per_s"]
+        # below the knee the tokens delivered are the tokens offered, a constant
+        # of the file: such a cell holds a latency end to end beside it
+        e2e = {m["name"] for m in harness.metrics_of(manifest, "end_to_end", cell)}
+        assert e2e - {"setup_s", "out_tok_s"}, f"{cell} bounds nothing that can move"
+
+
 def test_files_under_paths_use_plain_names(world):
     root, _manifest = world
     for path in (root / "benchmark").rglob("*"):
