@@ -823,12 +823,26 @@ def decode_segment(
     against ``last`` before calling `device_get` on segment N's ``toks``,
     so the copy-out (and all host bookkeeping behind it) overlaps the
     next segment's device compute instead of idling the chip."""
+    return sampled_segment(
+        lambda cache, toks: decode_step_batched(params, cache, toks, cfg),
+        cache, tokens, temps, key, n_steps, greedy,
+    )
+
+
+def sampled_segment(step, cache: Params, tokens: jax.Array, temps: jax.Array,
+                    key: jax.Array, n_steps: int, greedy: bool):
+    """The sample->feed chain of a decode segment over any one-token step
+    ``step(cache, toks [B, 1]) -> (logits [B, V], cache)``: the contiguous
+    and the paged decoder's, and a model kind's of its own
+    (models/hybrid_ssm.py). The gumbel chain is keyed off ``key`` alone:
+    per step, one split shared by every row. Returns ``(toks [B, n_steps],
+    last [B, 1], next_key, cache)``."""
     keys = jax.random.split(key, n_steps + 1)
     next_key, gumbel_keys = keys[0], keys[1:]
 
     def body(carry, step_key):
         cache, toks = carry
-        logits, cache = decode_step_batched(params, cache, toks, cfg)
+        logits, cache = step(cache, toks)
         if greedy:
             z = logits  # all-argmax batch: the [B, V] gumbel would cost
             # ~1.3ms/step at Gemma-2B's vocab for nothing
@@ -1339,29 +1353,13 @@ def paged_decode_segment(
     deterministic and IDENTICAL across ``kv_attention`` kernels (the
     regression gate for the blocked kernel: kernel choice may only
     perturb logits at fp tolerance, never the randomness)."""
-    keys = jax.random.split(key, n_steps + 1)
-    next_key, gumbel_keys = keys[0], keys[1:]
-
-    def body(carry, step_key):
-        cache, toks = carry
-        logits, cache = paged_decode_step_batched(
+    return sampled_segment(
+        lambda cache, toks: paged_decode_step_batched(
             params, cache, toks, cfg, kv_attention=kv_attention,
             spans=spans, live_to=live_to,
-        )
-        if greedy:
-            z = logits
-        else:
-            g = jax.random.gumbel(step_key, logits.shape, dtype=logits.dtype)
-            z = jnp.where(
-                temps[:, None] > 0.0,
-                logits / jnp.maximum(temps[:, None], 1e-4) + g,
-                logits,
-            )
-        nxt = jnp.argmax(z, axis=-1).astype(jnp.int32)[:, None]
-        return (cache, nxt), nxt[:, 0]
-
-    (cache, last), toks = lax.scan(body, (cache, tokens), gumbel_keys)
-    return toks.T, last, next_key, cache
+        ),
+        cache, tokens, temps, key, n_steps, greedy,
+    )
 
 
 def _advance_pos(

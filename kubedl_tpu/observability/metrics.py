@@ -658,6 +658,14 @@ class ServingMetrics:
             "view_keys over this is the share of the full view that was "
             "gathered and scored (1.0 for an engine with one span)",
         )
+        self.state_resets = r.counter(
+            "kubedl_tpu_serving_state_resets",
+            "Rows whose slab of recurrent state a prefill program zeroed "
+            "(the row's tokens began at position 0: an admission, or a "
+            "re-admission after preemption). 0 for a model whose rows "
+            "are K/V blocks alone; state_rows and state_bytes are in "
+            "/v1/stats",
+        )
         # controller-side replica health (the probe-failure satellite:
         # a replica that stops answering its stats probe must SURFACE,
         # not silently drop out of the QPS math)

@@ -16,6 +16,15 @@ gather programs attend over. The engine says how far a dispatch reaches
 (``live_to``); the runner owns the ladder of spans (:attr:`ModelRunner.spans`)
 its programs hold, and each program takes the branch of the smallest span
 that holds ``live_to``.
+
+Two runners stand here. :class:`ModelRunner` is the decoder's
+(``models/llama.py``). :class:`HybridRunner` is the hybrid state-space
+model's (``models/hybrid_ssm.py``): the same paged pools for its few
+attention layers, and beside them a fixed slab of recurrent state a row,
+which its programs reset, carry and advance themselves. Both answer the
+methods the engine calls, under the same program names; :func:`make_runner`
+picks one by the type of the preset's config, and what they share is
+:class:`_Runner`.
 """
 
 from __future__ import annotations
@@ -29,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kubedl_tpu import chaos
-from kubedl_tpu.models import llama
+from kubedl_tpu.models import hybrid_ssm, llama
 
 log = logging.getLogger("kubedl_tpu.serving.model_runner")
 
@@ -45,12 +54,10 @@ def _named(name: str, fn):
     return fn
 
 
-class ModelRunner:
-    """One model's config, K/V arrays and jitted programs. Programs take
-    ``params`` explicitly, so a second weight tree (hot swap) rides the
-    same compiles. ``llama.preset`` and ``llama.llama_init`` are looked up
-    on the module at call time: benchmark/program.py swaps both while it
-    builds an engine."""
+class _Runner:
+    """What every runner has: the mirror upload, the ladder of view spans,
+    and the two programs that know no model (the first-token sampler and
+    the chain merge)."""
 
     #: the shortest span of the gathered view, in keys. The ladder is the
     #: powers of two from here up to ``max_seq``, ``max_seq`` itself the
@@ -58,6 +65,122 @@ class ModelRunner:
     #: span and compiles what it always compiled. A class constant and no
     #: option, like ``LlamaEngine.SEGMENT_BUCKETS``; a test may shrink it.
     SPAN_FLOOR = 1024
+    #: bytes of recurrent state a row owns beside its blocks, whatever its
+    #: context; 0: a row is its blocks and nothing else. Non-zero, the
+    #: engine builds no prefix cache and refuses speculation and hand-off
+    #: (a prefix is then more than a list of blocks).
+    state_bytes_per_row = 0
+
+    def _build_samplers(self) -> None:
+        # first-token sampler, ON DEVICE: fetching the prefill logits to
+        # sample on the host moved the full [B, V] array to the host —
+        # 8MB for Gemma-2B at B=8. Only the sampled ids ([B] int32)
+        # cross now.
+        def _pick(logits, temps, key):
+            g = jax.random.gumbel(key, logits.shape, dtype=logits.dtype)
+            z = jnp.where(
+                temps[:, None] > 0.0,
+                logits / jnp.maximum(temps[:, None], 1e-4) + g,
+                logits,
+            )
+            return jnp.argmax(z, axis=-1).astype(jnp.int32)
+
+        self.sample_first = jax.jit(_named("engine_sample_first", _pick))
+        #: grafts prefill-sampled first tokens into the device token chain
+        #: (llama.merge_chain_tokens) so interleaved admissions never force
+        #: the chain back through the host
+        self.merge_chain = jax.jit(
+            _named("engine_merge_chain", lambda last, ids, mask: (
+                llama.merge_chain_tokens(last, ids, mask)
+            ))
+        )
+
+    def _restored(self, params, ckpt_dir: str, require_ckpt: bool):
+        """``params`` as the newest checkpoint under ``ckpt_dir`` holds them
+        (as they are where there is none, unless ``require_ckpt``)."""
+        from kubedl_tpu.training import checkpoint
+
+        step = checkpoint.latest_step(ckpt_dir) if ckpt_dir else None
+        if require_ckpt and step is None:
+            raise ValueError(f"no checkpoint found under {ckpt_dir!r}")
+        if ckpt_dir and step is not None:
+            state = checkpoint.restore_checkpoint(ckpt_dir, {"params": params})
+            if state is not None:
+                params = state["params"]
+                log.info("restored checkpoint from %s", ckpt_dir)
+            elif require_ckpt:
+                raise ValueError(
+                    f"no complete checkpoint step under {ckpt_dir!r} "
+                    "(every step torn/incomplete)"
+                )
+        return params
+
+    def _upload_mirror(self, arr):
+        """Upload a host mirror as an XLA-OWNED device buffer.
+
+        ``jnp.asarray`` zero-copy BORROWS an aligned numpy buffer, and the
+        cache is donated into every jitted dispatch — donating a
+        borrowed buffer lets XLA alias segment outputs onto it, which
+        either scribbles sampled tokens into the live mirror or hands the
+        harvest a stale view of the block table (both observed on the CPU
+        backend; whether a given numpy allocation is 64-byte aligned is
+        luck, hence flaky). The no-op add forces materialization into a
+        fresh buffer XLA owns outright. The add is dispatched
+        asynchronously, though, and the scheduler goes on editing the
+        mirror in place: it reads a private snapshot, or the device sees
+        whatever the mirror holds by the time the add runs (greedy
+        streams then differ from run to run on the CPU backend)."""
+        return jnp.asarray(arr.copy()) + 0
+
+    def upload_mirrors(self, bt, pos=None) -> None:
+        """Make the engine's authoritative HOST mirrors the paged cache's
+        block table and, when given, positions."""
+        if pos is not None:
+            self.cache["pos"] = self._upload_mirror(pos)
+        self.cache["bt"] = self._upload_mirror(bt)
+
+    @property
+    def pool_shape(self) -> tuple:
+        return tuple(self.cache["k"].shape)
+
+    # -- the span of the gathered view ---------------------------------------
+
+    @classmethod
+    def span_ladder(cls, max_seq: int, block: int = 1) -> tuple:
+        """The view's spans for a row of ``max_seq`` keys: powers of two
+        times :attr:`SPAN_FLOOR` below ``max_seq`` (whole blocks only),
+        then ``max_seq``."""
+        spans, s = [], max(1, int(cls.SPAN_FLOOR))
+        while s < max_seq:
+            if s % block == 0:
+                spans.append(s)
+            s *= 2
+        return tuple(spans) + (max_seq,)
+
+    def span_for(self, live_to: Optional[int]) -> int:
+        """The smallest span that holds positions ``[0, live_to)``, which
+        is the branch a program given ``live_to`` takes; ``max_seq`` for
+        None or anything longer."""
+        if live_to is not None:
+            for span in self.spans:
+                if live_to <= span:
+                    return span
+        return self.spans[-1]
+
+    def _live_to(self, live_to: Optional[int]) -> tuple:
+        """The argument that picks a program's span: none at all for a
+        runner with one span (its programs take none)."""
+        if len(self.spans) == 1:
+            return ()
+        return (np.int32(self.max_seq if live_to is None else live_to),)
+
+
+class ModelRunner(_Runner):
+    """One model's config, K/V arrays and jitted programs. Programs take
+    ``params`` explicitly, so a second weight tree (hot swap) rides the
+    same compiles. ``llama.preset`` and ``llama.llama_init`` are looked up
+    on the module at call time: benchmark/program.py swaps both while it
+    builds an engine."""
 
     def __init__(self, preset: str, *, max_batch: int, max_seq: int = 0,
                  paged: bool = True, kv_block_size: int = 16,
@@ -200,28 +323,7 @@ class ModelRunner:
             static_argnums=(2,),
         )
 
-        # first-token sampler, ON DEVICE: fetching the prefill logits to
-        # sample on the host moved the full [B, V] array to the host —
-        # 8MB for Gemma-2B at B=8. Only the sampled ids ([B] int32)
-        # cross now.
-        def _pick(logits, temps, key):
-            g = jax.random.gumbel(key, logits.shape, dtype=logits.dtype)
-            z = jnp.where(
-                temps[:, None] > 0.0,
-                logits / jnp.maximum(temps[:, None], 1e-4) + g,
-                logits,
-            )
-            return jnp.argmax(z, axis=-1).astype(jnp.int32)
-
-        self.sample_first = jax.jit(_named("engine_sample_first", _pick))
-        #: grafts prefill-sampled first tokens into the device token chain
-        #: (llama.merge_chain_tokens) so interleaved admissions never force
-        #: the chain back through the host
-        self.merge_chain = jax.jit(
-            _named("engine_merge_chain", lambda last, ids, mask: (
-                llama.merge_chain_tokens(last, ids, mask)
-            ))
-        )
+        self._build_samplers()
         #: jitted multi-step decode segments keyed by (n_steps, greedy),
         #: built on first use — llama.decode_segment
         self._segments: Dict[tuple, object] = {}
@@ -273,23 +375,10 @@ class ModelRunner:
         freshly initialized random weights under a version id would be a
         silent model swap. Init keeps the permissive behaviour (tests and
         cold starts serve the preset without a checkpoint)."""
-        from kubedl_tpu.training import checkpoint
-
         chaos.check("serving.weight_swap")
-        params = llama.llama_init(jax.random.PRNGKey(0), self.cfg)
-        step = checkpoint.latest_step(ckpt_dir) if ckpt_dir else None
-        if require_ckpt and step is None:
-            raise ValueError(f"no checkpoint found under {ckpt_dir!r}")
-        if ckpt_dir and step is not None:
-            state = checkpoint.restore_checkpoint(ckpt_dir, {"params": params})
-            if state is not None:
-                params = state["params"]
-                log.info("restored checkpoint from %s", ckpt_dir)
-            elif require_ckpt:
-                raise ValueError(
-                    f"no complete checkpoint step under {ckpt_dir!r} "
-                    "(every step torn/incomplete)"
-                )
+        params = self._restored(
+            llama.llama_init(jax.random.PRNGKey(0), self.cfg), ckpt_dir,
+            require_ckpt)
         if self.quantize == "int8":
             # weight-only int8: decode is HBM-bound and weights dominate
             # the bytes — halves the per-token floor (docs/serving.md)
@@ -316,39 +405,11 @@ class ModelRunner:
                 self.cfg, self.max_batch, self.max_seq
             )
 
-    def _upload_mirror(self, arr):
-        """Upload a host mirror as an XLA-OWNED device buffer.
-
-        ``jnp.asarray`` zero-copy BORROWS an aligned numpy buffer, and the
-        cache is donated into every jitted dispatch — donating a
-        borrowed buffer lets XLA alias segment outputs onto it, which
-        either scribbles sampled tokens into the live mirror or hands the
-        harvest a stale view of the block table (both observed on the CPU
-        backend; whether a given numpy allocation is 64-byte aligned is
-        luck, hence flaky). The no-op add forces materialization into a
-        fresh buffer XLA owns outright. The add is dispatched
-        asynchronously, though, and the scheduler goes on editing the
-        mirror in place: it reads a private snapshot, or the device sees
-        whatever the mirror holds by the time the add runs (greedy
-        streams then differ from run to run on the CPU backend)."""
-        return jnp.asarray(arr.copy()) + 0
-
-    def upload_mirrors(self, bt, pos=None) -> None:
-        """Make the engine's authoritative HOST mirrors the paged cache's
-        block table and, when given, positions."""
-        if pos is not None:
-            self.cache["pos"] = self._upload_mirror(pos)
-        self.cache["bt"] = self._upload_mirror(bt)
-
     def reset_row(self, row: int) -> None:
         """Contiguous admission: position 0; stale KV is masked by pos."""
         self.cache["pos"] = self.cache["pos"].at[row].set(0)
 
     # -- the K/V row's format -----------------------------------------------
-
-    @property
-    def pool_shape(self) -> tuple:
-        return tuple(self.cache["k"].shape)
 
     def fits_pool(self, kv_shape) -> bool:
         """Whether exported blocks of shape ``[L, n, BS, KV, hd]`` can be
@@ -376,37 +437,6 @@ class ModelRunner:
     def extract(self, row: int, p_len: int):
         """A contiguous row's first ``p_len`` positions as ``(k, v)``."""
         return self._extract(self.cache, row, p_len)
-
-    # -- the span of the gathered view ---------------------------------------
-
-    @classmethod
-    def span_ladder(cls, max_seq: int, block: int = 1) -> tuple:
-        """The view's spans for a row of ``max_seq`` keys: powers of two
-        times :attr:`SPAN_FLOOR` below ``max_seq`` (whole blocks only),
-        then ``max_seq``."""
-        spans, s = [], max(1, int(cls.SPAN_FLOOR))
-        while s < max_seq:
-            if s % block == 0:
-                spans.append(s)
-            s *= 2
-        return tuple(spans) + (max_seq,)
-
-    def span_for(self, live_to: Optional[int]) -> int:
-        """The smallest span that holds positions ``[0, live_to)``, which
-        is the branch a program given ``live_to`` takes; ``max_seq`` for
-        None or anything longer."""
-        if live_to is not None:
-            for span in self.spans:
-                if live_to <= span:
-                    return span
-        return self.spans[-1]
-
-    def _live_to(self, live_to: Optional[int]) -> tuple:
-        """The argument that picks a program's span: none at all for a
-        runner with one span (its programs take none)."""
-        if len(self.spans) == 1:
-            return ()
-        return (np.int32(self.max_seq if live_to is None else live_to),)
 
     # -- the programs, run on the runner's cache ----------------------------
 
@@ -458,10 +488,13 @@ class ModelRunner:
         return fn
 
     def decode_segment(self, n_steps: int, greedy: bool, params, tokens,
-                       temps, key, live_to: Optional[int] = None):
+                       temps, key, live_to: Optional[int] = None, rows=None):
         """``n_steps`` decode steps, sampled on the device, over the view
         span that holds ``live_to``: one past the highest position a row
         whose tokens are read will stand at (None: the whole table).
+        ``rows`` names the rows the dispatch scheduled; a decoder's row is
+        its blocks and ``pos``, which the mirrors put right before the next
+        dispatch, so nothing here needs them.
         Returns ``(toks [B, n_steps], last [B, 1], key)``."""
         toks, last, key, self.cache = self._segment_fn(n_steps, greedy)(
             params, self.cache, tokens, temps, key, *self._live_to(live_to),
@@ -481,3 +514,161 @@ class ModelRunner:
         return self._verify_tree(
             params, self.cache, toks, pos, mask, lens, starts
         )
+
+
+class HybridRunner(_Runner):
+    """The hybrid state-space model's config, cache and jitted programs
+    (``models/hybrid_ssm.py``), behind the methods and program names of
+    :class:`ModelRunner`. A row owns its blocks in the pools of the
+    attention layers (``block_bytes`` counts those layers alone) and a
+    fixed slab of recurrent state (``state_bytes_per_row``). Nothing of the
+    slab is the engine's to manage: a prefill program zeroes the slab of a
+    row it starts at position 0 (an admission, or a re-admission after
+    preemption) and carries it for a row it starts later (the next chunk of
+    a prompt); a decode segment advances the slabs of the rows the dispatch
+    scheduled (``rows``) and of no other. ``hybrid_ssm.preset`` and
+    ``hybrid_ssm.hybrid_init`` are looked up on the module at call time, as
+    the decoder's are.
+
+    Paged, gather attention only. What rests on "a prefix is a list of
+    blocks" has no meaning for a slab yet (prefix reuse, speculation's
+    rollback, block hand-off): the engine refuses those at construction."""
+
+    def __init__(self, preset: str, *, max_batch: int, max_seq: int = 0,
+                 kv_block_size: int = 16) -> None:
+        self.cfg = cfg = hybrid_ssm.preset(preset)
+        self.max_batch = max_batch
+        bs = self.kv_block_size = max(1, int(kv_block_size))
+        self.max_seq = -(-(max_seq or min(cfg.max_seq, 512)) // bs) * bs
+        self.block_bytes = int(
+            2 * cfg.periods * bs * cfg.n_kv_heads * cfg.head_dim
+            * np.dtype(cfg.dtype).itemsize
+        )
+        self.state_bytes_per_row = hybrid_ssm.state_bytes_per_row(cfg)
+        self.cache = None
+        spans = self.spans = self.span_ladder(self.max_seq, bs)
+        self._no_logits = jnp.zeros((max_batch, cfg.vocab_size), jnp.float32)
+
+        def view(live_to):
+            return {"spans": spans, "live_to": live_to[0]} if live_to else {}
+
+        def prefill(p, c, t, l, rows, acc):
+            lg, c = hybrid_ssm.prefill(p, c, t, l, cfg, rows)
+            return acc.at[rows].set(lg), c
+
+        def prefill_from(p, c, t, l, st, rows, acc, *live_to):
+            lg, c = hybrid_ssm.prefill(p, c, t, l, cfg, rows, starts=st,
+                                       **view(live_to))
+            return acc.at[rows].set(lg), c
+
+        self._view = view
+        self._prefill = jax.jit(
+            _named("engine_prefill", prefill), donate_argnums=(1,))
+        self._prefill_from = jax.jit(
+            _named("engine_prefill_from", prefill_from), donate_argnums=(1,))
+        self._build_samplers()
+        self._segments: Dict[tuple, object] = {}
+
+    def build_params(self, ckpt_dir: str, require_ckpt: bool = False):
+        """Init, then the newest checkpoint where there is one; committed
+        nowhere until it returns (:meth:`ModelRunner.build_params`)."""
+        chaos.check("serving.weight_swap")
+        return self._restored(
+            hybrid_ssm.hybrid_init(jax.random.PRNGKey(0), self.cfg), ckpt_dir,
+            require_ckpt)
+
+    def new_cache(self, kv_blocks: int = 0) -> None:
+        """The pools, ``pos``, ``bt`` and every row's slab, zeroed."""
+        self.cache = hybrid_ssm.init_cache(
+            self.cfg, self.max_batch, self.max_seq, kv_blocks,
+            self.kv_block_size)
+
+    def warmup(self, params) -> None:
+        """One decode step that advances no row: proof the model runs, by a
+        program the ticks use too."""
+        self.decode_segment(
+            1, True, params, jnp.zeros((self.max_batch, 1), jnp.int32),
+            jnp.zeros((self.max_batch,), jnp.float32), jax.random.PRNGKey(0),
+            live_to=1, rows=())
+        jax.block_until_ready(self.cache["pos"])
+
+    def prefill(self, params, toks, lens, starts=None, rows=None, acc=None,
+                live_to: Optional[int] = None):
+        """As :meth:`ModelRunner.prefill`. Without ``starts`` every row of
+        the program begins from a zero slab; with them, the rows whose
+        start is 0 do and the others carry theirs on."""
+        acc = self._no_logits if acc is None else acc
+        if starts is None:
+            logits, self.cache = self._prefill(
+                params, self.cache, toks, lens, rows, acc)
+        else:
+            logits, self.cache = self._prefill_from(
+                params, self.cache, toks, lens, starts, rows, acc,
+                *self._live_to(live_to))
+        return logits
+
+    def _segment_fn(self, n_steps: int, greedy: bool):
+        fn = self._segments.get((n_steps, greedy))
+        if fn is None:
+            cfg, view = self.cfg, self._view
+            name = f"engine_decode_seg{n_steps}" + ("" if greedy else "_sampled")
+            fn = jax.jit(
+                _named(name, lambda p, c, tokens, temps, key, live, *live_to: (
+                    hybrid_ssm.decode_segment(
+                        p, c, tokens, temps, key, live, cfg, n_steps=n_steps,
+                        greedy=greedy, **view(live_to))
+                )),
+                donate_argnums=(1,),
+            )
+            self._segments[(n_steps, greedy)] = fn
+        return fn
+
+    def decode_segment(self, n_steps: int, greedy: bool, params, tokens,
+                       temps, key, live_to: Optional[int] = None, rows=None):
+        """As :meth:`ModelRunner.decode_segment`; the slabs of ``rows`` (the
+        rows the dispatch scheduled; None: every row) advance, every other
+        row's stays as it is."""
+        live = np.ones((self.max_batch,), bool)
+        if rows is not None:
+            live[:] = False
+            live[list(rows)] = True
+        toks, last, key, self.cache = self._segment_fn(n_steps, greedy)(
+            params, self.cache, tokens, temps, key, jnp.asarray(live),
+            *self._live_to(live_to),
+        )
+        return toks, last, key
+
+
+def make_runner(preset: str, *, max_batch: int, max_seq: int = 0,
+                paged: bool = True, kv_block_size: int = 16,
+                kv_attention: str = "gather", quantize: str = "",
+                mesh_axes: Optional[Dict] = None, spec_k: int = 0,
+                spec_candidates: int = 1, spec_tree: bool = False):
+    """The runner for ``preset``, by the type of its config: the one place
+    that chooses. A preset of ``hybrid_ssm`` gets a :class:`HybridRunner`,
+    which refuses what it cannot do (``ValueError``, naming the reason);
+    any other name is ``llama.preset``'s."""
+    try:
+        cfg = hybrid_ssm.preset(preset)
+    except KeyError:
+        cfg = llama.preset(preset)
+    if not isinstance(cfg, hybrid_ssm.HybridConfig):
+        return ModelRunner(
+            preset, max_batch=max_batch, max_seq=max_seq, paged=paged,
+            kv_block_size=kv_block_size, kv_attention=kv_attention,
+            quantize=quantize, mesh_axes=mesh_axes, spec_k=spec_k,
+            spec_candidates=spec_candidates, spec_tree=spec_tree)
+    refused = {
+        "kv_layout='contiguous' (and mesh_axes, which forces it)": not paged,
+        "kv_attention='blocked'": kv_attention != "gather",
+        "quantize": bool(quantize),
+        "spec_k > 0 (a rejected draft would have to roll the recurrent "
+        "state back, and only K/V blocks can be freed in place)": spec_k > 0,
+    }
+    for what, asked in refused.items():
+        if asked:
+            raise ValueError(
+                f"preset {preset!r} holds recurrent state beside its K/V "
+                f"blocks and cannot be served with {what}")
+    return HybridRunner(preset, max_batch=max_batch, max_seq=max_seq,
+                        kv_block_size=kv_block_size)

@@ -167,7 +167,7 @@ class LlamaEngine:
                  model_version: str = "base") -> None:
         import jax
 
-        from kubedl_tpu.serving.model_runner import ModelRunner
+        from kubedl_tpu.serving.model_runner import make_runner
 
         if kv_layout not in ("paged", "contiguous"):
             raise ValueError(f"unknown kv_layout {kv_layout!r}")
@@ -224,7 +224,7 @@ class LlamaEngine:
         #: the model's side (serving/model_runner.py): the weights' making,
         #: the K/V arrays and every device program. Nothing below names
         #: the model; the runner never takes ``_cv``.
-        self._runner = runner = ModelRunner(
+        self._runner = runner = make_runner(
             preset, max_batch=self.max_batch, max_seq=max_seq,
             paged=self._paged, kv_block_size=kv_block_size,
             kv_attention=self.kv_attention, quantize=quantize,
@@ -233,6 +233,25 @@ class LlamaEngine:
         )
         self.cfg = runner.cfg
         self.max_seq = runner.max_seq
+        #: bytes of recurrent state a row owns beside its blocks (0: none;
+        #: docs/serving.md "Models with recurrent state"). The runner's
+        #: programs reset, carry and advance it; the engine only counts it,
+        #: and refuses what takes a prefix to be a list of blocks
+        self._state_bytes = int(runner.state_bytes_per_row)
+        if self._state_bytes:
+            if self.role != "colocated":
+                raise ValueError(
+                    f"preset {preset!r} holds recurrent state beside its K/V "
+                    f"blocks: role={self.role!r} hands a prompt over as "
+                    "blocks, and the state would stay behind"
+                )
+            if prefix_cache_mb > 0:
+                log.info(
+                    "preset %r holds recurrent state: no prefix cache is "
+                    "built (a cached prefix is blocks without the state "
+                    "that follows them)", preset,
+                )
+                prefix_cache_mb = 0.0
         if self._paged:
             self.kv_block_size = runner.kv_block_size
         #: chunked prefill (docs/serving.md "Continuous batching"): > 0
@@ -366,6 +385,7 @@ class LlamaEngine:
                        "handoff_failures": 0,
                        "prefill_tokens": 0, "prefill_positions": 0,
                        "view_keys": 0, "view_keys_full": 0,
+                       "state_resets": 0,
                        "started_at": time.time()}
         #: load-shedding budget: reject (503) instead of queueing once the
         #: queue is deeper than max_queue_depth or its head has waited
@@ -736,6 +756,12 @@ class LlamaEngine:
             )
             queued = len(self._waiting)
             active = sum(1 for s in self._slots if s is not None)
+            # rows whose slab of recurrent state is live: a first prefill
+            # program has run for them (0 for a model that has none)
+            state_rows = sum(
+                1 for s in self._slots
+                if s is not None and (s.fed > 0 or s.prefill_pos > 0)
+            ) if self._state_bytes else 0
             ttft = list(self._ttft_recent)
             qwait = list(self._queue_wait_recent)
             draining = self._draining
@@ -761,6 +787,8 @@ class LlamaEngine:
         out["lifetime_qps"] = round(out["requests"] / up, 3)
         out["active_slots"] = active
         out["max_batch"] = self.max_batch
+        out["state_rows"] = state_rows
+        out["state_bytes"] = state_rows * self._state_bytes
         out["queued"] = queued
         out["shed_recent"] = shed_recent
         for name, samples in (("ttft_ms", ttft), ("queue_wait_ms", qwait)):
@@ -1336,6 +1364,15 @@ class LlamaEngine:
         self._trace_request_locked(s, "prefill")
         s.done.set()
 
+    def _refuse_handoff_with_state(self) -> None:
+        """A hand-off is a list of blocks; a row of a model with recurrent
+        state is more than that, and the state would stay behind."""
+        if self._state_bytes:
+            raise ValueError(
+                f"preset {self.preset_name!r} holds recurrent state beside "
+                "its K/V blocks: a block hand-off would leave it behind"
+            )
+
     def prefill_handoff(self, prompt_ids, max_tokens: int = 16,
                         temperature: float = 0.0, timeout_s: float = 600.0,
                         cache_prefix: bool = False, request_id: str = "",
@@ -1354,6 +1391,7 @@ class LlamaEngine:
             raise ValueError(
                 "disaggregated prefill requires kv_layout='paged'"
             )
+        self._refuse_handoff_with_state()
         budget = self.max_seq - 1
         prompt = [int(t) for t in list(prompt_ids)[:budget]]
         if not prompt:
@@ -1545,6 +1583,7 @@ class LlamaEngine:
         resume decoding from the first token. The returned result has the
         same shape as generate()'s, and for greedy requests the token ids
         are bit-identical to a colocated single-engine call."""
+        self._refuse_handoff_with_state()
         if not self._paged:
             raise ValueError(
                 "adopting a KV handoff requires kv_layout='paged'"
@@ -1814,7 +1853,7 @@ class LlamaEngine:
         groups = [[t] for t in sched] if self._paged else [sched]
         logits = None  # of the tick's earlier programs
         prefill_ids = t0 = None
-        saved = positions = view_keys = views = 0
+        saved = positions = view_keys = views = resets = 0
         for n, group in enumerate(groups):
             slots = len(group) if self._paged else self.max_batch
             bucket = self._prefill_bucket(
@@ -1823,6 +1862,8 @@ class LlamaEngine:
             with TRACER.phase(
                 "engine.prefill_dispatch", bucket=bucket, rows=len(group),
                 tokens=sum(t for _i, _s, _b, t, _f in group), slots=slots,
+                # query-key pairs causal attention needs for these tokens
+                keys=sum(t * b + t * (t + 1) // 2 for _i, _s, b, t, _f in group),
             ) as ph:
                 toks = np.zeros((slots, bucket), np.int32)
                 lens = np.zeros((slots,), np.int32)
@@ -1855,6 +1896,12 @@ class LlamaEngine:
                     acc=logits, live_to=live_to,
                 )
                 positions += slots * bucket
+                if self._state_bytes:
+                    # rows that go on from the state their last chunk left;
+                    # the others start from a zero slab, inside the program
+                    carried = sum(1 for _i, _s, b, _t, _f in group if b > 0)
+                    ph.set(carried=carried)
+                    resets += len(group) - carried
                 if from_prefix and self._paged:
                     # the whole-prompt program attends locally: no view
                     span = self._runner.span_for(live_to)
@@ -1871,9 +1918,12 @@ class LlamaEngine:
             self.metrics.prefix_tokens_saved.inc(saved)
         self.metrics.prefill_tokens.inc(tokens)
         self.metrics.prefill_positions.inc(positions)
+        if resets:
+            self.metrics.state_resets.inc(resets)
         with self._cv:
             self._stats["prefill_tokens"] += tokens
             self._stats["prefill_positions"] += positions
+            self._stats["state_resets"] += resets
             self._count_view_keys_locked(view_keys, views)
         return prefill_ids, t0
 
@@ -2541,7 +2591,7 @@ class LlamaEngine:
         t0 = time.perf_counter()  # start of the rows' engine.decode_segment
         toks, last, self._key = self._runner.decode_segment(
             k, greedy, params, tokens_dev, self._temps_cache[1], self._key,
-            live_to=live_to,
+            live_to=live_to, rows=[i for i, _ in decoding],
         )
         self._chain = (
             self._prefill_gen, tuple(i for i, _ in decoding), last
@@ -2550,6 +2600,8 @@ class LlamaEngine:
         attrs = {}
         with self._cv:
             if self._paged:
+                # keys the scheduled rows hold as the segment starts
+                attrs["keys"] = sum(int(self._pos_host[i]) for i, _ in decoding)
                 attrs["span"] = span = self._runner.span_for(live_to)
                 self._count_view_keys_locked(
                     span * k * self.max_batch, k * self.max_batch)

@@ -158,3 +158,47 @@ def test_view_span_branches_compile_in_place(on_chip, program):
     pool = f"bf16[{cfg.n_layers},{NB},{BS},{cfg.n_kv_heads},{cfg.head_dim}]"
     copies = [ln for ln in text.splitlines() if " copy(" in ln and pool in ln]
     assert not copies, copies[:2]
+
+
+@pytest.mark.parametrize("program", ["decode_segment", "prefill_from"])
+def test_hybrid_programs_fit_the_chip_whole(on_chip, program):
+    """The hybrid runner's own programs at granite-4.0-h-micro's size, all 40
+    layers, 32 rows of 8192 keys: what the compiler needs for arguments and
+    temporaries stays under the 15 GiB a 16 GB chip leaves a program. Two
+    layouts hold that: the K/V pool keeps a token's 8 heads of 64 side by
+    side (a last axis of 64 is padded to 128 lanes: the pool twice over, and
+    the first compile asked for 16.6 GB), and ``in_proj`` is three leaves (a
+    ``[2048, 8512]`` leaf is stored transposed and every program copied its
+    1.17 GB). So no operation copies a pool or a stack of projections."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubedl_tpu.models import hybrid_ssm
+    from kubedl_tpu.serving.model_runner import HybridRunner
+
+    B, max_seq, BS = 32, 8192, 16
+    runner = HybridRunner("granite-4.0-h-micro", max_batch=B, max_seq=max_seq,
+                          kv_block_size=BS)
+    cfg = runner.cfg
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: on_chip(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: hybrid_ssm.hybrid_init(jax.random.PRNGKey(0), cfg)))
+    cache = place(jax.eval_shape(
+        lambda: hybrid_ssm.init_cache(cfg, B, max_seq, 1 + B * max_seq // BS, BS)))
+    i32 = lambda *s: on_chip(s, jnp.int32)  # noqa: E731
+    if program == "decode_segment":
+        lowered = runner._segment_fn(4, True).lower(
+            params, cache, i32(B, 1), on_chip((B,), jnp.float32),
+            on_chip((2,), jnp.uint32), on_chip((B,), jnp.bool_), i32())
+    else:
+        lowered = runner._prefill_from.lower(
+            params, cache, i32(1, 1024), i32(1), i32(1), i32(1),
+            on_chip((B, cfg.vocab_size), jnp.float32), i32())
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * 2**30
+    big = [ln for ln in compiled.as_text().splitlines() if " copy(" in ln and (
+        f"bf16[{cfg.periods},{1 + B * max_seq // BS}," in ln
+        or (f"bf16[{cfg.n_mamba},{cfg.dim}," in ln and ",64]" not in ln))]
+    assert not big, big[:2]
