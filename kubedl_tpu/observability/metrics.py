@@ -475,6 +475,14 @@ class ServingMetrics:
         self.segments = r.counter(
             "kubedl_tpu_serving_segments", "Decode segments dispatched"
         )
+        self.segment_lengths = r.counter(
+            "kubedl_tpu_serving_segment_lengths",
+            "Decode segments dispatched, by length (k: 32, 4 or 1 steps) "
+            "and by whether the prefill work the tick still owed made "
+            "the segment shorter than the rows' budgets asked for "
+            "(short: waiting = a request had no row, prefill = a row's "
+            "prompt was not wholly fed, no = neither)",
+        )
         self.deferred_harvests = r.counter(
             "kubedl_tpu_serving_deferred_harvests",
             "Segment harvests that overlapped the next in-flight segment",

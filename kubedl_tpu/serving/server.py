@@ -141,8 +141,9 @@ class LlamaEngine:
 
     #: allowed decode-segment sizes, largest first — a small fixed menu
     #: bounds compiles to len(menu) while still amortizing the dispatch +
-    #: host round trip ~32x on long generations; segments shrink to 4
-    #: whenever requests are waiting (admission latency <= 4 tokens)
+    #: host round trip ~32x on long generations; segments shrink to 4 or
+    #: 1 while the tick still owes prefill work: a request waits for a
+    #: row, or a row waits for its prompt's next chunk (`choose_segment`)
     SEGMENT_BUCKETS = (32, 4, 1)
 
     def __init__(self, preset: str = "tiny", ckpt_dir: str = "",
@@ -404,6 +405,10 @@ class LlamaEngine:
             "flushes": 0, "chain_rebuilds": 0, "errors": 0, "inflight": 0,
             "dispatch_ms_sum": 0.0, "harvest_ms_sum": 0.0,
             "host_ms_sum": 0.0, "tick_ms_sum": 0.0, "overlap_ms_sum": 0.0,
+            # decode segments by length, and those the owed prefill work
+            # made short, by reason (`choose_segment`)
+            **{f"segments_k{k}": 0 for k in self.SEGMENT_BUCKETS},
+            "short_waiting": 0, "short_prefill": 0,
         }
         self._pipe_recent: "deque[tuple]" = deque(maxlen=2048)
         #: completion timestamps for windowed QPS (autoscale signal must
@@ -842,6 +847,11 @@ class LlamaEngine:
         out = {
             "ticks": p["ticks"],
             "segments": p["segments"],
+            "segments_by_k": {
+                str(k): p[f"segments_k{k}"] for k in self.SEGMENT_BUCKETS
+            },
+            "segments_short": {"waiting": p["short_waiting"],
+                               "prefill": p["short_prefill"]},
             "deferred_harvests": p["deferred_harvests"],
             "flushes": p["flushes"],
             "chain_rebuilds": p["chain_rebuilds"],
@@ -1744,12 +1754,43 @@ class LlamaEngine:
         is small (<= a quarter of the bucket: rem=31 runs one 32-segment
         discarding 1), else steps DOWN to the largest bucket below
         (rem=7 runs a 4-segment instead of burning 25 wasted decodes).
-        ``cap`` (4 while requests wait) bounds admission latency."""
+        ``cap`` (4 or 1 while prefill work is owed, `choose_segment`)
+        bounds how long that work waits."""
         need = max(1, min(int(need), int(cap)))
         up = next((b for b in reversed(buckets) if b >= need), buckets[0])
         if up - need <= up // 4:
             return up
         return next((b for b in buckets if b <= need), 1)
+
+    @classmethod
+    def choose_segment(cls, need: int, waiting: int, mid_prefill: int,
+                       decoding: int,
+                       buckets: tuple = SEGMENT_BUCKETS) -> tuple:
+        """The tick's decode segment, from the prefill work it still owes
+        once this tick's prefill budget is spent: ``waiting`` requests
+        that have no row yet and ``mid_prefill`` rows whose prompt is not
+        wholly fed. Either is served only between two segments, so while
+        there is any the segment is short; with none owed the choice is
+        `segment_size` over the rows' budgets alone. A segment costs what
+        its steps cost (PERF.md section 5), so how short it is only
+        splits the device between the owed prompts and the ``decoding``
+        rows. A request with no row needs a row to finish: 4 steps. Rows
+        mid-prompt need only the next tick: one step where they are at
+        least as many as the rows decoding and nobody waits for a row
+        (more requests gain a sooner chunk than pay a longer token gap),
+        else 4 steps too. Returns ``(k, short)``; ``short`` says why the
+        cap made the segment shorter than the budgets asked for:
+        ``"waiting"``, ``"prefill"`` (no request waits, a row does), or
+        ``""`` where it did not."""
+        free = cls.segment_size(need, buckets[0], buckets)
+        if not (waiting or mid_prefill):
+            return free, ""
+        prefill_bound = not waiting and mid_prefill >= decoding
+        k = cls.segment_size(need, buckets[2 if prefill_bound else 1],
+                             buckets)
+        if k >= free:
+            return k, ""
+        return k, "waiting" if waiting else "prefill"
 
     # -- pipeline stages ---------------------------------------------------
 
@@ -2253,6 +2294,10 @@ class LlamaEngine:
             p = self._pipe
             p["ticks"] += 1
             p["segments"] += acct["segments"]
+            if acct["segment_k"]:
+                p[f"segments_k{acct['segment_k']}"] += 1
+            if acct["short"]:
+                p[f"short_{acct['short']}"] += 1
             p["deferred_harvests"] += acct["deferred"]
             p["flushes"] += acct["flushes"]
             p["chain_rebuilds"] += acct["rebuilds"]
@@ -2270,6 +2315,9 @@ class LlamaEngine:
         m = self.metrics
         if acct["segments"]:
             m.segments.inc(acct["segments"])
+        if acct["segment_k"]:
+            m.segment_lengths.inc(k=str(acct["segment_k"]),
+                                  short=acct["short"] or "no")
         if acct["deferred"]:
             m.deferred_harvests.inc(acct["deferred"])
         if acct["flushes"]:
@@ -2354,7 +2402,7 @@ class LlamaEngine:
 
         acct = {"dispatch_ms": 0.0, "harvest_ms": 0.0, "host_ms": 0.0,
                 "overlapped": False, "segments": 0, "deferred": 0,
-                "flushes": 0, "rebuilds": 0}
+                "flushes": 0, "rebuilds": 0, "segment_k": 0, "short": ""}
 
         if waiting and self._pending is not None:
             # requests queued: harvest FIRST so finished rows free up and
@@ -2487,11 +2535,21 @@ class LlamaEngine:
             decoding = []
 
         new_pending = None
+        backlog, short = 0, ""
         if decoding:
             need = max(self._rem(s) for _, s in decoding)
             with self._cv:
-                cap = 4 if self._waiting else self.SEGMENT_BUCKETS[0]
-            k = self.segment_size(need, cap)
+                # the prefill work still owed now that this tick's budget
+                # is spent. Rows of every version count: another
+                # version's chunk is dispatched by its own tick, behind
+                # this tick's segment, and `_waiting` knows no version
+                waiting = len(self._waiting)
+                mid_prefill = sum(
+                    1 for s in self._slots if s is not None and s.fed == 0
+                )
+            backlog = waiting + mid_prefill
+            k, short = self.choose_segment(need, waiting, mid_prefill,
+                                           len(decoding))
             temps = np.zeros((self.max_batch,), np.float32)
             for i, s in decoding:
                 temps[i] = max(float(s.temperature), 0.0)
@@ -2521,13 +2579,16 @@ class LlamaEngine:
                     if self._slots[i] is s and self._rem(s) > 0
                 ]
         if decoding:
-            with TRACER.phase("engine.decode_dispatch") as ph:
+            why = {"short": short} if short else {}
+            with TRACER.phase("engine.decode_dispatch", backlog=backlog,
+                              **why) as ph:
                 new_pending = self._dispatch_segment(
                     decoding, k, temps, greedy, tokens_dev, vp, ph
                 )
             acct["dispatch_ms"] += ph.ms
             if new_pending is not None:
                 acct["segments"] += 1
+                acct["segment_k"], acct["short"] = k, short
 
         # ---- harvest: segment N-1's ids (then prefill's first tokens)
         # while segment N runs on device — the overlap window

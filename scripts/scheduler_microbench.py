@@ -381,14 +381,20 @@ def run_chunked_admission_microbench(requests: int = 16,
                                      prompt_len: int = 48,
                                      max_tokens: int = 8,
                                      max_batch: int = 4,
-                                     chunk: int = 16) -> dict:
+                                     chunk: int = 16,
+                                     decoders: int = 0,
+                                     decoder_tokens: int = 64) -> dict:
     """Host overhead of CHUNKED admission (continuous batching): every
     tick with queued prompts runs the FIFO chunk scheduler — arrival
     sort, block-aligned budget carving, per-row progress cursors — on
     top of the paged tick. With the device stubbed, the tick must fit
     the same envelope as slot-granularity admission; reports chunk
     accounting so a budget miscount (chunks != ceil(len/budget)) fails
-    loudly too."""
+    loudly too. ``decoders`` requests of a one-chunk prompt and a long
+    budget go first: with every request in a row (``requests + decoders
+    <= max_batch``) they decode while the others' prompts are mid-way,
+    which is the mix in which the tick counts the prefill work it owes
+    and runs short segments for it (``segments_short``)."""
     from kubedl_tpu.serving.server import _Slot
 
     eng = build_stub_engine(max_batch=max_batch, kv_layout="paged",
@@ -396,22 +402,26 @@ def run_chunked_admission_microbench(requests: int = 16,
     try:
         assert eng.prefill_chunk_tokens == chunk
         slots = [
+            _Slot([200 + j, 2, 3], decoder_tokens, 0.0)
+            for j in range(decoders)
+        ] + [
             # distinct multi-chunk prompts (no prefix-cache rides)
             _Slot([j + 1] + list(range(5, 4 + prompt_len)), max_tokens, 0.0)
             for j in range(requests)
         ]
         wall_ms, tokens, pipe = _drive(
-            eng, slots, requests * (max_tokens + prompt_len) + 100
+            eng, slots, requests * (max_tokens + prompt_len)
+            + decoders * decoder_tokens + 100
         )
         assert all(
-            len(s.out_ids) == max_tokens for s in slots
+            len(s.out_ids) == s.max_tokens for s in slots
         ), "chunked stub pipeline dropped tokens"
         body = eng.metrics.registry.render()
         chunks = next(
             float(l.split()[-1]) for l in body.splitlines()
             if l.startswith("kubedl_tpu_serving_admission_chunks ")
         )
-        want = requests * -(-prompt_len // chunk)  # ceil per request
+        want = requests * -(-prompt_len // chunk) + decoders  # ceil each
         assert chunks == want, (chunks, want)
         st = eng._alloc.stats()
         assert st["used"] == 0, f"block leak: {st}"
@@ -426,6 +436,8 @@ def run_chunked_admission_microbench(requests: int = 16,
             "wall_ms": round(wall_ms, 2),
             "tick_ms_p50": tick_p50,
             "host_ms_p50": pipe.get("host_ms_p50", 0.0),
+            "segments_by_k": pipe["segments_by_k"],
+            "segments_short": pipe["segments_short"],
             "blocks_leaked": st["used"],
             "budget_ms": CHUNKED_BUDGET_MS,
             "within_budget": tick_p50 <= CHUNKED_BUDGET_MS,
@@ -744,6 +756,8 @@ def main() -> int:
     out["prefix"] = run_prefix_microbench()
     out["paged"] = run_paged_microbench()
     out["chunked_admission"] = run_chunked_admission_microbench()
+    out["owed_prefill"] = run_chunked_admission_microbench(
+        requests=12, decoders=2, max_batch=16, decoder_tokens=100)
     out["blocked_attention"] = run_blocked_attention_microbench()
     out["planner"] = run_planner_microbench()
     out["buckets"] = run_bucket_microbench()
@@ -754,6 +768,7 @@ def main() -> int:
     ok = (out["within_budget"] and out["prefix"]["within_budget"]
           and out["paged"]["within_budget"]
           and out["chunked_admission"]["within_budget"]
+          and out["owed_prefill"]["within_budget"]
           and out["blocked_attention"]["within_budget"]
           and out["planner"]["within_budget"]
           and out["buckets"]["within_budget"]

@@ -299,3 +299,100 @@ class TestSeveralSpans:
         # an engine with one span gathers the whole view every time
         st = one.stats()
         assert 0 < st["view_keys"] == st["view_keys_full"]
+
+
+# ---- the programs an engine runs, text for text -----------------------------
+
+PROGRAMS_FILE = os.path.join(REPO, "tests", "data", "engine_programs.json")
+#: the benchmark's two serve presets in miniature: a decoder and a model with
+#: recurrent state, paged, 16-token blocks, several view spans
+PROGRAM_PRESETS = ("tiny", "tiny-hybrid")
+
+
+def engine_program_texts(preset):
+    """name -> lowered text of every device program a paged engine on
+    ``preset`` can dispatch, at ``max_batch`` 2 and ``max_seq`` 4096 (view
+    spans 1024, 2048, 4096, as the benchmark's decoder cells have): the
+    three decode segments greedy and sampled, whole-prompt and suffix prefill
+    at a short and at a chunk-sized bucket, and whatever else the runner
+    holds jitted. The scheduler's choices are host code and appear in none."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubedl_tpu.serving.model_runner import HybridRunner, make_runner
+    from kubedl_tpu.serving.server import LlamaEngine
+
+    b, max_seq = 2, 4096
+    r = make_runner(preset, max_batch=b, max_seq=max_seq)
+    assert r.spans == (1024, 2048, 4096)
+    r.new_cache(1 + b * max_seq // r.kv_block_size)
+    shapes = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    p, c = shapes(r.build_params("")), shapes(r.cache)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    key, logits = shapes(jax.random.PRNGKey(0)), f32(b, r.cfg.vocab_size)
+    hybrid = isinstance(r, HybridRunner)
+    live = (jax.ShapeDtypeStruct((b,), jnp.bool_),) if hybrid else ()
+    out = {}
+    for k in LlamaEngine.SEGMENT_BUCKETS:
+        for greedy in (True, False):
+            fn = r._segment_fn(k, greedy)
+            out[fn.__name__] = fn.lower(p, c, i32(b, 1), f32(b), key, *live, i32())
+    for bucket in (16, 1024):
+        out[f"engine_prefill@{bucket}"] = r._prefill.lower(
+            p, c, i32(1, bucket), i32(1), i32(1), logits)
+        out[f"engine_prefill_from@{bucket}"] = r._prefill_from.lower(
+            p, c, i32(1, bucket), i32(1), i32(1), i32(1), logits, i32())
+    out["engine_sample_first"] = r.sample_first.lower(logits, f32(b), key)
+    out["engine_merge_chain"] = r.merge_chain.lower(
+        i32(b, 1), i32(b), jax.ShapeDtypeStruct((b,), jnp.bool_))
+    if not hybrid:
+        out["engine_decode_step"] = r._decode.lower(p, c, i32(b, 1))
+        out["engine_copy_block"] = r._copy_block.lower(c, 1, 2)
+    jitted = type(jax.jit(lambda: 0))
+    built = {v.__name__ for v in vars(r).values() if isinstance(v, jitted)}
+    built |= {fn.__name__ for fn in r._segments.values()}
+    # `engine_graft` takes a prefix-cache entry's arrays: direct inserts in
+    # tests only, its text is held by the prefix-cache tests' identities
+    assert built - {"engine_graft"} == {n.split("@")[0] for n in out}, built
+    return {name: lowered.as_text() for name, lowered in out.items()}
+
+
+def engine_program_digests():
+    import hashlib
+
+    return {preset: {name: hashlib.sha256(text.encode()).hexdigest()
+                     for name, text in sorted(engine_program_texts(preset).items())}
+            for preset in PROGRAM_PRESETS}
+
+
+@pytest.fixture(scope="module")
+def program_digests():
+    return engine_program_digests()
+
+
+@pytest.mark.parametrize("preset", PROGRAM_PRESETS)
+def test_engine_programs_are_the_recorded_ones(preset, program_digests):
+    """The set of programs and each one's lowered text are what
+    ``tests/data/engine_programs.json`` recorded: a PR to the scheduler
+    (PR 36: how the tick chooses its segment) adds no executable and changes
+    no device work. A PR that means to change a program writes the file anew,
+    ``python -c "import test_model_runner as t; t.write_engine_programs()"``
+    from ``tests/``, and says so."""
+    import json
+
+    with open(PROGRAMS_FILE) as f:
+        recorded = json.load(f)[preset]
+    got = program_digests[preset]
+    assert sorted(got) == sorted(recorded), "the set of programs changed"
+    changed = [name for name in got if got[name] != recorded[name]]
+    assert not changed, f"lowered text differs from the recorded one: {changed}"
+
+
+def write_engine_programs(path=PROGRAMS_FILE):
+    import json
+
+    with open(path, "w") as f:
+        json.dump(engine_program_digests(), f, indent=1, sort_keys=True)
+        f.write("\n")

@@ -25,7 +25,8 @@ from kubedl_tpu.observability.tracing import TRACER, Tracer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PIPELINE_KEYS = {
-    "ticks", "segments", "deferred_harvests", "flushes", "chain_rebuilds",
+    "ticks", "segments", "segments_by_k", "segments_short",
+    "deferred_harvests", "flushes", "chain_rebuilds",
     "errors", "inflight", "queued", "dispatch_ms_avg", "harvest_ms_avg",
     "host_ms_avg", "tick_ms_avg", "overlap_ratio", "dispatch_ms_p50",
     "harvest_ms_p50", "host_ms_p50", "tick_ms_p50",
@@ -300,6 +301,67 @@ class TestEnginePhases:
         if which == "chunked":
             # 41 tokens at 16 a tick: the prompt landed in three dispatches
             assert len(cap.named("engine.prefill_dispatch")) >= 5
+
+    @pytest.mark.parametrize("reason", ["prefill", "waiting"])
+    def test_decode_dispatch_says_what_the_tick_owed(self, reason, tmp_path):
+        """``backlog`` on every decode dispatch, ``short`` on those the owed
+        prefill work made shorter, and the engine's counters of both close
+        on the spans. Ticks are driven by hand: A decodes a long budget
+        while B's three-chunk prompt comes in (``prefill``), or two rows
+        are taken and a third request waits for one (``waiting``)."""
+        from kubedl_tpu.serving.server import _Slot
+
+        eng = make_engine(prefill_chunk_tokens=16, prefix_cache_mb=0)
+        with eng._cv:
+            eng._stop = True
+            eng._cv.notify_all()
+        eng._thread.join(timeout=10)
+        eng._stop = False
+        if reason == "prefill":
+            first = [_Slot([5, 9, 13], 60, 0.0)]
+            then = [_Slot(list(range(40, 81)), 4, 0.0)]
+        else:
+            first = [_Slot([5, 9, 13 + j], 40, 0.0) for j in range(3)]
+            then = []
+        try:
+            with capture(tmp_path) as cap:
+                with eng._cv:
+                    eng._waiting.extend(first)
+                eng._loop_once()
+                with eng._cv:
+                    eng._waiting.extend(then)
+                for _ in range(400):
+                    if all(s.done.is_set() for s in first + then):
+                        break
+                    eng._loop_once()
+            pipe = eng.pipeline_stats()
+            metric = eng.metrics.segment_lengths
+        finally:
+            eng.close()
+        assert all(s.done.is_set() for s in first + then)
+        spans = cap.named("engine.decode_dispatch")
+        assert spans and all("backlog" in e[4] for e in spans)
+        short = [e for e in spans if "short" in e[4]]
+        assert short, [e[4] for e in spans]
+        # one row mid-prompt beside one decoding is one step from its next
+        # chunk; a request with no row waits out four
+        for e in short:
+            assert e[4]["short"] == reason
+            assert e[4]["k"] == (1 if reason == "prefill" else 4)
+            assert e[4]["backlog"] >= 1
+        # the cap engages only where something is owed; with nothing owed
+        # the budgets choose, and a long budget takes the long segment
+        assert any(e[4]["k"] == 32 and e[4]["backlog"] == 0 for e in spans)
+        other = "waiting" if reason == "prefill" else "prefill"
+        assert pipe["segments_short"] == {reason: len(short), other: 0}
+        for k in (32, 4, 1):
+            n = sum(1 for e in spans if e[4]["k"] == k)
+            assert pipe["segments_by_k"][str(k)] == n
+            assert sum(metric.value(k=str(k), short=why)
+                       for why in ("no", "waiting", "prefill")) == n
+        assert metric.value(k="1" if reason == "prefill" else "4",
+                            short=reason) == len(short)
+        assert pipe["segments"] == len(spans)
 
     def test_tick_accounting_is_the_spans_own_durations(self, monkeypatch):
         eng = make_engine()

@@ -791,6 +791,224 @@ class TestSegmentPolicy:
         assert seg(100, 1) == 1   # cap dominates
         assert seg(5, 3) == 4     # need clamps to cap=3, then rounds to 4
 
+    @pytest.mark.parametrize("need,waiting,mid_prefill,decoding,want", [
+        # prefill work owed: a row mid-prompt is one short segment away
+        # from its next chunk, as a waiting request is from its row
+        (100, 0, 1, 12, (4, "prefill")),
+        (100, 0, 7, 8, (4, "prefill")),
+        (24, 0, 1, 2, (4, "prefill")),   # 24 would have rounded up to 32
+        (100, 1, 0, 3, (4, "waiting")),
+        (100, 3, 2, 1, (4, "waiting")),  # both: the older reason names it
+        # as many rows mid-prompt as rows decoding, and nobody waits for a
+        # row: the next chunk is one step away
+        (100, 0, 1, 1, (1, "prefill")),
+        (100, 0, 6, 2, (1, "prefill")),
+        (3, 0, 4, 4, (1, "prefill")),
+        (100, 1, 6, 2, (4, "waiting")),  # a row has to come free: 4 steps
+        # the budgets alone already ask for no more: the cap shortens nothing
+        (23, 0, 1, 2, (4, "")),
+        (7, 2, 0, 2, (4, "")),
+        (3, 0, 1, 2, (4, "")),
+        (2, 0, 5, 9, (1, "")),
+        (1, 1, 1, 1, (1, "")),
+        (2, 0, 5, 1, (1, "")),
+        # nothing owed: the budgets' own choice
+        (100, 0, 0, 5, (32, "")),
+        (24, 0, 0, 5, (32, "")),
+        (23, 0, 0, 5, (4, "")),
+        (2, 0, 0, 5, (1, "")),
+    ])
+    def test_owed_prefill_work_caps_the_segment(self, need, waiting,
+                                                mid_prefill, decoding, want):
+        from kubedl_tpu.serving.server import LlamaEngine
+
+        assert LlamaEngine.choose_segment(
+            need, waiting, mid_prefill, decoding) == want
+
+    @pytest.mark.parametrize("need", [1, 2, 3, 4, 5, 7, 8, 23, 24, 31, 32,
+                                      33, 100, 4000])
+    def test_choice_is_the_parents_where_no_row_is_mid_prompt(self, need):
+        """Nothing mid-prefill: `cap = 4 if waiting else 32`, as before,
+        however many rows decode."""
+        from kubedl_tpu.serving.server import LlamaEngine
+
+        seg, choose = LlamaEngine.segment_size, LlamaEngine.choose_segment
+        for decoding in (1, 16):
+            assert choose(need, 0, 0, decoding) == (seg(need, 32), "")
+            for waiting in (1, 9):
+                assert choose(need, waiting, 0, decoding)[0] == seg(need, 4)
+        # and rows mid-prompt that are fewer than the rows decoding ask
+        # for what a waiting request asks for
+        assert choose(need, 0, 1, 2)[0] == choose(need, 1, 0, 2)[0]
+        assert LlamaEngine.SEGMENT_BUCKETS == (32, 4, 1)
+
+
+class TestSegmentFromOwedPrefill:
+    """The tick chooses its decode segment from the prefill work it still
+    owes (PR 36): a row whose prompt is mid-way gets its next chunk after
+    a short segment, as a waiting request gets its row. Ticks are driven
+    by hand, so the order of dispatches is the schedule's alone."""
+
+    @staticmethod
+    def _logged(eng):
+        """Record every prefill program and decode segment the engine
+        dispatches, in order: ``("P", tokens)`` and ``("S", k)``."""
+        log, runner = [], eng._runner
+        prefill, segment = runner.prefill, runner.decode_segment
+
+        def logged_prefill(params, toks, lens, *a, **kw):
+            log.append(("P", int(lens[0])))
+            return prefill(params, toks, lens, *a, **kw)
+
+        def logged_segment(n_steps, *a, **kw):
+            log.append(("S", n_steps))
+            return segment(n_steps, *a, **kw)
+
+        runner.prefill, runner.decode_segment = logged_prefill, logged_segment
+        return log
+
+    @staticmethod
+    def _serve_b_behind_a(eng, a, b, b_version=""):
+        """``a`` is decoding when ``b`` arrives; tick until both are done."""
+        from kubedl_tpu.serving.server import _Slot
+
+        sa, sb = _Slot(*a, 0.0), _Slot(*b, 0.0)
+        sb.version = b_version
+        with eng._cv:
+            eng._waiting.append(sa)
+        eng._loop_once()
+        with eng._cv:
+            eng._waiting.append(sb)
+        for _ in range(400):
+            if sa.done.is_set() and sb.done.is_set():
+                break
+            eng._loop_once()
+        assert sa.done.is_set() and sb.done.is_set()
+        return sa.result["token_ids"], sb.result["token_ids"]
+
+    A = ([5, 9, 13], 100)             # decoding, a long budget
+    B = (list(range(40, 88)), 8)      # 48 tokens: three chunks of 16
+
+    @pytest.mark.parametrize("chunk,want", [
+        # chunked: B's second and third chunk each follow a short segment
+        # (one row mid-prompt, one decoding: one step); its last chunk
+        # leaves nothing owed and the budgets choose again
+        (16, [("P", 3), ("S", 32), ("P", 16), ("S", 1), ("P", 16), ("S", 1),
+              ("P", 16), ("S", 32)]),
+        # whole prompts in one program owe nothing after their prefill
+        (0, [("P", 3), ("S", 32), ("P", 48), ("S", 32), ("S", 32)]),
+    ])
+    def test_stub_engine_dispatch_order(self, chunk, want):
+        from scripts.scheduler_microbench import build_stub_engine
+
+        eng = build_stub_engine(max_batch=4, max_seq=256, kv_layout="paged",
+                                prefill_chunk_tokens=chunk)
+        try:
+            log = self._logged(eng)
+            out_a, out_b = self._serve_b_behind_a(eng, self.A, self.B)
+            assert (len(out_a), len(out_b)) == (100, 8)
+            assert log[:len(want)] == want
+            pipe = eng.pipeline_stats()
+            shorts = sum(1 for kind, k in log if kind == "S" and k == 1)
+            assert pipe["segments_short"] == {
+                "waiting": 0, "prefill": 2 if chunk else 0}
+            assert pipe["segments_by_k"]["1"] == shorts
+            assert sum(pipe["segments_by_k"].values()) == sum(
+                1 for kind, _k in log if kind == "S") == pipe["segments"]
+        finally:
+            eng.close()
+
+    def test_another_versions_row_is_owed_its_prefill(self):
+        """Two versions co-resident, whole prompts: B's version has the
+        next tick, so its prefill is one segment of A's away, and that
+        segment is the short one. Rows of every version count."""
+        from scripts.scheduler_microbench import build_stub_engine
+
+        eng = build_stub_engine(max_batch=4, max_seq=256, kv_layout="paged",
+                                prefill_chunk_tokens=0)
+        try:
+            with eng._cv:
+                # sorts before "base": the round-robin gives base the tick
+                # in which B is admitted
+                eng._versions["a-canary"] = eng.params
+            log = self._logged(eng)
+            out_a, out_b = self._serve_b_behind_a(eng, self.A, self.B,
+                                                  b_version="a-canary")
+            assert (len(out_a), len(out_b)) == (100, 8)
+            assert log[:5] == [("P", 3), ("S", 32), ("S", 1), ("P", 48),
+                               ("S", 4)]
+            assert eng.pipeline_stats()["segments_short"] == {
+                "waiting": 0, "prefill": 1}
+        finally:
+            eng.close()
+
+    def test_a_waiting_request_still_names_the_short_segment(self):
+        """Three requests on two rows: the third waits for a row, and the
+        segments it shortens are counted under the older reason."""
+        from kubedl_tpu.serving.server import _Slot
+        from scripts.scheduler_microbench import build_stub_engine
+
+        eng = build_stub_engine(max_batch=2, max_seq=256, kv_layout="paged",
+                                prefill_chunk_tokens=16)
+        try:
+            slots = [_Slot([7, 8, 9 + j], 40, 0.0) for j in range(3)]
+            with eng._cv:
+                eng._waiting.extend(slots)
+            for _ in range(400):
+                if all(s.done.is_set() for s in slots):
+                    break
+                eng._loop_once()
+            assert all(len(s.result["token_ids"]) == 40 for s in slots)
+            short = eng.pipeline_stats()["segments_short"]
+            assert short["waiting"] > 0 and short["prefill"] == 0
+            assert eng.metrics.segment_lengths.value(
+                k="4", short="waiting") == short["waiting"]
+        finally:
+            eng.close()
+
+    def test_speculative_ticks_choose_no_segment(self):
+        from kubedl_tpu.serving.server import LlamaEngine
+
+        eng = LlamaEngine(preset="tiny", max_batch=2, max_seq=128, spec_k=2,
+                          prefill_chunk_tokens=16, prefix_cache_mb=0)
+        try:
+            out = eng.generate(list(range(3, 40)), max_tokens=12)
+            assert len(out["token_ids"]) == 12
+            pipe = eng.pipeline_stats()
+            assert pipe["segments"] > 0
+            assert set(pipe["segments_by_k"].values()) == {0}
+            assert pipe["segments_short"] == {"waiting": 0, "prefill": 0}
+        finally:
+            eng.close()
+
+    def test_second_chunk_follows_a_short_segment_and_tokens_hold(self):
+        """On the CPU preset, whole: while A decodes, B's two-chunk prompt
+        gets its second chunk after a 1-step segment where the parent's
+        rule (`cap = 4 if waiting else 32`) put a 32-step one, and both
+        rules serve the same tokens."""
+        from kubedl_tpu.serving.server import LlamaEngine
+
+        a, b = ([5, 9, 13], 60), (list(range(40, 70)), 6)  # 30 = 16 + 14
+        eng = LlamaEngine(preset="tiny", max_batch=2, max_seq=256,
+                          prefill_chunk_tokens=16, prefix_cache_mb=0)
+        try:
+            TestChainAcrossPrefill()._freeze(eng)
+            log = self._logged(eng)
+            mine = self._serve_b_behind_a(eng, a, b)
+            mine_log = list(log)
+            del log[:]
+            eng.choose_segment = lambda need, waiting, _mid, _dec: (
+                LlamaEngine.segment_size(need, 4 if waiting else 32), "")
+            parents = self._serve_b_behind_a(eng, a, b)
+        finally:
+            eng.close()
+        assert mine == parents
+        assert [len(t) for t in mine] == [60, 6]
+        assert mine_log[:5] == [("P", 3), ("S", 32), ("P", 16), ("S", 1),
+                                ("P", 14)]
+        assert log[:5] == [("P", 3), ("S", 32), ("P", 16), ("S", 32),
+                           ("P", 14)]
+
 
 class TestChainAcrossPrefill:
     """The device token chain across interleaved prefills: merged on
@@ -1070,6 +1288,31 @@ class TestSchedulerMicrobench:
         )
         assert out["tokens"] == 8 * 8
         assert out["chunks"] == 8 * 3  # 48 tokens / 16-token budget
+        assert out["blocks_leaked"] == 0, out
+        assert out["tick_ms_p50"] <= CHUNKED_BUDGET_MS, out
+        assert out["within_budget"], out
+
+    def test_mid_prefill_rows_keep_the_tick_within_budget(self):
+        """The same gate with two rows decoding while twelve prompts are
+        mid-way and nobody waits for a row: the tick counts the prefill
+        work it owes and runs short segments for it (one step while the
+        rows mid-prompt outnumber the rows decoding, then four), at no
+        host cost."""
+        from scripts.scheduler_microbench import (
+            CHUNKED_BUDGET_MS,
+            run_chunked_admission_microbench,
+        )
+
+        out = run_chunked_admission_microbench(
+            requests=12, prompt_len=48, max_tokens=8, max_batch=16,
+            decoders=2, decoder_tokens=100,
+        )
+        assert out["tokens"] == 12 * 8 + 2 * 100
+        assert out["chunks"] == 12 * 3 + 2
+        assert out["segments_short"]["waiting"] == 0, out
+        assert out["segments_short"]["prefill"] >= 30, out
+        assert out["segments_by_k"]["1"] >= 20, out
+        assert out["segments_by_k"]["4"] >= 5, out
         assert out["blocks_leaked"] == 0, out
         assert out["tick_ms_p50"] <= CHUNKED_BUDGET_MS, out
         assert out["within_budget"], out
