@@ -394,7 +394,7 @@ def _view(pool: jax.Array, p: jax.Array, bt: jax.Array) -> jax.Array:
 
 
 def _attention_one_query(q: jax.Array, k: jax.Array, v: jax.Array,
-                         mask: jax.Array) -> jax.Array:
+                         mask: jax.Array, scores_type=None) -> jax.Array:
     """``llama.attention`` for one query a row (a decode step), computed on
     the view as the pool stores it, ``[B, T, KV * hd]``: each query head is
     laid into its key head's ``hd`` columns of a ``KV * hd`` row of zeros, so
@@ -404,12 +404,15 @@ def _attention_one_query(q: jax.Array, k: jax.Array, v: jax.Array,
     copy that splits ``KV * hd`` into heads, which pads ``hd`` = 64 to 128
     lanes and moves the whole view a second time. Same scaling, mask and
     float32 softmax as ``llama.attention``. ``q [B, 1, H, hd]``; ``k``, ``v``
-    ``[B, T, KV * hd]``; ``mask [B, T]``: the keys a row's query may see."""
+    ``[B, T, KV * hd]``; ``mask [B, T]``: the keys a row's query may see.
+    ``scores_type`` float32 keeps the scores as the product accumulates them
+    (None: rounded to the inputs' type first, as ``llama.attention``'s)."""
     B, _, H, hd = q.shape
     KV = k.shape[2] // hd
     own = (jnp.arange(H)[:, None] // (H // KV) == jnp.arange(KV)[None, :])[None, :, :, None]
     wide = jnp.where(own, q[:, 0][:, :, None, :], 0).reshape(B, H, KV * hd)
-    scores = jnp.einsum("bhw,btw->bht", wide, k).astype(jnp.float32) / math.sqrt(hd)
+    scores = jnp.einsum("bhw,btw->bht", wide, k, preferred_element_type=scores_type)
+    scores = scores.astype(jnp.float32) / math.sqrt(hd)
     scores = jnp.where(mask[:, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bht,btw->bhw", probs, v).reshape(B, H, KV, hd)

@@ -674,6 +674,38 @@ class ServingMetrics:
             "are K/V blocks alone; state_rows and state_bytes are in "
             "/v1/stats",
         )
+        # what a runner's decode segments count beside their tokens, by the
+        # name the runner gives it (serving/model_runner.py
+        # `segment_counters`); the table by layer and expert is in /v1/stats
+        self.segment_counters = {
+            "experts_touched": r.counter(
+                "kubedl_tpu_serving_experts_touched",
+                "Experts that computed at least one kept token, summed over "
+                "decode steps and layers: what a step has to read of its "
+                "expert weights. 0 for a model without routed experts",
+            ),
+            "expert_steps": r.counter(
+                "kubedl_tpu_serving_expert_steps",
+                "Layers times the decode steps that kept a token: "
+                "experts_touched over this is the experts a layer's step "
+                "touches",
+            ),
+        }
+        # the second kind of K/V block (kv_blocks.WindowTable): the pool of
+        # the layers that read only a window of the context
+        self.kv_window_blocks_total = r.gauge(
+            "kubedl_tpu_serving_kv_window_blocks_total",
+            "Usable blocks in the windowed K/V pool (0: one kind of block)",
+        )
+        self.kv_window_blocks_free = r.gauge(
+            "kubedl_tpu_serving_kv_window_blocks_free",
+            "Windowed K/V blocks on the free list",
+        )
+        self.kv_window_blocks_released = r.counter(
+            "kubedl_tpu_serving_kv_window_blocks_released",
+            "Windowed K/V blocks rows gave back while they ran, because "
+            "the blocks had fallen wholly behind the window",
+        )
         # controller-side replica health (the probe-failure satellite:
         # a replica that stops answering its stats probe must SURFACE,
         # not silently drop out of the QPS math)

@@ -37,12 +37,23 @@ Invariants the engine relies on:
 
 Thread safety: one internal lock; the scheduler thread and request
 threads (stats) both call in.
+
+**Two kinds of block.** A model whose layers keep different amounts of
+context (``models/sparse_window.py``) has a pool a kind, each with its own
+allocator, trash block and table, and the invariants above hold for each.
+The pool of the layers that keep everything is the one the engine always
+had. The pool of the layers that read only the last ``window`` keys is a
+:class:`WindowTable`: a row owns the blocks of a RANGE of its positions,
+grown at the front as the row advances and released at the back once a
+block lies wholly behind the window.
 """
 
 from __future__ import annotations
 
 import threading
 from typing import Dict, Iterable, List, Optional
+
+import numpy as np
 
 #: the reserved write-sink block every unmapped table entry points at
 TRASH_BLOCK = 0
@@ -232,4 +243,96 @@ class BlockAllocator:
             "low_watermark": self.low_watermark,
             "high_watermark": self.high_watermark,
         })
+        return out
+
+
+class WindowTable:
+    """Rows' blocks in a pool whose layers read only the last ``window``
+    keys: the host mirror of the pool's block table, indexed like the full
+    pool's by a position's block, and each row's range of live entries.
+
+    A row owns the blocks of logical blocks ``[lo, hi)``; every other entry
+    of its table row is the trash block. :meth:`reserve` grows ``hi``,
+    :meth:`release_behind` advances ``lo`` past the blocks no query at or
+    after a position can see. Blocks here are never shared (a prefix that
+    has lost its window blocks cannot be reused), so a free is a return to
+    the free list. Not thread safe on its own: the engine calls under its
+    lock; the allocator underneath keeps its own."""
+
+    def __init__(self, alloc: BlockAllocator, rows: int, table_blocks: int,
+                 window: int) -> None:
+        if window % alloc.block_size:
+            raise ValueError(
+                f"window {window} is not whole blocks of {alloc.block_size}")
+        self.alloc = alloc
+        self.window = int(window)
+        #: uploaded before every dispatch, like the full pool's table
+        self.table = np.zeros((rows, table_blocks), np.int32)
+        self._lo = [0] * rows
+        self._hi = [0] * rows
+        self.released = 0
+
+    @staticmethod
+    def blocks_per_row(window: int, reach: int, block_size: int,
+                       table_blocks: int) -> int:
+        """The most blocks a row holds at once: the window, one dispatch's
+        ``reach`` of new positions (a prefill chunk, a decode segment), and
+        one more where the window's edge lies inside a block."""
+        return min(table_blocks, window // block_size + -(-reach // block_size) + 1)
+
+    def held(self, row: int) -> int:
+        return self._hi[row] - self._lo[row]
+
+    def reserve(self, row: int, n_tokens: int) -> bool:
+        """Grow ``row`` to cover positions ``[.., n_tokens)``; all or
+        nothing."""
+        need = min(self.alloc.blocks_for(n_tokens), self.table.shape[1])
+        hi = self._hi[row]
+        if need <= hi:
+            return True
+        got = self.alloc.alloc(need - hi)
+        if got is None:
+            return False
+        self.table[row, hi:need] = got
+        self._hi[row] = need
+        return True
+
+    def release_behind(self, row: int, pos: int) -> int:
+        """Free the blocks of ``row`` that lie wholly before ``pos - window
+        + 1``, the first key a query at ``pos`` sees; returns how many."""
+        keep_from = max(0, int(pos) - self.window + 1) // self.alloc.block_size
+        lo, keep_from = self._lo[row], min(keep_from, self._hi[row])
+        if keep_from <= lo:
+            return 0
+        self._drop(row, lo, keep_from)
+        self._lo[row] = keep_from
+        self.released += keep_from - lo
+        return keep_from - lo
+
+    def trim(self, row: int, n_tokens: int) -> None:
+        """Free the blocks beyond what ``n_tokens`` positions need."""
+        keep = max(self._lo[row], self.alloc.blocks_for(n_tokens))
+        if keep < self._hi[row]:
+            self._drop(row, keep, self._hi[row])
+            self._hi[row] = keep
+
+    def free_row(self, row: int) -> None:
+        self._drop(row, self._lo[row], self._hi[row])
+        self._lo[row] = self._hi[row] = 0
+
+    def _drop(self, row: int, lo: int, hi: int) -> None:
+        self.alloc.free(int(b) for b in self.table[row, lo:hi])
+        self.table[row, lo:hi] = TRASH_BLOCK
+
+    def stats(self, live_rows: Iterable[int] = ()) -> Dict:
+        """The allocator's stats, the blocks released so far, and over
+        ``live_rows``: the blocks they hold and the blocks their ranges
+        would span had none been released."""
+        out = self.alloc.stats()
+        live = list(live_rows)
+        out.update(
+            released=self.released,
+            held=sum(self.held(r) for r in live),
+            spanned=sum(self._hi[r] for r in live),
+        )
         return out

@@ -17,14 +17,17 @@ gather programs attend over. The engine says how far a dispatch reaches
 its programs hold, and each program takes the branch of the smallest span
 that holds ``live_to``.
 
-Two runners stand here. :class:`ModelRunner` is the decoder's
+Three runners stand here. :class:`ModelRunner` is the decoder's
 (``models/llama.py``). :class:`HybridRunner` is the hybrid state-space
 model's (``models/hybrid_ssm.py``): the same paged pools for its few
 attention layers, and beside them a fixed slab of recurrent state a row,
-which its programs reset, carry and advance themselves. Both answer the
-methods the engine calls, under the same program names; :func:`make_runner`
-picks one by the type of the preset's config, and what they share is
-:class:`_Runner`.
+which its programs reset, carry and advance themselves.
+:class:`SparseWindowRunner` is the sparse-expert decoder's
+(``models/sparse_window.py``): a pool for the layers that keep the whole
+context and a second, with a block table of its own, for the layers that
+read only a window of it. All answer the methods the engine calls, under
+the same program names; :func:`make_runner` picks one by the type of the
+preset's config, and what they share is :class:`_Runner`.
 """
 
 from __future__ import annotations
@@ -38,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kubedl_tpu import chaos
-from kubedl_tpu.models import hybrid_ssm, llama
+from kubedl_tpu.models import hybrid_ssm, llama, sparse_window
 
 log = logging.getLogger("kubedl_tpu.serving.model_runner")
 
@@ -70,6 +73,15 @@ class _Runner:
     #: engine builds no prefix cache and refuses speculation and hand-off
     #: (a prefix is then more than a list of blocks).
     state_bytes_per_row = 0
+    #: keys the layers of a second, windowed pool read back from a row's
+    #: position; 0: one kind of block. Non-zero, the cache holds that pool
+    #: (``wk``/``wv``) with its own table ``wbt``, the engine keeps a
+    #: ``kv_blocks.WindowTable`` for it, and as with state a prefix is more
+    #: than a list of blocks.
+    window = 0
+    #: what the last decode segment counted beside its tokens (device
+    #: arrays by name, harvested with the tokens); None: nothing
+    segment_counters = None
 
     def _build_samplers(self) -> None:
         # first-token sampler, ON DEVICE: fetching the prefill logits to
@@ -132,12 +144,15 @@ class _Runner:
         streams then differ from run to run on the CPU backend)."""
         return jnp.asarray(arr.copy()) + 0
 
-    def upload_mirrors(self, bt, pos=None) -> None:
+    def upload_mirrors(self, bt, pos=None, wbt=None) -> None:
         """Make the engine's authoritative HOST mirrors the paged cache's
-        block table and, when given, positions."""
+        block table and, when given, positions and the windowed pool's
+        table."""
         if pos is not None:
             self.cache["pos"] = self._upload_mirror(pos)
         self.cache["bt"] = self._upload_mirror(bt)
+        if wbt is not None:
+            self.cache["wbt"] = self._upload_mirror(wbt)
 
     @property
     def pool_shape(self) -> tuple:
@@ -173,6 +188,57 @@ class _Runner:
         if len(self.spans) == 1:
             return ()
         return (np.int32(self.max_seq if live_to is None else live_to),)
+
+    def _build_row_prefills(self, model_prefill) -> None:
+        """``_view``, ``_prefill`` and ``_prefill_from`` of a paged runner whose
+        model has ONE prefill function, ``model_prefill(params, cache, tokens,
+        lengths, cfg, rows, starts=, spans=, live_to=)``: whole prompts, and
+        suffixes from ``starts`` over the span that holds ``live_to``. The
+        rows' last-token logits land at ``rows`` of ``acc``."""
+        cfg, spans = self.cfg, self.spans
+
+        def view(live_to):
+            return {"spans": spans, "live_to": live_to[0]} if live_to else {}
+
+        def prefill(p, c, t, l, rows, acc):
+            lg, c = model_prefill(p, c, t, l, cfg, rows)
+            return acc.at[rows].set(lg), c
+
+        def prefill_from(p, c, t, l, st, rows, acc, *live_to):
+            lg, c = model_prefill(p, c, t, l, cfg, rows, starts=st,
+                                  **view(live_to))
+            return acc.at[rows].set(lg), c
+
+        self._view = view
+        self._prefill = jax.jit(
+            _named("engine_prefill", prefill), donate_argnums=(1,))
+        self._prefill_from = jax.jit(
+            _named("engine_prefill_from", prefill_from), donate_argnums=(1,))
+
+    def _jit_segment(self, n_steps: int, greedy: bool, body):
+        """``body`` jitted as this runner's ``n_steps`` decode segment, the
+        cache (its second argument) donated, kept for the next dispatch. The
+        step count is in the name: a module event of a device profile
+        carries a duration and nothing else."""
+        name = f"engine_decode_seg{n_steps}" + ("" if greedy else "_sampled")
+        fn = self._segments[(n_steps, greedy)] = jax.jit(
+            _named(name, body), donate_argnums=(1,))
+        return fn
+
+    def _prefill_rows(self, params, toks, lens, starts, rows, acc, live_to):
+        """One prefill program of a paged runner whose ``_prefill`` and
+        ``_prefill_from`` take the compact batch's ``rows`` and the logits so
+        far (``acc``; None: none yet): whole prompts without ``starts``,
+        suffixes over the span that holds ``live_to`` with them."""
+        acc = self._no_logits if acc is None else acc
+        if starts is None:
+            logits, self.cache = self._prefill(
+                params, self.cache, toks, lens, rows, acc)
+        else:
+            logits, self.cache = self._prefill_from(
+                params, self.cache, toks, lens, starts, rows, acc,
+                *self._live_to(live_to))
+        return logits
 
 
 class ModelRunner(_Runner):
@@ -474,26 +540,22 @@ class ModelRunner(_Runner):
         fn = self._segments.get((n_steps, greedy))
         if fn is None:
             seg, cfg, view = self._segment, self.cfg, self._view
-            # the step count is in the name: a module event of a device
-            # profile carries a duration and nothing else
-            name = f"engine_decode_seg{n_steps}" + ("" if greedy else "_sampled")
-            fn = jax.jit(
-                _named(name, lambda p, c, tokens, temps, key, *live_to: seg(
+            fn = self._jit_segment(
+                n_steps, greedy, lambda p, c, tokens, temps, key, *live_to: seg(
                     p, c, tokens, temps, key, cfg=cfg,
                     n_steps=n_steps, greedy=greedy, **view(live_to),
-                )),
-                donate_argnums=(1,),
-            )
-            self._segments[(n_steps, greedy)] = fn
+                ))
         return fn
 
     def decode_segment(self, n_steps: int, greedy: bool, params, tokens,
-                       temps, key, live_to: Optional[int] = None, rows=None):
+                       temps, key, live_to: Optional[int] = None, rows=None,
+                       takes=None):
         """``n_steps`` decode steps, sampled on the device, over the view
         span that holds ``live_to``: one past the highest position a row
         whose tokens are read will stand at (None: the whole table).
-        ``rows`` names the rows the dispatch scheduled; a decoder's row is
-        its blocks and ``pos``, which the mirrors put right before the next
+        ``rows`` names the rows the dispatch scheduled and ``takes`` how
+        many of the segment's tokens each keeps; a decoder's row is its
+        blocks and ``pos``, which the mirrors put right before the next
         dispatch, so nothing here needs them.
         Returns ``(toks [B, n_steps], last [B, 1], key)``."""
         toks, last, key, self.cache = self._segment_fn(n_steps, greedy)(
@@ -546,26 +608,9 @@ class HybridRunner(_Runner):
         )
         self.state_bytes_per_row = hybrid_ssm.state_bytes_per_row(cfg)
         self.cache = None
-        spans = self.spans = self.span_ladder(self.max_seq, bs)
+        self.spans = self.span_ladder(self.max_seq, bs)
         self._no_logits = jnp.zeros((max_batch, cfg.vocab_size), jnp.float32)
-
-        def view(live_to):
-            return {"spans": spans, "live_to": live_to[0]} if live_to else {}
-
-        def prefill(p, c, t, l, rows, acc):
-            lg, c = hybrid_ssm.prefill(p, c, t, l, cfg, rows)
-            return acc.at[rows].set(lg), c
-
-        def prefill_from(p, c, t, l, st, rows, acc, *live_to):
-            lg, c = hybrid_ssm.prefill(p, c, t, l, cfg, rows, starts=st,
-                                       **view(live_to))
-            return acc.at[rows].set(lg), c
-
-        self._view = view
-        self._prefill = jax.jit(
-            _named("engine_prefill", prefill), donate_argnums=(1,))
-        self._prefill_from = jax.jit(
-            _named("engine_prefill_from", prefill_from), donate_argnums=(1,))
+        self._build_row_prefills(hybrid_ssm.prefill)
         self._build_samplers()
         self._segments: Dict[tuple, object] = {}
 
@@ -597,34 +642,23 @@ class HybridRunner(_Runner):
         """As :meth:`ModelRunner.prefill`. Without ``starts`` every row of
         the program begins from a zero slab; with them, the rows whose
         start is 0 do and the others carry theirs on."""
-        acc = self._no_logits if acc is None else acc
-        if starts is None:
-            logits, self.cache = self._prefill(
-                params, self.cache, toks, lens, rows, acc)
-        else:
-            logits, self.cache = self._prefill_from(
-                params, self.cache, toks, lens, starts, rows, acc,
-                *self._live_to(live_to))
-        return logits
+        return self._prefill_rows(params, toks, lens, starts, rows, acc, live_to)
 
     def _segment_fn(self, n_steps: int, greedy: bool):
         fn = self._segments.get((n_steps, greedy))
         if fn is None:
             cfg, view = self.cfg, self._view
-            name = f"engine_decode_seg{n_steps}" + ("" if greedy else "_sampled")
-            fn = jax.jit(
-                _named(name, lambda p, c, tokens, temps, key, live, *live_to: (
+            fn = self._jit_segment(
+                n_steps, greedy, lambda p, c, tokens, temps, key, live, *live_to: (
                     hybrid_ssm.decode_segment(
                         p, c, tokens, temps, key, live, cfg, n_steps=n_steps,
                         greedy=greedy, **view(live_to))
-                )),
-                donate_argnums=(1,),
-            )
-            self._segments[(n_steps, greedy)] = fn
+                ))
         return fn
 
     def decode_segment(self, n_steps: int, greedy: bool, params, tokens,
-                       temps, key, live_to: Optional[int] = None, rows=None):
+                       temps, key, live_to: Optional[int] = None, rows=None,
+                       takes=None):
         """As :meth:`ModelRunner.decode_segment`; the slabs of ``rows`` (the
         rows the dispatch scheduled; None: every row) advance, every other
         row's stays as it is."""
@@ -639,36 +673,163 @@ class HybridRunner(_Runner):
         return toks, last, key
 
 
+class SparseWindowRunner(_Runner):
+    """The sparse-expert decoder's config, cache and jitted programs
+    (``models/sparse_window.py``), behind the methods and program names of
+    :class:`ModelRunner`. A row owns blocks of two kinds: in the pool of the
+    full-attention layers, for its whole context (``block_bytes`` counts
+    those layers), and in the pool of the window layers, for the last
+    ``window`` keys and what a dispatch adds (``window_block_bytes``). The
+    engine keeps both tables and uploads both; the programs here gather a
+    full layer's view over the span ladder and a window layer's from the
+    blocks that end at the row's position. A decode segment is told how many
+    tokens each row keeps (``takes``): a step past that routes to no expert,
+    and the segment's expert counters (``segment_counters``) count kept
+    tokens alone. ``sparse_window.preset`` and ``sparse_window.sparse_init``
+    are looked up on the module at call time, as the decoder's are.
+
+    Paged, gather attention only. What rests on "a prefix is a list of
+    blocks" does not hold once a row's window blocks are released (prefix
+    reuse, speculation's rollback, block hand-off): the engine refuses
+    those at construction."""
+
+    def __init__(self, preset: str, *, max_batch: int, max_seq: int = 0,
+                 kv_block_size: int = 16) -> None:
+        self.cfg = cfg = sparse_window.preset(preset)
+        self.max_batch = max_batch
+        bs = self.kv_block_size = max(1, int(kv_block_size))
+        self.window = int(cfg.window)
+        if self.window % bs:
+            raise ValueError(
+                f"preset {preset!r} reads a window of {self.window} keys, which "
+                f"is not whole blocks of kv_block_size={bs}")
+        self.max_seq = -(-(max_seq or min(cfg.max_seq, 512)) // bs) * bs
+        token = 2 * bs * cfg.n_kv_heads * cfg.head_dim * np.dtype(cfg.dtype).itemsize
+        self.block_bytes = int(cfg.n_full * token)
+        self.window_block_bytes = int(cfg.n_window * token)
+        self.cache = None
+        self.window_blocks = 0  # size_window_pool()
+        self.spans = self.span_ladder(self.max_seq, bs)
+        self._no_logits = jnp.zeros((max_batch, cfg.vocab_size), jnp.float32)
+        self._build_row_prefills(sparse_window.prefill)
+        self._build_samplers()
+        self._segments: Dict[tuple, object] = {}
+
+    def build_params(self, ckpt_dir: str, require_ckpt: bool = False):
+        """Init, then the newest checkpoint where there is one; committed
+        nowhere until it returns (:meth:`ModelRunner.build_params`)."""
+        chaos.check("serving.weight_swap")
+        return self._restored(
+            sparse_window.sparse_init(jax.random.PRNGKey(0), self.cfg), ckpt_dir,
+            require_ckpt)
+
+    def size_window_pool(self, reach: int) -> int:
+        """Size the window pool so that every row can hold its most at once:
+        the window, one dispatch's ``reach`` of new positions (a prefill
+        chunk, or the longest decode segment) and a block for the window's
+        edge. Returns the pool's blocks, the trash block among them; the
+        engine's ``WindowTable`` hands out the others. Once, before
+        :meth:`new_cache`."""
+        from kubedl_tpu.serving.kv_blocks import WindowTable
+
+        self.window_blocks = 1 + self.max_batch * WindowTable.blocks_per_row(
+            self.window, reach, self.kv_block_size,
+            self.max_seq // self.kv_block_size)
+        return self.window_blocks
+
+    def new_cache(self, kv_blocks: int = 0) -> None:
+        """Both pools, ``pos`` and both tables, zeroed."""
+        self.cache = sparse_window.init_cache(
+            self.cfg, self.max_batch, self.max_seq, kv_blocks,
+            self.window_blocks, self.kv_block_size)
+
+    def warmup(self, params) -> None:
+        """One decode step that keeps no token: proof the model runs, by a
+        program the ticks use too."""
+        self.decode_segment(
+            1, True, params, jnp.zeros((self.max_batch, 1), jnp.int32),
+            jnp.zeros((self.max_batch,), jnp.float32), jax.random.PRNGKey(0),
+            live_to=1, rows=(), takes=())
+        jax.block_until_ready(self.cache["pos"])
+        self.segment_counters = None
+
+    def prefill(self, params, toks, lens, starts=None, rows=None, acc=None,
+                live_to: Optional[int] = None):
+        """As :meth:`ModelRunner.prefill`."""
+        return self._prefill_rows(params, toks, lens, starts, rows, acc, live_to)
+
+    def _segment_fn(self, n_steps: int, greedy: bool):
+        fn = self._segments.get((n_steps, greedy))
+        if fn is None:
+            cfg, view = self.cfg, self._view
+            fn = self._jit_segment(
+                n_steps, greedy, lambda p, c, tokens, temps, key, take, *live_to: (
+                    sparse_window.decode_segment(
+                        p, c, tokens, temps, key, take, cfg, n_steps=n_steps,
+                        greedy=greedy, **view(live_to))
+                ))
+        return fn
+
+    def decode_segment(self, n_steps: int, greedy: bool, params, tokens,
+                       temps, key, live_to: Optional[int] = None, rows=None,
+                       takes=None):
+        """As :meth:`ModelRunner.decode_segment`; row ``rows[j]`` keeps the
+        first ``takes[j]`` of the segment's tokens (None: every row keeps
+        them all) and no other row keeps any. Leaves what the segment
+        counted in ``segment_counters``."""
+        take = np.full((self.max_batch,), n_steps, np.int32)
+        if rows is not None:
+            take[:] = 0
+            take[list(rows)] = n_steps if takes is None else list(takes)
+        toks, last, key, self.cache, self.segment_counters = self._segment_fn(
+            n_steps, greedy)(
+            params, self.cache, tokens, temps, key, jnp.asarray(take),
+            *self._live_to(live_to),
+        )
+        return toks, last, key
+
+
 def make_runner(preset: str, *, max_batch: int, max_seq: int = 0,
                 paged: bool = True, kv_block_size: int = 16,
                 kv_attention: str = "gather", quantize: str = "",
                 mesh_axes: Optional[Dict] = None, spec_k: int = 0,
                 spec_candidates: int = 1, spec_tree: bool = False):
     """The runner for ``preset``, by the type of its config: the one place
-    that chooses. A preset of ``hybrid_ssm`` gets a :class:`HybridRunner`,
-    which refuses what it cannot do (``ValueError``, naming the reason);
-    any other name is ``llama.preset``'s."""
-    try:
-        cfg = hybrid_ssm.preset(preset)
-    except KeyError:
-        cfg = llama.preset(preset)
-    if not isinstance(cfg, hybrid_ssm.HybridConfig):
+    that chooses. A preset of ``hybrid_ssm`` gets a :class:`HybridRunner`
+    and one of ``sparse_window`` a :class:`SparseWindowRunner`, which refuse
+    what they cannot do (``ValueError``, naming the reason); any other name
+    is ``llama.preset``'s."""
+    cfg = None
+    for family in (hybrid_ssm, sparse_window):
+        try:
+            cfg = family.preset(preset)
+            break
+        except KeyError:
+            continue
+    if cfg is None:
         return ModelRunner(
             preset, max_batch=max_batch, max_seq=max_seq, paged=paged,
             kv_block_size=kv_block_size, kv_attention=kv_attention,
             quantize=quantize, mesh_axes=mesh_axes, spec_k=spec_k,
             spec_candidates=spec_candidates, spec_tree=spec_tree)
+    hybrid = isinstance(cfg, hybrid_ssm.HybridConfig)
+    if hybrid:
+        holds = "holds recurrent state beside its K/V blocks"
+        draft = ("would have to roll the recurrent state back, and only K/V "
+                 "blocks can be freed in place")
+    else:
+        holds = "keeps two kinds of K/V block, one a window of the context,"
+        draft = "would need window blocks the row has released"
     refused = {
         "kv_layout='contiguous' (and mesh_axes, which forces it)": not paged,
         "kv_attention='blocked'": kv_attention != "gather",
         "quantize": bool(quantize),
-        "spec_k > 0 (a rejected draft would have to roll the recurrent "
-        "state back, and only K/V blocks can be freed in place)": spec_k > 0,
+        f"spec_k > 0 (a rejected draft {draft})": spec_k > 0,
     }
     for what, asked in refused.items():
         if asked:
             raise ValueError(
-                f"preset {preset!r} holds recurrent state beside its K/V "
-                f"blocks and cannot be served with {what}")
-    return HybridRunner(preset, max_batch=max_batch, max_seq=max_seq,
-                        kv_block_size=kv_block_size)
+                f"preset {preset!r} {holds} and cannot be served with {what}")
+    runner = HybridRunner if hybrid else SparseWindowRunner
+    return runner(preset, max_batch=max_batch, max_seq=max_seq,
+                  kv_block_size=kv_block_size)

@@ -253,6 +253,26 @@ class LlamaEngine:
                     "that follows them)", preset,
                 )
                 prefix_cache_mb = 0.0
+        #: keys the layers of a second, windowed pool read back from a row's
+        #: position (0: one kind of block; docs/serving.md "Models whose
+        #: layers keep different amounts of context"). The engine keeps that
+        #: pool's table and releases what falls behind the window, and again
+        #: refuses what takes a prefix to be a list of blocks
+        self._window = int(runner.window)
+        if self._window:
+            if self.role != "colocated":
+                raise ValueError(
+                    f"preset {preset!r} keeps two kinds of K/V block: "
+                    f"role={self.role!r} hands a prompt over as blocks, and "
+                    "the window layers' have been released behind the window"
+                )
+            if prefix_cache_mb > 0:
+                log.info(
+                    "preset %r keeps only a window of K/V in some layers: no "
+                    "prefix cache is built (a cached prefix's window blocks "
+                    "are gone)", preset,
+                )
+                prefix_cache_mb = 0.0
         if self._paged:
             self.kv_block_size = runner.kv_block_size
         #: chunked prefill (docs/serving.md "Continuous batching"): > 0
@@ -283,7 +303,8 @@ class LlamaEngine:
         #: row frees — never while a row still dispatches on them
         self._retiring: set = set()
         self._vers_rr = 0
-        self.kv_blocks = 0
+        self.kv_blocks = self.window_kv_blocks = 0
+        self._wtable = None
         self._draft = self._spec_stats = None
         if self._paged:
             import math
@@ -310,6 +331,13 @@ class LlamaEngine:
                     )
                 nb = 1 + self.max_batch * mb + prefix_blocks
             self.kv_blocks = nb
+            #: the windowed pool is sized so that every row can hold what it
+            #: may at once: the window and one dispatch's reach (a prefill
+            #: chunk, or the longest decode segment)
+            if self._window:
+                self.window_kv_blocks = runner.size_window_pool(max(
+                    self.prefill_chunk_tokens or self.max_seq,
+                    self.SEGMENT_BUCKETS[0]))
             self._new_block_state(kv_low_watermark, kv_high_watermark)
             self.spec_draft = spec_draft
             if self.spec_k:
@@ -388,6 +416,10 @@ class LlamaEngine:
                        "view_keys": 0, "view_keys_full": 0,
                        "state_resets": 0,
                        "started_at": time.time()}
+        #: what the runner's decode segments count beside their tokens
+        #: (`_Runner.segment_counters`), summed at each harvest, by name
+        self._segment_counters: Dict[str, object] = {}
+        self._segment_seq = 0
         #: load-shedding budget: reject (503) instead of queueing once the
         #: queue is deeper than max_queue_depth or its head has waited
         #: longer than max_queue_age_s (the queue is not draining)
@@ -771,6 +803,12 @@ class LlamaEngine:
             qwait = list(self._queue_wait_recent)
             draining = self._draining
             parked_handoffs = len(self._handoffs)
+            # scalars as ints, a table (by layer and expert) as lists
+            out.update({name: v.tolist()
+                        for name, v in self._segment_counters.items()})
+            window_blocks = self._wtable.stats(
+                i for i, s in enumerate(self._slots) if s is not None
+            ) if self._wtable is not None else None
         up = max(now - out["started_at"], 1e-9)
         import jax
 
@@ -822,6 +860,9 @@ class LlamaEngine:
             out["kv_blocks"] = self._alloc.stats()
             out["kv_blocks"]["attention_kernel"] = self.kv_attention
             out["kv_blocks"]["role"] = self.role
+            if window_blocks is not None:
+                # the second kind of block, with what the rows released
+                out["kv_blocks"]["window"] = window_blocks
         if self._spec_stats is not None:
             out["speculative"] = self._spec_stats.snapshot()
             out["speculative"]["draft_kind"] = getattr(
@@ -950,7 +991,7 @@ class LlamaEngine:
         rejection, preemption, vacation) are just mirror edits."""
         import numpy as np
 
-        from kubedl_tpu.serving.kv_blocks import BlockAllocator
+        from kubedl_tpu.serving.kv_blocks import BlockAllocator, WindowTable
 
         bs = self.kv_block_size
         self._alloc = BlockAllocator(
@@ -959,6 +1000,44 @@ class LlamaEngine:
         self._pos_host = np.zeros((self.max_batch,), np.int32)
         self._bt_host = np.zeros((self.max_batch, self.max_seq // bs), np.int32)
         self._row_blocks: list = [[] for _ in range(self.max_batch)]
+        #: the windowed pool's allocator, table mirror and rows' ranges
+        #: (None: the runner has one kind of block). No watermarks: the
+        #: pool holds every row's most at once (`size_window_pool`), so it
+        #: is full by design and never a reason to hold admission
+        self._wtable = WindowTable(
+            BlockAllocator(self.window_kv_blocks, bs, low_watermark=0.0,
+                           high_watermark=0.0),
+            self.max_batch, self.max_seq // bs, self._window,
+        ) if self._window else None
+        self._window_released_seen = 0  # metric delta vs the table's count
+
+    def _upload_mirrors(self, pos: bool = True) -> None:
+        """The host mirrors to the device, before a dispatch: the block
+        table, the positions unless ``pos`` is False, and the windowed
+        pool's table where there is one."""
+        extra = {} if self._wtable is None else {"wbt": self._wtable.table}
+        self._runner.upload_mirrors(
+            self._bt_host, self._pos_host if pos else None, **extra)
+
+    def _advance_pos_locked(self, i: int, pos: int) -> None:
+        """Row ``i``'s position mirror after a dispatch that left it at
+        ``pos``; its window blocks that now lie wholly behind the window are
+        released and their table entries pointed at trash. The dispatch in
+        flight reads through the table it was given, and the device runs
+        dispatches in order, so a later owner's writes land after its reads
+        (the argument of `_free_row_locked`). Caller holds cv."""
+        self._pos_host[i] = min(int(pos), self.max_seq - 1)
+        if self._wtable is not None:
+            self._wtable.release_behind(i, int(self._pos_host[i]))
+
+    def _reserve_window_locked(self, i: int, n_tokens: int) -> None:
+        """Grow row ``i``'s window blocks to cover ``n_tokens`` positions
+        before a prefill dispatch writes them. The pool holds every row's
+        most at once, so this cannot fail while the releases keep up; if it
+        does the tick raises and the engine recovers. Caller holds cv."""
+        if self._wtable is not None and not self._wtable.reserve(i, n_tokens):
+            raise RuntimeError(
+                f"window pool exhausted growing row {i} to {n_tokens} tokens")
 
     def _free_row_locked(self, i: int) -> None:
         """Return row ``i``'s blocks to the pool and point its table rows
@@ -974,12 +1053,17 @@ class LlamaEngine:
         self._row_blocks[i] = []
         self._bt_host[i, :] = 0
         self._pos_host[i] = 0
+        if self._wtable is not None:
+            self._wtable.free_row(i)
 
     def _reserve_locked(self, i: int, n_tokens: int) -> bool:
         """Grow row ``i``'s block list to cover ``n_tokens`` cached
-        positions (all-or-nothing). Caller holds cv."""
+        positions (all-or-nothing), in the windowed pool too where there is
+        one. Caller holds cv."""
         need = self._alloc.blocks_for(min(int(n_tokens), self.max_seq))
         blocks = self._row_blocks[i]
+        if self._wtable is not None and not self._wtable.reserve(i, n_tokens):
+            return False  # what it did grow, the row keeps for its next try
         if need <= len(blocks):
             return True
         got = self._alloc.alloc(need - len(blocks))
@@ -995,6 +1079,8 @@ class LlamaEngine:
         positions are beyond the rolled-back pos mirror)."""
         keep = self._alloc.blocks_for(min(int(n_tokens), self.max_seq))
         blocks = self._row_blocks[i]
+        if self._wtable is not None:
+            self._wtable.trim(i, n_tokens)
         if len(blocks) <= keep:
             return
         drop = blocks[keep:]
@@ -1139,7 +1225,7 @@ class LlamaEngine:
         if not entry_blocks:
             # array-payload entry (direct insert): scatter its K/V into
             # the row's fresh blocks through the just-updated table
-            self._runner.upload_mirrors(self._bt_host)
+            self._upload_mirrors(pos=False)
             self._runner.graft(entry.k, entry.v, i, mlen)
         return True
 
@@ -1381,6 +1467,11 @@ class LlamaEngine:
             raise ValueError(
                 f"preset {self.preset_name!r} holds recurrent state beside "
                 "its K/V blocks: a block hand-off would leave it behind"
+            )
+        if self._window:
+            raise ValueError(
+                f"preset {self.preset_name!r} keeps two kinds of K/V block: "
+                "a block hand-off would miss what the window layers released"
             )
 
     def prefill_handoff(self, prompt_ids, max_tokens: int = 16,
@@ -1805,10 +1896,20 @@ class LlamaEngine:
             return
         with TRACER.phase("engine.harvest_wait", what="segment") as wait:
             rows = _to_host(pend["toks"])  # [B, k]
+            # and what the runner's segment counted beside them, by name
+            counted = {name: _to_host(v)
+                       for name, v in (pend["counters"] or {}).items()}
         t1 = time.perf_counter()
         seg_t0 = pend.get("t0", t1)
-        with TRACER.phase("engine.harvest_host") as host, self._cv:
+        with TRACER.phase("engine.harvest_host", seq=pend["seq"], **{
+            name: int(v) for name, v in counted.items() if v.ndim == 0
+        }) as host, self._cv:
             self._pipe["inflight"] = 0
+            for name, v in counted.items():
+                self._segment_counters[name] = (
+                    self._segment_counters.get(name, 0) + v)
+                if name in self.metrics.segment_counters:
+                    self.metrics.segment_counters[name].inc(int(v))
             for i, s, take in pend["sched"]:
                 s.pending -= take
                 if self._slots[i] is not s:
@@ -1921,9 +2022,7 @@ class LlamaEngine:
                     if self._paged:
                         # the programs after the first run on the cache
                         # the one before returned
-                        self._runner.upload_mirrors(
-                            self._bt_host, self._pos_host
-                        )
+                        self._upload_mirrors()
                     t0 = time.perf_counter()
                 from_prefix = suffix or bool(np.any(starts > 0))
                 # every position the program reads or writes lies below
@@ -2053,6 +2152,9 @@ class LlamaEngine:
             left -= take
         if not sched:
             return [], None
+        with self._cv:
+            for i, _s, base, take, _final in sched:
+                self._reserve_window_locked(i, base + take)
         # injected chunk-dispatch fault: the scheduler must recover
         # (fail in-flight slots, rebuild the donated cache, keep
         # serving) exactly as for a decode-segment fault
@@ -2067,7 +2169,7 @@ class LlamaEngine:
             for i, s, base, take, final in sched:
                 # mirror the device's pos advance for dispatched rows
                 # (vacated rows get reset at readmission)
-                self._pos_host[i] = min(base + take, self.max_seq - 1)
+                self._advance_pos_locked(i, base + take)
                 if self._slots[i] is not s:
                     continue  # vacated (request timeout) mid-chunk
                 s.prefill_pos = base + take
@@ -2191,7 +2293,7 @@ class LlamaEngine:
             "engine.spec_dispatch", k=k, rows=len(rows),
             slots=self.max_batch,
         ) as ph:
-            self._runner.upload_mirrors(self._bt_host, self._pos_host)
+            self._upload_mirrors()
             if tree:
                 # trie ranking pass (read-only, like multi): candidates
                 # sharing a prefix share trie nodes, one forward scores
@@ -2336,6 +2438,14 @@ class LlamaEngine:
             m.kv_blocks_total.set(float(st["total"]), **kern)
             m.kv_blocks_free.set(float(st["free"]), **kern)
             m.kv_blocks_shared.set(float(st["shared"]), **kern)
+            if self._wtable is not None:
+                wst = self._wtable.alloc.stats()
+                m.kv_window_blocks_total.set(float(wst["total"]))
+                m.kv_window_blocks_free.set(float(wst["free"]))
+                released = self._wtable.released
+                m.kv_window_blocks_released.inc(
+                    released - self._window_released_seen)
+                self._window_released_seen = released
         if self._spec_stats is not None:
             m.spec_acceptance_rate.set(self._spec_stats.acceptance_rate())
 
@@ -2470,6 +2580,9 @@ class LlamaEngine:
                     for _, s in bad:
                         s.cached_len = 0
                         self._release_prefix_locked(s)
+            with self._cv:
+                for i, s in todo:
+                    self._reserve_window_locked(i, len(s.prompt))
             prefill_ids, t0 = self._dispatch_prefill(
                 [(i, s, s.cached_len, len(s.prompt) - s.cached_len, True)
                  for i, s in todo],
@@ -2480,9 +2593,7 @@ class LlamaEngine:
                     if self._paged:
                         # mirror the device's pos update for dispatched
                         # rows (vacated rows get reset at readmission)
-                        self._pos_host[i] = min(
-                            len(s.prompt), self.max_seq - 1
-                        )
+                        self._advance_pos_locked(i, len(s.prompt))
                     if self._slots[i] is not s:
                         continue  # vacated (request timeout) mid-prefill
                     s.prefill_t0 = t0  # dispatch start, for engine.prefill
@@ -2623,15 +2734,18 @@ class LlamaEngine:
             for i, s in decoding:
                 tokens[i, 0] = s.next_input()
             tokens_dev = jnp.asarray(tokens)
-        if self._paged:
-            # block growth for the segment's k appends; on exhaustion the
-            # reserve preempts-and-requeues victims, and rows that still
-            # cannot grow sit this dispatch out (their device pos mirror
-            # stays put, so the skipped steps never happened for them)
-            with self._cv:
+        with self._cv:
+            if self._paged:
+                # block growth for the segment's k appends; on exhaustion the
+                # reserve preempts-and-requeues victims, and rows that still
+                # cannot grow sit this dispatch out (their device pos mirror
+                # stays put, so the skipped steps never happened for them)
                 decoding = self._reserve_decode_locked(decoding, k)
-            if not decoding:
-                return None
+            # the tokens of the segment each row keeps: its steps up to its
+            # budget
+            takes = [min(k, self._rem(s)) for _, s in decoding]
+        if not decoding:
+            return None
         # injected device fault mid-flight: raising here exercises the
         # _loop recovery contract (fail in-flight slots, rebuild the
         # donated cache, reset the pipeline, keep serving)
@@ -2640,7 +2754,7 @@ class LlamaEngine:
         if self._temps_cache is None or self._temps_cache[0] != fp:
             self._temps_cache = (fp, jnp.asarray(temps))
         if self._paged:
-            self._runner.upload_mirrors(self._bt_host, self._pos_host)
+            self._upload_mirrors()
         # the reserve above has grown every scheduled row to pos + k; rows
         # it left out run too, and nobody reads what they compute
         live_to = None
@@ -2652,7 +2766,7 @@ class LlamaEngine:
         t0 = time.perf_counter()  # start of the rows' engine.decode_segment
         toks, last, self._key = self._runner.decode_segment(
             k, greedy, params, tokens_dev, self._temps_cache[1], self._key,
-            live_to=live_to, rows=[i for i, _ in decoding],
+            live_to=live_to, rows=[i for i, _ in decoding], takes=takes,
         )
         self._chain = (
             self._prefill_gen, tuple(i for i, _ in decoding), last
@@ -2666,8 +2780,12 @@ class LlamaEngine:
                 attrs["span"] = span = self._runner.span_for(live_to)
                 self._count_view_keys_locked(
                     span * k * self.max_batch, k * self.max_batch)
-            for i, s in decoding:
-                take = min(k, self._rem(s))
+            if self._window:
+                # and those of them a window layer's query still sees
+                attrs["wkeys"] = sum(
+                    min(int(self._pos_host[i]), self._window)
+                    for i, _ in decoding)
+            for (i, s), take in zip(decoding, takes):
                 s.pending += take
                 s.fed += take
                 sched.append((i, s, take))
@@ -2675,13 +2793,15 @@ class LlamaEngine:
                     # scheduled rows advance k steps on device; rows
                     # NOT scheduled keep their mirror (the upload
                     # before the next dispatch rewinds device pos)
-                    self._pos_host[i] = min(
-                        int(self._pos_host[i]) + k, self.max_seq - 1
-                    )
+                    self._advance_pos_locked(i, int(self._pos_host[i]) + k)
             self._pipe["inflight"] = 1
+            self._segment_seq += 1
+        # `seq` joins this span to the harvest span of the same segment
         phase.set(k=k, rows=len(sched), slots=self.max_batch,
-                  take=sum(t for _i, _s, t in sched), **attrs)
-        return {"toks": toks, "sched": sched, "k": k, "t0": t0}
+                  take=sum(takes), seq=self._segment_seq, **attrs)
+        return {"toks": toks, "sched": sched, "k": k, "t0": t0,
+                "seq": self._segment_seq,
+                "counters": self._runner.segment_counters}
 
 
 def make_handler(engine: LlamaEngine, model_name: str):

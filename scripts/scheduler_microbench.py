@@ -168,7 +168,7 @@ class StubRunner:
         return last
 
     def decode_segment(self, n_steps, greedy, params, tokens, temps, key,
-                       live_to=None, rows=None):
+                       live_to=None, rows=None, takes=None):
         return self._seg_toks[n_steps], self._last, key
 
     def graft(self, k, v, row, length):

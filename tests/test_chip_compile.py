@@ -202,3 +202,60 @@ def test_hybrid_programs_fit_the_chip_whole(on_chip, program):
         f"bf16[{cfg.periods},{1 + B * max_seq // BS}," in ln
         or (f"bf16[{cfg.n_mamba},{cfg.dim}," in ln and ",64]" not in ln))]
     assert not big, big[:2]
+
+
+@pytest.mark.parametrize("program", ["decode_segment", "prefill_from"])
+def test_sparse_window_programs_fit_the_chip(on_chip, program, monkeypatch):
+    """The sparse-window runner's own programs at Mellum2-12B-A2.5B's widths,
+    12 of its 28 layers (three whole periods), 16 rows of 8192 keys and both
+    pools: arguments and temporaries stay under the 15 GiB a 16 GB chip
+    leaves a program (12.35 GB of weights and pools by the configuration's
+    table). No operation copies a pool, and none copies the stack of expert
+    weights: the grouped products are given the whole stack with the layer in
+    the group sizes, so no layer's 0.79 GB of experts is sliced out. A
+    chunk's products are the chip's grouped matmul, not a masked product over
+    all 64 experts; a decode step's few rows are multiplied by every expert
+    (``sparse_window.DENSE_BELOW``), which reads the layer's experts in place
+    too."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from kubedl_tpu.models import sparse_window
+    from kubedl_tpu.serving.model_runner import SparseWindowRunner
+
+    cfg = dataclasses.replace(sparse_window.MELLUM2_12B, periods=3)
+    monkeypatch.setitem(sparse_window.PRESETS, "mellum2-l12", cfg)
+    B, max_seq, BS = 16, 8192, 16
+    runner = SparseWindowRunner("mellum2-l12", max_batch=B, max_seq=max_seq,
+                                kv_block_size=BS)
+    window_blocks = runner.size_window_pool(1024)
+    assert window_blocks == 1 + B * 129
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: on_chip(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: sparse_window.sparse_init(jax.random.PRNGKey(0), cfg)))
+    cache = place(jax.eval_shape(lambda: sparse_window.init_cache(
+        cfg, B, max_seq, 1 + B * max_seq // BS, window_blocks, BS)))
+    i32 = lambda *s: on_chip(s, jnp.int32)  # noqa: E731
+    if program == "decode_segment":
+        lowered = runner._segment_fn(4, True).lower(
+            params, cache, i32(B, 1), on_chip((B,), jnp.float32),
+            on_chip((2,), jnp.uint32), i32(B), i32())
+    else:
+        lowered = runner._prefill_from.lower(
+            params, cache, i32(1, 1024), i32(1), i32(1), i32(1),
+            on_chip((B, cfg.vocab_size), jnp.float32), i32())
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * 2**30
+    text = compiled.as_text()
+    big = [ln for ln in text.splitlines() if " copy(" in ln and (
+        f"bf16[{cfg.n_full},{1 + B * max_seq // BS}," in ln
+        or f"bf16[{cfg.n_window},{window_blocks}," in ln
+        or f"bf16[{cfg.n_layers},{cfg.n_experts}," in ln
+        or f"bf16[{cfg.n_layers * cfg.n_experts}," in ln)]
+    assert not big, big[:2]
+    # a chunk's tokens are multiplied grouped; a decode step's 16 by every expert
+    assert ("ragged-dot" in text) == (program == "prefill_from")
