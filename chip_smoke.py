@@ -191,7 +191,10 @@ def phase_kernels(workdir: str) -> tuple:
             ],
         }
     else:
-        llama = {"B": 8, "KV": 8, "group": 4, "hd": 64}  # Llama-3.2-1B decode
+        # Mistral-7B's decode heads: the paged kernel takes a pool whose heads
+        # are whole 128-lane tiles (Llama-3.2-1B's 64 are refused by name, and
+        # the serve arms below run that model's decode through the lax scan)
+        mistral = {"B": 8, "KV": 8, "group": 4, "hd": 128}
         gemma = {"B": 8, "KV": 1, "group": 8, "hd": 256}  # Gemma-2B decode
         spec = {
             "dtype": "bfloat16", "interpret": False,
@@ -202,7 +205,7 @@ def phase_kernels(workdir: str) -> tuple:
                 {"shape": [1, 8192, 4, 1, 64], "block": 1024},
             ],
             "paged": [
-                llama, {**llama, "fused": True}, {**llama, "S": 16},
+                mistral, {**mistral, "fused": True}, {**mistral, "S": 16},
                 gemma, {**gemma, "fused": True},
             ],
         }

@@ -1225,6 +1225,7 @@ def paged_decode_step_batched(
     kv_attention: str = "gather",
     spans: Optional[Tuple[int, ...]] = None,
     live_to: Optional[jax.Array] = None,
+    live: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Params]:
     """Block-table twin of :func:`decode_step_batched`: scatter the new
     K/V into each row's current block at ``(bt[b, pos//BS], pos%BS)``,
@@ -1237,13 +1238,21 @@ def paged_decode_step_batched(
     under donation (:func:`_scan_layers_over_pools`).
 
     ``kv_attention`` picks the attention implementation: ``"gather"``
-    (the default bit-exactness oracle — materialize the logical view,
-    dense masked attention) or ``"blocked"`` (the
-    :mod:`kubedl_tpu.models.paged_attention` online-softmax kernel that
-    walks the block table; fp-close, greedy-token-identical). The
-    blocked path hands the step's K/V to the kernel (``new_k``/``new_v``)
-    which writes them into the pool block in the same invocation — one
-    dispatch per layer instead of scatter + attend.
+    (the bit-exactness oracle and the CPU's path — materialize the logical
+    view, dense masked attention) or ``"blocked"``
+    (:func:`kubedl_tpu.models.paged_attention.paged_attention` over the
+    WHOLE pools with the layer in the index: the same scatter, then an
+    online softmax that walks the block table; fp-close,
+    greedy-token-identical). On a TPU that is one Pallas kernel a layer
+    (``paged_decode_attention``) which fetches, for each row, the blocks
+    the row holds and no others, so a step costs the keys read and not a
+    span; ``ModelRunner`` runs its decode segments through this arm there
+    whatever the option says.
+
+    ``live`` (``[B]`` bool; None: every row) names the rows the dispatch
+    scheduled. Any other row writes its K/V to the trash block, is not
+    attended by the blocked arm (the kernel fetches nothing for it) and
+    computes garbage nobody reads; its ``pos`` advances all the same.
 
     ``spans`` (static; gather only) with ``live_to`` (traced scalar):
     attend over the smallest span that holds ``live_to``, see
@@ -1273,6 +1282,8 @@ def paged_decode_step_batched(
     if spans is not None:
         span_at = _span_index(spans, live_to)
         blk = jnp.where(pos < jnp.asarray(spans, jnp.int32)[span_at], blk, 0)
+    if live is not None:
+        blk = jnp.where(live, blk, 0)
     off = pos % BS
 
     def rot(t):
@@ -1287,17 +1298,12 @@ def paged_decode_step_batched(
         k = rot((h @ deq(lp["wk"])).reshape(B, 1, cfg.n_kv_heads, hd))
         v = (h @ deq(lp["wv"])).reshape(B, 1, cfg.n_kv_heads, hd)
         if kv_attention == "blocked":
-            # fused KV write: the kernel lands this step's K/V into the
-            # row's current block itself, retiring the separate scatter
-            # dispatch the gather path still performs. It aliases ONE
-            # layer's pool, so that layer is sliced out and put back
-            attn, ckp, cvp = blocked_attention.paged_attention(
-                q, lax.dynamic_index_in_dim(kp, layer, keepdims=False),
-                lax.dynamic_index_in_dim(vp, layer, keepdims=False),
-                bt, pos, new_k=k[:, 0], new_v=v[:, 0],
+            # the same in-place scatter as below, then attention straight
+            # from the whole pools at this layer: no view, no slice
+            attn, kp, vp = blocked_attention.paged_attention(
+                q, kp, vp, bt, pos, layer=layer, live=live,
+                new_k=k[:, 0], new_v=v[:, 0],
             )
-            kp = lax.dynamic_update_index_in_dim(kp, ckp, layer, 0)
-            vp = lax.dynamic_update_index_in_dim(vp, cvp, layer, 0)
         else:
             kp = kp.at[layer, blk, off].set(k[:, 0])
             vp = vp.at[layer, blk, off].set(v[:, 0])
@@ -1340,13 +1346,15 @@ def paged_decode_segment(
     kv_attention: str = "gather",
     spans: Optional[Tuple[int, ...]] = None,
     live_to: Optional[jax.Array] = None,
+    live: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, Params]:
     """Block-table twin of :func:`decode_segment` — same on-device
     sample->feed chain and return contract, over the paged step. The
     engine reserves blocks covering ``pos + n_steps`` for every decoding
     row BEFORE dispatch, so in-segment writes never need a host trip.
-    ``spans`` and ``live_to`` as in :func:`paged_decode_step_batched`:
-    ``live_to`` holds ``pos + n_steps`` of every row whose tokens are read.
+    ``spans``, ``live_to`` and ``live`` as in
+    :func:`paged_decode_step_batched`: ``live_to`` holds ``pos + n_steps``
+    of every row whose tokens are read, ``live`` marks those rows.
 
     The gumbel sample chain is keyed off ``key`` alone — per step, one
     split shared by every row — so for a fixed seed the sampled path is
@@ -1356,7 +1364,7 @@ def paged_decode_segment(
     return sampled_segment(
         lambda cache, toks: paged_decode_step_batched(
             params, cache, toks, cfg, kv_attention=kv_attention,
-            spans=spans, live_to=live_to,
+            spans=spans, live_to=live_to, live=live,
         ),
         cache, tokens, temps, key, n_steps, greedy,
     )

@@ -656,9 +656,11 @@ class ServingMetrics:
         )
         self.view_keys = r.counter(
             "kubedl_tpu_serving_view_keys",
-            "Keys of the paged gathered view the decode and suffix-"
-            "prefill programs attended over, a layer: span x rows "
-            "computed, once a decode step and once a prefill program",
+            "Keys of the paged pool the decode and suffix-prefill "
+            "programs attended over, a layer: span x rows computed, once "
+            "a decode step and once a prefill program, over a gathered "
+            "view; the keys fetched (scheduled rows' lengths in whole "
+            "compute blocks) where a decode step runs the decode kernel",
         )
         self.view_keys_full = r.counter(
             "kubedl_tpu_serving_view_keys_full",

@@ -136,8 +136,9 @@ def paged_check(
     ] if fused else []
 
     def pallas(q, kp, vp, bt, starts, *new):
-        return pa._pallas_paged_attention(
-            q, kp, vp, bt, starts, *new, interpret=interpret
+        kw = {"new_k": new[0], "new_v": new[1]} if new else {}
+        return pa.paged_attention(
+            q, kp, vp, bt, starts, kernel="pallas", interpret=interpret, **kw
         )
 
     def lax_ref(q, kp, vp, bt, starts, *new):

@@ -17,6 +17,14 @@ gather programs attend over. The engine says how far a dispatch reaches
 its programs hold, and each program takes the branch of the smallest span
 that holds ``live_to``.
 
+A fifth since the decoder's decode step got a kernel: what its attention
+reads. On a TPU, over a paged pool the kernel can take as it stands,
+:class:`ModelRunner`'s decode segments attend through
+``paged_attention``'s Pallas kernel, which fetches each scheduled row's own
+blocks (:attr:`ModelRunner.decode_tile`); anywhere else, and in every other
+program, the gathered view with its spans. No option chooses: ``kv_attention``
+still picks the suffix programs' arm, and the CPU's decode step.
+
 Three runners stand here. :class:`ModelRunner` is the decoder's
 (``models/llama.py``). :class:`HybridRunner` is the hybrid state-space
 model's (``models/hybrid_ssm.py``): the same paged pools for its few
@@ -41,7 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kubedl_tpu import chaos
-from kubedl_tpu.models import hybrid_ssm, llama, sparse_window
+from kubedl_tpu.models import hybrid_ssm, llama, paged_attention, sparse_window
 
 log = logging.getLogger("kubedl_tpu.serving.model_runner")
 
@@ -189,6 +197,21 @@ class _Runner:
             return ()
         return (np.int32(self.max_seq if live_to is None else live_to),)
 
+    def _live_mask(self, rows) -> np.ndarray:
+        """``[max_batch]`` bool: the rows a decode dispatch scheduled
+        (``rows``; None: every row)."""
+        live = np.ones((self.max_batch,), bool)
+        if rows is not None:
+            live[:] = False
+            live[list(rows)] = True
+        return live
+
+    def keys_read(self, positions, n_steps: int) -> Optional[int]:
+        """Keys the attention of an ``n_steps`` decode segment fetches for
+        scheduled rows standing at ``positions``; None where a step attends
+        over the gathered view, whose cost is its span and not the rows."""
+        return None
+
     def _build_row_prefills(self, model_prefill) -> None:
         """``_view``, ``_prefill`` and ``_prefill_from`` of a paged runner whose
         model has ONE prefill function, ``model_prefill(params, cache, tokens,
@@ -291,10 +314,23 @@ class ModelRunner(_Runner):
         if paged and kv_attention == "gather":
             self.spans = self.span_ladder(self.max_seq, self.kv_block_size)
 
+        #: keys a compute block of the decode kernel holds; 0: the decode
+        #: steps attend over the gathered view (or the option's lax arm)
+        self.decode_tile = 0
+
         # ---- the one place that picks the device-function family ----
         if paged:
             att = {"kv_attention": kv_attention}
             spans = self.spans
+            # what can be observed: a TPU, and a pool whose blocks the
+            # kernel can take as they stand. Then a decode step reads each
+            # scheduled row's own blocks, whatever ``kv_attention`` says
+            if jax.default_backend() == "tpu" and (
+                    paged_attention.decode_kernel_fits(
+                        1, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, bs,
+                        cfg.dtype)):
+                self.decode_tile = paged_attention.DEFAULT_TILE
+            decode_att = {"kv_attention": "blocked"} if self.decode_tile else att
 
             def view(live_to):
                 """What a program handed ``live_to`` (a 1-tuple, or none
@@ -302,7 +338,7 @@ class ModelRunner(_Runner):
                 return {"spans": spans, "live_to": live_to[0]} if live_to else {}
 
             def decode_step(p, c, t):
-                return llama.paged_decode_step_batched(p, c, t, cfg, **att)
+                return llama.paged_decode_step_batched(p, c, t, cfg, **decode_att)
 
             # a paged prefill program computes a COMPACT batch: the cache
             # rows ``rows`` that hold prompt tokens this dispatch, and no
@@ -331,7 +367,7 @@ class ModelRunner(_Runner):
             # fires for array-payload entries (direct inserts in tests)
             graft, segment = llama.paged_graft_prefix, llama.paged_decode_segment
         else:
-            att = {}
+            att = decode_att = {}
 
             def decode_step(p, c, t):
                 return llama.decode_step_batched(p, c, t, cfg)
@@ -352,7 +388,7 @@ class ModelRunner(_Runner):
 
             def view(live_to):
                 return {}
-        self._segment, self._view = functools.partial(segment, **att), view
+        self._segment, self._view = functools.partial(segment, **decode_att), view
 
         # the cache is DONATED: decode/prefill update it in place in HBM
         # instead of allocating a fresh copy every step
@@ -507,11 +543,14 @@ class ModelRunner(_Runner):
     # -- the programs, run on the runner's cache ----------------------------
 
     def warmup(self, params) -> None:
-        # cache is donated — reassign, the old buffer is dead after the call
-        logits, self.cache = self._decode(
-            params, self.cache, jnp.zeros((self.max_batch, 1), jnp.int32),
-        )
-        jax.block_until_ready(logits)
+        """One decode step that schedules no row: proof the model runs, by
+        a program the ticks use too (``engine_decode_step`` was one more
+        executable to trace, lower and load in every start, for no tick)."""
+        self.decode_segment(
+            1, True, params, jnp.zeros((self.max_batch, 1), jnp.int32),
+            jnp.zeros((self.max_batch,), jnp.float32), jax.random.PRNGKey(0),
+            live_to=1, rows=())
+        jax.block_until_ready(self.cache["pos"])
 
     def prefill(self, params, toks, lens, starts=None, rows=None, acc=None,
                 live_to: Optional[int] = None):
@@ -535,16 +574,22 @@ class ModelRunner(_Runner):
 
     def _segment_fn(self, n_steps: int, greedy: bool):
         """Jitted n-step decode with on-device sampling (cache donated);
-        one compile per (segment size, greedy) combination. With several
-        spans it takes ``live_to`` last, and holds a branch a span."""
+        one compile per (segment size, greedy) combination. Over the
+        gathered view with several spans it takes ``live_to`` last, and
+        holds a branch a span; through the decode kernel it takes the
+        ``[max_batch]`` mask of scheduled rows instead, and has no span."""
         fn = self._segments.get((n_steps, greedy))
         if fn is None:
             seg, cfg, view = self._segment, self.cfg, self._view
-            fn = self._jit_segment(
-                n_steps, greedy, lambda p, c, tokens, temps, key, *live_to: seg(
-                    p, c, tokens, temps, key, cfg=cfg,
-                    n_steps=n_steps, greedy=greedy, **view(live_to),
-                ))
+            if self.decode_tile:
+                def body(p, c, tokens, temps, key, live):
+                    return seg(p, c, tokens, temps, key, cfg=cfg,
+                               n_steps=n_steps, greedy=greedy, live=live)
+            else:
+                def body(p, c, tokens, temps, key, *live_to):
+                    return seg(p, c, tokens, temps, key, cfg=cfg,
+                               n_steps=n_steps, greedy=greedy, **view(live_to))
+            fn = self._jit_segment(n_steps, greedy, body)
         return fn
 
     def decode_segment(self, n_steps: int, greedy: bool, params, tokens,
@@ -553,15 +598,32 @@ class ModelRunner(_Runner):
         """``n_steps`` decode steps, sampled on the device, over the view
         span that holds ``live_to``: one past the highest position a row
         whose tokens are read will stand at (None: the whole table).
-        ``rows`` names the rows the dispatch scheduled and ``takes`` how
+        ``rows`` names the rows the dispatch scheduled (None: every row):
+        the decode kernel attends those and fetches nothing for the others;
+        the gathered view computes every row at the span. ``takes`` is how
         many of the segment's tokens each keeps; a decoder's row is its
         blocks and ``pos``, which the mirrors put right before the next
-        dispatch, so nothing here needs them.
+        dispatch, so nothing here needs it.
         Returns ``(toks [B, n_steps], last [B, 1], key)``."""
+        if self.decode_tile:
+            how = (jnp.asarray(self._live_mask(rows)),)
+        else:
+            how = self._live_to(live_to)
         toks, last, key, self.cache = self._segment_fn(n_steps, greedy)(
-            params, self.cache, tokens, temps, key, *self._live_to(live_to),
+            params, self.cache, tokens, temps, key, *how,
         )
         return toks, last, key
+
+    def keys_read(self, positions, n_steps: int) -> Optional[int]:
+        if not self.decode_tile:
+            return None
+        # step j of the segment attends a row's pos + j + 1 keys
+        lengths = np.minimum(
+            np.asarray(positions, np.int64)[:, None] + np.arange(1, n_steps + 1),
+            self.max_seq)
+        return paged_attention.decode_keys_read(
+            lengths, self.kv_block_size, self.max_seq // self.kv_block_size,
+            self.decode_tile)
 
     def verify(self, params, toks, lens, starts):
         """The write-path verify: consume ``toks`` from ``starts``, return
@@ -662,13 +724,9 @@ class HybridRunner(_Runner):
         """As :meth:`ModelRunner.decode_segment`; the slabs of ``rows`` (the
         rows the dispatch scheduled; None: every row) advance, every other
         row's stays as it is."""
-        live = np.ones((self.max_batch,), bool)
-        if rows is not None:
-            live[:] = False
-            live[list(rows)] = True
         toks, last, key, self.cache = self._segment_fn(n_steps, greedy)(
-            params, self.cache, tokens, temps, key, jnp.asarray(live),
-            *self._live_to(live_to),
+            params, self.cache, tokens, temps, key,
+            jnp.asarray(self._live_mask(rows)), *self._live_to(live_to),
         )
         return toks, last, key
 

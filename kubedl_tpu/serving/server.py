@@ -1828,8 +1828,9 @@ class LlamaEngine:
         return min(b, self.max_seq)
 
     def _count_view_keys_locked(self, keys: int, gathers: int) -> None:
-        """``gathers`` row views, ``keys`` keys in all, were gathered (a
-        layer): the counters that say how often the view's span engages.
+        """Attention touched ``keys`` keys (a layer) where ``gathers`` rows'
+        whole tables would have been ``max_seq`` each: the share of the
+        full table that a view's span, or the decode kernel's reads, leave.
         Caller holds cv."""
         self.metrics.view_keys.inc(keys)
         self.metrics.view_keys_full.inc(self.max_seq * gathers)
@@ -2776,10 +2777,15 @@ class LlamaEngine:
         with self._cv:
             if self._paged:
                 # keys the scheduled rows hold as the segment starts
-                attrs["keys"] = sum(int(self._pos_host[i]) for i, _ in decoding)
+                held = [int(self._pos_host[i]) for i, _ in decoding]
+                attrs["keys"] = sum(held)
                 attrs["span"] = span = self._runner.span_for(live_to)
-                self._count_view_keys_locked(
-                    span * k * self.max_batch, k * self.max_batch)
+                read = self._runner.keys_read(held, k)
+                if read is None:  # the gathered view: every row, at the span
+                    read = span * k * self.max_batch
+                else:  # the kernel: what the scheduled rows hold, in whole
+                    attrs["read"] = read  # compute blocks
+                self._count_view_keys_locked(read, k * self.max_batch)
             if self._window:
                 # and those of them a window layer's query still sees
                 attrs["wkeys"] = sum(

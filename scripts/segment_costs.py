@@ -10,7 +10,10 @@ execution with the ``engine.decode_dispatch`` span that dispatched it (in
 order, as ``benchmark/hybrid_costs.paired`` does), and prints the device time
 of a segment by its length ``k`` and its view's ``span``. Where a span was run
 at two lengths it fits ``time = k * s + F``: ``s`` a step, ``F`` what a
-segment costs whatever its length (PERF.md section 5). Beside it: how many
+segment costs whatever its length (PERF.md section 5). Where the dispatch says
+what its attention ``read`` (the decoder's kernel on a chip: the keys fetched,
+all steps together) it prints every segment's ``read`` beside its ``span`` and
+device time, and fits a step's time to the keys a step reads. Beside it: how many
 segments were short, by the reason the tick gave (``short``), the backlog it
 saw, the prefill programs by bucket, and what a segment runs outside its step
 loop (the operations of an execution that lie in no ``while``), a segment.
@@ -45,11 +48,15 @@ def main(argv) -> int:
     print(span_reader.program_table(spans))
 
     by = defaultdict(list)  # (k, span) -> [(ms, rows)]
+    reads = []  # (keys read a step, ms a step, k, span, rows)
     why = defaultdict(int)
     backlog = defaultdict(list)
     for s, m in hybrid_costs.paired(spans, "engine.decode_dispatch", "jit_engine_decode_seg"):
         k, span = int(s.stats["k"]), int(s.stats.get("span", 0))
         by[(k, span)].append((1e3 * (m.end - m.start), int(s.stats["rows"])))
+        if "read" in s.stats:
+            reads.append((int(s.stats["read"]) / k, 1e3 * (m.end - m.start) / k, k,
+                          span, int(s.stats["rows"])))
         reason = s.stats.get("short", "no")
         why[(k, reason)] += 1
         if "backlog" in s.stats:
@@ -67,6 +74,7 @@ def main(argv) -> int:
             s_ms = (med[b] - med[a]) / (b - a)
             print(f"span {span}: seg{a} {med[a]:.3f} ms, seg{b} {med[b]:.3f} ms -> "
                   f"s = {s_ms:.3f} ms a step, F = {med[a] - a * s_ms:.3f} ms a segment")
+    print_by_keys_read(reads)
     print("segments paired, by length and reason: "
           + ", ".join(f"k={k} short={r}: {n}" for (k, r), n in sorted(why.items())))
     for reason, seen in sorted(backlog.items()):
@@ -84,6 +92,24 @@ def main(argv) -> int:
               f"real tokens median {statistics.median(n for _t, n in got)}")
     print_outside_the_loop(path, spans)
     return 0
+
+
+def print_by_keys_read(reads) -> None:
+    """Every segment whose dispatch named its ``read``, by the keys a step
+    reads, and the line ``ms a step = a + b x thousand keys`` through the
+    segments of each length (least squares)."""
+    for keys, ms, k, span, rows in sorted(reads):
+        print(f"decode seg{k} span {span} rows {rows}: read {keys:.0f} keys a step, "
+              f"device {ms * k:.3f} ms, a step {ms:.3f}")
+    for k in sorted({r[2] for r in reads}):
+        pts = [(keys / 1e3, ms) for keys, ms, kk, _s, _r in reads if kk == k]
+        if len({x for x, _y in pts}) < 2:
+            continue
+        mx, my = statistics.fmean(x for x, _ in pts), statistics.fmean(y for _, y in pts)
+        b = sum((x - mx) * (y - my) for x, y in pts) / sum((x - mx) ** 2 for x, _ in pts)
+        print(f"seg{k}: a step = {my - b * mx:.3f} ms + {b:.4f} ms a thousand keys read "
+              f"(n={len(pts)}, {min(x for x, _ in pts) * 1e3:.0f}-"
+              f"{max(x for x, _ in pts) * 1e3:.0f} keys a step)")
 
 
 def print_outside_the_loop(path, spans) -> None:

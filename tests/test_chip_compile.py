@@ -89,9 +89,13 @@ def test_flash_kernels_compile_at_llama_1b_heads(on_chip, which, monkeypatch):
 
 @pytest.mark.parametrize("fused", [False, True], ids=["read", "fused-write"])
 @pytest.mark.parametrize(
-    "KV,group,hd", [(8, 4, 64), (1, 8, 256)], ids=["llama-3.2-1b", "gemma-2b"],
+    "KV,group,hd", [(8, 4, 128), (1, 8, 256)], ids=["mistral-7b", "gemma-2b"],
 )
 def test_paged_kernels_compile_at_decode_shapes(on_chip, KV, group, hd, fused):
+    """The decode kernel (``paged_decode_attention``) over one layer's pool
+    as it stands: the block a ``[BS*KV, hd]`` matrix, no copy of the pool in
+    front of it. (Llama-3.2-1B's heads of 64 are half a lane tile: the
+    kernel refuses them by name, ``tests/test_paged_attention.py``.)"""
     import jax.numpy as jnp
 
     from kubedl_tpu.models import paged_attention as pa
@@ -104,10 +108,92 @@ def test_paged_kernels_compile_at_decode_shapes(on_chip, KV, group, hd, fused):
     ]
     if fused:
         args += [on_chip((B, KV, hd), jnp.bfloat16)] * 2
-    text = _compiled_text(
-        lambda *a: pa._pallas_paged_attention(*a, interpret=False), *args
+
+    def call(q, kp, vp, bt, starts, *new):
+        kw = {"new_k": new[0], "new_v": new[1]} if new else {}
+        return pa.paged_attention(q, kp, vp, bt, starts, kernel="pallas", **kw)
+
+    text = _compiled_text(call, *args)
+    assert "tpu_custom_call" in text and pa.DECODE_KERNEL_NAME in text
+    pool_text = f"bf16[{1 + B * MB},{BS},{KV},{hd}]"
+    copies = [ln for ln in text.splitlines() if " copy(" in ln and pool_text in ln]
+    assert not copies, copies[:2]
+
+
+def _mistral_2_layers(on_chip, B, NB=1087, BS=16):
+    """Mistral-7B's attention widths at 2 layers and a 4096-key table, as
+    shapes on the described chip: ``(cfg, params, cache, i32)``."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubedl_tpu.models import llama
+
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, dim=4096, n_layers=2, n_heads=32, n_kv_heads=8,
+        ffn_dim=14336, max_seq=4096, rope_theta=1e6, dtype=jnp.bfloat16,
     )
-    assert "tpu_custom_call" in text
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: on_chip(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: llama.llama_init(jax.random.PRNGKey(0), cfg)))
+    cache = place(jax.eval_shape(
+        lambda: llama.init_paged_cache(cfg, B, 4096, NB, BS)))
+    return cfg, params, cache, lambda *s: on_chip(s, jnp.int32)
+
+
+def test_decode_segment_with_the_kernel_compiles_in_place(on_chip, monkeypatch):
+    """The 4-step decode segment as a TPU's ``ModelRunner`` runs it (the
+    blocked arm: scatter in place, then the decode kernel over the whole
+    pools with the layer in the index), at Mistral-7B's widths, 2 layers, 16
+    rows, a 4096-key table: the custom call stands inside the layer loop, no
+    conditional is left (the kernel's work follows the rows' lengths, not a
+    span), no operation copies a pool-shaped array and the pools are donated
+    and written in place (PR 29's property), and the program's temporaries
+    are no larger than the gathered program's with its span branches."""
+    import functools
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from kubedl_tpu.models import llama
+    from kubedl_tpu.models import paged_attention as pa
+
+    B, BS, NB, spans = 16, 16, 4159, (1024, 2048, 4096)
+    cfg, params, cache, i32 = _mistral_2_layers(on_chip, B, NB, BS)
+    common = (params, cache, i32(B, 1), on_chip((B,), jnp.float32),
+              on_chip((2,), jnp.uint32))
+    # this process's backend is the CPU, where "auto" is the lax arm: name
+    # the kernel a TPU's "auto" takes
+    monkeypatch.setattr(pa, "paged_attention", functools.partial(
+        pa.paged_attention, kernel="pallas"))
+
+    def with_kernel(p, c, tokens, temps, key, live):
+        return llama.paged_decode_segment(
+            p, c, tokens, temps, key, cfg, n_steps=4, greedy=True,
+            kv_attention="blocked", live=live)
+
+    def gathered(p, c, tokens, temps, key, live_to):
+        return llama.paged_decode_segment(
+            p, c, tokens, temps, key, cfg, n_steps=4, greedy=True,
+            spans=spans, live_to=live_to)
+
+    kernel = jax.jit(with_kernel, donate_argnums=(1,)).lower(
+        *common, on_chip((B,), jnp.bool_)).compile()
+    text = kernel.as_text()
+    assert " conditional(" not in text
+    # one custom call, in the layer scan's body inside the step loop's
+    (called,) = [ln for ln in text.splitlines() if pa.DECODE_KERNEL_NAME in ln
+                 and "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert re.search(r"/while/body/.*/while/body/.*pallas_call", called), called
+    pool = f"bf16[{cfg.n_layers},{NB},{BS},{cfg.n_kv_heads},{cfg.head_dim}]"
+    copies = [ln for ln in text.splitlines() if " copy(" in ln and pool in ln]
+    assert not copies, copies[:2]
+    assert text.count("may-alias") + text.count("must-alias") >= 2  # k and v
+    view = jax.jit(gathered, donate_argnums=(1,)).lower(*common, i32()).compile()
+    got, had = kernel.memory_analysis(), view.memory_analysis()
+    assert got.temp_size_in_bytes <= had.temp_size_in_bytes, (
+        got.temp_size_in_bytes, had.temp_size_in_bytes)
 
 
 @pytest.mark.parametrize("program", ["decode_segment", "prefill_from"])
@@ -125,18 +211,8 @@ def test_view_span_branches_compile_in_place(on_chip, program):
 
     from kubedl_tpu.models import llama
 
-    cfg = llama.LlamaConfig(
-        vocab_size=32768, dim=4096, n_layers=2, n_heads=32, n_kv_heads=8,
-        ffn_dim=14336, max_seq=4096, rope_theta=1e6, dtype=jnp.bfloat16,
-    )
     B, BS, NB, spans = 4, 16, 1087, (1024, 2048, 4096)
-    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
-        lambda a: on_chip(a.shape, a.dtype), tree)
-    params = place(jax.eval_shape(
-        lambda: llama.llama_init(jax.random.PRNGKey(0), cfg)))
-    cache = place(jax.eval_shape(
-        lambda: llama.init_paged_cache(cfg, B, 4096, NB, BS)))
-    i32 = lambda *s: on_chip(s, jnp.int32)  # noqa: E731
+    cfg, params, cache, i32 = _mistral_2_layers(on_chip, B, NB, BS)
     if program == "decode_segment":
         def fn(p, c, tokens, temps, key, live_to):
             return llama.paged_decode_segment(

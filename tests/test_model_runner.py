@@ -309,6 +309,88 @@ PROGRAMS_FILE = os.path.join(REPO, "tests", "data", "engine_programs.json")
 PROGRAM_PRESETS = ("tiny", "tiny-hybrid")
 
 
+@pytest.fixture(scope="module")
+def kernel_engines():
+    """``(kernel, plain)``: an engine whose runner saw a TPU and a pool the
+    decode kernel can take (steered here: the backend's name, the tiling
+    predicate, and the interpreter in the compiled kernel's place), and the
+    same engine as a CPU builds it."""
+    import functools
+
+    import jax
+
+    from kubedl_tpu.models import paged_attention as pa
+    from kubedl_tpu.serving.server import LlamaEngine
+
+    kw = dict(preset="tiny", max_batch=3, max_seq=128, prefill_chunk_tokens=16,
+              prefix_cache_mb=0)
+    with pytest.MonkeyPatch.context() as mp:
+        # for as long as the engines live: their programs trace on first use
+        mp.setattr(pa, "paged_attention", functools.partial(
+            pa.paged_attention, kernel="pallas", interpret=True, tile=32))
+        with pytest.MonkeyPatch.context() as seen:  # what the constructor observes
+            seen.setattr(jax, "default_backend", lambda: "tpu")
+            seen.setattr(pa, "decode_kernel_fits", lambda *a: True)
+            seen.setattr(pa, "DEFAULT_TILE", 32)
+            kernel = LlamaEngine(**kw)
+        plain = LlamaEngine(**kw)
+        yield kernel, plain
+        kernel.close()
+        plain.close()
+
+
+class TestDecodeKernelRunner:
+    REQUESTS = [
+        (list(range(1, 6)), 9),      # 5-token prompt
+        (list(range(10, 33)), 5),    # 23 tokens: two chunks
+        (list(range(40, 81)), 14),   # 41 tokens: three chunks of 16
+    ]
+
+    def test_the_runner_picks_the_kernel_from_what_it_observes(self, kernel_engines):
+        kernel, plain = kernel_engines
+        assert kernel._runner.decode_tile == 32 and plain._runner.decode_tile == 0
+        assert plain._runner.keys_read([5, 40], 4) is None
+        # rows at 5 and 40 keys, 4 steps: 6..9 and 41..44 keys, in blocks of 32
+        assert kernel._runner.keys_read([5, 40], 4) == 4 * 32 + 4 * 64
+        assert kernel._runner.keys_read([126], 4) == 4 * 128  # clamped at max_seq
+        # the option still names the suffix programs' arm; spans stay theirs
+        assert kernel._runner.spans == plain._runner.spans
+
+    def test_serves_the_gathered_engines_tokens_with_rows_sitting_out(
+            self, kernel_engines, tmp_path, monkeypatch):
+        """Chunked prompts beside decoding rows: a row mid-prompt sits the
+        decode dispatches out (the kernel fetches nothing for it) and every
+        request gets the tokens the gathered view gives; the dispatch phase
+        carries ``read`` and the counters add what the kernel fetched."""
+        from kubedl_tpu.observability.tracing import TRACER
+        from test_phase_spans import capture, serve
+
+        monkeypatch.setattr(TRACER, "enabled", True)
+        kernel, plain = kernel_engines
+        want = serve(plain, self.REQUESTS)
+        before = kernel.stats()
+        with capture(tmp_path) as cap:
+            got = serve(kernel, self.REQUESTS)
+        after = kernel.stats()
+        assert [r["token_ids"] for r in got] == [r["token_ids"] for r in want]
+        decode = [e[4] for e in cap.named("engine.decode_dispatch")]
+        assert decode and all("read" in e and "span" in e for e in decode)
+        assert any(e["rows"] < 3 for e in decode)  # somebody sat out
+        for e in decode:  # whole compute blocks of what the rows hold
+            assert e["read"] % 32 == 0
+            assert e["keys"] * e["k"] < e["read"] <= (
+                e["keys"] + e["rows"] * (e["k"] + 32)) * e["k"]
+        prefill = cap.named("engine.prefill_dispatch")
+        keys = (sum(e["read"] for e in decode)
+                + sum(e[4]["span"] * e[4]["slots"] for e in prefill if "span" in e[4]))
+        assert after["view_keys"] - before["view_keys"] == keys
+        full = after["view_keys_full"] - before["view_keys_full"]
+        assert 0 < keys < full
+        with capture(tmp_path / "plain") as cap:  # the gathered view names no read
+            serve(plain, self.REQUESTS)
+        assert not any("read" in e[4] for e in cap.named("engine.decode_dispatch"))
+
+
 def engine_program_texts(preset):
     """name -> lowered text of every device program a paged engine on
     ``preset`` can dispatch, at ``max_batch`` 2 and ``max_seq`` 4096 (view

@@ -5,7 +5,9 @@ Four layers, mirroring the subsystem's split:
 - Kernel-level: the lax chunked scan and the pallas kernel (interpret
   mode) against a dense masked-softmax reference over the gathered view,
   across ragged rows, partial tail blocks, and trash-block rows — the
-  garbage-contributes-exact-0.0 contract.
+  garbage-contributes-exact-0.0 contract; the pallas kernel over whole
+  pools with the layer in the index and rows that are not scheduled
+  (`TestDecodeKernel`).
 - Model-level: blocked vs gather through `paged_decode_step_batched` /
   `paged_verify` — logits fp-close, greedy argmax identical, and the
   read-only `paged_verify_multi` scoring pass agrees with the write-path
@@ -176,6 +178,91 @@ class TestKernelParity:
                                kernel="dense")
 
 
+class TestDecodeKernel:
+    """The decode kernel (``paged_decode_attention``, through the
+    interpreter) over WHOLE ``[L, NB, BS, KV, hd]`` pools against the
+    gathered view, the oracle every paged program is held to: each live
+    row attends the blocks it holds, a row that is not live is skipped
+    whole (zeros out, nothing of its blocks read)."""
+
+    L, B, MB, BS, KV, H, hd = 3, 4, 8, 16, 2, 4, 16
+    TILE = 64  # a compute block of 4 table entries: two a row
+
+    # name -> (positions, live, layer)
+    CASES = {
+        "ragged": ([5, 70, 33, 101], [1, 1, 1, 1], 1),
+        "block-edge": ([15, 16, 31, 47], [1, 1, 1, 1], 2),
+        "compute-block-edge": ([63, 64, 62, 127], [1, 1, 1, 1], 1),
+        "zero-between-live-rows": ([40, 90, 9, 77], [1, 0, 1, 0], 2),
+        "row-at-max-seq": ([127, 127, 0, 126], [1, 1, 1, 1], 1),
+        "unowned-entries-at-trash": ([20, 3, 50, 0], [1, 1, 1, 1], 0),
+        "shared-prefix-block": ([37, 45, 60, 18], [1, 1, 0, 1], 2),
+        "nobody-live": ([10, 20, 30, 40], [0, 0, 0, 0], 1),
+    }
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_matches_the_gathered_view(self, case):
+        import jax.numpy as jnp
+
+        from kubedl_tpu.models import llama
+        from kubedl_tpu.models import paged_attention as pa
+
+        pos, live, layer = self.CASES[case]
+        pos, live = np.asarray(pos, np.int32), np.asarray(live, bool)
+        rng = np.random.RandomState(len(case))
+        NB = 1 + self.B * self.MB
+        shape = (self.L, NB, self.BS, self.KV, self.hd)
+        kp = rng.randn(*shape).astype(np.float32)
+        vp = rng.randn(*shape).astype(np.float32)
+        kp[:, 0], vp[:, 0] = 37.0, -29.0  # the trash block, poisoned
+        bt = np.arange(1, NB, dtype=np.int32).reshape(self.B, self.MB)
+        if case == "unowned-entries-at-trash":
+            # a row owns the blocks up to its position; the rest is trash
+            for b in range(self.B):
+                bt[b, pos[b] // self.BS + 1:] = 0
+        if case == "shared-prefix-block":
+            bt[1, :2] = bt[0, :2]  # rows 0 and 1 hold one prefix by reference
+            bt[3, 0] = bt[0, 0]
+        # what a row that is not live holds must not be read: poison it
+        for b in np.flatnonzero(~live):
+            own = np.setdiff1d(bt[b], bt[live].ravel())
+            kp[:, own], vp[:, own] = np.nan, np.nan
+        q = rng.randn(self.B, 1, self.H, self.hd).astype(np.float32)
+        args = [jnp.asarray(a) for a in (q, kp, vp, bt, pos)]
+        got = np.asarray(pa.paged_attention(
+            *args, layer=jnp.int32(layer), live=jnp.asarray(live),
+            kernel="pallas", tile=self.TILE, interpret=True))
+        mask = (jnp.arange(self.MB * self.BS)[None, :] <= pos[:, None])
+        want = np.asarray(llama.attention(
+            args[0], llama._paged_view(args[1], layer, args[3]),
+            llama._paged_view(args[2], layer, args[3]),
+            causal=False, mask=mask[:, None, None, None, :]))
+        assert np.isfinite(got).all()
+        assert np.abs(got[live] - want[live]).max(initial=0.0) < 1e-5
+        assert not got[~live].any()  # skipped whole: zeros, never read
+        lens = np.where(live, pos + 1, 0)
+        assert pa.decode_keys_read(lens, self.BS, self.MB, self.TILE) == sum(
+            -(-n // 64) * 64 for n in lens)
+
+    def test_refuses_a_pool_it_cannot_take_as_it_stands(self):
+        """Heads of 64 are half a lane tile (Llama-3.2-1B's): compiled, the
+        kernel raises by name, and ``auto`` takes the lax scan there."""
+        import jax.numpy as jnp
+
+        from kubedl_tpu.models import paged_attention as pa
+
+        assert pa.decode_kernel_fits(1, 32, 8, 128, 16, jnp.bfloat16)
+        assert not pa.decode_kernel_fits(1, 32, 8, 64, 16, jnp.bfloat16)
+        assert not pa.decode_kernel_fits(1, 4, 1, 128, 8, jnp.bfloat16)
+        assert not pa.decode_kernel_fits(32, 32, 8, 128, 16, jnp.bfloat16)
+        kp, vp, bt = _random_pool(0, 1, 2, 16, 2, 64)
+        with pytest.raises(ValueError, match="whole tiles"):
+            pa.paged_attention(
+                jnp.zeros((1, 1, 4, 64), jnp.float32), jnp.asarray(kp),
+                jnp.asarray(vp), jnp.asarray(bt), jnp.zeros((1,), jnp.int32),
+                kernel="pallas")
+
+
 class TestModelParity:
     """Blocked vs gather through the llama paged twins."""
 
@@ -304,6 +391,69 @@ class TestModelParity:
         assert d < 1e-4, d
         assert np.array_equal(np.asarray(jnp.argmax(lg, -1)),
                               np.asarray(jnp.argmax(lp, -1)))
+
+    def test_decode_kernel_through_step_and_segment(self, monkeypatch):
+        """The decode kernel forced (interpreter) through
+        ``paged_decode_step_batched`` and ``paged_decode_segment`` over the
+        whole pools, with a row the dispatch did not schedule: the live
+        rows' greedy tokens are the gathered view's over 8 steps, their
+        logits close; the pools differ from the gathered program's nowhere
+        but in float32 rounding (layer 0, whose input no attention has
+        touched, to the bit), nothing is written outside the live rows'
+        slots and the trash block, and what the other row holds is never
+        read (it is poisoned)."""
+        import functools
+
+        import jax
+        import jax.numpy as jnp
+
+        from kubedl_tpu.models import paged_attention as pa
+
+        llama, cfg, params, cache = self._setup(batch=3)
+        toks = jnp.asarray(np.array(
+            [[5, 9, 13, 0], [1, 2, 0, 0], [7, 7, 7, 3]], np.int32))
+        lens = jnp.asarray(np.array([3, 2, 4], np.int32))
+        logits, cache = llama.paged_prefill_batched(params, cache, toks, lens, cfg)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+        live = jnp.asarray(np.array([True, False, True]))
+        own = np.asarray(cache["bt"])[1]  # row 1 sits this dispatch out
+        for f in ("k", "v"):
+            cache[f] = cache[f].at[:, own].set(jnp.nan)
+        monkeypatch.setattr(pa, "paged_attention", functools.partial(
+            pa.paged_attention, kernel="pallas", interpret=True, tile=32))
+        before = pa.TRACE_COUNT["pallas"]
+        lg, cg = llama.paged_decode_step_batched(
+            params, dict(cache), nxt, cfg, kv_attention="gather", live=live)
+        lk, ck = llama.paged_decode_step_batched(
+            params, dict(cache), nxt, cfg, kv_attention="blocked", live=live)
+        assert pa.TRACE_COUNT["pallas"] > before
+        rows = np.array([0, 2])
+        assert np.isfinite(np.asarray(lk)[rows]).all()
+        assert float(jnp.max(jnp.abs(lg[rows] - lk[rows]))) < 1e-4
+        assert np.array_equal(np.asarray(jnp.argmax(lg, -1))[rows],
+                              np.asarray(jnp.argmax(lk, -1))[rows])
+        assert np.array_equal(np.asarray(ck["pos"]), np.asarray(cg["pos"]))
+        for f in ("k", "v"):
+            got, want, was = (np.asarray(c[f]) for c in (ck, cg, cache))
+            assert np.array_equal(got[0, 1:], want[0, 1:], equal_nan=True)
+            assert np.allclose(got[:, 1:], want[:, 1:], atol=1e-5, equal_nan=True)
+            # written: the live rows' slot at their position, and the trash
+            # block (the row sitting out); every other slot is as it was
+            wrote = np.zeros(got.shape[1:3], bool)
+            wrote[0] = True
+            for b in rows:
+                p = int(cache["pos"][b])
+                wrote[np.asarray(cache["bt"])[b, p // 16], p % 16] = True
+            assert np.array_equal(got[:, ~wrote], was[:, ~wrote], equal_nan=True)
+            assert np.isnan(got[:, own]).all()  # and row 1's stayed poisoned
+        temps, key = jnp.zeros((3,), jnp.float32), jax.random.PRNGKey(1)
+        streams = {}
+        for kern in ("gather", "blocked"):
+            t, _, _, _ = llama.paged_decode_segment(
+                params, dict(cache), nxt, temps, key, cfg, n_steps=8,
+                greedy=True, kv_attention=kern, live=live)
+            streams[kern] = np.asarray(t)[rows]
+        assert np.array_equal(streams["gather"], streams["blocked"])
 
     def test_tiny_deep_early_exit_slice_matches_target_at_init(self):
         """The tiny-deep preset zero-inits residual outputs (wo/w_down)
