@@ -635,6 +635,15 @@ class ServingMetrics:
             "assigned), ms — the TTFT component chunked prefill bounds",
             buckets=_TTFT_MS_BUCKETS,
         )
+        self.ttft_part_ms = r.histogram(
+            "kubedl_tpu_serving_ttft_part_ms",
+            "Per-request time to first token by part, ms: queue (enqueue "
+            "-> batch row assigned), backlog (-> its first prefill "
+            "program dispatched), chunks (-> its final one dispatched), "
+            "first (-> first sampled id harvested); the four sum to "
+            "ttft_ms",
+            buckets=_TTFT_MS_BUCKETS,
+        )
         self.admission_chunks = r.counter(
             "kubedl_tpu_serving_admission_chunks",
             "Prefill chunk dispatches under chunked admission (one "

@@ -1,6 +1,5 @@
 """Distributed tracing: trace/span identity, context propagation, ring
-buffer, Chrome-trace export, and host *phase* spans written to the
-profiler (``Tracer.phase``).
+buffer, and host *phase* spans written to the profiler (``Tracer.phase``).
 
 The reference has NO tracing (SURVEY.md §5: observability is logs + metrics
 only, three log stacks coexisting). The TPU build adds what the survey
@@ -14,7 +13,7 @@ replica processes), spans carry real identity:
   ``00-<32 hex>-<16 hex>-<flags>``) propagates the context over HTTP hops;
 * timestamps are anchored to the wall-clock epoch (``time.perf_counter``
   has a per-process epoch — raw values from two replicas can never be
-  overlaid), so ``chrome_trace()`` dumps from different processes merge on
+  overlaid), so ``GET /v1/trace`` dumps from different processes merge on
   one timeline (``scripts/tracemerge.py``).
 
 Zero-dependency by design: a lock-guarded ring buffer, thread-aware, cheap
@@ -39,7 +38,6 @@ this module without it.
 
 from __future__ import annotations
 
-import json
 import random
 import threading
 import time
@@ -480,59 +478,6 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
-
-    # ---- aggregation ------------------------------------------------------
-
-    def summary(self) -> Dict[str, Dict[str, float]]:
-        """Per-span-name {count, total_s, max_s} — the quick 'where does
-        reconcile time go' answer without exporting anything."""
-        agg: Dict[str, Dict[str, float]] = {}
-        for s in self.spans():
-            a = agg.setdefault(s.name, {"count": 0, "total_s": 0.0, "max_s": 0.0})
-            a["count"] += 1
-            a["total_s"] += s.duration
-            a["max_s"] = max(a["max_s"], s.duration)
-        return agg
-
-    # ---- export -----------------------------------------------------------
-
-    def chrome_trace(self, pid: int = 1, process_name: str = "") -> str:
-        """Chrome trace-event JSON ('X' complete events, µs timebase).
-
-        ``ts`` is wall-clock epoch µs, so dumps from different processes
-        (distinct ``pid`` per replica) overlay on one timeline — see
-        ``scripts/tracemerge.py``.
-        """
-        tids: Dict[str, int] = {}
-        events: List[Dict[str, Any]] = []
-        if process_name:
-            events.append({
-                "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
-                "args": {"name": process_name},
-            })
-        for s in self.spans():
-            tid = tids.setdefault(s.thread, len(tids) + 1)
-            args = dict(s.attrs)
-            if s.trace_id:
-                args["trace_id"] = s.trace_id
-                args["span_id"] = s.span_id
-                args["parent_id"] = s.parent_id
-            events.append(
-                {
-                    "name": s.name,
-                    "ph": "X",
-                    "ts": (s.ts if s.ts else self.epoch_of(s.start)) * 1e6,
-                    "dur": s.duration * 1e6,
-                    "pid": pid,
-                    "tid": tid,
-                    "args": args,
-                }
-            )
-        return json.dumps({"traceEvents": events})
-
-    def dump(self, path: str, pid: int = 1, process_name: str = "") -> None:
-        with open(path, "w") as f:
-            f.write(self.chrome_trace(pid=pid, process_name=process_name))
 
 
 #: process-wide default tracer (the engine, router, and manager use this)

@@ -76,6 +76,34 @@ def _to_host(dev):
     return np.array(jax.device_get(dev))
 
 
+#: the parts of a request's time to first token, in the order its record
+#: holds them (`LlamaEngine._note_first_token_locked`)
+TTFT_PARTS = ("queue", "backlog", "chunks", "first")
+
+#: backend compiles that ended in this process since the first engine was
+#: built (jax's monitoring listeners are global and cannot be taken off,
+#: so one listener feeds every engine)
+_COMPILES = [0]
+_COMPILE_LISTENER_ON = False
+
+
+def _count_compiles() -> int:
+    """Start counting (once a process) and return the count so far. The
+    event closes around ``compile_or_get_cached``: a program loaded from
+    the persistent cache counts as one built does."""
+    global _COMPILE_LISTENER_ON
+    if not _COMPILE_LISTENER_ON:
+        _COMPILE_LISTENER_ON = True
+        import jax.monitoring
+
+        def _on_duration(event: str, _secs: float, **_kw) -> None:
+            if event == "/jax/core/compile/backend_compile_duration":
+                _COMPILES[0] += 1
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    return _COMPILES[0]
+
+
 class _Slot:
     """One in-flight sequence occupying a batch row."""
 
@@ -118,7 +146,21 @@ class _Slot:
         #: parent under it
         self.trace: Optional[TraceContext] = None
         self.span_id = ""
+        #: the way to the first token (docs/observability.md "Where a
+        #: request's time to first token goes"), stamped for every
+        #: request on ``t0``'s clock: ``seq`` the engine's arrival number,
+        #: ``t_row`` the row assigned, ``prefill_t0`` the dispatch of its
+        #: first prefill program, ``t_final`` that of the program holding
+        #: its last prompt token; ``n_chunks`` the programs it took,
+        #: ``seg_mark`` the engine's (segments, steps) dispatched so far
+        #: at ``t_row`` and ``ahead`` how many more at ``t_final``
+        self.seq = 0
+        self.t_row: Optional[float] = None
         self.prefill_t0: Optional[float] = None
+        self.t_final: Optional[float] = None
+        self.n_chunks = 0
+        self.seg_mark = (0, 0)
+        self.ahead = (0, 0)
         self.done = threading.Event()
         self.result: Optional[Dict] = None
         self.t0 = time.perf_counter()
@@ -419,7 +461,11 @@ class LlamaEngine:
         #: what the runner's decode segments count beside their tokens
         #: (`_Runner.segment_counters`), summed at each harvest, by name
         self._segment_counters: Dict[str, object] = {}
+        #: decode segments dispatched so far, and their steps (Σ k)
         self._segment_seq = 0
+        self._steps_dispatched = 0
+        #: requests enqueued so far: a slot's ``seq``
+        self._arrivals = 0
         #: load-shedding budget: reject (503) instead of queueing once the
         #: queue is deeper than max_queue_depth or its head has waited
         #: longer than max_queue_age_s (the queue is not draining)
@@ -455,6 +501,12 @@ class LlamaEngine:
         #: the half of TTFT chunked prefill is built to shrink, so it
         #: gets its own p50/p95 in stats() and the Poisson bench arm
         self._queue_wait_recent: "deque[float]" = deque(maxlen=4096)
+        #: where each request's time to first token went, newest 1,024:
+        #: ``(seq, queue, backlog, chunks, first, n_chunks, segments,
+        #: steps)``, the four parts in ms summing to its ``ttft_ms``
+        #: (`_note_first_token_locked`), rounded when stored
+        self._first_tokens: "deque[tuple]" = deque(maxlen=1024)
+        self._compiles0 = _count_compiles()
         self.qps_window_s = 60.0
         runner.warmup(self.params)
         self._thread = threading.Thread(
@@ -719,7 +771,11 @@ class LlamaEngine:
         engine.queue_wait/engine.admission spans when the request is
         traced. Caller holds cv. Chunked admission changes nothing
         here: a request is admitted once (the wait ends when its row is
-        assigned), however many prefill chunks follow."""
+        assigned), however many prefill chunks follow. ``t_adm`` is the
+        slot's ``t_row``: the end of the ``queue`` part of its time to
+        first token."""
+        s.t_row = t_adm
+        s.seg_mark = (self._segment_seq, self._steps_dispatched)
         wait_ms = (t_adm - s.t0) * 1e3
         self._queue_wait_recent.append(wait_ms)
         self.metrics.queue_wait_ms.observe(wait_ms)
@@ -834,13 +890,19 @@ class LlamaEngine:
         out["state_bytes"] = state_rows * self._state_bytes
         out["queued"] = queued
         out["shed_recent"] = shed_recent
-        for name, samples in (("ttft_ms", ttft), ("queue_wait_ms", qwait)):
+        out["pipeline"] = self.pipeline_stats()
+        # each part of the time to first token beside the whole and beside
+        # the queue wait (the first part, over a longer record)
+        parts = [(f"ttft_{part}_ms",
+                  [r[n] for r in out["pipeline"]["first_tokens"]])
+                 for n, part in enumerate(TTFT_PARTS, start=1)]
+        for name, samples in [("ttft_ms", ttft), ("queue_wait_ms", qwait),
+                              *parts]:
             if samples:
                 srt = sorted(samples)
-                out[f"{name}_p50"] = round(srt[len(srt) // 2], 3)
-                out[f"{name}_p95"] = round(
-                    srt[min(len(srt) - 1, int(len(srt) * 0.95))], 3
-                )
+                for q in (50, 95, 99):
+                    out[f"{name}_p{q}"] = round(
+                        srt[min(len(srt) - 1, len(srt) * q // 100)], 3)
         if self._pcache is not None:
             out["prefix_cache"] = self._pcache.stats()
             # block-aware affinity advertisement: digests of the cached
@@ -869,7 +931,6 @@ class LlamaEngine:
                 self._draft, "name", self.spec_draft
             )
             out["speculative"]["candidates"] = self.spec_candidates
-        out["pipeline"] = self.pipeline_stats()
         out["versions"] = self.versions()
         return out
 
@@ -885,6 +946,7 @@ class LlamaEngine:
             p = dict(self._pipe)
             recent = list(self._pipe_recent)
             queued = len(self._waiting)
+            first = list(self._first_tokens)
         out = {
             "ticks": p["ticks"],
             "segments": p["segments"],
@@ -899,6 +961,11 @@ class LlamaEngine:
             "errors": p["errors"],
             "inflight": p["inflight"],
             "queued": queued,
+            # where each request's time to first token went, oldest first:
+            # (seq, queue, backlog, chunks, first, n_chunks, segments, steps)
+            "first_tokens": first,
+            # programs built or loaded since the engine's own warm-up began
+            "compiles": _COMPILES[0] - self._compiles0,
         }
         if p["ticks"]:
             n = p["ticks"]
@@ -1130,6 +1197,8 @@ class LlamaEngine:
         s.fed = 0
         s.cached_len = 0
         s.prefill_pos = -1  # its chunks went with its blocks: start over
+        s.prefill_t0 = s.t_final = None  # and so does its way to a token
+        s.n_chunks = 0
         s.out_ids = []
         s.pending = 0
         self._waiting.appendleft(s)
@@ -1565,6 +1634,8 @@ class LlamaEngine:
                     f"{self._alloc.total} below low watermark",
                     retry_after_s=1.0,
                 )
+            self._arrivals += 1
+            slot.seq = self._arrivals
             self._waiting.append(slot)
             if slot.request_id:
                 self._requests[slot.request_id] = slot
@@ -1929,6 +2000,28 @@ class LlamaEngine:
         acct["harvest_ms"] += wait.ms
         acct["host_ms"] += host.ms
 
+    def _note_first_token_locked(self, s: _Slot, now: float) -> None:
+        """Where ``s``'s time to first token went, from its stamps and the
+        ``now`` that stamped ``ttft_ms``: the four `TTFT_PARTS` in ms
+        (``queue`` to the row, ``backlog`` to its first prefill program,
+        ``chunks`` to its final one, ``first`` to the token on the host),
+        which share ``ttft_ms``'s two ends and so sum to it, then the
+        prefill programs it took and the decode segments and steps the
+        tick dispatched between its row and its final program. One record
+        for `stats()` and ``pipeline_stats()["first_tokens"]``, one
+        histogram sample a part, one ``engine.first_token`` phase (inside
+        the caller's ``engine.harvest_host``). Caller holds cv."""
+        ms = [round((b - a) * 1e3, 3) for a, b in zip(
+            (s.t0, s.t_row, s.prefill_t0, s.t_final),
+            (s.t_row, s.prefill_t0, s.t_final, now))]
+        self._first_tokens.append((s.seq, *ms, s.n_chunks, *s.ahead))
+        for part, v in zip(TTFT_PARTS, ms):
+            self.metrics.ttft_part_ms.observe(v, part=part)
+        with TRACER.phase("engine.first_token", req=s.seq,
+                          **dict(zip(TTFT_PARTS, ms)), n_chunks=s.n_chunks,
+                          segments=s.ahead[0], steps=s.ahead[1]):
+            pass
+
     def _harvest_prefill(self, pre, ids_dev, acct: Dict) -> None:
         """Harvest prefill's device-sampled first tokens ([B] int32 — the
         logits never left the device) and record them. Runs AFTER the next
@@ -1949,15 +2042,17 @@ class LlamaEngine:
                     s.ttft_ms = (now - s.t0) * 1e3
                     self._ttft_recent.append(s.ttft_ms)
                     self.metrics.ttft_ms.observe(s.ttft_ms)
+                    self._note_first_token_locked(s, now)
                 if budgeted:
                     s.out_ids.append(int(ids[i]))
                 if s.span_id:
-                    p0 = s.prefill_t0 if s.prefill_t0 is not None else now
-                    TRACER.record("engine.prefill", start=p0,
-                                  duration=now - p0, trace=s.trace,
-                                  parent_id=s.span_id,
+                    TRACER.record("engine.prefill", start=s.prefill_t0,
+                                  duration=now - s.prefill_t0,
+                                  trace=s.trace, parent_id=s.span_id,
                                   prompt_len=len(s.prompt),
-                                  cached_len=s.cached_len)
+                                  cached_len=s.cached_len,
+                                  chunks=s.n_chunks, segments=s.ahead[0],
+                                  steps=s.ahead[1])
                 # the row's prefix KV is now self-contained (prefill has
                 # completed) — the grafted entry no longer needs its pin
                 self._release_prefix_locked(s)
@@ -1986,16 +2081,16 @@ class LlamaEngine:
         logits and one chain merge (final rows only) for the whole of
         ``sched``, whatever the number of programs, so a sampled request
         draws the noise it always drew. Each program runs under its own
-        ``engine.prefill_dispatch`` phase (``slots`` = rows it computes).
-        Returns ``(prefill_ids, t0)``: the sampled ids, still on the
-        device, and the time of the first dispatch."""
+        ``engine.prefill_dispatch`` phase (``slots`` = rows it computes),
+        and its dispatch stamps the slots it feeds (`_stamp_dispatched`).
+        Returns the sampled ids, still on the device."""
         import jax
         import jax.numpy as jnp
         import numpy as np
 
         groups = [[t] for t in sched] if self._paged else [sched]
         logits = None  # of the tick's earlier programs
-        prefill_ids = t0 = None
+        prefill_ids = None
         saved = positions = view_keys = views = resets = 0
         for n, group in enumerate(groups):
             slots = len(group) if self._paged else self.max_batch
@@ -2024,10 +2119,10 @@ class LlamaEngine:
                         # the programs after the first run on the cache
                         # the one before returned
                         self._upload_mirrors()
-                    t0 = time.perf_counter()
                 from_prefix = suffix or bool(np.any(starts > 0))
                 # every position the program reads or writes lies below
                 live_to = min(int(starts.max()) + bucket, self.max_seq)
+                t_disp = time.perf_counter()
                 logits = self._runner.prefill(
                     params, jnp.asarray(toks), jnp.asarray(lens),
                     starts=jnp.asarray(starts) if from_prefix else None,
@@ -2036,6 +2131,7 @@ class LlamaEngine:
                     ) if self._paged else None,
                     acc=logits, live_to=live_to,
                 )
+                self._stamp_dispatched(group, t_disp, ph)
                 positions += slots * bucket
                 if self._state_bytes:
                     # rows that go on from the state their last chunk left;
@@ -2066,7 +2162,36 @@ class LlamaEngine:
             self._stats["prefill_positions"] += positions
             self._stats["state_resets"] += resets
             self._count_view_keys_locked(view_keys, views)
-        return prefill_ids, t0
+        return prefill_ids
+
+    def _stamp_dispatched(self, group, t_disp: float, phase) -> None:
+        """One prefill program of ``group`` was dispatched at ``t_disp``
+        (taken just before the jitted call): stamp the slots it feeds
+        with ``prefill_t0`` (their first program), ``t_final`` and the
+        decode work dispatched since their row (the program that holds
+        their last prompt token) and ``n_chunks``, and name on the
+        program's ``engine.prefill_dispatch`` phase whose tokens it
+        computes (``req``), from which position (``base``) and whether
+        they end the prompt (``final``); a batched program's ``req`` and
+        ``base`` are comma-joined in the order of its rows. Kept out of
+        `_dispatch_prefill`: the time JAX takes to lower a program it
+        meets for the first time moves with the code of the frame that
+        calls it (PERF.md section 6, PR 39)."""
+        for _i, s, _base, _take, final in group:
+            s.n_chunks += 1
+            if s.prefill_t0 is None:
+                s.prefill_t0 = t_disp
+            if final:
+                s.t_final = t_disp
+                s.ahead = (self._segment_seq - s.seg_mark[0],
+                           self._steps_dispatched - s.seg_mark[1])
+        if len(group) == 1:
+            _i, s, base, _take, final = group[0]
+            phase.set(req=s.seq, base=base, final=int(final))
+        else:
+            phase.set(req=",".join(str(s.seq) for _i, s, *_ in group),
+                      base=",".join(str(b) for _i, _s, b, *_ in group),
+                      final=int(all(t[4] for t in group)))
 
     def _sample_first(self, sched, logits, pick_key):
         """Sample the first token of every row on the device (``[B]``
@@ -2160,7 +2285,7 @@ class LlamaEngine:
         # (fail in-flight slots, rebuild the donated cache, keep
         # serving) exactly as for a decode-segment fault
         chaos.check("serving.chunk_admit")
-        prefill_ids, t0 = self._dispatch_prefill(
+        prefill_ids = self._dispatch_prefill(
             sched, acct, self.params if params is None else params,
             suffix=True,
         )
@@ -2174,8 +2299,6 @@ class LlamaEngine:
                 if self._slots[i] is not s:
                     continue  # vacated (request timeout) mid-chunk
                 s.prefill_pos = base + take
-                if s.prefill_t0 is None:
-                    s.prefill_t0 = t0  # first chunk starts the TTFT span
                 if final:
                     pre.append(self._prompt_fed_locked(i, s))
         return pre, (prefill_ids if pre else None)
@@ -2500,9 +2623,11 @@ class LlamaEngine:
         # the tick's times ARE its phase spans' durations: one measurement
         # feeds pipeline_stats(), /metrics and a profiler capture alike
         with TRACER.phase("engine.tick") as tick:
+            compiles = _COMPILES[0]
             acct = self._run_tick(waiting)
             tick.set(segments=acct["segments"], waiting=int(waiting),
-                     rebuilds=acct["rebuilds"])
+                     rebuilds=acct["rebuilds"],
+                     compiles=_COMPILES[0] - compiles)
         self._commit_tick(acct, tick.ms)
         return False
 
@@ -2584,7 +2709,7 @@ class LlamaEngine:
             with self._cv:
                 for i, s in todo:
                     self._reserve_window_locked(i, len(s.prompt))
-            prefill_ids, t0 = self._dispatch_prefill(
+            prefill_ids = self._dispatch_prefill(
                 [(i, s, s.cached_len, len(s.prompt) - s.cached_len, True)
                  for i, s in todo],
                 acct, vp, suffix=False,
@@ -2597,7 +2722,6 @@ class LlamaEngine:
                         self._advance_pos_locked(i, len(s.prompt))
                     if self._slots[i] is not s:
                         continue  # vacated (request timeout) mid-prefill
-                    s.prefill_t0 = t0  # dispatch start, for engine.prefill
                     pre.append(self._prompt_fed_locked(i, s))
                 active = list(self._slots)
 
@@ -2802,6 +2926,7 @@ class LlamaEngine:
                     self._advance_pos_locked(i, int(self._pos_host[i]) + k)
             self._pipe["inflight"] = 1
             self._segment_seq += 1
+            self._steps_dispatched += k
         # `seq` joins this span to the harvest span of the same segment
         phase.set(k=k, rows=len(sched), slots=self.max_batch,
                   take=sum(takes), seq=self._segment_seq, **attrs)

@@ -15,9 +15,12 @@ what its attention ``read`` (the decoder's kernel on a chip: the keys fetched,
 all steps together) it prints every segment's ``read`` beside its ``span`` and
 device time, and fits a step's time to the keys a step reads. Beside it: how many
 segments were short, by the reason the tick gave (``short``), the backlog it
-saw, the prefill programs by bucket, and what a segment runs outside its step
-loop (the operations of an execution that lie in no ``while``), a segment.
-Reads files only; needs no chip.
+saw, the prefill programs by bucket and then one by one (``req``, ``base``,
+``final`` of the ``engine.prefill_dispatch`` span that dispatched each, its
+device time and how long it waited on the device behind work queued before it:
+the pairing ``prefill_dev_wait_ms`` reads, ``benchmark/first_tokens.py``), and
+what a segment runs outside its step loop (the operations of an execution that
+lie in no ``while``), a segment. Reads files only; needs no chip.
 """
 
 from __future__ import annotations
@@ -34,7 +37,7 @@ sys.path.insert(0, str(ROOT))
 
 
 def main(argv) -> int:
-    from benchmark import hybrid_costs, span_reader
+    from benchmark import first_tokens, hybrid_costs, span_reader
 
     cell = argv[1] if len(argv) > 1 else "*"
     root = Path(argv[2]).resolve() if len(argv) > 2 else ROOT
@@ -82,7 +85,8 @@ def main(argv) -> int:
               f"max {max(seen)} (n={len(seen)})")
 
     chunks = defaultdict(list)
-    for s, m in hybrid_costs.paired(spans, "engine.prefill_dispatch", "jit_engine_prefill"):
+    waits = first_tokens.prefill_waits(spans)
+    for s, m, _wait in waits:
         chunks[(int(s.stats.get("bucket", 0)), int(s.stats.get("span", 0)))].append(
             (1e3 * (m.end - m.start), int(s.stats.get("tokens", 0))))
     for (bucket, span), got in sorted(chunks.items()):
@@ -90,6 +94,13 @@ def main(argv) -> int:
         print(f"prefill bucket {bucket} span {span}: n={len(ms)} device ms median "
               f"{statistics.median(ms):.3f} min {min(ms):.3f} max {max(ms):.3f}; "
               f"real tokens median {statistics.median(n for _t, n in got)}")
+    for s, m, wait in waits:
+        print(f"prefill req {s.stats.get('req', '?')} base {s.stats.get('base', '?')} "
+              f"final {s.stats.get('final', '?')} bucket {s.stats.get('bucket', 0)}: "
+              f"dispatched at {s.start - spans.window[0]:.4f} s of the window, device "
+              f"{1e3 * (m.end - m.start):.3f} ms, waited {wait:.3f} ms on the device")
+    if waits:
+        print(first_tokens.describe_waits(waits))
     print_outside_the_loop(path, spans)
     return 0
 

@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Fuse per-replica trace dumps into ONE Chrome/Perfetto trace.
 
-Every process exports its own spans — router + each serving replica via
-``GET /v1/trace``, or ``Tracer.dump()`` Chrome-trace files — with
-timestamps already anchored to the wall-clock epoch
+Every process exports its own spans (router + each serving replica via
+``GET /v1/trace``) with timestamps already anchored to the wall-clock epoch
 (kubedl_tpu/observability/tracing.py), so fusing is pure bookkeeping:
 assign each input file a distinct ``pid`` (Perfetto renders one process
 track per pid), emit a ``process_name`` metadata event naming the source
@@ -11,11 +10,8 @@ file, and concatenate the events. Cross-process spans line up on the
 shared epoch timeline, and span/parent ids (carried in ``args``) let you
 follow one request router → prefill replica → decode replica.
 
-Accepted input shapes, sniffed per file:
-
-* Chrome trace JSON: ``{"traceEvents": [...]}``
-* flight-recorder / ``/v1/trace`` JSON: ``{"spans": [<span dicts>]}``
-  (also a bare list of span dicts)
+Input shape: flight-recorder / ``/v1/trace`` JSON, ``{"spans": [<span
+dicts>]}`` (also a bare list of span dicts).
 
 Usage::
 
@@ -54,17 +50,8 @@ def _span_to_event(span: Dict[str, Any], pid: int,
 
 
 def load_events(path: Path, pid: int) -> List[Dict[str, Any]]:
-    """Read one dump (either shape), rewriting every event onto ``pid``."""
+    """Read one dump, turning every span into an event on ``pid``."""
     data = json.loads(path.read_text())
-    if isinstance(data, dict) and "traceEvents" in data:
-        events = []
-        for ev in data["traceEvents"]:
-            ev = dict(ev)
-            ev["pid"] = pid
-            if ev.get("ph") == "M" and ev.get("name") == "process_name":
-                continue  # replaced by our own per-file metadata event
-            events.append(ev)
-        return events
     spans = data.get("spans", data) if isinstance(data, dict) else data
     if not isinstance(spans, list):
         raise ValueError(f"{path}: unrecognized trace dump shape")
@@ -91,7 +78,7 @@ def merge(paths: List[Path], trace_id: str = "") -> Dict[str, Any]:
 def main(argv: List[str]) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("inputs", nargs="+", type=Path,
-                    help="per-process trace dumps (chrome-trace or span JSON)")
+                    help="per-process trace dumps (/v1/trace span JSON)")
     ap.add_argument("-o", "--output", type=Path, default=Path("merged.json"))
     ap.add_argument("--trace-id", default="",
                     help="keep only spans of one trace (32 hex chars)")

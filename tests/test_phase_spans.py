@@ -27,7 +27,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PIPELINE_KEYS = {
     "ticks", "segments", "segments_by_k", "segments_short",
     "deferred_harvests", "flushes", "chain_rebuilds",
-    "errors", "inflight", "queued", "dispatch_ms_avg", "harvest_ms_avg",
+    "errors", "inflight", "queued", "first_tokens", "compiles",
+    "dispatch_ms_avg", "harvest_ms_avg",
     "host_ms_avg", "tick_ms_avg", "overlap_ratio", "dispatch_ms_p50",
     "harvest_ms_p50", "host_ms_p50", "tick_ms_p50",
 }
@@ -413,6 +414,217 @@ class TestEnginePhases:
         for key in ("dispatch_ms_avg", "harvest_ms_avg", "host_ms_avg", "tick_ms_avg",
                     "tick_ms_p50", "overlap_ratio"):
             assert stats[key] > 0, key
+
+
+# ---- where a request's time to first token went ---------------------------
+
+
+def first_tokens(eng):
+    return eng.pipeline_stats()["first_tokens"]
+
+
+def stop_loop(eng):
+    """Join the scheduler thread: the test drives `_loop_once` by hand."""
+    with eng._cv:
+        eng._stop = True
+        eng._cv.notify_all()
+    eng._thread.join(timeout=10)
+    eng._stop = False
+
+
+def drive(eng, slots, ticks=600):
+    for _ in range(ticks):
+        if all(s.done.is_set() for s in slots):
+            return
+        eng._loop_once()
+    raise AssertionError("requests still open")
+
+
+class TestFirstTokenRecords:
+    @pytest.mark.parametrize("which", ["whole_prompt", "chunked"])
+    def test_every_request_leaves_one_record_that_sums_to_its_ttft(
+            self, which, engine, chunked_engine):
+        eng = engine if which == "whole_prompt" else chunked_engine
+        for prompt, n in REQUESTS:
+            before = first_tokens(eng)
+            (reply,) = serve(eng, [(prompt, n)])  # alone: nothing is put ahead
+            after = first_tokens(eng)
+            assert len(after) == len(before) + 1 and after[:-1] == before[-1023:]
+            seq, queue, backlog, chunks, first, n_chunks, segments, steps = after[-1]
+            assert seq > (before[-1][0] if before else 0)
+            assert min(queue, backlog, chunks, first) >= 0 and first > 0
+            assert queue + backlog + chunks + first == pytest.approx(
+                reply["ttft_ms"], abs=0.01)
+            suffix = len(prompt) - int(reply.get("cached_prefix_len", 0))
+            assert n_chunks == (-(-suffix // 16) if which == "chunked" else 1)
+            assert (chunks > 0) == (n_chunks > 1)
+            assert (segments, steps) == (0, 0)
+
+    @pytest.mark.parametrize("waits_for", ["a_row", "anothers_chunks"])
+    def test_the_parts_say_what_a_request_waited_for(self, waits_for):
+        from kubedl_tpu.serving.server import LlamaEngine, _Slot
+
+        eng = LlamaEngine(preset="tiny", max_batch=2 if waits_for == "a_row" else 3,
+                          max_seq=128, prefill_chunk_tokens=16, prefix_cache_mb=0)
+        stop_loop(eng)
+        if waits_for == "a_row":
+            # three long budgets on two rows: the third has no row until one ends
+            first = [_Slot([5, 9, 13 + j], 40, 0.0) for j in range(3)]
+            then = []
+        else:
+            # A decodes; B's three chunks take the tick's 16 tokens ahead of C's
+            first = [_Slot([5, 9, 13], 60, 0.0)]
+            then = [_Slot(list(range(40, 81)), 4, 0.0), _Slot(list(range(10, 33)), 4, 0.0)]
+        try:
+            for s in first:
+                eng._enqueue_slot_locked_checks(s)
+            eng._loop_once()
+            for s in then:
+                eng._enqueue_slot_locked_checks(s)
+            drive(eng, first + then)
+            records = {r[0]: r for r in first_tokens(eng)}
+        finally:
+            eng.close()
+        assert sorted(records) == [s.seq for s in first + then] == [1, 2, 3]
+        for s in first + then:
+            assert sum(records[s.seq][1:5]) == pytest.approx(s.ttft_ms, abs=0.01)
+        if waits_for == "a_row":
+            _seq, queue, _b, _c, _f, n_chunks, _segments, _steps = records[3]
+            assert queue > 10 * max(records[1][1], records[2][1]) and n_chunks == 1
+            assert queue > 0.5 * first[2].ttft_ms  # it is most of what it waited
+        else:
+            a, b, c = (records[n] for n in (1, 2, 3))
+            assert a[5:] == (1, 0, 0)
+            # B: three programs with A's one-step segments between them
+            assert b[5] == 3 and b[3] > 0 and b[6] >= 2 and b[7] >= b[6]
+            # C has a row from the same tick on and gets no token of three ticks
+            assert c[5] == 2 and c[2] > b[2] and c[6] >= 3 and c[7] >= c[6]
+            assert c[2] > 0.5 * b[3]
+
+    @pytest.mark.parametrize("which", ["whole_prompt", "chunked"])
+    def test_capture_marks_each_first_token_and_names_each_prefill_program(
+            self, which, engine, chunked_engine, tmp_path):
+        eng = engine if which == "whole_prompt" else chunked_engine
+        before = len(first_tokens(eng))
+        with capture(tmp_path) as cap:
+            replies = serve(eng, REQUESTS)
+        records = first_tokens(eng)[before:]
+        assert len(records) == len(REQUESTS)
+        marks = cap.named("engine.first_token")
+        keys = ("req", "queue", "backlog", "chunks", "first", "n_chunks", "segments", "steps")
+        assert sorted(tuple(e[4][k] for k in keys) for e in marks) == sorted(records)
+        hosts = cap.named("engine.harvest_host")
+        for e in marks:
+            around = [h for h in hosts if h[0] == e[0] and h[2] <= e[2] and e[3] <= h[3]]
+            # inside the prefill's harvest, which names no segment
+            assert len(around) == 1 and "seq" not in around[0][4], around
+        programs = cap.named("engine.prefill_dispatch")
+        assert all({"req", "base", "final"} <= set(e[4]) for e in programs)
+        by_req = {r[0]: r for r in records}
+        for seq, rec in by_req.items():
+            mine = sorted((e for e in programs if e[4]["req"] == seq), key=lambda e: e[2])
+            assert len(mine) == rec[5]  # n_chunks
+            assert [e[4]["final"] for e in mine] == [0] * (len(mine) - 1) + [1]
+            assert [e[4]["base"] for e in mine] == sorted(e[4]["base"] for e in mine)
+        assert sum(e[4]["final"] for e in programs) == len(replies)
+        assert all("compiles" in t[4] for t in cap.named("engine.tick"))
+
+    def test_disarmed_engine_keeps_its_records_and_percentiles(self, tmp_path):
+        TRACER.enabled = False
+        eng = make_engine(prefill_chunk_tokens=16)
+        try:
+            with capture(tmp_path) as cap:
+                replies = serve(eng, REQUESTS)
+            records, stats = first_tokens(eng), eng.stats()
+            total, _sum = eng.metrics.ttft_part_ms.summary(part="first")
+        finally:
+            eng.close()
+        assert cap.events == []
+        assert len(records) == len(REQUESTS) == total
+        assert sorted(round(sum(r[1:5]), 3) for r in records) == pytest.approx(
+            sorted(r["ttft_ms"] for r in replies), abs=0.01)
+        assert sorted(r[5] for r in records) == [1, 2, 3]
+        for part in ("queue", "backlog", "chunks", "first"):
+            for q in (50, 95, 99):
+                assert stats[f"ttft_{part}_ms_p{q}"] >= 0
+        assert stats["ttft_first_ms_p99"] == max(r[4] for r in records)
+        assert stats["queue_wait_ms_p99"] >= stats["queue_wait_ms_p50"] > 0
+        assert stats["pipeline"]["compiles"] >= 0
+
+
+# ---- the benchmark's readers of that record -------------------------------
+
+
+def _reader(name):
+    from benchmark.run import load_reader
+
+    return load_reader("layer_metrics", name)
+
+
+def _served(ttfts, late=0.25):
+    return {"kind": "serve", "window_s": 51.0, "requests": [
+        {"ok": t is not None, "ttft_ms": None if t is None else t + late, "late_ms": late}
+        for t in ttfts]}
+
+
+WARM_UP = [(1, 0.1, 0.2, 0.0, 30.0, 1, 0, 0), (2, 0.1, 0.3, 0.0, 31.0, 1, 0, 0)]
+WINDOW = [(3, 100.0, 1.0, 0.0, 50.0, 1, 0, 0), (4, 5.0, 40.0, 90.0, 300.0, 3, 2, 5),
+          (5, 0.5, 0.5, 0.0, 20.0, 1, 0, 0)]
+
+
+class TestFirstTokenReaders:
+    def test_the_window_is_the_last_n_records(self, capsys):
+        stats = {"pipeline": {"first_tokens": WARM_UP + WINDOW}}
+        record = _served([151.0, 435.0, 21.0])
+        got = {part: _reader(f"ttft_{part}_mean_ms")(None, stats, record)
+               for part in ("queue", "backlog", "chunks", "first")}
+        assert got == pytest.approx(
+            {"queue": 105.5 / 3, "backlog": 41.5 / 3, "chunks": 30.0, "first": 370.0 / 3})
+        assert sum(got.values()) == pytest.approx((151.0 + 435.0 + 21.0) / 3)
+        out = capsys.readouterr().out
+        assert "median 5.000, p95 100.000 (n=3)" in out
+        assert "n_chunks 1, segments 0, steps 0" in out
+
+    @pytest.mark.parametrize("why", ["no_record_kept", "fewer_records", "another_requests"])
+    def test_none_where_the_records_are_not_the_windows(self, why, capsys):
+        read = _reader("ttft_first_mean_ms")
+        if why == "no_record_kept":  # a program from before the record
+            assert read(None, {"pipeline": {"ticks": 3}}, _served([151.0])) is None
+            assert read(None, {}, _served([151.0])) is None
+            assert capsys.readouterr().out == ""
+        elif why == "fewer_records":
+            stats = {"pipeline": {"first_tokens": WINDOW[:2]}}
+            assert read(None, stats, _served([151.0, 435.0, 21.0])) is None
+            assert "2 records for 3 finished requests" in capsys.readouterr().out
+        else:
+            # a request that got its first token and then failed left a record
+            # and is not ok: the last two records are not the two ok requests'
+            stats = {"pipeline": {"first_tokens": WARM_UP + WINDOW}}
+            assert read(None, stats, _served([151.0, None, 21.0])) is None
+            assert "sum to" in capsys.readouterr().out
+        assert _reader("prefill_dev_wait_ms")(None, {}, _served([151.0])) is None
+
+    def test_a_prefill_programs_wait_is_from_its_spans_end_to_its_start(self):
+        from benchmark import first_tokens as ft
+        from benchmark.span_reader import Module, Span, Spans
+
+        def span(start, end, **stats):
+            return Span("engine.prefill_dispatch", start, end, stats, "decode-scheduler")
+
+        spans = Spans((10.0, 14.0), spans=[
+            span(10.100, 10.101, req=7, base=0, final=0),
+            span(10.200, 10.202, req=7, base=1024, final=1),
+            span(10.900, 10.903, req=8, base=0, final=1),
+        ], modules=[[
+            Module("jit_engine_decode_seg32", 9.9, 10.25),
+            Module("jit_engine_prefill_from", 10.25, 10.30),   # waited out the segment
+            Module("jit_engine_prefill_from", 10.30, 10.35),
+            Module("jit_engine_prefill_from", 10.902, 10.95),  # began inside its span
+        ]])
+        waits = ft.prefill_waits(spans)
+        assert [(s.stats["req"], s.stats["final"]) for s, _m, _w in waits] == [
+            (7, 0), (7, 1), (8, 1)]
+        assert [w for _s, _m, w in waits] == pytest.approx([149.0, 98.0, 0.0])
 
 
 # ---- the trainer's phases --------------------------------------------------

@@ -62,38 +62,12 @@ class TestTracer:
         assert len(t.spans()) == 8
         assert t.spans()[0].name == "s12"
 
-    def test_summary_and_chrome_export(self):
-        t = Tracer()
-        for _ in range(3):
-            with t.span("phase"):
-                pass
-        agg = t.summary()["phase"]
-        assert agg["count"] == 3 and agg["total_s"] >= 0
-        trace = json.loads(t.chrome_trace())
-        assert len(trace["traceEvents"]) == 3
-        assert trace["traceEvents"][0]["ph"] == "X"
-
     def test_disabled_is_free(self):
         t = Tracer()
         t.enabled = False
         with t.span("skipped"):
             pass
         assert t.spans() == []
-
-    def test_thread_names_become_tids(self):
-        t = Tracer()
-
-        def work():
-            with t.span("x"):
-                pass
-
-        th = threading.Thread(target=work, name="worker-th")
-        th.start()
-        th.join()
-        with t.span("x"):
-            pass
-        trace = json.loads(t.chrome_trace())
-        assert len({e["tid"] for e in trace["traceEvents"]}) == 2
 
 
 class TestTraceIdentity:
@@ -211,6 +185,44 @@ class TestEngineIntegration:
         names = [s.name for s in
                  TRACER.trace_spans(trace_for_job(uid).trace_id)]
         assert len(names) == len(set(names)), names
+
+
+# ---------------------------------------------------------------------------
+# a traced request's tree and its first_tokens record come from one set of stamps
+# ---------------------------------------------------------------------------
+
+class TestServingEngineStamps:
+    @pytest.mark.parametrize("chunk", [0, 16])
+    def test_queue_wait_and_prefill_spans_are_the_records_parts(self, chunk):
+        from kubedl_tpu.serving.server import LlamaEngine
+
+        eng = LlamaEngine(preset="tiny", max_batch=2, max_seq=128,
+                          prefill_chunk_tokens=chunk, prefix_cache_mb=0)
+        try:
+            reply = eng.generate(list(range(40, 81)), max_tokens=4, debug_trace=True)
+            untraced = eng.generate(list(range(1, 6)), max_tokens=2)
+            records = eng.pipeline_stats()["first_tokens"]
+        finally:
+            eng.close()
+        assert "trace" not in untraced and len(records) == 2  # traced or not
+        _seq, queue, backlog, chunks, first, n_chunks, segments, steps = records[0]
+        (root,) = reply["trace"]["spans"]
+        spans = {c["name"]: c for c in root["children"]}
+        assert root["name"] == "engine.request"
+        # rounded to the microsecond when stored, each end once
+        assert spans["engine.queue_wait"]["duration_ms"] == pytest.approx(queue, abs=0.001)
+        assert spans["engine.prefill"]["duration_ms"] == pytest.approx(
+            chunks + first, abs=0.002)
+        # the admission's work starts where the queue wait ends, inside the backlog
+        assert spans["engine.admission"]["ts"] == pytest.approx(
+            spans["engine.queue_wait"]["ts"] + queue / 1e3, abs=2e-6)
+        assert spans["engine.admission"]["duration_ms"] <= backlog + 0.001
+        assert spans["engine.prefill"]["ts"] == pytest.approx(
+            spans["engine.queue_wait"]["ts"] + (queue + backlog) / 1e3, abs=3e-6)
+        attrs = spans["engine.prefill"]["attrs"]
+        assert (attrs["chunks"], attrs["segments"], attrs["steps"]) == (
+            n_chunks, segments, steps) == (3 if chunk else 1, 0, 0)
+        assert queue + backlog + chunks + first == pytest.approx(reply["ttft_ms"], abs=0.01)
 
 
 # ---------------------------------------------------------------------------
