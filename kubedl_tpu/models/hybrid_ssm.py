@@ -299,9 +299,13 @@ def mamba_prefill(h: jax.Array, lp: Params, ssm: jax.Array, conv: jax.Array,
 
 
 def mamba_step(h: jax.Array, lp: Params, ssm: jax.Array, conv: jax.Array,
-               live: jax.Array, cfg: HybridConfig):
+               live: jax.Array, cfg: HybridConfig, at=None):
     """One token a row: ``h [B, D]``; rows not ``live`` compute and leave
-    their state and window as they were. Returns ``(out [B, D], ssm, conv)``."""
+    their state and window as they were. Returns ``(out [B, D], ssm, conv)``.
+    ``ssm`` is the layer's ``[B, H, P, N]``, every row's slab swept by
+    ``ssd_scan.ssd_step``; with ``at = (layer, rows, count)`` it is the whole
+    ``[B, L, H, P, N]`` state, of which the kernel ``ssd_scan.ssd_step_rows``
+    touches that layer's slabs of the listed rows and nothing else."""
     K = cfg.conv_kernel
     z, xbc, dt_raw = _in_proj(h, lp)
     w = lp["conv_w"].astype(jnp.float32)
@@ -311,8 +315,11 @@ def mamba_step(h: jax.Array, lp: Params, ssm: jax.Array, conv: jax.Array,
     moved = jnp.concatenate([conv[:, 1:], xbc[:, None].astype(conv.dtype)], axis=1)
     conv = jnp.where(live[:, None, None], moved, conv)
     x, Bm, Cm, dt, A = _ssm_inputs(jax.nn.silu(acc).astype(h.dtype), dt_raw, lp, cfg)
-    dt = jnp.where(live[:, None], dt, 0.0)  # decay 1, nothing added
-    ssm, y = ssd_scan.ssd_step(x, dt, A, Bm, Cm, ssm)
+    if at is None:
+        dt = jnp.where(live[:, None], dt, 0.0)  # decay 1, nothing added
+        ssm, y = ssd_scan.ssd_step(x, dt, A, Bm, Cm, ssm)
+    else:
+        ssm, y = ssd_scan.ssd_step_rows(x, dt, A, Bm, Cm, ssm, *at)
     return _gate_out(y, x, z, lp, cfg), ssm, conv
 
 
@@ -522,10 +529,19 @@ def decode_step(
 ) -> Tuple[jax.Array, Params]:
     """One token for every cache row (as ``llama.paged_decode_step_batched``:
     the new K/V scattered into the row's current block, attention over the
-    gathered view's span), the one-step recurrence on every row's slab. A
-    row not in ``live`` (vacant, between two chunks of its prompt, left out
+    gathered view's span), the one-step recurrence on the slabs of ``live``.
+    A row not in ``live`` (vacant, between two chunks of its prompt, left out
     by the block reserve) computes garbage nobody reads, writes its K/V to
-    the trash block and keeps its slab."""
+    the trash block and keeps its slab.
+
+    How the slabs advance is chosen here, from what can be observed
+    (:func:`steps_listed_rows`): on a TPU, a float32 state of whole tiles
+    goes whole to the kernel ``ssd_scan.ssd_step_rows``, one call a mamba
+    layer, which reads and writes the ``live`` rows' slabs once and names no
+    other row's; anywhere else ``ssd_scan.ssd_step`` sweeps every row's slab
+    of the layer with a decay of 1 for the rows not ``live`` (a CPU, and a
+    shape the kernel refuses: ``ssm_state`` not a multiple of 128,
+    ``ssm_head_dim`` not of 8, a state that is not float32)."""
     B = tokens.shape[0]
     pos, bt = cache["pos"], cache["bt"]
     BS = cache["k"].shape[2]
@@ -538,12 +554,19 @@ def decode_step(
         blk = jnp.where(pos < jnp.asarray(spans, jnp.int32)[span_at], blk, 0)
     off = pos % BS
 
+    listed = ssd_scan.scheduled_rows(live) if steps_listed_rows(cache) else None
+
     def mamba_fn(h, lp, ssm, conv, m):
-        out, s1, c1 = mamba_step(
-            h[:, 0], lp, lax.dynamic_index_in_dim(ssm, m, 1, keepdims=False),
-            lax.dynamic_index_in_dim(conv, m, 1, keepdims=False), live, cfg)
-        return (out[:, None], lax.dynamic_update_index_in_dim(ssm, s1, m, 1),
-                lax.dynamic_update_index_in_dim(conv, c1, m, 1))
+        window = lax.dynamic_index_in_dim(conv, m, 1, keepdims=False)
+        if listed is None:
+            out, s1, c1 = mamba_step(
+                h[:, 0], lp, lax.dynamic_index_in_dim(ssm, m, 1, keepdims=False),
+                window, live, cfg)
+            ssm = lax.dynamic_update_index_in_dim(ssm, s1, m, 1)
+        else:  # the state whole: no plane of it is sliced out or put back
+            out, ssm, c1 = mamba_step(h[:, 0], lp, ssm, window, live, cfg,
+                                      at=(m, *listed))
+        return out[:, None], ssm, lax.dynamic_update_index_in_dim(conv, c1, m, 1)
 
     def attn_fn(h, lp, kp, vp, p):
         q, k, v = _qkv(h, lp, cfg)
@@ -561,16 +584,30 @@ def decode_step(
     }
 
 
+def steps_listed_rows(cache: Params) -> bool:
+    """Whether a decode step of this process advances the scheduled rows'
+    slabs alone, by the kernel (else it sweeps every row's): a TPU, and a
+    state the kernel can take as it stands."""
+    return jax.default_backend() == "tpu" and ssd_scan.step_kernel_fits(cache["ssm"])
+
+
 def decode_segment(
     params: Params, cache: Params, tokens: jax.Array, temps: jax.Array,
     key: jax.Array, live: jax.Array, cfg: HybridConfig, n_steps: int,
     greedy: bool = False, spans: Optional[Tuple[int, ...]] = None,
     live_to: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, jax.Array, jax.Array, Params]:
+) -> Tuple[jax.Array, jax.Array, jax.Array, Params, Dict[str, jax.Array]]:
     """``n_steps`` of :func:`decode_step` with the decoder's own on-device
     sample-and-feed chain (``llama.sampled_segment``): ``(toks [B, n_steps],
-    last [B, 1], next_key, cache)``."""
+    last [B, 1], next_key, cache, counters)``. The counters say how much of
+    the state the segment's steps moved: ``slabs_stepped``, the sum over its
+    steps of the rows whose slab the step fetched (the ``live`` rows through
+    the kernel, every row where ``ssd_step`` sweeps them all), and
+    ``slabs_held``, rows times steps."""
     step = partial(decode_step, live=live, cfg=cfg, spans=spans, live_to=live_to)
-    return llama.sampled_segment(
+    B = live.shape[0]
+    stepped = jnp.sum(live, dtype=jnp.int32) if steps_listed_rows(cache) else jnp.int32(B)
+    counters = {"slabs_stepped": n_steps * stepped, "slabs_held": jnp.int32(n_steps * B)}
+    return (*llama.sampled_segment(
         lambda cache, toks: step(params, cache, toks), cache, tokens, temps, key,
-        n_steps, greedy)
+        n_steps, greedy), counters)

@@ -701,6 +701,18 @@ class ServingMetrics:
                 "experts_touched over this is the experts a layer's step "
                 "touches",
             ),
+            "slabs_stepped": r.counter(
+                "kubedl_tpu_serving_slabs_stepped",
+                "Rows whose slab of recurrent state a decode step fetched, "
+                "summed over decode steps: the scheduled rows' where the "
+                "step lists them for its kernel (a TPU), every row's where "
+                "it sweeps them all. 0 for a model without such state",
+            ),
+            "slabs_held": r.counter(
+                "kubedl_tpu_serving_slabs_held",
+                "Rows times decode steps: slabs_stepped over this is the "
+                "share of the recurrent state a step moves",
+            ),
         }
         # the second kind of K/V block (kv_blocks.WindowTable): the pool of
         # the layers that read only a window of the context

@@ -698,6 +698,7 @@ class HybridRunner(_Runner):
             jnp.zeros((self.max_batch,), jnp.float32), jax.random.PRNGKey(0),
             live_to=1, rows=())
         jax.block_until_ready(self.cache["pos"])
+        self.segment_counters = None
 
     def prefill(self, params, toks, lens, starts=None, rows=None, acc=None,
                 live_to: Optional[int] = None):
@@ -723,8 +724,11 @@ class HybridRunner(_Runner):
                        takes=None):
         """As :meth:`ModelRunner.decode_segment`; the slabs of ``rows`` (the
         rows the dispatch scheduled; None: every row) advance, every other
-        row's stays as it is."""
-        toks, last, key, self.cache = self._segment_fn(n_steps, greedy)(
+        row's stays as it is. Leaves in ``segment_counters`` how many slabs
+        the segment's steps fetched (``slabs_stepped``) of how many the rows
+        hold (``slabs_held``): on a TPU the scheduled rows' alone."""
+        toks, last, key, self.cache, self.segment_counters = self._segment_fn(
+            n_steps, greedy)(
             params, self.cache, tokens, temps, key,
             jnp.asarray(self._live_mask(rows)), *self._live_to(live_to),
         )

@@ -237,7 +237,7 @@ def test_view_span_branches_compile_in_place(on_chip, program):
 
 
 @pytest.mark.parametrize("program", ["decode_segment", "prefill_from"])
-def test_hybrid_programs_fit_the_chip_whole(on_chip, program):
+def test_hybrid_programs_fit_the_chip_whole(on_chip, program, monkeypatch):
     """The hybrid runner's own programs at granite-4.0-h-micro's size, all 40
     layers, 32 rows of 8192 keys: what the compiler needs for arguments and
     temporaries stays under the 15 GiB a 16 GB chip leaves a program. Two
@@ -245,13 +245,26 @@ def test_hybrid_programs_fit_the_chip_whole(on_chip, program):
     side (a last axis of 64 is padded to 128 lanes: the pool twice over, and
     the first compile asked for 16.6 GB), and ``in_proj`` is three leaves (a
     ``[2048, 8512]`` leaf is stored transposed and every program copied its
-    1.17 GB). So no operation copies a pool or a stack of projections."""
+    1.17 GB). So no operation copies a pool or a stack of projections.
+
+    The decode segment is compiled as a TPU process traces it: the state goes
+    whole to the kernel ``ssd_step_rows`` (PR 40), one custom call inside the
+    layer loops inside the step loop, aliased in and out, so nothing copies
+    the 2.42 GB of state and no fusion makes an array of its shape (the
+    einsum form's update in place was one, every row's slab of the layer
+    read and written)."""
+    import re
+
     import jax
     import jax.numpy as jnp
 
     from kubedl_tpu.models import hybrid_ssm
+    from kubedl_tpu.ops import ssd_scan
     from kubedl_tpu.serving.model_runner import HybridRunner
 
+    # this process's backend is the CPU: what `hybrid_ssm.steps_listed_rows`
+    # observes on a TPU (jax's own code asks `xla_bridge`, not this name)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     B, max_seq, BS = 32, 8192, 16
     runner = HybridRunner("granite-4.0-h-micro", max_batch=B, max_seq=max_seq,
                           kv_block_size=BS)
@@ -274,10 +287,24 @@ def test_hybrid_programs_fit_the_chip_whole(on_chip, program):
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * 2**30
-    big = [ln for ln in compiled.as_text().splitlines() if " copy(" in ln and (
+    text = compiled.as_text().splitlines()
+    big = [ln for ln in text if " copy(" in ln and (
         f"bf16[{cfg.periods},{1 + B * max_seq // BS}," in ln
         or (f"bf16[{cfg.n_mamba},{cfg.dim}," in ln and ",64]" not in ln))]
     assert not big, big[:2]
+    if program == "decode_segment":
+        assert ssd_scan.step_kernel_fits(cache["ssm"])
+        state = "f32[%d,%d,%d,%d,%d]" % cache["ssm"].shape
+        made = [ln for ln in text if re.match(
+            rf"\s*(ROOT )?%\S+ = {re.escape(state)}\S* (copy|fusion)\(", ln)]
+        assert not made, made[:2]
+        calls = [ln for ln in text if ssd_scan.STEP_KERNEL_NAME in ln
+                 and "custom_call_target=\"tpu_custom_call\"" in ln]
+        # before and after the attention layer of a period: two call sites
+        assert len(calls) == 2, calls
+        for ln in calls:
+            assert re.search(r"/while/body/.*/while/body/.*/while/body/.*pallas_call", ln), ln
+            assert "output_to_operand_aliasing" in ln, ln
 
 
 @pytest.mark.parametrize("program", ["decode_segment", "prefill_from"])

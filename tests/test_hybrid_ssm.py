@@ -134,6 +134,125 @@ def test_scan_in_two_calls_carries_its_state():
     np.testing.assert_allclose(sb, s, atol=2e-4)
 
 
+# ---- the one-step kernel ------------------------------------------------------
+
+
+def forced_kernel(mp):
+    """What a TPU process observes, steered for a CPU: the backend's name,
+    the tiling predicate (the tiny preset's ``N`` is 16), and the interpreter
+    in the compiled kernel's place. Holds for as long as ``mp`` does:
+    programs trace on first use."""
+    import functools
+
+    mp.setattr(jax, "default_backend", lambda: "tpu")
+    mp.setattr(ssd_scan, "step_kernel_fits", lambda state: True)
+    mp.setattr(ssd_scan, "ssd_step_rows",
+               functools.partial(ssd_scan.ssd_step_rows, interpret=True))
+
+
+SCHEDULED = {"none": (), "one": (3,), "three": (0, 2, 4), "every": (0, 1, 2, 3, 4)}
+
+
+@pytest.mark.parametrize("which", sorted(SCHEDULED))
+def test_the_step_kernel_advances_the_listed_rows_and_touches_no_other(which, monkeypatch):
+    """``ssd_step_rows`` through the interpreter against ``ssd_step`` on the
+    tiny preset's shapes, five rows: ``y`` and the listed rows' slabs of the
+    layer agree to float32 rounding (the state's arithmetic is the same in
+    the same order, ``y`` sums ``N`` in its own), every other slab of the
+    state is bit for bit what it was, and a decode segment's counters say
+    how many slabs its steps fetched."""
+    B, L = 5, CFG.n_mamba
+    H, P, N = CFG.ssm_heads, CFG.ssm_head_dim, CFG.ssm_state
+    k = jax.random.split(jax.random.PRNGKey(40), 6)
+    x = jax.random.normal(k[0], (B, H, P))
+    dt = jax.nn.softplus(jax.random.normal(k[1], (B, H)))
+    A = -jnp.exp(jax.random.normal(k[2], (H,)))
+    Bm, Cm = jax.random.normal(k[3], (B, N)), jax.random.normal(k[4], (B, N))
+    state = jax.random.normal(k[5], (B, L, H, P, N))
+    live = np.zeros((B,), bool)
+    live[list(SCHEDULED[which])] = True
+    rows, count = ssd_scan.scheduled_rows(jnp.asarray(live))
+    assert int(count) == live.sum() and rows[:int(count)].tolist() == list(SCHEDULED[which])
+    for layer in (0, L - 1):
+        got_s, got_y = jax.jit(lambda *a: ssd_scan.ssd_step_rows(*a, interpret=True))(
+            x, dt, A, Bm, Cm, state, jnp.int32(layer), rows, count)
+        want_s, want_y = ssd_scan.ssd_step(x, dt, A, Bm, Cm, state[:, layer])
+        np.testing.assert_allclose(np.asarray(got_y)[live], np.asarray(want_y)[live],
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(got_s)[live, layer], np.asarray(want_s)[live],
+                                   rtol=1e-6, atol=1e-6)
+        untouched = np.ones((B, L), bool)
+        untouched[live, layer] = False
+        assert (np.asarray(got_s)[untouched] == np.asarray(state)[untouched]).all()
+        assert not np.asarray(got_y)[~live].any()
+    # the same set through two steps of the whole model: the counters, and
+    # (whatever the set) no slab of a row that was not scheduled moves
+    forced_kernel(monkeypatch)
+    params = hy.hybrid_init(jax.random.PRNGKey(3), CFG)
+    cache = fresh_cache(rows=B)
+    cache["pos"] = jnp.full((B,), 5, jnp.int32)
+    _toks, _last, _key, after, counters = jax.jit(
+        lambda p, c, live: hy.decode_segment(
+            p, c, jnp.ones((B, 1), jnp.int32), jnp.zeros((B,), jnp.float32),
+            jax.random.PRNGKey(0), live, CFG, n_steps=2, greedy=True)
+    )(params, cache, jnp.asarray(live))
+    assert {n: int(v) for n, v in counters.items()} == {
+        "slabs_stepped": 2 * int(live.sum()), "slabs_held": 2 * B}
+    assert (np.asarray(after["ssm"])[~live] == np.asarray(cache["ssm"])[~live]).all()
+    if live.any():
+        assert (np.asarray(after["ssm"])[live] != np.asarray(cache["ssm"])[live]).any()
+
+
+@pytest.mark.parametrize("shape, dtype, fits", [
+    ((32, 36, 64, 64, 128), jnp.float32, True),   # granite-4.0-h-micro's state
+    ((3, 6, 4, 32, 16), jnp.float32, False),      # the tiny preset's: N is no whole lane tile
+    ((32, 36, 64, 64, 128), jnp.bfloat16, False),  # another type
+    ((32, 36, 64, 12, 128), jnp.float32, False),   # P is no whole sublane tile
+])
+def test_the_step_kernel_takes_a_float32_state_of_whole_tiles(shape, dtype, fits):
+    state = jax.ShapeDtypeStruct(shape, dtype)
+    assert ssd_scan.step_kernel_fits(state) is fits
+    # and no CPU process picks it, whatever the state
+    assert not hy.steps_listed_rows({"ssm": state})
+
+
+def test_a_runner_with_the_kernel_serves_the_sweeps_tokens(params):
+    """``HybridRunner.decode_segment`` for 4 steps, two of three rows
+    scheduled, with the kernel forced through the interpreter and as a CPU
+    runs it (``ssd_step`` over every row): the same greedy tokens, final
+    slabs that agree to float32 rounding, the row that sat out bit for bit
+    where it was, and counters that say what each path fetched."""
+    from kubedl_tpu.serving.model_runner import HybridRunner
+
+    def run(force):
+        with pytest.MonkeyPatch.context() as mp:
+            if force:
+                forced_kernel(mp)
+            r = HybridRunner("tiny-hybrid", max_batch=3, max_seq=64, kv_block_size=8)
+            r.new_cache(1 + 3 * 64 // 8)
+            r.cache["bt"] = jnp.asarray(1 + np.arange(3 * 8, dtype=np.int32).reshape(3, 8))
+            r.cache["ssm"] = r.cache["ssm"] + 1.0  # a slab that sits out is not zero
+            for row, prompt in ((0, PROMPTS[2]), (2, PROMPTS[0])):
+                toks = np.zeros((1, 32), np.int32)
+                toks[0, :len(prompt)] = prompt
+                r.prefill(params, jnp.asarray(toks), jnp.asarray([len(prompt)], jnp.int32),
+                          rows=jnp.asarray([row], jnp.int32))
+            before = np.asarray(r.cache["ssm"])
+            toks, _last, _key = r.decode_segment(
+                4, True, params, jnp.asarray([[7], [0], [9]], jnp.int32),
+                jnp.zeros((3,), jnp.float32), jax.random.PRNGKey(0), live_to=64, rows=[0, 2])
+            counted = {n: int(v) for n, v in r.segment_counters.items()}
+            return np.asarray(toks), before, np.asarray(r.cache["ssm"]), counted
+
+    toks, before, after, counted = run(force=True)
+    want_toks, _, want_after, want_counted = run(force=False)
+    assert (toks[[0, 2]] == want_toks[[0, 2]]).all()
+    np.testing.assert_allclose(after[[0, 2]], want_after[[0, 2]], rtol=1e-5, atol=1e-6)
+    assert (after[1] == before[1]).all() and (after[0] != before[0]).any()
+    assert counted == {"slabs_stepped": 4 * 2, "slabs_held": 4 * 3}
+    assert want_counted == {"slabs_stepped": 4 * 3, "slabs_held": 4 * 3}
+
+
 def test_one_query_attention_is_the_head_by_head_form():
     k = jax.random.split(jax.random.PRNGKey(2), 3)
     q = jax.random.normal(k[0], (3, 1, 4, 16))
@@ -362,6 +481,23 @@ def test_dispatch_phases_say_what_the_state_did(engine, monkeypatch):
     assert dec[0]["keys"] == 40
     assert engine.stats()["state_resets"] == resets + 1
     assert "kubedl_tpu_serving_state_resets" in engine.metrics.registry.render()
+
+
+def test_stats_and_metrics_say_how_many_slabs_the_steps_fetched(engine):
+    """After a request ``stats()`` holds the runner's two segment counters
+    under its names, and ``/metrics`` renders them. On a CPU ``ssd_step``
+    sweeps every row's slab, so the two are equal: the counter says what the
+    path that ran did, not what a TPU would have done."""
+    before = engine.stats()
+    engine.generate(PROMPTS[0], max_tokens=6, temperature=0.0)
+    after = engine.stats()
+    held = after["slabs_held"] - before.get("slabs_held", 0)
+    assert held > 0 and held % engine.max_batch == 0
+    assert after["slabs_stepped"] - before.get("slabs_stepped", 0) == held
+    text = engine.metrics.registry.render()
+    for name in ("kubedl_tpu_serving_slabs_stepped", "kubedl_tpu_serving_slabs_held"):
+        (line,) = [ln for ln in text.splitlines() if ln.startswith(name + " ")]
+        assert float(line.split()[1]) >= held
 
 
 def test_live_state_is_counted_while_a_request_runs(engine):
