@@ -46,6 +46,10 @@ had. The pool of the layers that read only the last ``window`` keys is a
 :class:`WindowTable`: a row owns the blocks of a RANGE of its positions,
 grown at the front as the row advances and released at the back once a
 block lies wholly behind the window.
+
+**No block at all.** A model whose every layer keeps a fixed recurrent state
+(``models/retention.py``) has no pool; its engine holds a :class:`NoBlocks`,
+for which every request is already met.
 """
 
 from __future__ import annotations
@@ -71,10 +75,13 @@ class BlockAllocator:
     device pool's leading dimension; ``total`` reports usable blocks.
     """
 
+    #: the fewest blocks a pool has: the trash block and one to hand out
+    MIN_BLOCKS = 2
+
     def __init__(self, num_blocks: int, block_size: int,
                  low_watermark: float = 0.05,
                  high_watermark: float = 0.15) -> None:
-        if num_blocks < 2:
+        if num_blocks < self.MIN_BLOCKS:
             raise ValueError("need at least one usable block beyond trash")
         if block_size < 1:
             raise ValueError("block_size must be >= 1")
@@ -244,6 +251,23 @@ class BlockAllocator:
             "high_watermark": self.high_watermark,
         })
         return out
+
+
+class NoBlocks(BlockAllocator):
+    """The allocator of an engine whose rows own no block: a model that is
+    recurrent state and nothing else reports ``block_bytes == 0``, and the
+    engine then has no pool to hand out. A position needs no block, so
+    every reserve is met by the empty list, a trim and a free find nothing
+    to give back, admission never closes for want of blocks (rows bound
+    it), and ``stats()`` reads 0 of 0."""
+
+    MIN_BLOCKS = 1  # the trash block's index, with no pool behind it
+
+    def __init__(self, block_size: int) -> None:
+        super().__init__(1, block_size, low_watermark=0.0, high_watermark=0.0)
+
+    def blocks_for(self, n_tokens: int) -> int:
+        return 0
 
 
 class WindowTable:

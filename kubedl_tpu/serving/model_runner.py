@@ -25,7 +25,7 @@ blocks (:attr:`ModelRunner.decode_tile`); anywhere else, and in every other
 program, the gathered view with its spans. No option chooses: ``kv_attention``
 still picks the suffix programs' arm, and the CPU's decode step.
 
-Three runners stand here. :class:`ModelRunner` is the decoder's
+Four runners stand here. :class:`ModelRunner` is the decoder's
 (``models/llama.py``). :class:`HybridRunner` is the hybrid state-space
 model's (``models/hybrid_ssm.py``): the same paged pools for its few
 attention layers, and beside them a fixed slab of recurrent state a row,
@@ -33,7 +33,10 @@ which its programs reset, carry and advance themselves.
 :class:`SparseWindowRunner` is the sparse-expert decoder's
 (``models/sparse_window.py``): a pool for the layers that keep the whole
 context and a second, with a block table of its own, for the layers that
-read only a window of it. All answer the methods the engine calls, under
+read only a window of it. :class:`RetentionRunner` is the attention-free
+decoder's (``models/retention.py``): no pool at all, a row is its slab of
+recurrent state and ``block_bytes`` is 0, from which the engine knows to
+build no block pool. All answer the methods the engine calls, under
 the same program names; :func:`make_runner` picks one by the type of the
 preset's config, and what they share is :class:`_Runner`.
 """
@@ -49,7 +52,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from kubedl_tpu import chaos
-from kubedl_tpu.models import hybrid_ssm, llama, paged_attention, sparse_window
+from kubedl_tpu.models import (
+    hybrid_ssm, llama, paged_attention, retention, sparse_window)
 
 log = logging.getLogger("kubedl_tpu.serving.model_runner")
 
@@ -76,10 +80,11 @@ class _Runner:
     #: span and compiles what it always compiled. A class constant and no
     #: option, like ``LlamaEngine.SEGMENT_BUCKETS``; a test may shrink it.
     SPAN_FLOOR = 1024
-    #: bytes of recurrent state a row owns beside its blocks, whatever its
-    #: context; 0: a row is its blocks and nothing else. Non-zero, the
-    #: engine builds no prefix cache and refuses speculation and hand-off
-    #: (a prefix is then more than a list of blocks).
+    #: bytes of recurrent state a row owns, whatever its context (beside
+    #: its blocks, or instead of them where ``block_bytes`` is 0); 0: a row
+    #: is its blocks and nothing else. Non-zero, the engine builds no prefix
+    #: cache and refuses speculation and hand-off (a prefix is then more
+    #: than a list of blocks).
     state_bytes_per_row = 0
     #: keys the layers of a second, windowed pool read back from a row's
     #: position; 0: one kind of block. Non-zero, the cache holds that pool
@@ -851,18 +856,96 @@ class SparseWindowRunner(_Runner):
         return toks, last, key
 
 
+class RetentionRunner(HybridRunner):
+    """The attention-free decoder's config, cache and jitted programs
+    (``models/retention.py``), behind the methods and program names of
+    :class:`ModelRunner`. A row owns a fixed slab of recurrent state
+    (``state_bytes_per_row``) and NO K/V block: ``block_bytes`` is 0, the
+    cache is ``pos`` and the slabs, and the engine, told by that, builds no
+    pool, admits by free rows and never preempts for want of blocks. The
+    slab is the programs' to manage, by :class:`HybridRunner`'s rules: a
+    prefill program zeroes the slab of a row it starts at position 0 and
+    carries it for a row it starts later; a decode segment advances the
+    slabs of the rows the dispatch scheduled (``rows``) and of no other.
+    There is no view, so no span: ``max_seq`` bounds positions and nothing
+    else. ``retention.preset`` and ``retention.retention_init`` are looked
+    up on the module at call time, as the decoder's are.
+
+    What rests on "a prefix is a list of blocks" has no meaning for a slab
+    yet (prefix reuse, speculation's rollback, block hand-off): the engine
+    refuses those at construction. The warm-up, the prefill and the decode
+    segment's call are :class:`HybridRunner`'s own (with one span they hand
+    the programs no ``live_to``); the programs are this model's."""
+
+    block_bytes = 0
+
+    def __init__(self, preset: str, *, max_batch: int, max_seq: int = 0,
+                 kv_block_size: int = 16) -> None:
+        self.cfg = cfg = retention.preset(preset)
+        self.max_batch = max_batch
+        # a prompt's chunks are cut in units of this, and nothing else is
+        bs = self.kv_block_size = max(1, int(kv_block_size))
+        self.max_seq = -(-(max_seq or min(cfg.max_seq, 512)) // bs) * bs
+        self.state_bytes_per_row = retention.state_bytes_per_row(cfg)
+        self.cache = None
+        self.spans = (self.max_seq,)  # one: the programs take no ``live_to``
+        self._no_logits = jnp.zeros((max_batch, cfg.vocab_size), jnp.float32)
+        self._build_row_prefills(retention.prefill)
+        self._build_samplers()
+        self._segments: Dict[tuple, object] = {}
+
+    def build_params(self, ckpt_dir: str, require_ckpt: bool = False):
+        """Init, then the newest checkpoint where there is one; committed
+        nowhere until it returns (:meth:`ModelRunner.build_params`)."""
+        chaos.check("serving.weight_swap")
+        return self._restored(
+            retention.retention_init(jax.random.PRNGKey(0), self.cfg), ckpt_dir,
+            require_ckpt)
+
+    def new_cache(self, kv_blocks: int = 0) -> None:
+        """``pos`` and every row's slab, zeroed; there is no pool to size."""
+        self.cache = retention.init_cache(self.cfg, self.max_batch)
+
+    def upload_mirrors(self, bt, pos=None, wbt=None) -> None:
+        """The positions alone: the cache holds no block table."""
+        if pos is not None:
+            self.cache["pos"] = self._upload_mirror(pos)
+
+    def span_for(self, live_to: Optional[int]) -> int:
+        """0: no program gathers a view."""
+        return 0
+
+    def keys_read(self, positions, n_steps: int) -> Optional[int]:
+        """0: a step reads the rows' state, and no key."""
+        return 0
+
+    def _segment_fn(self, n_steps: int, greedy: bool):
+        fn = self._segments.get((n_steps, greedy))
+        if fn is None:
+            cfg = self.cfg
+            fn = self._jit_segment(
+                n_steps, greedy, lambda p, c, tokens, temps, key, live: (
+                    retention.decode_segment(
+                        p, c, tokens, temps, key, live, cfg, n_steps=n_steps,
+                        greedy=greedy)
+                ))
+        return fn
+
+
 def make_runner(preset: str, *, max_batch: int, max_seq: int = 0,
                 paged: bool = True, kv_block_size: int = 16,
                 kv_attention: str = "gather", quantize: str = "",
                 mesh_axes: Optional[Dict] = None, spec_k: int = 0,
                 spec_candidates: int = 1, spec_tree: bool = False):
     """The runner for ``preset``, by the type of its config: the one place
-    that chooses. A preset of ``hybrid_ssm`` gets a :class:`HybridRunner`
-    and one of ``sparse_window`` a :class:`SparseWindowRunner`, which refuse
-    what they cannot do (``ValueError``, naming the reason); any other name
-    is ``llama.preset``'s."""
+    that chooses. A preset of ``hybrid_ssm`` gets a :class:`HybridRunner`,
+    one of ``sparse_window`` a :class:`SparseWindowRunner` and one of
+    ``retention`` a :class:`RetentionRunner`, which refuse what they cannot
+    do (``ValueError``, naming the reason: a row that holds recurrent state,
+    beside its K/V blocks or in their place, or two kinds of block); any
+    other name is ``llama.preset``'s."""
     cfg = None
-    for family in (hybrid_ssm, sparse_window):
+    for family in (hybrid_ssm, sparse_window, retention):
         try:
             cfg = family.preset(preset)
             break
@@ -874,14 +957,17 @@ def make_runner(preset: str, *, max_batch: int, max_seq: int = 0,
             kv_block_size=kv_block_size, kv_attention=kv_attention,
             quantize=quantize, mesh_axes=mesh_axes, spec_k=spec_k,
             spec_candidates=spec_candidates, spec_tree=spec_tree)
-    hybrid = isinstance(cfg, hybrid_ssm.HybridConfig)
-    if hybrid:
-        holds = "holds recurrent state beside its K/V blocks"
-        draft = ("would have to roll the recurrent state back, and only K/V "
-                 "blocks can be freed in place")
-    else:
+    if isinstance(cfg, sparse_window.SparseWindowConfig):
+        runner = SparseWindowRunner
         holds = "keeps two kinds of K/V block, one a window of the context,"
         draft = "would need window blocks the row has released"
+    else:
+        if isinstance(cfg, hybrid_ssm.HybridConfig):
+            runner, holds = HybridRunner, "holds recurrent state beside its K/V blocks"
+        else:
+            runner, holds = RetentionRunner, "holds recurrent state and no K/V block"
+        draft = ("would have to roll the recurrent state back, and only K/V "
+                 "blocks can be freed in place")
     refused = {
         "kv_layout='contiguous' (and mesh_axes, which forces it)": not paged,
         "kv_attention='blocked'": kv_attention != "gather",
@@ -892,6 +978,5 @@ def make_runner(preset: str, *, max_batch: int, max_seq: int = 0,
         if asked:
             raise ValueError(
                 f"preset {preset!r} {holds} and cannot be served with {what}")
-    runner = HybridRunner if hybrid else SparseWindowRunner
     return runner(preset, max_batch=max_batch, max_seq=max_seq,
                   kv_block_size=kv_block_size)

@@ -282,10 +282,14 @@ class LlamaEngine:
         #: and refuses what takes a prefix to be a list of blocks
         self._state_bytes = int(runner.state_bytes_per_row)
         if self._state_bytes:
+            #: what such a row holds, in a refusal's words
+            self._state_held = "holds recurrent state " + (
+                "beside its K/V blocks" if runner.block_bytes
+                else "and no K/V block")
             if self.role != "colocated":
                 raise ValueError(
-                    f"preset {preset!r} holds recurrent state beside its K/V "
-                    f"blocks: role={self.role!r} hands a prompt over as "
+                    f"preset {preset!r} {self._state_held}: "
+                    f"role={self.role!r} hands a prompt over as "
                     "blocks, and the state would stay behind"
                 )
             if prefix_cache_mb > 0:
@@ -354,7 +358,12 @@ class LlamaEngine:
             from kubedl_tpu.serving.speculative import SpecStats, make_draft
 
             mb = self.max_seq // self.kv_block_size
-            if kv_blocks:
+            if not runner.block_bytes:
+                # a row of this model owns no block (it is recurrent state
+                # and nothing else): no pool, whatever ``kv_blocks`` asks;
+                # rows bound admission and ``max_seq`` bounds positions only
+                nb = 0
+            elif kv_blocks:
                 nb = int(kv_blocks)
                 if nb < mb + 1:
                     raise ValueError(
@@ -1058,14 +1067,19 @@ class LlamaEngine:
         rejection, preemption, vacation) are just mirror edits."""
         import numpy as np
 
-        from kubedl_tpu.serving.kv_blocks import BlockAllocator, WindowTable
+        from kubedl_tpu.serving.kv_blocks import (
+            BlockAllocator, NoBlocks, WindowTable)
 
         bs = self.kv_block_size
+        # no pool (a runner whose rows own no block): reserve, trim and free
+        # find nothing to do, and the table has no column
         self._alloc = BlockAllocator(
             self.kv_blocks, bs, low_watermark=low, high_watermark=high,
-        )
+        ) if self.kv_blocks else NoBlocks(bs)
         self._pos_host = np.zeros((self.max_batch,), np.int32)
-        self._bt_host = np.zeros((self.max_batch, self.max_seq // bs), np.int32)
+        self._bt_host = np.zeros(
+            (self.max_batch, self.max_seq // bs if self.kv_blocks else 0),
+            np.int32)
         self._row_blocks: list = [[] for _ in range(self.max_batch)]
         #: the windowed pool's allocator, table mirror and rows' ranges
         #: (None: the runner has one kind of block). No watermarks: the
@@ -1534,8 +1548,8 @@ class LlamaEngine:
         state is more than that, and the state would stay behind."""
         if self._state_bytes:
             raise ValueError(
-                f"preset {self.preset_name!r} holds recurrent state beside "
-                "its K/V blocks: a block hand-off would leave it behind"
+                f"preset {self.preset_name!r} {self._state_held}: a block "
+                "hand-off would leave it behind"
             )
         if self._window:
             raise ValueError(

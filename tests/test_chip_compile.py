@@ -362,3 +362,70 @@ def test_sparse_window_programs_fit_the_chip(on_chip, program, monkeypatch):
     assert not big, big[:2]
     # a chunk's tokens are multiplied grouped; a decode step's 16 by every expert
     assert ("ragged-dot" in text) == (program == "prefill_from")
+
+
+@pytest.mark.parametrize("program", ["decode_segment", "prefill_from"])
+def test_retention_programs_fit_the_chip(on_chip, program, monkeypatch):
+    """The retention runner's own programs at the cell's size
+    (``brumby-14b-base-l8``: 8 of Brumby-14B-Base's 40 layers at published
+    widths, 16 rows): 8.40 GB of weights and 4.40 GB of state, all a row owns.
+    What the compiler needs for arguments and temporaries stays under the
+    15 GiB a 16 GB chip leaves a program, and no program copies the slab
+    array or makes another of its shape.
+
+    The decode segment is compiled as a TPU process traces it: the state goes
+    whole to the kernel ``retention_step_rows``, one custom call inside the
+    layer loop inside the step loop, aliased in and out. The suffix prefill
+    holds both of its first chunk's arms (a fresh row reads no state), and
+    ``phi`` of a chunk's queries stands for one key group at a time."""
+    import dataclasses
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from kubedl_tpu.models import retention
+    from kubedl_tpu.ops import power_retention
+    from kubedl_tpu.serving.model_runner import RetentionRunner
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(retention.BRUMBY_14B_BASE, n_layers=8)
+    monkeypatch.setitem(retention.PRESETS, "brumby-14b-base-l8", cfg)
+    B = 16
+    runner = RetentionRunner("brumby-14b-base-l8", max_batch=B, max_seq=8192)
+    assert runner.block_bytes == 0 and runner.state_bytes_per_row == 8 * 8 * 8320 * 129 * 4
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: on_chip(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: retention.retention_init(jax.random.PRNGKey(0), cfg)))
+    cache = place(jax.eval_shape(lambda: retention.init_cache(cfg, B)))
+    assert set(cache) == {"pos", "S", "z"}  # no pool, no block table
+    i32 = lambda *s: on_chip(s, jnp.int32)  # noqa: E731
+    if program == "decode_segment":
+        lowered = runner._segment_fn(4, True).lower(
+            params, cache, i32(B, 1), on_chip((B,), jnp.float32),
+            on_chip((2,), jnp.uint32), on_chip((B,), jnp.bool_))
+    else:
+        lowered = runner._prefill_from.lower(
+            params, cache, i32(1, 1024), i32(1), i32(1), i32(1),
+            on_chip((B, cfg.vocab_size), jnp.float32))
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    print(program, "arguments", mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * 2**30
+    text = compiled.as_text().splitlines()
+    state = "f32[%d,%d,%d,%d,%d,%d]" % cache["S"].shape
+    # a prefill program writes its row's slab of a layer back in place: one
+    # fusion of the array's shape, aliased to its operand, and no copy
+    kinds = "copy|fusion" if program == "decode_segment" else "copy"
+    made = [ln for ln in text if re.match(
+        rf"\s*(ROOT )?%\S+ = {re.escape(state)}\S* ({kinds})\(", ln)]
+    assert not made, made[:2]
+    if program == "decode_segment":
+        assert power_retention.step_kernel_fits(cache["S"])
+        calls = [ln for ln in text if power_retention.STEP_KERNEL_NAME in ln
+                 and "custom_call_target=\"tpu_custom_call\"" in ln]
+        assert len(calls) == 1, calls
+        assert re.search(r"/while/body/.*/while/body/.*pallas_call", calls[0]), calls[0]
+        assert "output_to_operand_aliasing" in calls[0], calls[0]
