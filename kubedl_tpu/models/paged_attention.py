@@ -131,6 +131,7 @@ def _lax_paged_attention(
     tile: int,
     self_mask: Optional[jax.Array] = None,  # [B, S, S] bool (tree verify)
     layer: Optional[jax.Array] = None,  # with whole [L, NB, BS, KV, hd] pools
+    window: int = 0,  # > 0: a query sees only keys t > posq - window
 ) -> jax.Array:
     TRACE_COUNT["lax"] += 1
     B, S, H, hd = q.shape
@@ -160,6 +161,8 @@ def _lax_paged_attention(
         t = c * (C * BS) + jnp.arange(C * BS)
         if self_k is None:
             valid = t[None, None, :] <= posq[:, :, None]  # [B, S, C*BS]
+            if window:
+                valid &= t[None, None, :] > posq[:, :, None] - window
         else:
             # read-only mode: pool keys are committed history only
             valid = jnp.broadcast_to(
@@ -210,7 +213,7 @@ def _decode_kernel(
     rows_ref, cbs_ref,  # SMEM [B * NC]: the work list
     acc_ref, m_ref, l_ref,  # the running softmax of the row in hand
     *, scale: float, heads: int, n_kv: int, block_size: int, chunk: int,
-    table: int, pool_blocks: int, max_s: int,
+    table: int, pool_blocks: int, max_s: int, window: int = 0,
 ):
     """Every scheduled row's attention over the blocks it holds, in ONE
     invocation. ``len_ref[b]`` is how many keys row b attends (0: not
@@ -293,6 +296,8 @@ def _decode_kernel(
         qpos = jnp.minimum(start_ref[row] + r // heads, max_s - 1)
         visible = (cb * CK + w // KV <= qpos) & (
             w % KV == (r % heads) // group)
+        if window:  # a window layer's query sees the last ``window`` keys
+            visible &= cb * CK + w // KV > qpos - window
         s = lax.dot_general(
             q_ref[row], kbuf[slot], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -353,6 +358,7 @@ def _pallas_paged_attention(
     live: Optional[jax.Array] = None,  # [B] bool: rows to attend (None: all)
     tile: int = DEFAULT_TILE,
     interpret: bool = False,
+    window: int = 0,
 ) -> jax.Array:
     from jax.experimental.pallas import tpu as pltpu
 
@@ -384,6 +390,7 @@ def _pallas_paged_attention(
     kernel = functools.partial(
         _decode_kernel, scale=1.0 / math.sqrt(hd), heads=H, n_kv=KV,
         block_size=BS, chunk=C, table=MB, pool_blocks=NB, max_s=max_s,
+        window=int(window),
     )
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -449,6 +456,7 @@ def paged_attention(
     kernel: str = "auto",  # "auto" | "lax" | "pallas"
     tile: int = DEFAULT_TILE,
     interpret: bool = False,
+    window: int = 0,  # > 0: keys ``t > posq - window`` only (a window layer)
 ):
     """Blocked paged attention over the pool — returns [B, S, H, hd],
     or ``(out, k_pool, v_pool)`` when ``new_k``/``new_v`` carry the
@@ -470,6 +478,13 @@ def paged_attention(
     zeros, its write goes to the trash block), and the pallas kernel
     fetches nothing for it.
 
+    ``window`` > 0 narrows what a query sees to its last ``window`` keys
+    (its own among them), on either kernel; the table may then be a RUN of
+    a row's blocks that begins at any block, with ``starts`` counted from
+    that block's first key: the masks compare differences of positions only
+    (``models/sparse_window.py`` hands a window layer's decode step the run
+    that ends at the row's position, so the kernel fetches that and no more).
+
     ``kernel="auto"`` is the compiled pallas kernel on a TPU for one query
     a row (the decode step) and the lax scan elsewhere; a kernel the TPU
     compiler refuses raises, nothing falls back. ``interpret=True``
@@ -483,6 +498,8 @@ def paged_attention(
         raise ValueError(f"unknown paged-attention kernel {kernel!r}")
     if self_mask is not None and self_k is None:
         raise ValueError("self_mask requires self_k/self_v")
+    if window and self_k is not None:
+        raise ValueError("window excludes self_k/self_v")
     if (layer is None) != (k_pool.ndim == 4):
         raise ValueError("layer goes with whole [L, NB, BS, KV, hd] pools")
     fused = new_k is not None
@@ -500,12 +517,12 @@ def paged_attention(
     if kernel == "pallas" and self_k is None:
         out = _pallas_paged_attention(
             q, k_pool, v_pool, bt, starts, layer, live, tile,
-            interpret=interpret,
+            interpret=interpret, window=window,
         )
     else:
         out = _lax_paged_attention(
             q, k_pool, v_pool, bt, starts, self_k, self_v, tile,
-            self_mask=self_mask, layer=layer,
+            self_mask=self_mask, layer=layer, window=window,
         )
         if live is not None:
             out = jnp.where(live[:, None, None, None], out, 0)

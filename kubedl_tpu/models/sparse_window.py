@@ -48,6 +48,32 @@ place under donation. The grouped products take the WHOLE stack of expert
 weights, seen as ``n_layers * n_experts`` groups of which all but one layer's
 are empty: the layer is in the group sizes, as ``llama._paged_view`` has it in
 the gather's index, so no program slices a layer's experts out.
+
+The block is a setting, not a copy (every default is the block above):
+``norm`` ``"layer"`` centres on the mean before it scales (a weight, no bias);
+``parallel_block`` feeds ONE norm to attention and to the expert layer and
+adds both to the stream at once, ``h = h + attn(n) + moe(n) + shared(n)``;
+``router_score`` ``"sigmoid"`` scores each expert on its own before the top-k
+and its renormalisation; ``n_shared`` experts of width ``shared_ffn`` see every
+token, side by side as one product, their outputs averaged
+(``shared_average``) or summed; a :class:`Rope`'s ``form`` is ``"half"``,
+``"interleaved"`` (pairs ``(2i, 2i + 1)``) or ``"none"`` (no positions at all);
+``tied_head`` multiplies by the embedding's transpose, times ``logit_scale``.
+``experts_held`` says that the weight stacks hold only experts
+``[expert_first, expert_first + experts_held)`` of the ``n_experts`` the router
+scores: one chip's share of a layer that several chips divide by experts. An
+assignment to an expert that is not here adds nothing, here or in the
+reference; no code stands in for the chips that hold the others.
+
+The blocked arm (``init_cache(..., blocked=True)``; the programs tell it by
+the pools' five axes, ``[n, NB, BS, KV, hd]``, the layout ``paged_attention``
+reads as it stands): no program gathers a view. A prefill program folds the
+keys in tiles of :data:`PREFILL_TILE` with an online softmax (a full layer
+over the row's live blocks, a window layer over the fixed run of blocks that
+ends at the chunk), so scores are never whole in memory; a decode step
+attends through ``paged_attention`` in both kinds of layer, a window layer
+over the run of blocks that ends at the row's position. On a TPU that is the
+Pallas kernel, which fetches each scheduled row's own blocks.
 """
 
 from __future__ import annotations
@@ -62,7 +88,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from kubedl_tpu.models import llama
+from kubedl_tpu.models import llama, paged_attention
 from kubedl_tpu.models.hybrid_ssm import _at, _attention_one_query, _heads, _view
 
 Params = Dict[str, Any]
@@ -71,7 +97,9 @@ Params = Dict[str, Any]
 @dataclass(frozen=True)
 class Rope:
     """One kind's rotary table. ``factor`` 1 is the plain table; above 1 it is
-    YaRN over ``original_max`` positions."""
+    YaRN over ``original_max`` positions. ``form``: which dimensions make a
+    pair, ``"half"`` (``d`` and ``d + hd / 2``) or ``"interleaved"`` (``2i`` and
+    ``2i + 1``); ``"none"``: the kind's q and k are not rotated at all."""
 
     theta: float = 500000.0
     factor: float = 1.0
@@ -79,6 +107,7 @@ class Rope:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+    form: str = "half"
 
 
 @dataclass(frozen=True)
@@ -101,6 +130,34 @@ class SparseWindowConfig:
     norm_eps: float = 1e-6
     max_seq: int = 131072
     dtype: Any = jnp.bfloat16
+    #: ``"rms"``, or ``"layer"``: mean-centred, a weight and no bias
+    norm: str = "rms"
+    #: one norm a layer feeds attention and the expert layer, one add
+    parallel_block: bool = False
+    #: ``"softmax"`` over all experts, or ``"sigmoid"`` of each, before top-k
+    router_score: str = "softmax"
+    #: experts every token passes through, each ``shared_ffn`` wide, their
+    #: outputs averaged (``shared_average``) or summed
+    n_shared: int = 0
+    shared_ffn: int = 0
+    shared_average: bool = True
+    #: logits are ``logit_scale * norm(h) @ embed^T``: no ``lm_head`` leaf
+    tied_head: bool = False
+    logit_scale: float = 1.0
+    #: the weight stacks hold experts ``[expert_first, expert_first +
+    #: experts_held)`` of the ``n_experts`` the router scores; 0: all of them
+    expert_first: int = 0
+    experts_held: int = 0
+
+    @property
+    def held(self) -> int:
+        """Experts the weight stacks hold."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def shared_width(self) -> int:
+        """The shared experts side by side: the width of their one product."""
+        return self.n_shared * self.shared_ffn
 
     @property
     def windows_per_period(self) -> int:
@@ -125,9 +182,12 @@ class SparseWindowConfig:
     def num_params(self) -> int:
         attn = 2 * self.dim * self.n_heads * self.head_dim \
             + 2 * self.dim * self.n_kv_heads * self.head_dim
-        moe = self.dim * self.n_experts + self.n_experts * 3 * self.dim * self.expert_ffn
-        return (self.n_layers * (attn + moe + 2 * self.dim)
-                + 2 * self.vocab_size * self.dim + self.dim)
+        moe = (self.dim * self.n_experts + self.held * 3 * self.dim * self.expert_ffn
+               + 3 * self.dim * self.shared_width)
+        norms = 1 if self.parallel_block else 2
+        heads = 1 if self.tied_head else 2
+        return (self.n_layers * (attn + moe + norms * self.dim)
+                + heads * self.vocab_size * self.dim + self.dim)
 
 
 def pattern_of(layer_types: Sequence[str]) -> Tuple[int, Tuple[str, ...]]:
@@ -157,7 +217,34 @@ TINY_SPARSE = SparseWindowConfig(
     max_seq=256, dtype=jnp.float32,
 )
 
-PRESETS = {"mellum2-12b-a2.5b": MELLUM2_12B, "tiny-sparse": TINY_SPARSE}
+#: command-a-plus-05-2026 (huggingface.co/CohereLabs/command-a-plus-05-2026,
+#: config.json, ``cohere2_moe``) at published widths, ONE chip's share of the
+#: deployment in which 8 chips divide each layer by experts: one period of the
+#: pattern (4 of 32 layers), experts 0-15 of 128, an eighth of the vocabulary
+#: (32,768 of 262,144 rows). A parallel block on a mean-centring norm, 128
+#: query heads, sigmoid routing, four averaged shared experts; the window
+#: layers rotate interleaved pairs, the full layers know no positions.
+COMMAND_A_PLUS_L4 = SparseWindowConfig(
+    vocab_size=32768, dim=4096, periods=1, n_heads=128, n_kv_heads=8, head_dim=128,
+    window=4096, n_experts=128, top_k=8, expert_ffn=4096,
+    rope_window=Rope(theta=50000.0, form="interleaved"), rope_full=Rope(form="none"),
+    norm_eps=1e-5, max_seq=200000, norm="layer", parallel_block=True,
+    router_score="sigmoid", n_shared=4, shared_ffn=4096, tied_head=True,
+    experts_held=16,
+)
+#: CPU-test size of the same block: a window of 32 keys, 2 of 8 experts held
+#: (the second pair: ``expert_first`` 2), two shared experts
+TINY_PARALLEL = SparseWindowConfig(
+    vocab_size=256, dim=64, periods=2, period=("window", "window", "full"),
+    n_heads=4, n_kv_heads=2, head_dim=16, window=32, n_experts=8, top_k=2,
+    expert_ffn=32, rope_window=Rope(theta=10000.0, form="interleaved"),
+    rope_full=Rope(form="none"), norm_eps=1e-5, max_seq=256, dtype=jnp.float32,
+    norm="layer", parallel_block=True, router_score="sigmoid", n_shared=2,
+    shared_ffn=32, tied_head=True, logit_scale=0.5, expert_first=2, experts_held=2,
+)
+
+PRESETS = {"mellum2-12b-a2.5b": MELLUM2_12B, "tiny-sparse": TINY_SPARSE,
+           "command-a-plus-05-2026-l4": COMMAND_A_PLUS_L4, "tiny-parallel": TINY_PARALLEL}
 
 
 def preset(name: str) -> SparseWindowConfig:
@@ -181,7 +268,7 @@ def sparse_init(key: jax.Array, cfg: SparseWindowConfig) -> Params:
                 "wo": dense(next(k), (n, Hq, D), Hq)}
 
     L = cfg.n_layers
-    return {
+    params = {
         "embed": dense(next(k), (V, D), D),
         "lm_head": dense(next(k), (D, V), D),
         "final_norm": jnp.ones((D,), dt_),
@@ -191,34 +278,50 @@ def sparse_init(key: jax.Array, cfg: SparseWindowConfig) -> Params:
             "norm": jnp.ones((L, D), dt_),
             "router": dense(next(k), (L, D, E), D),
             # gate then up, side by side: one grouped product makes both
-            "w_in": dense(next(k), (L, E, D, 2 * F), D),
-            "w_out": dense(next(k), (L, E, F, D), F),
+            "w_in": dense(next(k), (L, cfg.held, D, 2 * F), D),
+            "w_out": dense(next(k), (L, cfg.held, F, D), F),
         },
     }
+    if cfg.tied_head:
+        del params["lm_head"]
+    if cfg.parallel_block:
+        del params["moe"]["norm"]  # the attention leaves' norm is the layer's one
+    if cfg.n_shared:
+        W = cfg.shared_width  # every shared expert's gate, then every one's up
+        k_in, k_out = jax.random.split(jax.random.fold_in(key, 1))
+        params["moe"]["shared_in"] = dense(k_in, (L, D, 2 * W), D)
+        params["moe"]["shared_out"] = dense(k_out, (L, W, D), cfg.shared_ffn)
+    return params
 
 
 def init_cache(cfg: SparseWindowConfig, batch: int, max_seq: int, num_blocks: int,
-               window_blocks: int, block_size: int) -> Params:
+               window_blocks: int, block_size: int, blocked: bool = False) -> Params:
     """The two pools, zeroed, ``pos``, the two block tables (every entry at
-    its pool's trash block) and ``expert_tokens [n_layers, n_experts]``: the
-    kept tokens each expert has computed in the prefill programs since the
-    last decode segment, which takes the count over and hands it out with
-    its own (:func:`decode_segment`)."""
+    its pool's trash block) and ``expert_tokens [n_layers, held]``: the
+    kept tokens each held expert has computed in the prefill programs since
+    the last decode segment, which takes the count over and hands it out with
+    its own (:func:`decode_segment`); where the stacks hold a share of the
+    experts, ``assign_all`` beside it: every kept assignment those programs
+    routed, to an expert here or not. ``blocked``: the pools of the blocked
+    arm, a block ``[BS, KV, hd]``."""
     if max_seq % block_size or cfg.window % block_size:
         raise ValueError(f"max_seq {max_seq} and window {cfg.window} must be whole "
                          f"blocks of {block_size}")
-    W = cfg.n_kv_heads * cfg.head_dim
+    W = (cfg.n_kv_heads, cfg.head_dim) if blocked else (cfg.n_kv_heads * cfg.head_dim,)
     table = (batch, max_seq // block_size)
-    return {
-        "k": jnp.zeros((cfg.n_full, num_blocks, block_size, W), cfg.dtype),
-        "v": jnp.zeros((cfg.n_full, num_blocks, block_size, W), cfg.dtype),
-        "wk": jnp.zeros((cfg.n_window, window_blocks, block_size, W), cfg.dtype),
-        "wv": jnp.zeros((cfg.n_window, window_blocks, block_size, W), cfg.dtype),
+    cache = {
+        "k": jnp.zeros((cfg.n_full, num_blocks, block_size, *W), cfg.dtype),
+        "v": jnp.zeros((cfg.n_full, num_blocks, block_size, *W), cfg.dtype),
+        "wk": jnp.zeros((cfg.n_window, window_blocks, block_size, *W), cfg.dtype),
+        "wv": jnp.zeros((cfg.n_window, window_blocks, block_size, *W), cfg.dtype),
         "pos": jnp.zeros((batch,), jnp.int32),
         "bt": jnp.zeros(table, jnp.int32),
         "wbt": jnp.zeros(table, jnp.int32),
-        "expert_tokens": jnp.zeros((cfg.n_layers, cfg.n_experts), jnp.int32),
+        "expert_tokens": jnp.zeros((cfg.n_layers, cfg.held), jnp.int32),
     }
+    if cfg.experts_held:
+        cache["assign_all"] = jnp.zeros((), jnp.int32)
+    return cache
 
 
 # ---- rotary tables ---------------------------------------------------------
@@ -251,8 +354,20 @@ def _rope_at(rope: Rope, head_dim: int, posq: jax.Array):
     return jnp.cos(ang) * rope.attention_factor, jnp.sin(ang) * rope.attention_factor
 
 
-def _rotate(t: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """``llama.apply_rope`` (rotate-half) with a table a row: ``t [B, S, H, hd]``."""
+def _rotate(t: jax.Array, cos: jax.Array, sin: jax.Array, form: str = "half") -> jax.Array:
+    """``llama.apply_rope`` (rotate-half) with a table a row: ``t [B, S, H, hd]``.
+    ``form`` ``"interleaved"`` pairs dimension ``2i`` with ``2i + 1`` instead
+    of ``d`` with ``d + hd / 2``, frequency ``i`` for pair ``i`` either way."""
+    if form == "interleaved":
+        # each lane's partner is its neighbour, brought over by a roll: the
+        # head dimension is never split into (pair, 2), a reshape that XLA
+        # moves through the projection into its weight, which it then lays
+        # out anew every step (PERF.md section 6, PR 45)
+        x = t.astype(jnp.float32)
+        even = jnp.arange(t.shape[-1]) % 2 == 0
+        partner = jnp.where(even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
+        return (x * jnp.repeat(cos, 2, axis=-1)
+                + partner * jnp.repeat(sin, 2, axis=-1)).astype(t.dtype)
     t1, t2 = jnp.split(t.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([t1 * cos - t2 * sin, t1 * sin + t2 * cos], axis=-1).astype(t.dtype)
 
@@ -261,10 +376,13 @@ def _rotate(t: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 def route(x: jax.Array, router: jax.Array, cfg: SparseWindowConfig):
     """``(experts [T, top_k], gates [T, top_k])`` of ``x [T, D]``: softmax in
-    float32 over every expert, the ``top_k`` largest, renormalised to sum 1."""
+    float32 over every expert (or each expert's own sigmoid:
+    ``cfg.router_score``), the ``top_k`` largest, renormalised to sum 1."""
     logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
-    top_p, top_e = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+    score = jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid" else jax.nn.softmax(
+        logits, axis=-1)
+    top_p, top_e = lax.top_k(score, cfg.top_k)
     return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
 
@@ -289,6 +407,7 @@ def _few_tokens(x, moe, layer, top_e, gates, held, cfg, first, count):
     hit = held[:, :, None] & (
         top_e[:, :, None] == first + jnp.arange(count)[None, None, :])
     gate = jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1)  # [T, count]
+    first = first - cfg.expert_first  # from here on, where the stacks hold it
     h = jnp.einsum("td,edf->tef", x, w_in[first:first + count])
     act = jax.nn.silu(h[..., :F].astype(jnp.float32)) * h[..., F:].astype(jnp.float32)
     act = (act * gate[:, :, None]).astype(x.dtype)
@@ -297,13 +416,48 @@ def _few_tokens(x, moe, layer, top_e, gates, held, cfg, first, count):
     return y, jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
 
 
+def _share_rows(assignments: int, count: int, cfg: SparseWindowConfig) -> int:
+    """How many of a chunk's ``assignments``, ordered with those on the
+    ``count`` experts in hand first, the grouped products take at first:
+    twice what an even router sends to ``count`` of ``n_experts``, in whole
+    tiles of 512 rows; all of them where every expert is held."""
+    if not cfg.experts_held:
+        return assignments
+    even = 2 * assignments * count // cfg.n_experts
+    return min(assignments, -(-max(even, 1) // 512) * 512)
+
+
+def _grouped(x, moe, sizes, order, n, held, gates, cfg):
+    """The grouped products of :func:`expert_layer` over the first ``n``
+    assignments of ``order`` (the order by expert), summed back a token:
+    ``y [T, D]`` float32. An assignment past the first ``n`` adds nothing."""
+    T, D = x.shape
+    K, F = cfg.top_k, cfg.expert_ffn
+    take = order if n == T * K else order[:n]
+    xs = x[take // K]  # [n, D]: each assignment's token, by expert
+    h = lax.ragged_dot(xs, moe["w_in"].reshape(-1, D, 2 * F), sizes)
+    act = jax.nn.silu(h[:, :F].astype(jnp.float32)).astype(x.dtype) * h[:, F:]
+    out = lax.ragged_dot(act, moe["w_out"].reshape(-1, F, D), sizes,
+                         preferred_element_type=jnp.float32)
+    weight = jnp.where(held, gates, 0.0).reshape(T * K)[take]
+    out = jnp.where(weight[:, None] > 0, out * weight[:, None], 0.0)
+    back = jnp.zeros((T * K,), jnp.int32).at[order].set(jnp.arange(T * K, dtype=jnp.int32))
+    if n < T * K:  # row n: nothing, for the assignments past it
+        out = jnp.concatenate([out, jnp.zeros((1, D), out.dtype)])
+        back = jnp.minimum(back, n)
+    return jnp.sum(out[back].reshape(T, K, D), axis=1)
+
+
 def expert_layer(x: jax.Array, moe: Params, layer, kept: jax.Array,
-                 cfg: SparseWindowConfig, first: int = 0,
+                 cfg: SparseWindowConfig, first: Optional[int] = None,
                  count: Optional[int] = None) -> Tuple[jax.Array, jax.Array]:
     """The part of layer ``layer``'s expert output that experts ``[first,
-    first + count)`` give (all of them by default), for ``x [T, D]`` (normed):
-    ``(y [T, D] float32, load [count])``, ``load`` the kept tokens each held
-    expert computed. ``moe`` is the WHOLE stacked tree; ``layer`` may be traced.
+    first + count)`` give (by default all that the stacks hold: every expert,
+    or the share ``cfg.experts_held`` names), for ``x [T, D]`` (normed):
+    ``(y [T, D] float32, load [count])``, ``load`` the kept tokens each of
+    those experts computed. ``moe`` is the WHOLE stacked tree, its expert
+    axis the held ones; ``layer`` may be traced. ``first`` counts among all
+    ``n_experts``, as the router does.
 
     Every token routes over all ``n_experts``; an assignment counts where its
     token is ``kept`` and its expert is held. Many tokens are ordered by
@@ -312,8 +466,9 @@ def expert_layer(x: jax.Array, moe: Params, layer, kept: jax.Array,
     (:data:`DENSE_BELOW`) are multiplied by every held expert under their
     gates."""
     T, D = x.shape
-    E, K, F = cfg.n_experts, cfg.top_k, cfg.expert_ffn
-    count = E - first if count is None else count
+    E, K, F = cfg.held, cfg.top_k, cfg.expert_ffn
+    first = cfg.expert_first if first is None else first
+    count = cfg.expert_first + E - first if count is None else count
     router = lax.dynamic_index_in_dim(moe["router"], layer, 0, keepdims=False)
     top_e, gates = route(x, router, cfg)
     held = kept[:, None] & (top_e >= first) & (top_e < first + count)
@@ -325,23 +480,48 @@ def expert_layer(x: jax.Array, moe: Params, layer, kept: jax.Array,
     # the layer's groups among the whole stack's: every other group is empty
     L = moe["w_in"].shape[0]
     sizes = lax.dynamic_update_slice(
-        jnp.zeros((L * E,), jnp.int32), load, (layer * E + first,))
-    xs = x[order // K]  # [T * K, D]: each assignment's token, by expert
-    h = lax.ragged_dot(xs, moe["w_in"].reshape(L * E, D, 2 * F), sizes)
-    act = jax.nn.silu(h[:, :F].astype(jnp.float32)).astype(x.dtype) * h[:, F:]
-    out = lax.ragged_dot(act, moe["w_out"].reshape(L * E, F, D), sizes,
-                         preferred_element_type=jnp.float32)
-    weight = jnp.where(held, gates, 0.0).reshape(T * K)[order]
-    out = jnp.where(weight[:, None] > 0, out * weight[:, None], 0.0)
-    back = jnp.zeros((T * K,), jnp.int32).at[order].set(jnp.arange(T * K, dtype=jnp.int32))
-    return jnp.sum(out[back].reshape(T, K, D), axis=1), load
+        jnp.zeros((L * E,), jnp.int32), load, (layer * E + first - cfg.expert_first,))
+    rows = _share_rows(T * K, count, cfg)
+    if rows == T * K:
+        return _grouped(x, moe, sizes, order, rows, held, gates, cfg), load
+    # a share: most assignments route to experts that are not here, and they
+    # stand last in the order. The grouped products take the first ``rows``
+    # assignments (twice what an even router sends here) where those hold
+    # every one that counts, and all of them where they do not: nothing is
+    # dropped either way
+    def part(n):
+        return _grouped(x, moe, sizes, order, n, held, gates, cfg)
+
+    return lax.cond(jnp.sum(load) <= rows, lambda: part(rows), lambda: part(T * K)), load
 
 
 # ---- the layers ------------------------------------------------------------
 
 def _norm(x: jax.Array, w: jax.Array, cfg: SparseWindowConfig) -> jax.Array:
-    """``rmsnorm`` of the float32 stream, in the type the weights multiply."""
+    """``rmsnorm`` of the float32 stream (``cfg.norm`` ``"layer"``: centred on
+    its mean first, a weight and no bias), in the type the weights multiply."""
+    if cfg.norm == "layer":
+        x = x.astype(jnp.float32)
+        x = x - jnp.mean(x, axis=-1, keepdims=True)
+        x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + cfg.norm_eps)
+        return (x * w.astype(jnp.float32)).astype(cfg.dtype)
     return llama.rmsnorm(x, w, cfg.norm_eps).astype(cfg.dtype)
+
+
+def shared_experts(x: jax.Array, moe: Params, layer, cfg: SparseWindowConfig) -> jax.Array:
+    """What layer ``layer``'s ``n_shared`` experts give every token of ``x [T,
+    D]`` (normed), float32: side by side they are ONE gated product of width
+    ``shared_width``, whose output product sums over experts and width at
+    once. Their mean is that sum over ``n_shared``, taken on the activations
+    in float32 (a weight scaled instead would be rounded anew)."""
+    W = cfg.shared_width
+    w_in = lax.dynamic_index_in_dim(moe["shared_in"], layer, 0, keepdims=False)
+    w_out = lax.dynamic_index_in_dim(moe["shared_out"], layer, 0, keepdims=False)
+    h = x @ w_in
+    act = jax.nn.silu(h[:, :W].astype(jnp.float32)) * h[:, W:].astype(jnp.float32)
+    if cfg.shared_average:
+        act = act / cfg.n_shared
+    return _out(act.astype(x.dtype), w_out)
 
 
 def _out(a: jax.Array, w: jax.Array) -> jax.Array:
@@ -353,6 +533,17 @@ def _qkv(h: jax.Array, lp: Params, rope: Rope, posq: jax.Array, cfg: SparseWindo
     """``q [B, S, H, hd]`` and ``k``, ``v`` ``[B, S, KV * hd]`` as the pools
     store them, q and k rotated by ``rope`` at ``posq``."""
     B, S, _ = h.shape
+    if rope.form != "half":
+        # the projection's output stands whole before it is split into heads:
+        # XLA otherwise moves the split through the product into ``wq``, and a
+        # decode step's 16 rows then pay for the weight laid out anew, 134 MB
+        # read and written a layer (PERF.md section 6, PR 45)
+        q = lax.optimization_barrier(h @ lp["wq"]).reshape(B, S, cfg.n_heads, cfg.head_dim)
+        if rope.form == "none":  # the kind knows no positions
+            return q, h @ lp["wk"], h @ lp["wv"]
+        cos, sin = _rope_at(rope, cfg.head_dim, posq)
+        k = _rotate(_heads(h @ lp["wk"], cfg), cos, sin, rope.form)
+        return _rotate(q, cos, sin, rope.form), k.reshape(B, S, -1), h @ lp["wv"]
     cos, sin = _rope_at(rope, cfg.head_dim, posq)
     q = _rotate((h @ lp["wq"]).reshape(B, S, cfg.n_heads, cfg.head_dim), cos, sin)
     k = _rotate(_heads(h @ lp["wk"], cfg), cos, sin)
@@ -425,6 +616,78 @@ def _attend_window(q: jax.Array, wkp: jax.Array, wvp: jax.Array, w, wbt: jax.Arr
     return _attention(q, _view(wkp, w, blocks), _view(wvp, w, blocks), mask, cfg)
 
 
+#: keys a step of the blocked arm's prefill fold takes: the float32 scores
+#: that exist at once are ``heads x queries x PREFILL_TILE``, 256 MiB at 128
+#: heads and a chunk of 1024 where the gathered view's are ``x span``
+PREFILL_TILE = 512
+
+
+def _fold_keys(q: jax.Array, kp: jax.Array, vp: jax.Array, index, table: jax.Array,
+               posq: jax.Array, first: jax.Array, n_tiles, window: int,
+               cfg: SparseWindowConfig) -> jax.Array:
+    """Attention of ``q [B, S, H, hd]`` at ``posq [B, S]`` over ``n_tiles``
+    (traced, or a Python int) tiles of :data:`PREFILL_TILE` keys, read from
+    layer ``index`` of the blocked pools ``[n, NB, BS, KV, hd]`` through each
+    row's ``table [B, MB]`` from its block ``first [B]`` on, and folded into
+    an online softmax: scores exist a tile at a time, float32 as the product
+    accumulates them. A query sees keys ``j <= i`` and, with ``window``, ``j >
+    i - window``; a block before the table's start or past its end is the
+    trash block, masked. The recurrence of ``paged_attention``'s lax arm,
+    with a run that may begin anywhere and end early."""
+    B, S, H, hd = q.shape
+    n, NB, BS, KV, _ = kp.shape
+    MB = table.shape[1]
+    C = PREFILL_TILE // BS
+    G = H // KV
+    kf = kp.reshape(n * NB, BS, KV, hd)
+    vf = vp.reshape(n * NB, BS, KV, hd)
+    qg = q.reshape(B, S, KV, G, hd).transpose(0, 2, 3, 1, 4)  # [B, KV, G, S, hd]
+    scale = 1.0 / math.sqrt(hd)
+
+    def tile(j, carry):
+        at = first[:, None] + j * C + jnp.arange(C)[None, :]  # [B, C] table entries
+        inside = (at >= 0) & (at < MB)
+        blocks = index * NB + jnp.where(inside, jnp.take_along_axis(
+            table, jnp.clip(at, 0, MB - 1), axis=1), 0)
+        kb = kf[blocks].reshape(B, C * BS, KV, hd)
+        vb = vf[blocks].reshape(B, C * BS, KV, hd)
+        t = (at[:, :, None] * BS + jnp.arange(BS)[None, None, :]).reshape(B, 1, C * BS)
+        seen = jnp.repeat(inside, BS, axis=1)[:, None, :] & (t <= posq[:, :, None])
+        if window:
+            seen &= t > posq[:, :, None] - window
+        s = jnp.einsum("bkgsh,btkh->bkgst", qg, kb,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(seen[:, None, None], s, paged_attention.NEG_INF)
+        m, l, acc = carry
+        m_new = jnp.maximum(jnp.maximum(m, s.max(axis=-1)), -1e29)
+        p = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(m - m_new)
+        pv = jnp.einsum("bkgst,btkh->bkgsh", p.astype(vb.dtype), vb,
+                        preferred_element_type=jnp.float32)
+        return m_new, l * corr + p.sum(axis=-1), acc * corr[..., None] + pv
+
+    m0 = jnp.full((B, KV, G, S), -1e29, jnp.float32)
+    m, l, acc = lax.fori_loop(0, n_tiles, tile, (
+        m0, jnp.zeros_like(m0), jnp.zeros((B, KV, G, S, hd), jnp.float32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd).astype(q.dtype)
+
+
+def _run_of_blocks(wbt: jax.Array, pos: jax.Array, BS: int, window: int):
+    """For a window layer's decode step through ``paged_attention``: each
+    row's RUN of table entries that ends at its position, ``(run [B, n],
+    at [B])``, ``at`` the row's position counted from the run's first key. The
+    run begins ``window / BS`` blocks back (at the table's start for a young
+    row) and is 16 entries longer, a whole number of the kernel's compute
+    blocks; entries past the table's end are the trash block, and past the
+    position nothing is read."""
+    MB = wbt.shape[1]
+    first = jnp.maximum(pos // BS - window // BS, 0)
+    at = first[:, None] + jnp.arange(window // BS + 16)[None, :]
+    run = jnp.where(at < MB, jnp.take_along_axis(wbt, jnp.minimum(at, MB - 1), axis=1), 0)
+    return run, pos - first * BS
+
+
 def _run_layers(params: Params, cfg: SparseWindowConfig, x, pools, kept, attn_fn):
     """Every layer in order, one ``lax.scan`` over the periods with the four
     pools as carries. ``attn_fn(kind, h, lp, pools, index) -> (out, pools)``
@@ -432,8 +695,7 @@ def _run_layers(params: Params, cfg: SparseWindowConfig, x, pools, kept, attn_fn
     in float32. ``x [B, S, D]``, ``kept [B, S]``. The residual stream is
     float32 from the embedding to the head: a layer reads it through its
     norm, in the weights' type, and adds to it what its output product
-    accumulated, unrounded. Returns ``(x, pools, load [n_layers,
-    n_experts])``."""
+    accumulated, unrounded. Returns ``(x, pools, load [n_layers, held])``."""
     B, S, D = x.shape
     T = len(cfg.period)
     per = {"window": cfg.windows_per_period, "full": cfg.fulls_per_period}
@@ -446,17 +708,27 @@ def _run_layers(params: Params, cfg: SparseWindowConfig, x, pools, kept, attn_fn
             index = p * per[kind] + seen[kind]
             seen[kind] += 1
             lp = _at(params[kind], index)
-            out, pools = attn_fn(kind, _norm(x, lp["norm"], cfg), lp, pools, index)
-            x = x + out
-            layer = p * T + j
-            h = _norm(x, lax.dynamic_index_in_dim(
-                params["moe"]["norm"], layer, 0, keepdims=False), cfg)
-            y, n = expert_layer(h.reshape(B * S, D), params["moe"], layer, flat, cfg)
-            x = x + y.reshape(B, S, D)  # float32 to float32
+            if cfg.parallel_block:
+                # one norm feeds both branches; one add takes both
+                layer = p * T + j
+                h = _norm(x, lp["norm"], cfg)
+                out, pools = attn_fn(kind, h, lp, pools, index)
+            else:
+                out, pools = attn_fn(kind, _norm(x, lp["norm"], cfg), lp, pools, index)
+                x = x + out
+                layer = p * T + j
+                h = _norm(x, lax.dynamic_index_in_dim(
+                    params["moe"]["norm"], layer, 0, keepdims=False), cfg)
+            h = h.reshape(B * S, D)
+            y, n = expert_layer(h, params["moe"], layer, flat, cfg)
+            if cfg.n_shared:
+                y = y + shared_experts(h, params["moe"], layer, cfg)
+            y = y.reshape(B, S, D)
+            x = x + out + y if cfg.parallel_block else x + y  # float32 to float32
             load = lax.dynamic_update_index_in_dim(load, n, layer, 0)
         return (x, pools, load), None
 
-    load = jnp.zeros((cfg.n_layers, cfg.n_experts), jnp.int32)
+    load = jnp.zeros((cfg.n_layers, cfg.held), jnp.int32)
     return lax.scan(period, (x, pools, load), jnp.arange(cfg.periods, dtype=jnp.int32))[0]
 
 
@@ -467,8 +739,13 @@ def _embed(params: Params, tokens: jax.Array, cfg: SparseWindowConfig) -> jax.Ar
 def _logits(params: Params, x: jax.Array, cfg: SparseWindowConfig) -> jax.Array:
     """``x [B, D]`` (before the final norm) -> float32 logits, not rounded to
     the weights' type on the way (a bfloat16 logit near 4 is a multiple of
-    1/32, and near-ties would be broken by the rounding)."""
-    return _out(_norm(x, params["final_norm"], cfg), params["lm_head"])
+    1/32, and near-ties would be broken by the rounding). A tied head is the
+    embedding's transpose, the logits times ``logit_scale``."""
+    h = _norm(x, params["final_norm"], cfg)
+    if not cfg.tied_head:
+        return _out(h, params["lm_head"])
+    logits = jnp.einsum("bd,vd->bv", h, params["embed"], preferred_element_type=jnp.float32)
+    return logits if cfg.logit_scale == 1.0 else logits * cfg.logit_scale
 
 
 _POOLS = ("k", "v", "wk", "wv")
@@ -490,6 +767,8 @@ def prefill(
     """``lengths`` prompt tokens of each of ``rows`` from ``starts``:
     last-token logits ``[B, V]`` and the cache.
 
+    On the blocked arm (pools of five axes) every program attends through
+    the pools, in tiles (:func:`_fold_keys`), and takes no span. Else:
     ``starts`` None: whole prompts from position 0, attention local (no pool
     read), a window layer's by its mask. Given: suffixes that attend through
     the pools, a full layer over the span holding ``live_to`` (as
@@ -500,6 +779,7 @@ def prefill(
     B, S = tokens.shape
     bt, wbt = cache["bt"][rows], cache["wbt"][rows]
     BS = cache["k"].shape[2]
+    blocked = cache["k"].ndim == 5
     max_s = bt.shape[1] * BS
     active = lengths > 0
     begin = jnp.zeros((B,), jnp.int32) if starts is None else starts
@@ -509,7 +789,7 @@ def prefill(
     blk = {"full": jnp.where(writable, bt[at], 0), "window": jnp.where(writable, wbt[at], 0)}
     off = posq % BS
     span_at = None
-    if starts is not None and spans is not None:
+    if starts is not None and spans is not None and not blocked:
         llama._check_spans(spans, bt, BS, "gather", live_to)
         span_at = llama._span_index(spans, live_to)
     # a chunk's first query reaches back a window; one block more where the
@@ -518,12 +798,28 @@ def prefill(
     n_blocks = cfg.window // BS + -(-S // BS) + 1
     causal = jnp.arange(S)[None, :, None] >= jnp.arange(S)[None, None, :]
     local = {"full": causal, "window": causal & _window_mask(posq, posq, cfg.window)}
+    # the blocked arm: a full layer folds the tiles that hold the batch's
+    # last position, a window layer those of its fixed run of blocks
+    if blocked:
+        per_tile = PREFILL_TILE // BS
+        full_tiles = jnp.max(begin + jnp.maximum(lengths, 1) - 1) // PREFILL_TILE + 1
+        zero = jnp.zeros((B,), jnp.int32)
 
     def attn_fn(kind, h, lp, pools, index):
         window = kind == "window"
         q, k, v = _qkv(h, lp, cfg.rope_window if window else cfg.rope_full, posq, cfg)
         kn, vn = ("wk", "wv") if window else ("k", "v")
         pools = dict(pools)
+        if blocked:
+            pools[kn] = pools[kn].at[index, blk[kind], off].set(_heads(k, cfg))
+            pools[vn] = pools[vn].at[index, blk[kind], off].set(_heads(v, cfg))
+            if window:
+                a = _fold_keys(q, pools[kn], pools[vn], index, wbt, posq, first,
+                               -(-n_blocks // per_tile), cfg.window, cfg)
+            else:
+                a = _fold_keys(q, pools[kn], pools[vn], index, bt, posq, zero,
+                               full_tiles, 0, cfg)
+            return _out(a.reshape(B, S, -1), lp["wo"]), pools
         pools[kn] = pools[kn].at[index, blk[kind], off].set(k)
         pools[vn] = pools[vn].at[index, blk[kind], off].set(v)
         if starts is None:
@@ -540,11 +836,16 @@ def prefill(
         writable, attn_fn)
     last = jnp.take_along_axis(
         x, jnp.maximum(lengths - 1, 0)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    return _logits(params, last, cfg), {
+    logits = _logits(params, last, cfg)
+    out = {
         **pools, "bt": cache["bt"], "wbt": cache["wbt"],
         "pos": llama._advance_pos(cache["pos"], rows, active, begin + lengths, max_s),
         "expert_tokens": cache["expert_tokens"] + load,
     }
+    if cfg.experts_held:
+        out["assign_all"] = cache["assign_all"] + (
+            cfg.n_layers * cfg.top_k * jnp.sum(writable, dtype=jnp.int32))
+    return logits, out
 
 
 # ---- decode ----------------------------------------------------------------
@@ -561,18 +862,22 @@ def decode_step(
     """One token for every cache row: the new K/V scattered into the row's
     current block of each pool, a full layer's attention over the gathered
     view's span, a window layer's over the ``window / BS + 1`` blocks that end
-    at the row's position. A row not ``kept`` (vacant, between two chunks of
+    at the row's position (the blocked arm: both through ``paged_attention``
+    over the row's own blocks, no span). A row not ``kept`` (vacant, between two chunks of
     its prompt, left out by the block reserve, past its budget) computes
     garbage nobody reads, writes its K/V to the trash blocks and routes to no
     expert. Returns ``(logits [B, V], cache, load [n_layers, n_experts])``."""
     B = tokens.shape[0]
     pos, bt, wbt = cache["pos"], cache["bt"], cache["wbt"]
     BS = cache["k"].shape[2]
+    blocked = cache["k"].ndim == 5
     max_s = bt.shape[1] * BS
     at = (jnp.arange(B), pos // BS)
     blk = {"full": jnp.where(kept, bt[at], 0), "window": jnp.where(kept, wbt[at], 0)}
     span_at = None
-    if spans is not None:
+    if blocked:
+        run, run_pos = _run_of_blocks(wbt, pos, BS, cfg.window)
+    elif spans is not None:
         llama._check_spans(spans, bt, BS, "gather", live_to)
         span_at = llama._span_index(spans, live_to)
         blk["full"] = jnp.where(pos < jnp.asarray(spans, jnp.int32)[span_at], blk["full"], 0)
@@ -585,6 +890,16 @@ def decode_step(
         q, k, v = _qkv(h, lp, cfg.rope_window if window else cfg.rope_full, posq, cfg)
         kn, vn = ("wk", "wv") if window else ("k", "v")
         pools = dict(pools)
+        if blocked:
+            # the step's K/V written and the row's own blocks read by one
+            # call: every key it holds in a full layer, the run that ends at
+            # its position in a window layer; a row not kept reads nothing
+            where = {"window": cfg.window} if window else {}
+            a, pools[kn], pools[vn] = paged_attention.paged_attention(
+                q, pools[kn], pools[vn], run if window else bt,
+                run_pos if window else pos, layer=index, live=kept,
+                new_k=_heads(k[:, 0], cfg), new_v=_heads(v[:, 0], cfg), **where)
+            return _out(a.reshape(B, 1, -1), lp["wo"]), pools
         pools[kn] = pools[kn].at[index, blk[kind], off].set(k[:, 0])
         pools[vn] = pools[vn].at[index, blk[kind], off].set(v[:, 0])
         if window:
@@ -618,16 +933,23 @@ def decode_segment(
     and in the prefill programs since the segment before: the cache's count,
     which goes back zeroed), ``experts_touched`` (sum over the segment's steps
     and layers of experts with at least one) and ``expert_steps`` (layers
-    times the steps some row still needed)."""
+    times the steps some row still needed). Where the stacks hold a share of
+    the experts (``cfg.experts_held``), also ``assign_all``, every kept
+    assignment the routers made in the same programs, and ``assign_held``,
+    those of them that fell on an expert held here (``expert_tokens``
+    summed): an eighth of them is an even router over eight shares."""
     one = partial(decode_step, cfg=cfg, spans=spans, live_to=live_to)
     zero = jnp.zeros((), jnp.int32)
-    cache_names = tuple(n for n in cache if n != "expert_tokens")
+    counted = ("expert_tokens", "assign_all") if cfg.experts_held else ("expert_tokens",)
+    cache_names = tuple(n for n in cache if n not in counted)
 
     def step(carry, toks):
         kept = carry["step"] < take
         logits, cache, load = one(params, {n: carry[n] for n in cache_names}, toks, kept)
+        more = {"assign_all": carry["assign_all"] + cfg.n_layers * cfg.top_k * jnp.sum(
+            kept, dtype=jnp.int32)} if cfg.experts_held else {}
         return logits, {
-            **cache, "step": carry["step"] + 1,
+            **cache, "step": carry["step"] + 1, **more,
             "expert_tokens": carry["expert_tokens"] + load,
             "experts_touched": carry["experts_touched"] + jnp.sum(load > 0, dtype=jnp.int32),
             "expert_steps": carry["expert_steps"] + cfg.n_layers * jnp.any(kept).astype(jnp.int32),
@@ -636,7 +958,10 @@ def decode_segment(
     carry = {**cache, "step": zero, "experts_touched": zero, "expert_steps": zero}
     toks, last, next_key, carry = llama.sampled_segment(
         step, carry, tokens, temps, key, n_steps, greedy)
-    counters = {n: carry[n] for n in ("expert_tokens", "experts_touched", "expert_steps")}
+    counters = {n: carry[n] for n in (*counted, "experts_touched", "expert_steps")}
     cache = {n: carry[n] for n in cache_names}
-    cache["expert_tokens"] = jnp.zeros_like(counters["expert_tokens"])
+    for n in counted:  # taken over: the next prefill programs count from zero
+        cache[n] = jnp.zeros_like(counters[n])
+    if cfg.experts_held:  # of all kept assignments, those an expert here took
+        counters["assign_held"] = jnp.sum(counters["expert_tokens"], dtype=jnp.int32)
     return toks, last, next_key, cache, counters

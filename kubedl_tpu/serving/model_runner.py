@@ -95,6 +95,9 @@ class _Runner:
     #: what the last decode segment counted beside its tokens (device
     #: arrays by name, harvested with the tokens); None: nothing
     segment_counters = None
+    #: keys a compute block of the decode kernel holds, where a runner's
+    #: decode steps attend through it; 0: they do not
+    decode_tile = 0
 
     def _build_samplers(self) -> None:
         # first-token sampler, ON DEVICE: fetching the prefill logits to
@@ -213,9 +216,19 @@ class _Runner:
 
     def keys_read(self, positions, n_steps: int) -> Optional[int]:
         """Keys the attention of an ``n_steps`` decode segment fetches for
-        scheduled rows standing at ``positions``; None where a step attends
-        over the gathered view, whose cost is its span and not the rows."""
-        return None
+        scheduled rows standing at ``positions``, through the decode kernel
+        (one layer's call; a layer that reads a window of them fetches
+        fewer); None where a step attends over the gathered view, whose cost
+        is its span and not the rows."""
+        if not self.decode_tile:
+            return None
+        # step j of the segment attends a row's pos + j + 1 keys
+        lengths = np.minimum(
+            np.asarray(positions, np.int64)[:, None] + np.arange(1, n_steps + 1),
+            self.max_seq)
+        return paged_attention.decode_keys_read(
+            lengths, self.kv_block_size, self.max_seq // self.kv_block_size,
+            self.decode_tile)
 
     def _build_row_prefills(self, model_prefill) -> None:
         """``_view``, ``_prefill`` and ``_prefill_from`` of a paged runner whose
@@ -619,17 +632,6 @@ class ModelRunner(_Runner):
         )
         return toks, last, key
 
-    def keys_read(self, positions, n_steps: int) -> Optional[int]:
-        if not self.decode_tile:
-            return None
-        # step j of the segment attends a row's pos + j + 1 keys
-        lengths = np.minimum(
-            np.asarray(positions, np.int64)[:, None] + np.arange(1, n_steps + 1),
-            self.max_seq)
-        return paged_attention.decode_keys_read(
-            lengths, self.kv_block_size, self.max_seq // self.kv_block_size,
-            self.decode_tile)
-
     def verify(self, params, toks, lens, starts):
         """The write-path verify: consume ``toks`` from ``starts``, return
         the target's argmax after each input ``[B, S]``."""
@@ -755,13 +757,21 @@ class SparseWindowRunner(_Runner):
     tokens alone. ``sparse_window.preset`` and ``sparse_window.sparse_init``
     are looked up on the module at call time, as the decoder's are.
 
-    Paged, gather attention only. What rests on "a prefix is a list of
-    blocks" does not hold once a row's window blocks are released (prefix
-    reuse, speculation's rollback, block hand-off): the engine refuses
-    those at construction."""
+    ``kv_attention="blocked"`` is the model's blocked arm: pools whose blocks
+    ``paged_attention`` reads as they stand, no gathered view and so one
+    span; a prefill program folds a row's keys in tiles, a decode step
+    attends through ``paged_attention`` in both kinds of layer, which on a
+    TPU (a pool the kernel can take) fetches each scheduled row's own blocks
+    (:attr:`decode_tile`, as :class:`ModelRunner`'s).
+
+    Paged only. What rests on "a prefix is a list of blocks" does not hold
+    once a row's window blocks are released (prefix reuse, speculation's
+    rollback, block hand-off): the engine refuses those at construction."""
 
     def __init__(self, preset: str, *, max_batch: int, max_seq: int = 0,
-                 kv_block_size: int = 16) -> None:
+                 kv_block_size: int = 16, kv_attention: str = "gather") -> None:
+        if kv_attention not in ("gather", "blocked"):
+            raise ValueError(f"unknown kv_attention {kv_attention!r}")
         self.cfg = cfg = sparse_window.preset(preset)
         self.max_batch = max_batch
         bs = self.kv_block_size = max(1, int(kv_block_size))
@@ -776,7 +786,14 @@ class SparseWindowRunner(_Runner):
         self.window_block_bytes = int(cfg.n_window * token)
         self.cache = None
         self.window_blocks = 0  # size_window_pool()
-        self.spans = self.span_ladder(self.max_seq, bs)
+        self.blocked = kv_attention == "blocked"
+        #: keys a compute block of the decode kernel holds; 0: a decode step
+        #: attends over gathered views, or through the blocked arm's lax scan
+        self.decode_tile = paged_attention.DEFAULT_TILE if (
+            self.blocked and jax.default_backend() == "tpu"
+            and paged_attention.decode_kernel_fits(
+                1, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, bs, cfg.dtype)) else 0
+        self.spans = (self.max_seq,) if self.blocked else self.span_ladder(self.max_seq, bs)
         self._no_logits = jnp.zeros((max_batch, cfg.vocab_size), jnp.float32)
         self._build_row_prefills(sparse_window.prefill)
         self._build_samplers()
@@ -808,7 +825,7 @@ class SparseWindowRunner(_Runner):
         """Both pools, ``pos`` and both tables, zeroed."""
         self.cache = sparse_window.init_cache(
             self.cfg, self.max_batch, self.max_seq, kv_blocks,
-            self.window_blocks, self.kv_block_size)
+            self.window_blocks, self.kv_block_size, blocked=self.blocked)
 
     def warmup(self, params) -> None:
         """One decode step that keeps no token: proof the model runs, by a
@@ -819,6 +836,14 @@ class SparseWindowRunner(_Runner):
             live_to=1, rows=(), takes=())
         jax.block_until_ready(self.cache["pos"])
         self.segment_counters = None
+
+    def span_for(self, live_to: Optional[int]) -> int:
+        """The blocked arm has no view: what a prefill program's full layers
+        fold, ``live_to`` in whole tiles."""
+        if not self.blocked:
+            return super().span_for(live_to)
+        tile = sparse_window.PREFILL_TILE
+        return min(-(-(live_to or self.max_seq) // tile) * tile, self.max_seq)
 
     def prefill(self, params, toks, lens, starts=None, rows=None, acc=None,
                 live_to: Optional[int] = None):
@@ -942,8 +967,9 @@ def make_runner(preset: str, *, max_batch: int, max_seq: int = 0,
     one of ``sparse_window`` a :class:`SparseWindowRunner` and one of
     ``retention`` a :class:`RetentionRunner`, which refuse what they cannot
     do (``ValueError``, naming the reason: a row that holds recurrent state,
-    beside its K/V blocks or in their place, or two kinds of block); any
-    other name is ``llama.preset``'s."""
+    beside its K/V blocks or in their place, or two kinds of block; only the
+    sparse-window runner takes ``kv_attention="blocked"``); any other name is
+    ``llama.preset``'s."""
     cfg = None
     for family in (hybrid_ssm, sparse_window, retention):
         try:
@@ -957,10 +983,13 @@ def make_runner(preset: str, *, max_batch: int, max_seq: int = 0,
             kv_block_size=kv_block_size, kv_attention=kv_attention,
             quantize=quantize, mesh_axes=mesh_axes, spec_k=spec_k,
             spec_candidates=spec_candidates, spec_tree=spec_tree)
+    more = {}
     if isinstance(cfg, sparse_window.SparseWindowConfig):
         runner = SparseWindowRunner
         holds = "keeps two kinds of K/V block, one a window of the context,"
         draft = "would need window blocks the row has released"
+        # this runner has the blocked arm: its model folds both pools in tiles
+        more, kv_attention = {"kv_attention": kv_attention}, "gather"
     else:
         if isinstance(cfg, hybrid_ssm.HybridConfig):
             runner, holds = HybridRunner, "holds recurrent state beside its K/V blocks"
@@ -979,4 +1008,4 @@ def make_runner(preset: str, *, max_batch: int, max_seq: int = 0,
             raise ValueError(
                 f"preset {preset!r} {holds} and cannot be served with {what}")
     return runner(preset, max_batch=max_batch, max_seq=max_seq,
-                  kv_block_size=kv_block_size)
+                  kv_block_size=kv_block_size, **more)

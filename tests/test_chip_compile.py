@@ -365,6 +365,72 @@ def test_sparse_window_programs_fit_the_chip(on_chip, program, monkeypatch):
 
 
 @pytest.mark.parametrize("program", ["decode_segment", "prefill_from"])
+def test_parallel_block_programs_fit_the_chip_on_the_blocked_arm(on_chip, program, monkeypatch):
+    """The sparse-window runner's programs under the parallel block's settings
+    at command-a-plus-05-2026's widths, one chip's share (4 layers, 16 of 128
+    experts, an eighth of the vocabulary), 16 rows of 32768 keys, on the
+    blocked arm: arguments and temporaries stay under the 15 GiB a 16 GB chip
+    leaves a program (12.6 GB of weights and pools by the configuration's
+    table), where the gathered view's float32 scores of 128 heads alone would
+    be 16 GiB at this span. No operation copies a pool or a stack of expert
+    weights. The decode segment calls the decode kernel once a layer, in a
+    window layer over the run of blocks that ends at the row (the kernel
+    compiled with a window), over pools it reads as they stand; the prefill
+    program folds its keys in a loop and holds no kernel."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from kubedl_tpu.models import paged_attention as pa
+    from kubedl_tpu.models import sparse_window
+    from kubedl_tpu.serving.model_runner import SparseWindowRunner
+
+    cfg = sparse_window.preset("command-a-plus-05-2026-l4")
+    B, max_seq, BS = 16, 32768, 16
+    runner = SparseWindowRunner("command-a-plus-05-2026-l4", max_batch=B, max_seq=max_seq,
+                                kv_block_size=BS, kv_attention="blocked")
+    window_blocks = runner.size_window_pool(1024)
+    assert window_blocks == 1 + B * 321 and runner.spans == (max_seq,)
+    # this process's backend is the CPU, where "auto" is the lax arm: name
+    # the kernel a TPU's "auto" takes for one query a row
+    real = pa.paged_attention
+    monkeypatch.setattr(pa, "paged_attention", lambda q, *a, **kw: real(
+        q, *a, **{**kw, "kernel": "pallas" if q.shape[1] == 1 else "lax"}))
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: on_chip(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: sparse_window.sparse_init(jax.random.PRNGKey(0), cfg)))
+    cache = place(jax.eval_shape(lambda: sparse_window.init_cache(
+        cfg, B, max_seq, 1 + B * max_seq // BS, window_blocks, BS, blocked=True)))
+    i32 = lambda *s: on_chip(s, jnp.int32)  # noqa: E731
+    if program == "decode_segment":
+        lowered = runner._segment_fn(4, True).lower(
+            params, cache, i32(B, 1), on_chip((B,), jnp.float32),
+            on_chip((2,), jnp.uint32), i32(B))
+    else:
+        lowered = runner._prefill_from.lower(
+            params, cache, i32(1, 1024), i32(1), i32(1), i32(1),
+            on_chip((B, cfg.vocab_size), jnp.float32))
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * 2**30
+    text = compiled.as_text()
+    big = [ln for ln in text.splitlines() if " copy(" in ln and (
+        f"bf16[{cfg.n_full},{1 + B * max_seq // BS}," in ln
+        or f"bf16[{cfg.n_window},{window_blocks}," in ln
+        or f"bf16[{cfg.n_layers},{cfg.held}," in ln
+        or f"bf16[{cfg.n_layers * cfg.held}," in ln)]
+    assert not big, big[:2]
+    calls = [ln for ln in text.splitlines() if pa.DECODE_KERNEL_NAME in ln
+             and "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(calls) == (len(cfg.period) if program == "decode_segment" else 0), calls
+    # no span to branch on; a chunk's one conditional a layer is the share's:
+    # the grouped products over the first assignments, or over all of them
+    assert text.count(" conditional(") == (0 if program == "decode_segment" else len(cfg.period))
+
+
+@pytest.mark.parametrize("program", ["decode_segment", "prefill_from"])
 def test_retention_programs_fit_the_chip(on_chip, program, monkeypatch):
     """The retention runner's own programs at the cell's size
     (``brumby-14b-base-l8``: 8 of Brumby-14B-Base's 40 layers at published
