@@ -22,8 +22,14 @@ over all ``n_experts`` router outputs, the ``top_k`` largest kept and
 renormalised, every kept token computed by every one of its experts. It is
 TOLD which experts it holds (a contiguous range), routes over all of them,
 and returns the part of the result its own give; the parts of all ranges add
-up to the layer. Tokens are ordered by expert and the products run grouped
-(``lax.ragged_dot``), so the FLOPs are the routed ones. A token not ``kept``
+up to the layer. The assignments are ordered by expert and the two products
+run grouped over that order, so the FLOPs are the routed ones: on a TPU as
+ONE Pallas kernel (``ops/expert_gmm.py``) whose work list names an expert
+only where a row tile of its group exists, so a step reads the experts its
+kept tokens touch and no other, and a share runs no tile past the
+assignments that fell on it; on any other backend as two ``lax.ragged_dot``
+(:func:`_grouped`), the plain twin the tests hold the kernel to. A decode
+step's 16 rows and a chunk's 1024 take the same path. A token not ``kept``
 (padding, a row the dispatch did not schedule, a step past a row's budget)
 routes nowhere: it touches no expert and counts in no load.
 
@@ -45,9 +51,10 @@ Parameters are stacked by kind in layer order: ``window`` leaves
 ``[n_layers, ...]`` with the experts' ``[n_layers, n_experts, ...]``. One
 ``lax.scan`` runs over the periods with the four pools as carries, written in
 place under donation. The grouped products take the WHOLE stack of expert
-weights, seen as ``n_layers * n_experts`` groups of which all but one layer's
-are empty: the layer is in the group sizes, as ``llama._paged_view`` has it in
-the gather's index, so no program slices a layer's experts out.
+weights, seen as ``n_layers * n_experts`` groups: the layer is in the index
+of the kernel's fetches (a scalar it prefetches; in the group sizes of the
+plain twin, of which all but one layer's are empty), as ``llama._paged_view``
+has it in the gather's index, so no program slices a layer's experts out.
 
 The block is a setting, not a copy (every default is the block above):
 ``norm`` ``"layer"`` centres on the mean before it scales (a weight, no bias);
@@ -90,6 +97,7 @@ from jax import lax
 
 from kubedl_tpu.models import llama, paged_attention
 from kubedl_tpu.models.hybrid_ssm import _at, _attention_one_query, _heads, _view
+from kubedl_tpu.ops import expert_gmm
 
 Params = Dict[str, Any]
 
@@ -386,66 +394,28 @@ def route(x: jax.Array, router: jax.Array, cfg: SparseWindowConfig):
     return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
 
-#: up to this many tokens the held experts are multiplied whole, each token by
-#: every one of them under its gate (0 where it is not routed), instead of
-#: grouped: the product then does ``T`` FLOPs a byte of expert weights, under
-#: half the chip's ridge of 240, so it is a read of the weights either way,
-#: and it reads them at the memory's speed where the grouped product over two
-#: rows an expert reads them at half of it (PERF.md section 6, PR 37). A
-#: decode step's rows and a prompt's short last chunk pass here.
-DENSE_BELOW = 128
+def grouped_by_kernel(n: int, moe: Params, cfg: SparseWindowConfig) -> int:
+    """The row tile in which this process multiplies ``n`` assignments by
+    their experts in the Pallas kernel (``ops/expert_gmm.py``): a TPU, and
+    stacks whose tiles the kernel can take as they stand. 0 where it does not
+    (:func:`_grouped` multiplies them)."""
+    if jax.default_backend() != "tpu":
+        return 0
+    return expert_gmm.rows_for(n, moe["w_in"], moe["w_out"], cfg.n_experts)
 
 
-def _few_tokens(x, moe, layer, top_e, gates, held, cfg, first, count):
-    """:func:`expert_layer` for a few tokens: ``x [T, D]`` times every held
-    expert's ``w_in``, the activations times the tokens' gates (``[T,
-    count]``, 0 where an assignment is not held), and one product over
-    experts and width with ``w_out``. The same sums as the grouped form."""
-    F = cfg.expert_ffn
-    w_in = lax.dynamic_index_in_dim(moe["w_in"], layer, 0, keepdims=False)
-    w_out = lax.dynamic_index_in_dim(moe["w_out"], layer, 0, keepdims=False)
-    hit = held[:, :, None] & (
-        top_e[:, :, None] == first + jnp.arange(count)[None, None, :])
-    gate = jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1)  # [T, count]
-    first = first - cfg.expert_first  # from here on, where the stacks hold it
-    h = jnp.einsum("td,edf->tef", x, w_in[first:first + count])
-    act = jax.nn.silu(h[..., :F].astype(jnp.float32)) * h[..., F:].astype(jnp.float32)
-    act = (act * gate[:, :, None]).astype(x.dtype)
-    y = jnp.einsum("tef,efd->td", act, w_out[first:first + count],
-                   preferred_element_type=jnp.float32)
-    return y, jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
-
-
-def _share_rows(assignments: int, count: int, cfg: SparseWindowConfig) -> int:
-    """How many of a chunk's ``assignments``, ordered with those on the
-    ``count`` experts in hand first, the grouped products take at first:
-    twice what an even router sends to ``count`` of ``n_experts``, in whole
-    tiles of 512 rows; all of them where every expert is held."""
-    if not cfg.experts_held:
-        return assignments
-    even = 2 * assignments * count // cfg.n_experts
-    return min(assignments, -(-max(even, 1) // 512) * 512)
-
-
-def _grouped(x, moe, sizes, order, n, held, gates, cfg):
-    """The grouped products of :func:`expert_layer` over the first ``n``
-    assignments of ``order`` (the order by expert), summed back a token:
-    ``y [T, D]`` float32. An assignment past the first ``n`` adds nothing."""
-    T, D = x.shape
-    K, F = cfg.top_k, cfg.expert_ffn
-    take = order if n == T * K else order[:n]
-    xs = x[take // K]  # [n, D]: each assignment's token, by expert
-    h = lax.ragged_dot(xs, moe["w_in"].reshape(-1, D, 2 * F), sizes)
-    act = jax.nn.silu(h[:, :F].astype(jnp.float32)).astype(x.dtype) * h[:, F:]
-    out = lax.ragged_dot(act, moe["w_out"].reshape(-1, F, D), sizes,
-                         preferred_element_type=jnp.float32)
-    weight = jnp.where(held, gates, 0.0).reshape(T * K)[take]
-    out = jnp.where(weight[:, None] > 0, out * weight[:, None], 0.0)
-    back = jnp.zeros((T * K,), jnp.int32).at[order].set(jnp.arange(T * K, dtype=jnp.int32))
-    if n < T * K:  # row n: nothing, for the assignments past it
-        out = jnp.concatenate([out, jnp.zeros((1, D), out.dtype)])
-        back = jnp.minimum(back, n)
-    return jnp.sum(out[back].reshape(T, K, D), axis=1)
+def _grouped(xs, w_in, w_out, load, place):
+    """The plain twin of ``expert_gmm.expert_gmm``: the two products of the
+    assignments ``xs [n, D]``, ordered by expert, as ``lax.ragged_dot`` over
+    the WHOLE stacks ``[L * E, ...]``, whose groups are all empty but the
+    ``load [count]`` that begin at ``place``. ``[n, D]`` float32; a row past
+    ``sum(load)`` is nobody's and holds nothing that is read."""
+    F = w_out.shape[1]
+    sizes = lax.dynamic_update_slice(
+        jnp.zeros((w_in.shape[0],), jnp.int32), load, (place,))
+    h = lax.ragged_dot(xs, w_in, sizes)
+    act = jax.nn.silu(h[:, :F].astype(jnp.float32)).astype(xs.dtype) * h[:, F:]
+    return lax.ragged_dot(act, w_out, sizes, preferred_element_type=jnp.float32)
 
 
 def expert_layer(x: jax.Array, moe: Params, layer, kept: jax.Array,
@@ -460,11 +430,13 @@ def expert_layer(x: jax.Array, moe: Params, layer, kept: jax.Array,
     ``n_experts``, as the router does.
 
     Every token routes over all ``n_experts``; an assignment counts where its
-    token is ``kept`` and its expert is held. Many tokens are ordered by
-    expert, the assignments that do not count last, beyond the groups' rows,
-    where the grouped product computes nothing that is read; a few tokens
-    (:data:`DENSE_BELOW`) are multiplied by every held expert under their
-    gates."""
+    token is ``kept`` and its expert is held. The assignments are ordered by
+    expert, those that do not count last, beyond the groups' rows, and the
+    two products run grouped over that order: on a TPU in one kernel that
+    fetches an expert where a row tile of its group exists and runs no tile
+    past the groups (:func:`grouped_by_kernel`), elsewhere as two
+    ``lax.ragged_dot``. A decode step's few rows and a chunk's many take the
+    same path."""
     T, D = x.shape
     E, K, F = cfg.held, cfg.top_k, cfg.expert_ffn
     first = cfg.expert_first if first is None else first
@@ -472,27 +444,21 @@ def expert_layer(x: jax.Array, moe: Params, layer, kept: jax.Array,
     router = lax.dynamic_index_in_dim(moe["router"], layer, 0, keepdims=False)
     top_e, gates = route(x, router, cfg)
     held = kept[:, None] & (top_e >= first) & (top_e < first + count)
-    if T <= DENSE_BELOW:
-        return _few_tokens(x, moe, layer, top_e, gates, held, cfg, first, count)
     group = jnp.where(held, top_e - first, count).reshape(T * K)
     order = jnp.argsort(group)  # stable: an expert's tokens stay in order
     load = jnp.sum(group[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
-    # the layer's groups among the whole stack's: every other group is empty
-    L = moe["w_in"].shape[0]
-    sizes = lax.dynamic_update_slice(
-        jnp.zeros((L * E,), jnp.int32), load, (layer * E + first - cfg.expert_first,))
-    rows = _share_rows(T * K, count, cfg)
-    if rows == T * K:
-        return _grouped(x, moe, sizes, order, rows, held, gates, cfg), load
-    # a share: most assignments route to experts that are not here, and they
-    # stand last in the order. The grouped products take the first ``rows``
-    # assignments (twice what an even router sends here) where those hold
-    # every one that counts, and all of them where they do not: nothing is
-    # dropped either way
-    def part(n):
-        return _grouped(x, moe, sizes, order, n, held, gates, cfg)
-
-    return lax.cond(jnp.sum(load) <= rows, lambda: part(rows), lambda: part(T * K)), load
+    xs = x[order // K]  # [T * K, D]: each assignment's token, by expert
+    # the layer's groups among the whole stack's: no layer's experts sliced out
+    w_in, w_out = moe["w_in"].reshape(-1, D, 2 * F), moe["w_out"].reshape(-1, F, D)
+    place = layer * E + first - cfg.expert_first
+    if grouped_by_kernel(T * K, moe, cfg):
+        out = expert_gmm.expert_gmm(xs, w_in, w_out, load, place, experts=cfg.n_experts)
+    else:
+        out = _grouped(xs, w_in, w_out, load, place)
+    weight = jnp.where(held, gates, 0.0).reshape(T * K)[order]
+    out = jnp.where(weight[:, None] > 0, out * weight[:, None], 0.0)
+    back = jnp.zeros((T * K,), jnp.int32).at[order].set(jnp.arange(T * K, dtype=jnp.int32))
+    return jnp.sum(out[back].reshape(T, K, D), axis=1), load
 
 
 # ---- the layers ------------------------------------------------------------
@@ -942,6 +908,7 @@ def decode_segment(
     zero = jnp.zeros((), jnp.int32)
     counted = ("expert_tokens", "assign_all") if cfg.experts_held else ("expert_tokens",)
     cache_names = tuple(n for n in cache if n not in counted)
+    tile = grouped_by_kernel(tokens.shape[0] * cfg.top_k, params["moe"], cfg)
 
     def step(carry, toks):
         kept = carry["step"] < take
@@ -953,12 +920,16 @@ def decode_segment(
             "expert_tokens": carry["expert_tokens"] + load,
             "experts_touched": carry["experts_touched"] + jnp.sum(load > 0, dtype=jnp.int32),
             "expert_steps": carry["expert_steps"] + cfg.n_layers * jnp.any(kept).astype(jnp.int32),
+            "expert_tiles": carry["expert_tiles"] + (
+                expert_gmm.row_tiles(load, tile) if tile else zero),
         }
 
-    carry = {**cache, "step": zero, "experts_touched": zero, "expert_steps": zero}
+    carry = {**cache, "step": zero, "experts_touched": zero, "expert_steps": zero,
+             "expert_tiles": zero}
     toks, last, next_key, carry = llama.sampled_segment(
         step, carry, tokens, temps, key, n_steps, greedy)
-    counters = {n: carry[n] for n in (*counted, "experts_touched", "expert_steps")}
+    counters = {n: carry[n] for n in (
+        *counted, "experts_touched", "expert_steps", "expert_tiles")}
     cache = {n: carry[n] for n in cache_names}
     for n in counted:  # taken over: the next prefill programs count from zero
         cache[n] = jnp.zeros_like(counters[n])

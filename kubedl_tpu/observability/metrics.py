@@ -701,6 +701,13 @@ class ServingMetrics:
                 "experts_touched over this is the experts a layer's step "
                 "touches",
             ),
+            "expert_tiles": r.counter(
+                "kubedl_tpu_serving_expert_tiles",
+                "Row tiles the expert kernel ran in decode steps: one for "
+                "each expert and each tile of the order by expert in which "
+                "it has a row. expert_tiles over experts_touched is the "
+                "tiles a fetched expert fed. 0 where no kernel runs",
+            ),
             "slabs_stepped": r.counter(
                 "kubedl_tpu_serving_slabs_stepped",
                 "Rows whose slab of recurrent state a decode step fetched, "

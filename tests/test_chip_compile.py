@@ -307,6 +307,38 @@ def test_hybrid_programs_fit_the_chip_whole(on_chip, program, monkeypatch):
             assert "output_to_operand_aliasing" in ln, ln
 
 
+def _as_a_tpu_routes_its_experts(monkeypatch):
+    """This process's backend is the CPU, where the expert layer takes its
+    plain twin: let the shapes alone decide, as they do on a TPU."""
+    from kubedl_tpu.models import sparse_window
+    from kubedl_tpu.ops import expert_gmm
+
+    monkeypatch.setattr(
+        sparse_window, "grouped_by_kernel", lambda n, moe, cfg: expert_gmm.rows_for(
+            n, moe["w_in"], moe["w_out"], cfg.n_experts))
+
+
+def _the_expert_kernel_is_the_layers_products(text: str, cfg, in_steps: bool):
+    """One call of ``expert_gmm`` a layer of the period, inside the layer loop
+    (a decode segment's inside its step loop too), and nothing beside it
+    that multiplies by experts: no ``ragged-dot``, no operand or result with
+    an axis of every held expert before an axis of their width."""
+    import re
+
+    from kubedl_tpu.ops import expert_gmm
+
+    calls = [ln for ln in text.splitlines() if expert_gmm.KERNEL_NAME in ln
+             and "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(calls) == len(cfg.period), calls
+    loops = r"/while/body/.*/while/body/.*pallas_call" if in_steps else r"/while/body/.*pallas_call"
+    for ln in calls:
+        assert re.search(loops, ln), ln
+    assert "ragged-dot" not in text
+    every = re.compile(rf"\[\d+,{cfg.held},({2 * cfg.expert_ffn}|{cfg.expert_ffn})\]")
+    made = [ln for ln in text.splitlines() if every.search(ln.split(" = ")[-1].split("(")[0])]
+    assert not made, made[:2]
+
+
 @pytest.mark.parametrize("program", ["decode_segment", "prefill_from"])
 def test_sparse_window_programs_fit_the_chip(on_chip, program, monkeypatch):
     """The sparse-window runner's own programs at Mellum2-12B-A2.5B's widths,
@@ -314,12 +346,12 @@ def test_sparse_window_programs_fit_the_chip(on_chip, program, monkeypatch):
     pools: arguments and temporaries stay under the 15 GiB a 16 GB chip
     leaves a program (12.35 GB of weights and pools by the configuration's
     table). No operation copies a pool, and none copies the stack of expert
-    weights: the grouped products are given the whole stack with the layer in
-    the group sizes, so no layer's 0.79 GB of experts is sliced out. A
-    chunk's products are the chip's grouped matmul, not a masked product over
-    all 64 experts; a decode step's few rows are multiplied by every expert
-    (``sparse_window.DENSE_BELOW``), which reads the layer's experts in place
-    too."""
+    weights: the expert layer's two products are the kernel ``expert_gmm``,
+    compiled as a TPU process traces it, which is given the whole stack with
+    the layer in the index of its fetches, so no layer's 0.79 GB of experts
+    is sliced out. One custom call a layer in both programs (a decode step's
+    16 rows take the path a chunk's 1024 take), inside the layer loop; no
+    ``ragged-dot`` and no product over every expert is left in either."""
     import dataclasses
 
     import jax
@@ -328,6 +360,7 @@ def test_sparse_window_programs_fit_the_chip(on_chip, program, monkeypatch):
     from kubedl_tpu.models import sparse_window
     from kubedl_tpu.serving.model_runner import SparseWindowRunner
 
+    _as_a_tpu_routes_its_experts(monkeypatch)
     cfg = dataclasses.replace(sparse_window.MELLUM2_12B, periods=3)
     monkeypatch.setitem(sparse_window.PRESETS, "mellum2-l12", cfg)
     B, max_seq, BS = 16, 8192, 16
@@ -360,8 +393,7 @@ def test_sparse_window_programs_fit_the_chip(on_chip, program, monkeypatch):
         or f"bf16[{cfg.n_layers},{cfg.n_experts}," in ln
         or f"bf16[{cfg.n_layers * cfg.n_experts}," in ln)]
     assert not big, big[:2]
-    # a chunk's tokens are multiplied grouped; a decode step's 16 by every expert
-    assert ("ragged-dot" in text) == (program == "prefill_from")
+    _the_expert_kernel_is_the_layers_products(text, cfg, program == "decode_segment")
 
 
 @pytest.mark.parametrize("program", ["decode_segment", "prefill_from"])
@@ -376,7 +408,11 @@ def test_parallel_block_programs_fit_the_chip_on_the_blocked_arm(on_chip, progra
     weights. The decode segment calls the decode kernel once a layer, in a
     window layer over the run of blocks that ends at the row (the kernel
     compiled with a window), over pools it reads as they stand; the prefill
-    program folds its keys in a loop and holds no kernel."""
+    program folds its keys in a loop and holds no attention kernel. Both hold
+    the expert kernel once a layer (``expert_gmm``, the held share's 16
+    experts walked in tiles of their width), no ``ragged-dot``, no product
+    over every held expert, and no conditional: a share's products are
+    compiled once."""
     import functools
 
     import jax
@@ -386,6 +422,7 @@ def test_parallel_block_programs_fit_the_chip_on_the_blocked_arm(on_chip, progra
     from kubedl_tpu.models import sparse_window
     from kubedl_tpu.serving.model_runner import SparseWindowRunner
 
+    _as_a_tpu_routes_its_experts(monkeypatch)
     cfg = sparse_window.preset("command-a-plus-05-2026-l4")
     B, max_seq, BS = 16, 32768, 16
     runner = SparseWindowRunner("command-a-plus-05-2026-l4", max_batch=B, max_seq=max_seq,
@@ -425,9 +462,9 @@ def test_parallel_block_programs_fit_the_chip_on_the_blocked_arm(on_chip, progra
     calls = [ln for ln in text.splitlines() if pa.DECODE_KERNEL_NAME in ln
              and "custom_call_target=\"tpu_custom_call\"" in ln]
     assert len(calls) == (len(cfg.period) if program == "decode_segment" else 0), calls
-    # no span to branch on; a chunk's one conditional a layer is the share's:
-    # the grouped products over the first assignments, or over all of them
-    assert text.count(" conditional(") == (0 if program == "decode_segment" else len(cfg.period))
+    # no span to branch on, and a share's products stand once
+    assert text.count(" conditional(") == 0
+    _the_expert_kernel_is_the_layers_products(text, cfg, program == "decode_segment")
 
 
 @pytest.mark.parametrize("program", ["decode_segment", "prefill_from"])

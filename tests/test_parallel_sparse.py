@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import parallel_sparse_ref as ref
+from test_expert_gmm import forced_kernel
 from test_hybrid_ssm import _Recorded, serve_together
 from kubedl_tpu.models import paged_attention
 from kubedl_tpu.models import sparse_window as sw
@@ -259,21 +260,21 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(kind, monkeypatch):
 
 
 @pytest.mark.parametrize("case", ["an even router", "every token routed here"])
-def test_a_shares_grouped_products_take_the_first_assignments_and_drop_none(params, case, monkeypatch):
-    """A chunk's grouped products run over the first assignments of the order
-    by expert (twice what an even router sends to 2 experts of 8: 1,024 rows
-    of 1,200), where the held ones stand; where more than that fall here they
-    run over all. Either way the layer is the reference's loop over the held
-    experts, nothing dropped."""
-    monkeypatch.setattr(sw, "DENSE_BELOW", 0)
-    layer, T = 3, 600
-    assert sw._share_rows(T * CFG.top_k, 2, CFG) == 1024 < T * CFG.top_k
-    assert sw._share_rows(T * CFG.top_k, 8, sw.TINY_SPARSE) == T * CFG.top_k  # no share: all
+def test_a_shares_kernel_call_runs_no_tile_past_the_assignments_that_fell_on_it(params, case, monkeypatch):
+    """A chunk of 600 tokens, two of eight experts held: the kernel is handed
+    all 1,200 assignments in their order by expert and runs the row tiles
+    that hold the some 300 that fell here (one for each expert and tile, at
+    most one more than those rows fill, since the two groups share one tile
+    at most), and none past ``sum(load)``; with every token pushed to the
+    held pair it runs them all. Either way the layer is the reference's loop
+    over the held experts, nothing dropped."""
+    layer, T, rows, seen = 3, 600, 64, []
+    forced_kernel(monkeypatch, (rows, 32), seen)
     h = jax.random.normal(jax.random.PRNGKey(8), (T, CFG.dim), jnp.float32)
     if case == "every token routed here":
         h = h + 40.0 * (params["moe"]["router"][layer][:, 2] + params["moe"]["router"][layer][:, 3])[None]
     kept = jnp.arange(T) % 7 != 3
-    got, load = jax.jit(lambda h, kept: sw.expert_layer(h, params["moe"], jnp.int32(layer), kept, CFG))(h, kept)
+    got, load = sw.expert_layer(h, params["moe"], jnp.int32(layer), kept, CFG)
     lw = jax.tree_util.tree_map(lambda leaf: leaf[layer], ref_tree(params)["moe"])
     top_e, gates = ref.routing(h, lw["router"], CFG.top_k, "float32")
     want = jnp.zeros_like(h)
@@ -282,7 +283,13 @@ def test_a_shares_grouped_products_take_the_first_assignments_and_drop_none(para
         gu = h @ lw["gate_up_proj"][e - 2]
         want = want + g[:, None] * ((jax.nn.silu(gu[:, :32]) * gu[:, 32:]) @ lw["down_proj"][e - 2])
     here = int(np.isin(np.asarray(top_e)[np.asarray(kept)], (2, 3)).sum())
-    assert int(load.sum()) == here and (here > 1024) == (case == "every token routed here")
+    (seen_load, entries, tile), = seen  # one call, of all the assignments
+    assert int(jnp.sum(seen_load)) == int(load.sum()) == here
+    filled = -(-here // rows)
+    assert filled <= int(entries) <= filled + 1
+    assert int(tile[:int(entries)].max()) == filled - 1  # no entry names a tile past the rows that fell here
+    every = T * CFG.top_k // rows
+    assert (here > 1000) == (case == "every token routed here") == (int(entries) > every // 2)
     assert np.abs(np.asarray(got) - np.asarray(want)).max() <= 1e-4
 
 
@@ -421,18 +428,22 @@ def test_prefill_then_decode_through_both_pools_is_the_full_forward(params, arm)
     assert table.held(1) == 8 - (119 - 32 + 1) // BS
 
 
-#: sha256 of ``str(jax.make_jaxpr(...))`` of tiny-sparse's three programs at commit
-#: 83b04a5 (before the block became a setting): equal text is an equal program
-MELLUM_PROGRAMS = {"prefill": "8642d7c3d3ee579b", "prefill_from": "9326bfee3d2dcd9e",
-                   "decode_seg4": "51915f7a202ef40d"}
+#: sha256 of ``str(jax.make_jaxpr(...))`` of tiny-sparse's three programs as PR 46
+#: left them (one grouped form of the expert layer for a chunk and a decode step
+#: alike; before it, since commit 83b04a5, a decode step's rows were multiplied by
+#: every expert): equal text is an equal program
+MELLUM_PROGRAMS = {"prefill": "e7f508bb523c1b22", "prefill_from": "e69a641f157c4a6f",
+                   "decode_seg4": "9bc49a98e0d14243"}
 
 
 @pytest.mark.parametrize("program", sorted(MELLUM_PROGRAMS))
 def test_mellum2s_tiny_preset_traces_to_the_program_it_was(program):
     """The settings default to Mellum2's block: its tiny preset's programs are,
-    to the character of their jaxprs, what they were before this block
-    existed, so their logits are what they were bit for bit. A PR that means
-    to change that program changes the digest with it."""
+    to the character of their jaxprs, what the last PR that meant to change
+    them left, so their logits are what they were bit for bit. A PR that
+    means to change that program changes the digest with it (PR 46 did, and
+    held the new programs' logits to the reference loop instead:
+    ``tests/test_expert_gmm.py``, ``tests/test_sparse_window.py``)."""
     cfg, S = sw.TINY_SPARSE, jax.ShapeDtypeStruct
     params = jax.eval_shape(lambda: sw.sparse_init(jax.random.PRNGKey(0), cfg))
     cache = jax.eval_shape(lambda: sw.init_cache(cfg, 3, 256, 49, 49, 16))

@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import sparse_window_ref as ref
+from test_expert_gmm import forced_kernel
 from test_hybrid_ssm import _Recorded, serve_together
 from kubedl_tpu.models import sparse_window as sw
 from kubedl_tpu.observability.tracing import TRACER
@@ -105,12 +106,14 @@ def _toward(params, layer, experts, n, seed=0):
     return h + 40.0 * push[None, :]
 
 
-@pytest.fixture(params=["grouped", "few tokens"])
+@pytest.fixture(params=["kernel", "grouped"])
 def form(request, monkeypatch):
-    """Both forms of the expert layer: ordered by expert and multiplied
-    grouped, as a prompt's chunk is, or, the few tokens of a decode step,
-    multiplied by every held expert under their gates."""
-    monkeypatch.setattr(sw, "DENSE_BELOW", 0 if request.param == "grouped" else 1 << 20)
+    """Both forms of the expert layer's two products over the order by
+    expert: the Pallas kernel a TPU runs (through the interpreter, at the
+    tiles the shapes give), or the two ``lax.ragged_dot`` of every other
+    backend."""
+    if request.param == "kernel":
+        forced_kernel(monkeypatch)
     return request.param
 
 
@@ -517,8 +520,34 @@ def test_phases_and_counters_say_what_the_experts_and_the_window_did(engine, mon
     assert touched == sum(a["experts_touched"] for a in harvests.values())
     assert touched == take * CFG.n_layers * CFG.top_k  # the decode steps' alone
     assert after["expert_steps"] - before.get("expert_steps", 0) == take * CFG.n_layers
+    # this backend multiplies by the plain twin: the kernel ran no tile, and says so
+    assert all(a["expert_tiles"] == 0 for a in harvests.values()) and after["expert_tiles"] == 0
     w0, w1 = before["kv_blocks"]["window"], after["kv_blocks"]["window"]
     assert w1["released"] - w0["released"] == (70 + 5 - 32) // BS
+
+
+def test_an_engine_on_the_kernel_serves_the_reference_and_counts_its_tiles(monkeypatch):
+    """The engine built and driven as a TPU process would trace it (the
+    expert kernel through the interpreter in every program): a served
+    request's tokens are the reference's best at their positions, and
+    ``expert_tiles`` rides the ``engine.harvest_host`` span and ``stats()``
+    beside ``experts_touched``. One row decodes, so a step's two assignments
+    a layer stand in one row tile and each touched expert is one tile."""
+    forced_kernel(monkeypatch)
+    log, real = [], TRACER.phase
+    monkeypatch.setattr(TRACER, "phase",
+                        lambda name, **attrs: _Recorded(real(name, **attrs), name, attrs, log))
+    eng = make_engine()
+    try:
+        tokens = eng.generate(PROMPTS[1], max_tokens=6, temperature=0.0)["token_ids"]
+        stats = eng.stats()
+    finally:
+        eng.close()
+    logits = reference_logits(eng.params, PROMPTS[1] + tokens[:-1])[len(PROMPTS[1]) - 1:]
+    assert (logits.max(axis=-1) - logits[np.arange(6), tokens]).max() <= TOL
+    harvests = [a for n, a in log if n == "engine.harvest_host" and a.get("seq")]
+    assert harvests and all(a["expert_tiles"] == a["experts_touched"] for a in harvests)
+    assert stats["expert_tiles"] == stats["experts_touched"] == 5 * CFG.n_layers * CFG.top_k
 
 
 @pytest.mark.parametrize("kw, reason", [
